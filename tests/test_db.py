@@ -879,7 +879,7 @@ def test_get_live_files_and_wal_files(tmp_db_path):
         assert db2.get(b"k0000") == b"V" * 10
 
 
-def test_error_handler_severity_taxonomy(tmp_path):
+def test_error_handler_severity_classes(tmp_path):
     """Reference ErrorHandler severity mapping (db/error_handler.h:28):
     SOFT keeps foreground writes alive, HARD blocks writes until resume(),
     FATAL/UNRECOVERABLE (corruption / MANIFEST) refuse resume()."""

@@ -725,65 +725,9 @@ def test_front_coded_upload_parity(seed):
     assert np.array_equal(z0, z1) and np.array_equal(c0, c1) and h0 == h1
 
 
-def test_segmented_merge_parity_vs_sort():
-    """The segmented rank-merge of presorted runs (the reference's k-way
-    heap merge role, table/merging_iterator.cc:476) must produce EXACTLY
-    the lax.sort path's outputs — order, flags, counts — across run
-    counts, including the single-run skip mode."""
-    import os
-
-    import numpy as np
-
-    from toplingdb_tpu.ops import compaction_kernels as ck
-
-    rng = np.random.default_rng(17)
-    uk_len = 8
-
-    def make_chunks(n_chunks, rows_per):
-        chunks = []
-        for _ in range(n_chunks):
-            n = int(rows_per + rng.integers(-rows_per // 3,
-                                            rows_per // 3 + 1))
-            uk = rng.integers(0, 99999, n)
-            seqs = rng.integers(1, 1 << 20, n).astype(np.uint64)
-            ks = np.array([b"%08d" % k for k in uk])
-            order = np.lexsort(
-                (np.iinfo(np.int64).max - seqs.view(np.int64), ks))
-            kb = np.zeros((n, uk_len + 8), np.uint8)
-            for i, oi in enumerate(order):
-                kb[i, :uk_len] = np.frombuffer(ks[oi], np.uint8)
-                packed = (int(seqs[oi]) << 8) | 1
-                kb[i, uk_len:] = np.frombuffer(
-                    packed.to_bytes(8, "little"), np.uint8)
-            chunks.append(ck.prepare_uniform_chunk(
-                np.ascontiguousarray(kb).reshape(-1), n, uk_len + 8))
-        return chunks
-
-    old = os.environ.get("TPULSM_DEVICE_MERGE")
-    try:
-        for n_chunks in (1, 2, 4, 6):
-            chunks = make_chunks(n_chunks, 900)
-            outs = {}
-            for mode in ("0", "1"):
-                os.environ["TPULSM_DEVICE_MERGE"] = mode
-                h = ck.upload_uniform_shard(chunks)
-                pend = ck.fused_uniform_shard_start(h, [9, 4000], True)
-                outs[mode] = ck.fused_uniform_shard_finish(pend)
-            a, b = outs["0"], outs["1"]
-            assert np.array_equal(a[0], b[0]), n_chunks
-            assert np.array_equal(a[1], b[1])
-            assert np.array_equal(a[2], b[2])
-            assert a[3] == b[3]
-    finally:
-        if old is None:
-            os.environ.pop("TPULSM_DEVICE_MERGE", None)
-        else:
-            os.environ["TPULSM_DEVICE_MERGE"] = old
-
-
 def test_host_merge_runs_matches_full_sort():
-    """tpulsm_merge_runs (multi-threaded k-way merge of presorted runs,
-    the host twin of the device segmented merge) must reproduce
+    """tpulsm_merge_runs (multi-threaded k-way merge of presorted runs)
+    must reproduce
     tpulsm_sort_entries' exact order/new_key/packed outputs."""
     import numpy as np
 

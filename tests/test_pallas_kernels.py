@@ -1,4 +1,5 @@
-"""Pallas kernel: shared-prefix lengths vs a Python reference."""
+"""Pallas kernels vs their references (interpret mode on the CPU; the same
+checks run compiled on the chip in chip_smoke.py)."""
 
 import numpy as np
 import pytest
@@ -100,94 +101,3 @@ def test_gc_rows_matches_lax_mask():
     assert np.array_equal(np.asarray(fis) | new_key, want_fis | new_key)
     assert np.array_equal(np.asarray(covered), want_cov)
     assert np.array_equal(np.asarray(cx), want_cx)
-
-
-def test_bitonic_merge_pair_parity():
-    """Kernel-backed pairwise merge == numpy lexsort over the key words
-    (4-column internal-key shape: key_hi, key_lo, inv_hi, inv_lo)."""
-    from toplingdb_tpu.ops.pallas_kernels import bitonic_merge_pair
-
-    rng = np.random.default_rng(11)
-    for na, nb in ((0, 7), (7, 0), (1000, 1000), (1237, 777),
-                   (5000, 12000)):
-        def mk(n):
-            cols = [rng.integers(0, 1 << 32, n, dtype=np.uint64)
-                    .astype(np.uint32) for _ in range(4)]
-            order = np.lexsort(tuple(reversed(cols)))
-            return [c[order] for c in cols]
-
-        a, b = mk(na), mk(nb)
-        pm = bitonic_merge_pair(a, b, interpret=True)
-        cat = [np.concatenate([x, y]) for x, y in zip(a, b)]
-        want = np.lexsort(tuple(reversed(cat)))
-        got_keys = np.stack([c[pm] for c in cat])
-        want_keys = np.stack([c[want] for c in cat])
-        assert np.array_equal(got_keys, want_keys), (na, nb)
-
-
-def test_bitonic_merge_runs_parity_with_host_merge():
-    """Segmented multi-run kernel merge realizes the SAME order as the
-    native host merge (the flagship compaction order) on 8B-key runs."""
-    from toplingdb_tpu.ops import compaction_kernels as ck
-    from toplingdb_tpu.ops.pallas_kernels import bitonic_merge_runs
-
-    rng = np.random.default_rng(12)
-    n_runs, per = 4, 3000
-    keys = []
-    starts = [0]
-    for r in range(n_runs):
-        draws = rng.integers(0, 4000, per)
-        seqs = np.arange(r * per + 1, r * per + per + 1, dtype=np.uint64)
-        order = np.lexsort(
-            (np.iinfo(np.int64).max - seqs.view(np.int64), draws))
-        for i in order:
-            packed = (int(seqs[i]) << 8) | 1
-            keys.append(b"%08d" % draws[i] + packed.to_bytes(8, "little"))
-        starts.append(len(keys))
-    buf = np.frombuffer(b"".join(keys), np.uint8)
-    offs = np.arange(len(keys), dtype=np.int64) * 16
-    lens = np.full(len(keys), 16, np.int64)
-    nat = ck.host_sort_order(buf, offs, lens,
-                             run_starts=np.array(starts, np.int64))
-    assert nat is not None
-    want_order = nat[0]
-    # Column encoding: BE key words ascending, then INVERTED packed
-    # (seq desc) — the device sort's order.
-    kb = buf.reshape(len(keys), 16)
-    key_hi = kb[:, :4].copy().view(">u4").reshape(-1).astype(np.uint32)
-    key_lo = kb[:, 4:8].copy().view(">u4").reshape(-1).astype(np.uint32)
-    packed = kb[:, 8:16].copy().view("<u8").reshape(-1)
-    inv = ~packed
-    inv_hi = (inv >> np.uint64(32)).astype(np.uint32)
-    inv_lo = (inv & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-    pm = bitonic_merge_runs([key_hi, key_lo, inv_hi, inv_lo], starts,
-                            interpret=True)
-    assert np.array_equal(pm, want_order)
-
-
-def test_bitonic_merge_stability_on_equal_keys():
-    """Equal keys come out in concat(A, B) order — the tiebreak column
-    makes the (inherently unstable) bitonic network stable."""
-    from toplingdb_tpu.ops.pallas_kernels import bitonic_merge_pair
-
-    a = [np.zeros(3, np.uint32)]
-    b = [np.zeros(4, np.uint32)]
-    pm = bitonic_merge_pair(a, b, interpret=True)
-    assert pm.tolist() == [0, 1, 2, 3, 4, 5, 6]
-
-
-def test_bitonic_merge_runs_oversized_pair_falls_back():
-    from toplingdb_tpu.ops import pallas_kernels as pk
-
-    rng = np.random.default_rng(3)
-    old = pk._BITONIC_MAX_ROWS
-    pk._BITONIC_MAX_ROWS = 1 << 10  # force the host fallback path
-    try:
-        n = 4096
-        col = np.sort(rng.integers(0, 1 << 20, n).astype(np.uint32)
-                      .reshape(2, n // 2), axis=1).reshape(n)
-        starts = [0, n // 2, n]
-        pm = pk.bitonic_merge_runs([col], starts, interpret=True)
-        assert np.array_equal(col[pm], np.sort(col))
-    finally:
-        pk._BITONIC_MAX_ROWS = old

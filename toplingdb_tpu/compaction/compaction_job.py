@@ -77,6 +77,12 @@ class CompactionStats:
     # the worker resolved inputs from the shared store and published its
     # outputs back — the job shipped only metadata).
     sst_bytes_shipped: int = 0
+    # XLA programs this device job compiled, how many more the persistent
+    # compile cache served, and the wall inside compile-or-load
+    # (ops/device_runtime.py::count_compiles). 0/0/0 on host jobs.
+    jit_compiles: int = 0
+    jit_cache_hits: int = 0
+    jit_compile_usec: int = 0
 
     def phase_dict(self) -> dict:
         """Non-zero timing phases, seconds — for bench/dcompact reporting.

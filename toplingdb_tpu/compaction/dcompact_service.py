@@ -2,15 +2,22 @@
 
 The transport shape of the reference's distributed compaction (curl control
 plane + NFS data plane; CompactionExecutorFactory::JobUrl,
-compaction_executor.h:146,177 in /root/reference): a worker host runs
-`DcompactWorkerService` (one process per TPU chip in a pod); the DB side's
-`HttpCompactionExecutor` POSTs {"job_dir": ...} to /dcompact and waits for
-CompactionResults. Bulk data (input SSTs, output SSTs, params/results JSON)
-moves through the shared filesystem, exactly like the reference's
-NFS/S3 exchange.
+compaction_executor.h:146,177 in /root/reference): a worker host runs ONE
+`DcompactWorkerService` process, which owns every chip of that host —
+nothing binds a process to one chip of several, and a chip belongs to one
+process at a time, so a four-chip host runs one service with `--chips 4`,
+never four services. The DB side's `HttpCompactionExecutor` POSTs
+{"job_dir": ...} to /dcompact and waits for CompactionResults; the DB
+process itself stays off JAX. Bulk data (input SSTs, output SSTs,
+params/results JSON) moves through the shared filesystem, exactly like
+the reference's NFS/S3 exchange.
 
 Worker:  python -m toplingdb_tpu.compaction.dcompact_service --port 8080 \
              [--device tpu] [--workers 1] [--chips N]
+
+`--device tpu` is the TPU or an error: the service checks what JAX reports
+before it prints "listening" and exits non-zero on a mismatch; /health and
+/stats carry JAX's own platform, device kind and device count.
 
 Pod-level packing (`--chips N`): the worker host owns N chips; each chip
 is a failure domain behind its own circuit breaker (PR 1's
@@ -38,7 +45,7 @@ from toplingdb_tpu.compaction.executor import (
     CompactionExecutorFactory,
     SubprocessCompactionExecutor,
 )
-from toplingdb_tpu.utils.status import IOError_
+from toplingdb_tpu.utils.status import InvalidArgument, IOError_
 
 
 class ChipPool:
@@ -147,22 +154,37 @@ class DcompactWorkerService:
     def __init__(self, device: str = "cpu", max_workers: int = 1,
                  chips: int = 0):
         self.device = device
+        # What JAX reports (platform/kind/count) once the requested device
+        # has been checked against it; None for the per-entry "cpu"
+        # service, which never imports JAX.
+        self.jax_devices = None
+        if device != "cpu":
+            from toplingdb_tpu.ops import device_runtime
+
+            device_runtime.require_device(device)
+            self.jax_devices = device_runtime.describe_devices()
+            if chips > self.jax_devices["count"]:
+                raise InvalidArgument(
+                    f"--chips {chips} but JAX sees "
+                    f"{self.jax_devices['count']} device(s)")
         self._sem = threading.Semaphore(max_workers)
         self._server: ThreadingHTTPServer | None = None
         self._counter_mu = ccy.Lock("dcompact_service.DcompactWorkerService._counter_mu")
         self.jobs_done = 0
         self.jobs_failed = 0
         # Pod-level packing: chips > 0 builds the per-chip admission pool;
-        # 0 keeps the legacy one-process-per-chip shape.
+        # 0 is the one-chip host (every job on the default device).
         self.pool = ChipPool(chips) if chips > 0 else None
 
     def _run_with_chips(self, run) -> int:
         """Admit chips for one job, size the mesh plane to the grant via
-        env, run, and feed the outcome back into the chip breakers. The
-        env export is process-wide, so with --workers > 1 overlapping jobs
-        may see each other's grant size — that only skews chip COUNTS
-        (outputs are byte-identical at any count); the admission ledger
-        itself is race-free under the pool lock."""
+        env, run, and feed the outcome back into the chip breakers. Only
+        the grant's LENGTH travels: the mesh plane takes the first n of
+        jax.devices(), whichever chips were granted, and the export is
+        process-wide — so the supported shape is --workers 1 with every
+        chip healthy (ROADMAP S5 has the placement finding). Outputs are
+        byte-identical at any count; the admission ledger itself is
+        race-free under the pool lock."""
         if self.pool is None:
             return run()
         grant = self.pool.admit()
@@ -212,9 +234,15 @@ class DcompactWorkerService:
             def do_GET(self):
                 if self.path == "/stats":
                     body = {
-                        "device": svc.device, "jobs_done": svc.jobs_done,
+                        "device": svc.device, "jax": svc.jax_devices,
+                        "jobs_done": svc.jobs_done,
                         "jobs_failed": svc.jobs_failed,
                     }
+                    if svc.jax_devices is not None:
+                        from toplingdb_tpu.ops import device_runtime
+
+                        body["device_memory"] = \
+                            device_runtime.device_memory()
                     if svc.pool is not None:
                         body["chips"] = svc.pool.snapshot()
                     self._reply(200, body)
@@ -222,7 +250,8 @@ class DcompactWorkerService:
                     # Liveness probe for the DB-side health registry /
                     # half-open breaker checks; tools/fleet_health.py
                     # maps this bare shape onto its health-doc format.
-                    self._reply(200, {"ok": True, "device": svc.device})
+                    self._reply(200, {"ok": True, "device": svc.device,
+                                      "jax": svc.jax_devices})
                 elif self.path == "/metrics":
                     # Minimal Prometheus exposition so the worker shows
                     # up on the same scrape config as the DB repos.

@@ -10,9 +10,11 @@ CompactionExecutor plugin API (db/compaction/compaction_executor.h:160-178 in
   plane somewhere else and return (outputs, stats).
 
 Three executors:
-  DeviceCompactionExecutor      in-process JAX data plane (device=tpu|cpu) —
-                                the TPU analogue of a same-host dcompact
-                                worker with HBM DMA instead of NFS.
+  DeviceCompactionExecutor      in-process JAX data plane (device="tpu", or
+                                "cpu-jax" for XLA:CPU in tests) — the TPU
+                                analogue of a same-host dcompact worker
+                                with HBM DMA instead of NFS. The DB process
+                                then holds the chip.
   SubprocessCompactionExecutor  full process boundary: CompactionParams
                                 serialized to a job dir, a worker process
                                 (toplingdb_tpu.compaction.worker) executes
@@ -40,7 +42,7 @@ from toplingdb_tpu.utils.table_properties_collector import (
     serialize_collector_factory,
 )
 from toplingdb_tpu.db.version_edit import FileMetaData
-from toplingdb_tpu.utils.status import Corruption, IOError_
+from toplingdb_tpu.utils.status import Corruption, IOError_, NotSupported
 
 
 def _telemetry():
@@ -283,6 +285,14 @@ class SubprocessCompactionExecutor(CompactionExecutor):
         self._plan = None             # active injected-fault plan
 
     def _spawn_local(self, job_dir: str, device: str) -> None:
+        if device == "tpu" and "jax" in sys.modules:
+            # A chip belongs to one process: a parent that has touched
+            # JAX holds it, and the worker would fail or hang at backend
+            # start-up. The DB process of a device deployment stays off
+            # JAX (or runs the in-process DeviceCompactionExecutor).
+            raise NotSupported(
+                "cannot spawn a device='tpu' worker from a process that "
+                "has imported jax: it would hold the chip the worker needs")
         env = dict(os.environ)
         if device == "cpu":
             env.setdefault("JAX_PLATFORMS", "cpu")
@@ -456,9 +466,9 @@ class SubprocessCompactionExecutor(CompactionExecutor):
                 os.replace(os.path.join(params.output_dir, d["path"]), dst)
                 shipped += int(d["file_size"])
             outputs.append(decode_file_meta(d, num))
+        # stats.device stays what the worker ran on, not what was asked.
         stats = CompactionStats(**results.stats)
         stats.sst_bytes_shipped = shipped
-        stats.device = self.device
         stats.remote = True
         stats.work_time_usec = results.work_time_usec
         # Transport time, the analogue of the reference's curl_time_usec.

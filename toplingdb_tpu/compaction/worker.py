@@ -157,7 +157,7 @@ def _run_job_body(job_dir: str, params, t_enter: float,
         counter[0] += 1
         return counter[0]
 
-    device_job = params.device in ("tpu", "cpu-jax", "device")
+    device_job = params.device in ("tpu", "cpu-jax")
     if device_job and ucmp.name() == dbformat.BYTEWISE.name():
         # Full data plane — the same columnar/pipelined path the in-process
         # device executor takes (ops/device_compaction.py), so the worker

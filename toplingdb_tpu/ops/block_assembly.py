@@ -27,8 +27,7 @@ blob refs. A survivor bitmap (1 bit/row) rides down so the host builds
 the bloom byte-identically without the full order download.
 Transfers: values ride UP and finished blocks ride DOWN, so this path
 pays ~2x the bytes of the order-download path — it wins where the host
-CPU, not the link, is the bottleneck (TPULSM_DEVICE_BLOCKS=1 opts in;
-auto-off on tunneled rigs).
+CPU, not the link, is the bottleneck (TPULSM_DEVICE_BLOCKS=1 opts in).
 """
 
 from __future__ import annotations
@@ -42,6 +41,7 @@ import numpy as np
 
 from toplingdb_tpu.db.dbformat import ValueType
 from toplingdb_tpu.ops import compaction_kernels as ck
+from toplingdb_tpu.ops import device_runtime  # noqa: F401  (compile cache)
 from toplingdb_tpu.utils.status import NotSupported
 from toplingdb_tpu.utils import errors as _errors
 

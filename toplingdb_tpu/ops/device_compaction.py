@@ -9,7 +9,8 @@ Replaces the CPU heap-merge + CompactionIterator with:
 
 This is the kernel surface called out in SURVEY.md §3.4/§7 step 5; the
 serializable executor boundary (compaction/executor.py) selects it with
-device="tpu"|"cpu" (the jax backend).
+device="tpu" (or "cpu-jax", XLA:CPU, in tests) — a label that
+run_device_compaction checks against what JAX reports.
 """
 
 from __future__ import annotations
@@ -29,7 +30,9 @@ from toplingdb_tpu.compaction.compaction_job import (
 from toplingdb_tpu.db import dbformat
 from toplingdb_tpu.db.range_del import RangeDelAggregator, RangeTombstone, fragment_tombstones
 from toplingdb_tpu.ops import compaction_kernels as ck
+from toplingdb_tpu.ops import device_runtime
 from toplingdb_tpu.ops.columnar import ColumnarEntries
+from toplingdb_tpu.utils.status import NotSupported
 
 
 def collect_raw_entries(compaction, table_cache, icmp, stats=None):
@@ -91,8 +94,8 @@ MAX_DEVICE_KEY_BYTES = 128
 
 
 def _host_sort() -> bool:
-    """TPULSM_HOST_SORT=1: no accelerator attached — the numpy twins beat
-    running the jax programs on the cpu backend (set by bench's fallback)."""
+    """TPULSM_HOST_SORT=1: a chipless deployment asks for the native host
+    twins in place of the jax programs. Only a user (or a test) sets it."""
     return os.environ.get("TPULSM_HOST_SORT") == "1"
 
 
@@ -108,8 +111,6 @@ def device_gc_entries(entries, icmp, snapshots, bottommost,
     if max_key_bytes is None:
         longest = max(len(k) for k, _ in entries) - 8
         if longest > MAX_DEVICE_KEY_BYTES:
-            from toplingdb_tpu.utils.status import NotSupported
-
             raise NotSupported(
                 f"user keys up to {longest}B exceed the device key budget "
                 f"({MAX_DEVICE_KEY_BYTES}B); use the CPU path"
@@ -117,8 +118,6 @@ def device_gc_entries(entries, icmp, snapshots, bottommost,
     if icmp.user_comparator.name() != dbformat.BYTEWISE.name():
         # The device sort realizes bytewise-ascending user-key order; other
         # comparators must use the host path (scheduler falls back).
-        from toplingdb_tpu.utils.status import NotSupported
-
         raise NotSupported(
             f"device compaction requires the bytewise comparator, "
             f"got {icmp.user_comparator.name()!r}"
@@ -390,8 +389,6 @@ def _prepare_uniform_shards(parts):
     """Host half of the sharded uniform device path: validate density +
     uniform key length, pick range splitters, slice every part into
     per-shard chunks. Returns shards list or None when ineligible."""
-    from toplingdb_tpu.utils.status import NotSupported
-
     uniform_len = 0
     total_rows = 0
     for part in parts:
@@ -612,8 +609,6 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
     )
     from toplingdb_tpu.db.version_edit import FileMetaData
     from toplingdb_tpu.ops.columnar_io import write_tables_columnar
-
-    from toplingdb_tpu.utils.status import NotSupported
 
     t0 = time.time()
     stats = CompactionStats(device=device_name)
@@ -896,11 +891,38 @@ def run_device_compaction(env, dbname, icmp, compaction, table_cache,
     byte-identical outputs (including output cutting). Jobs without a
     compaction filter take the fully-columnar native fast path; the rest
     stream through the per-entry generator. Active blob GC rewrites values,
-    so it routes through the per-entry path."""
+    so it routes through the per-entry path.
+
+    `device_name` is checked, not trusted: the job raises NotSupported
+    unless JAX runs on the platform it names (device_runtime), and it
+    raises when the native library is missing — the device path has no
+    quiet detour through the CPU or through per-entry Python. A kernel
+    that fails to compile raises out of here as it is."""
     from toplingdb_tpu import native
 
-    if (native.lib() is not None
-            and compaction_filter is None
+    device_runtime.require_device(device_name)
+    if native.lib() is None:
+        raise NotSupported(
+            "the device compaction path needs the native library "
+            "(toplingdb_tpu/native: g++ missing or the build failed)")
+    with device_runtime.count_compiles() as jit:
+        outputs, stats = _run_device_compaction(
+            env, dbname, icmp, compaction, table_cache, table_options,
+            snapshots, merge_operator, compaction_filter, new_file_number,
+            creation_time, device_name, blob_resolver, blob_gc,
+            column_family)
+    stats.jit_compiles = jit.compiled
+    stats.jit_cache_hits = jit.cache_hits
+    stats.jit_compile_usec = int(jit.seconds * 1e6)
+    return outputs, stats
+
+
+def _run_device_compaction(env, dbname, icmp, compaction, table_cache,
+                           table_options, snapshots, merge_operator,
+                           compaction_filter, new_file_number, creation_time,
+                           device_name, blob_resolver, blob_gc,
+                           column_family):
+    if (compaction_filter is None
             and (blob_gc is None or not blob_gc.active)
             and not getattr(table_options, "properties_collector_factories", None)
             and getattr(table_options, "format", "block") in ("block",
@@ -914,35 +936,7 @@ def run_device_compaction(env, dbname, icmp, compaction, table_cache,
                 device_name, column_family, blob_resolver=blob_resolver,
             )
         except _FallbackToEntries:
-            pass
-        except Exception as e:  # noqa: BLE001
-            # A compiled-kernel failure on the real chip (e.g. a Mosaic
-            # lowering gap in an optional kernel) must degrade to the
-            # CONSERVATIVE device kernels — not lose the device data
-            # plane to the scheduler's run-local fallback. One retry
-            # with the optional kernels disabled and trace caches
-            # cleared (the kernel-choice env vars read at trace time).
-            if os.environ.get("TPULSM_PALLAS_GC") == "0" \
-                    and os.environ.get("TPULSM_DEVICE_MERGE") == "0":
-                raise
-            import sys as _sys
-
-            print(f"device columnar path failed ({e!r:.200}); retrying "
-                  "with conservative kernels", file=_sys.stderr, flush=True)
-            os.environ["TPULSM_PALLAS_GC"] = "0"
-            os.environ["TPULSM_DEVICE_MERGE"] = "0"
-            import jax as _jax
-
-            _jax.clear_caches()
-            try:
-                return _run_device_compaction_columnar(
-                    env, dbname, icmp, compaction, table_cache,
-                    table_options, snapshots, merge_operator,
-                    new_file_number, creation_time, device_name,
-                    column_family, blob_resolver=blob_resolver,
-                )
-            except _FallbackToEntries:
-                pass
+            pass  # eligibility, not error handling: per-entry semantics
     t0 = time.time()
     stats = CompactionStats(device=device_name)
     stats.input_bytes = compaction.total_input_bytes()

@@ -23,10 +23,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from toplingdb_tpu.db.dbformat import ValueType
+from toplingdb_tpu.ops import device_runtime  # noqa: F401  (compile cache)
 
 _SIGN = 0x80000000
 INT32MAX = np.iinfo(np.int32).max
@@ -250,7 +250,7 @@ def make_distributed_gc_step(mesh: Mesh, num_key_words: int,
         total_overflow = jax.lax.psum(overflow, "range")
         return keep, zero_seq, sidx, is_cx, total_overflow
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(
             P("jobs", "range", None), P("jobs", "range"), P("jobs", "range"),
@@ -261,7 +261,7 @@ def make_distributed_gc_step(mesh: Mesh, num_key_words: int,
             P("jobs", "range"), P("jobs", "range"), P("jobs", "range"),
             P("jobs", "range"), P("jobs"),
         ),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 

@@ -49,7 +49,6 @@ def main(argv=None) -> int:
     try:
         import jax
 
-        mesh_plan.pin_cpu_backend()
         n_dev = len(jax.devices())
     except Exception as e:  # no usable backend: a skip, not a failure
         print(json.dumps({"skip": f"jax backend unavailable: {e!r}"[:200]}))

@@ -220,7 +220,9 @@ def main(argv=None) -> int:
 
     # Pipelined vs serial compaction data plane: the SAME job run with
     # TPULSM_PIPELINE=0 and =1, printing per-phase sums vs wall so the
-    # scan/compute/encode overlap is directly visible.
+    # scan/compute/encode overlap is directly visible. The compute stage
+    # is the XLA:CPU program; export TPULSM_HOST_SORT=1 to time the native
+    # host twin instead (this tool no longer sets it on itself).
     if args.filter in "compaction_pipeline":
         from toplingdb_tpu.compaction.picker import Compaction
         from toplingdb_tpu.db.table_cache import TableCache
@@ -273,9 +275,7 @@ def main(argv=None) -> int:
                 ))
         tc = TableCache(cenv, "/cp", icmp, TableOptions())
         saved_env = {k: os.environ.get(k)
-                     for k in ("TPULSM_PIPELINE", "TPULSM_HOST_SORT",
-                               "TPULSM_PIPELINE_SHARDS")}
-        os.environ["TPULSM_HOST_SORT"] = "1"
+                     for k in ("TPULSM_PIPELINE", "TPULSM_PIPELINE_SHARDS")}
         os.environ["TPULSM_PIPELINE_SHARDS"] = "4"
         try:
             fn_c[0] = 1000
@@ -329,7 +329,6 @@ def main(argv=None) -> int:
 
         from toplingdb_tpu.parallel import mesh_plan
 
-        mesh_plan.pin_cpu_backend()
         n_dev = len(jax.devices())
         if n_dev < 2:
             print(json.dumps({"bench": "compaction_mesh",

@@ -2,7 +2,7 @@
 
 The reference threads a `Status` value through every call
 (util/status.cc, include/rocksdb/status.h in /root/reference). Python has
-exceptions; we use them, but keep a Status taxonomy so error classification
+exceptions; we use them, but keep a Status hierarchy so error classification
 (ErrorHandler severity mapping, reference db/error_handler.h:28) has the same
 vocabulary.
 """
