@@ -1,0 +1,137 @@
+"""From a profiler trace to numbers: device busy and idle share, device time
+under the job annotations, the top device operations, and the idle gaps
+named by what the host was doing.
+
+Two stages, so the arithmetic can be tested on a recorded trace without
+JAX: `xplane_events` (needs jax.profiler.ProfileData; run by the process
+that holds the chip) turns an .xplane.pb into plain lists, and `reduce`
+turns those lists into the summary the metric readers read.
+
+  device operations  events of line "XLA Ops" on planes "/device:TPU:<i>";
+                     in a CPU rehearsal the XLA:CPU client's threads stand
+                     in as device 0 (their numbers are never reported as a
+                     device's).
+  host annotations   `jax.profiler.TraceAnnotation` events of the traced
+                     launcher: "bench:window_open", "bench:window_close"
+                     and one "bench:job" around each `worker.run_job`.
+"""
+
+from __future__ import annotations
+
+WINDOW_OPEN = "bench:window_open"
+WINDOW_CLOSE = "bench:window_close"
+JOB = "bench:job"
+TOP = 10
+
+
+def xplane_events(path: str) -> dict:
+    """{"device_ops": {device: [[name, start_ns, dur_ns], ...]},
+        "host": [[name, start_ns, dur_ns], ...]} from one .xplane.pb."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(path)
+    device_ops: dict[str, list] = {}
+    host = []
+    cpu_standin = []
+    for plane in data.planes:
+        if plane.name.startswith("/device:TPU:"):
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    device_ops.setdefault(plane.name, []).extend(
+                        [e.name, e.start_ns, e.duration_ns]
+                        for e in line.events)
+        elif plane.name == "/host:CPU":
+            for line in plane.lines:
+                standin = line.name.startswith(("tf_XLAPjRtCpuClient",
+                                                "tf_XLAEigen"))
+                for e in line.events:
+                    if e.name.startswith("bench:"):
+                        host.append([e.name, e.start_ns, e.duration_ns])
+                    elif standin and e.duration_ns > 0 \
+                            and not e.name.startswith("end: "):
+                        cpu_standin.append(
+                            [e.name, e.start_ns, e.duration_ns])
+    if not device_ops and cpu_standin:
+        device_ops["/host:CPU (XLA:CPU threads, a rehearsal)"] = cpu_standin
+    return {"device_ops": device_ops, "host": host}
+
+
+def _merge(intervals):
+    """Sorted union of [start, end) intervals."""
+    out = []
+    for a, b in sorted(intervals):
+        if out and a <= out[-1][1]:
+            if b > out[-1][1]:
+                out[-1][1] = b
+        elif b > a:
+            out.append([a, b])
+    return out
+
+
+def _clip(merged, lo, hi):
+    return [[max(a, lo), min(b, hi)] for a, b in merged
+            if b > lo and a < hi]
+
+
+def _total(intervals) -> float:
+    return sum(b - a for a, b in intervals)
+
+
+def reduce(events: dict) -> dict:
+    """The summary of one traced window; times in seconds. Shares and
+    means are over the devices that ran at least one operation plus, where
+    the trace names more device planes, those too (an idle chip counts)."""
+    host = events["host"]
+    opens = [s for n, s, d in host if n == WINDOW_OPEN]
+    closes = [s + d for n, s, d in host if n == WINDOW_CLOSE]
+    if not opens or not closes:
+        raise ValueError("the trace lacks the window's annotations")
+    w0, w1 = min(opens), max(closes)
+    jobs = _merge([s, s + d] for n, s, d in host if n == JOB)
+    jobs = _clip(jobs, w0, w1)
+    out = {"window_s": (w1 - w0) / 1e9, "jobs_seen": len(jobs),
+           "job_s": _total(jobs) / 1e9, "devices": len(events["device_ops"])}
+    by_name: dict[str, float] = {}
+    busy_by_dev, in_jobs_by_dev = {}, {}
+    gaps = {"no_job": 0.0, "job: before first op": 0.0,
+            "job: between ops": 0.0, "job: after last op": 0.0,
+            "job: no op": 0.0}
+    for dev, ops in sorted(events["device_ops"].items()):
+        busy = _clip(_merge([s, s + d] for _n, s, d in ops), w0, w1)
+        for n, s, d in ops:
+            a, b = max(s, w0), min(s + d, w1)
+            if b > a:
+                by_name[n] = by_name.get(n, 0.0) + (b - a) / 1e9
+        busy_by_dev[dev] = _total(busy) / 1e9
+        in_jobs = 0.0
+        for a, b in jobs:
+            inside = _clip(busy, a, b)
+            t = _total(inside)
+            in_jobs += t
+            if not inside:
+                gaps["job: no op"] += (b - a) / 1e9
+                continue
+            first, last = inside[0][0], inside[-1][1]
+            gaps["job: before first op"] += (first - a) / 1e9
+            gaps["job: after last op"] += (b - last) / 1e9
+            gaps["job: between ops"] += (last - first - t) / 1e9
+        in_jobs_by_dev[dev] = in_jobs / 1e9
+        gaps["no_job"] += ((w1 - w0) - _total(jobs)
+                           - (_total(busy) - in_jobs)) / 1e9
+    n_dev = max(1, len(busy_by_dev))
+    out["busy_by_device_s"] = busy_by_dev
+    out["busy_s"] = sum(busy_by_dev.values()) / n_dev
+    out["busy_in_jobs_chip_s"] = sum(in_jobs_by_dev.values())
+    out["device_ops"] = [
+        [_short(n), s] for n, s in
+        sorted(by_name.items(), key=lambda kv: -kv[1])[:TOP]]
+    out["idle_gaps"] = [
+        [n, s / n_dev] for n, s in
+        sorted(gaps.items(), key=lambda kv: -kv[1]) if s > 0][:TOP]
+    return out
+
+
+def _short(name: str) -> str:
+    """An operation's name as the trace has it is a line of HLO: keep its
+    head, which names the fusion and its result shape."""
+    return " ".join(name.split())[:96]
