@@ -9,6 +9,7 @@ and the check_telemetry name lint."""
 import json
 import os
 import re
+import threading
 import time
 import urllib.request
 
@@ -107,6 +108,169 @@ def test_cross_thread_span_under_and_remote_stitch():
         "dcompact.worker"}
     assert {s.proc for s in t.spans} == {"db", "dcompact-worker"}
     assert tr.status()["remote_spans_dropped"] == 1
+
+
+# ---------------------------------------------------------------------------
+# The mirror (real spans only), nesting below span_under, open spans
+# ---------------------------------------------------------------------------
+
+
+class _Mirror:
+    """Stands in for jax.profiler.TraceAnnotation: logs each enter and
+    exit with the thread it happened on."""
+
+    log: list = []
+
+    def __init__(self, name, **tags):
+        self.name, self.tags = name, tags
+
+    def __enter__(self):
+        _Mirror.log.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *a):
+        _Mirror.log.append(("exit", self.name, threading.get_ident()))
+
+    def set_metadata(self, **kw):
+        _Mirror.log.append(("meta", self.name, kw))
+
+
+@pytest.fixture
+def mirror():
+    """The logging mirror for one test; the process's own (the profiler's
+    annotation, once a test has loaded the device path) comes back after."""
+    before = tm._mirror
+    _Mirror.log = []
+    tm.set_mirror(_Mirror)
+    yield _Mirror
+    tm.set_mirror(before)
+
+
+def test_mirror_sees_every_real_span_once_nested_on_its_thread(mirror):
+    tr = tm.Tracer(sample_every=1)
+    root = tr.start("dcompact.worker", job_id=7)
+    handle = tm.current_handle()
+    with tm.span("pipeline.plan"):
+        pass
+
+    def reader():
+        with tm.span_under(handle, "pipeline.scan", shard=0):
+            with tm.span("runtime.gc_pause"):  # nests below span_under
+                pass
+        assert tm.current_span() is None
+
+    t = threading.Thread(target=reader)
+    t.start()
+    t.join()
+    root.tag(input_records=9)
+    root.finish()
+
+    me = threading.get_ident()
+    steps = [(kind, name) for kind, name, _ in mirror.log]
+    assert steps == [
+        ("enter", "dcompact.worker"),
+        ("enter", "pipeline.plan"), ("exit", "pipeline.plan"),
+        ("enter", "pipeline.scan"), ("enter", "runtime.gc_pause"),
+        ("exit", "runtime.gc_pause"), ("exit", "pipeline.scan"),
+        ("meta", "dcompact.worker"), ("exit", "dcompact.worker")]
+    threads = {name: {th for kind, n, th in mirror.log
+                      if n == name and kind != "meta"}
+               for name in ("dcompact.worker", "pipeline.plan",
+                            "pipeline.scan", "runtime.gc_pause")}
+    assert threads["dcompact.worker"] == threads["pipeline.plan"] == {me}
+    assert threads["pipeline.scan"] == threads["runtime.gc_pause"]
+    assert threads["pipeline.scan"] != {me}
+    assert ("meta", "dcompact.worker", {"input_records": 9}) in mirror.log
+    # The mirror nests as the tree does.
+    (trace,) = tr.finished()
+    by_name = {s.name: s for s in trace.spans}
+    assert by_name["runtime.gc_pause"].parent_id == \
+        by_name["pipeline.scan"].span_id
+    assert by_name["pipeline.scan"].parent_id == root.span_id
+    assert by_name["pipeline.scan"].tid != root.tid
+
+
+@pytest.mark.parametrize("record", ["span_event", "span_event_under",
+                                    "note_slow"])
+def test_back_dated_spans_never_reach_the_mirror(mirror, record):
+    tr = tm.Tracer(sample_every=1, slow_usec=1)
+    if record == "note_slow":
+        tr.note_slow("db.get", 5000)
+        assert mirror.log == []
+        return
+    root = tr.start("db.write")
+    if record == "span_event":
+        tm.span_event("native.wal_frame", 1500)
+    else:
+        tm.span_event_under(tm.current_handle(), "native.wal_frame", 1500)
+    root.finish()
+    assert [name for _k, name, _t in mirror.log] == ["db.write", "db.write"]
+    (trace,) = tr.finished()
+    assert [s.name for s in trace.spans] == ["db.write", "native.wal_frame"]
+    assert trace.spans[1].dur_us == 1500
+
+
+def test_without_a_mirror_spans_cost_no_annotation(mirror):
+    tm.set_mirror(None)
+    tr = tm.Tracer(sample_every=1)
+    with tr.start("db.write") as root:
+        with tm.span("write.wal_frame") as sp:
+            assert sp._mirror is None and root._mirror is None
+    assert mirror.log == []
+
+
+def test_span_left_open_ends_with_its_parent_and_finish_is_idempotent():
+    tr = tm.Tracer(sample_every=1)
+    with pytest.raises(RuntimeError):
+        with tr.start("dcompact.worker") as root:
+            left_open = tm.span("compaction.prepare")
+            raise RuntimeError("boom")
+    assert tm.current_span() is None
+    assert not left_open._open and left_open.dur_us <= root.dur_us
+    assert "boom" in root.tags["error"]
+    dur = root.dur_us
+    root.finish()  # a second finish changes nothing, retires nothing
+    assert root.dur_us == dur and len(tr.finished()) == 1
+
+
+def test_open_span_exports_its_time_so_far():
+    tr = tm.Tracer(sample_every=1)
+    root = tr.start("dcompact.worker")
+    time.sleep(0.003)
+    d = tr.export_trace(root.trace_id)[0]
+    assert d["name"] == "dcompact.worker" and d["dur_us"] >= 3000
+    assert d["tid"] == threading.get_ident()
+    root.finish()
+    assert tr.export_trace(root.trace_id)[0]["dur_us"] == root.dur_us
+    assert tm.Span.from_dict(d).tid == d["tid"]
+
+
+@pytest.mark.parametrize("module", ["toplingdb_tpu.utils.telemetry",
+                                    "toplingdb_tpu.db.db",
+                                    "toplingdb_tpu.compaction.worker"])
+def test_importing_the_db_side_loads_no_jax(module):
+    """The DB process and the harness never import JAX; the mirror is
+    installed by ops/device_runtime.py, which only a process that holds a
+    chip loads."""
+    import subprocess
+    import sys
+
+    code = (f"import sys, {module}; "
+            "from toplingdb_tpu.utils import telemetry; "
+            "assert telemetry._mirror is None; "
+            "sys.exit(int('jax' in sys.modules))")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    p = subprocess.run([sys.executable, "-c", code], cwd=root,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-500:]
+
+
+def test_device_runtime_installs_the_profilers_annotation():
+    import jax
+
+    from toplingdb_tpu.ops import device_runtime  # noqa: F401
+
+    assert tm._mirror is jax.profiler.TraceAnnotation
 
 
 # ---------------------------------------------------------------------------
@@ -255,10 +419,14 @@ def test_dcompact_http_job_stitches_worker_spans(tmp_path, monkeypatch):
         assert "dcompact.worker" in names
         # every worker span belongs to the SAME trace id (one waterfall)
         assert {s.trace_id for s in worker_spans} == {t.trace_id}
-        # the worker root parents under the DB-side compaction root
+        # the worker's job parents under the service's request span, and
+        # that under the DB-side compaction root
         wroot = next(s for s in worker_spans
                      if s.name == "dcompact.worker")
-        assert wroot.parent_id == t.root.span_id
+        request = next(s for s in worker_spans
+                       if s.name == "dcompact.request")
+        assert wroot.parent_id == request.span_id
+        assert request.parent_id == t.root.span_id
         assert t.root.tags.get("mode") == "remote"
         # the PIPELINED interior stages recorded inside the worker:
         # per-shard scan/merge spans plus writer chunks

@@ -83,6 +83,22 @@ class CompactionStats:
     jit_compiles: int = 0
     jit_cache_hits: int = 0
     jit_compile_usec: int = 0
+    # Counted where the work happens, for the per-layer metrics (PERF.md
+    # §3). pipeline_exit: empty when the job ran pipelined (or never tried
+    # to), else "<Exception class>: <message>" of what sent it to the
+    # serial program. h2d/d2h bytes: `nbytes` of the buffers uploaded to
+    # and downloaded from the device. gc_*: collections of generation >= 1
+    # of the Python heap while the job ran, and the wall inside them.
+    # stall_wait_*: the compute thread starved by the readers
+    # (`pipeline.wait_scan`) and held back by the writer
+    # (`pipeline.wait_writer`).
+    pipeline_exit: str = ""
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
+    gc_pause_usec: int = 0
+    gc_collections: int = 0
+    stall_wait_scan_usec: int = 0
+    stall_wait_writer_usec: int = 0
 
     def phase_dict(self) -> dict:
         """Non-zero timing phases, seconds — for bench/dcompact reporting.

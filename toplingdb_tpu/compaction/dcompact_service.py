@@ -149,7 +149,9 @@ class ChipPool:
 class DcompactWorkerService:
     """Hosts job execution: POST /dcompact {"job_dir": ...} → runs the job
     in-process (owning the chip), returns the results JSON. GET /stats for
-    introspection."""
+    introspection; GET /traces and /traces/<trace_id> serve the last jobs'
+    spans (every request is recorded, `self.tracer`'s ring bounds them)
+    the way a DB's SidePluginRepo serves its traces."""
 
     def __init__(self, device: str = "cpu", max_workers: int = 1,
                  chips: int = 0):
@@ -172,6 +174,10 @@ class DcompactWorkerService:
         self._counter_mu = ccy.Lock("dcompact_service.DcompactWorkerService._counter_mu")
         self.jobs_done = 0
         self.jobs_failed = 0
+        self.jobs_left_pipeline = 0  # ran, but not on the pipelined plane
+        from toplingdb_tpu.utils import telemetry
+
+        self.tracer = telemetry.Tracer(proc="dcompact-worker", ring=256)
         # Pod-level packing: chips > 0 builds the per-chip admission pool;
         # 0 is the one-chip host (every job on the default device).
         self.pool = ChipPool(chips) if chips > 0 else None
@@ -209,12 +215,14 @@ class DcompactWorkerService:
                     os.environ[k] = v
             self.pool.release(grant, ok=ok)
 
-    def _count(self, ok: bool) -> None:
+    def _count(self, ok: bool, left_pipeline: bool = False) -> None:
         with self._counter_mu:
             if ok:
                 self.jobs_done += 1
             else:
                 self.jobs_failed += 1
+            if left_pipeline:
+                self.jobs_left_pipeline += 1
 
     def start(self, port: int = 0, host: str = "127.0.0.1") -> int:
         svc = self
@@ -237,6 +245,7 @@ class DcompactWorkerService:
                         "device": svc.device, "jax": svc.jax_devices,
                         "jobs_done": svc.jobs_done,
                         "jobs_failed": svc.jobs_failed,
+                        "jobs_left_pipeline": svc.jobs_left_pipeline,
                     }
                     if svc.jax_devices is not None:
                         from toplingdb_tpu.ops import device_runtime
@@ -246,6 +255,18 @@ class DcompactWorkerService:
                     if svc.pool is not None:
                         body["chips"] = svc.pool.snapshot()
                     self._reply(200, body)
+                elif self.path == "/traces":
+                    self._reply(200, {
+                        "tracer": svc.tracer.status(),
+                        "traces": [t.summary()
+                                   for t in svc.tracer.finished()]})
+                elif self.path.startswith("/traces/"):
+                    doc = svc.tracer.chrome_trace(
+                        self.path[len("/traces/"):])
+                    if doc is None:
+                        self._reply(404, {"error": "no such trace"})
+                    else:
+                        self._reply(200, doc)
                 elif self.path == "/health":
                     # Liveness probe for the DB-side health registry /
                     # half-open breaker checks; tools/fleet_health.py
@@ -293,13 +314,28 @@ class DcompactWorkerService:
                 if self.path != "/dcompact":
                     self._reply(404, {"error": "not found"})
                     return
+                # The submitter's wall on this side, from the request
+                # read to the reply written; what lies in it outside
+                # `dcompact.worker` is this boundary's overhead. It adopts
+                # the submitter's trace when the header carries one.
+                hdr = self.headers.get("X-Tpulsm-Trace")
+                try:
+                    ctx = json.loads(hdr) if hdr else None
+                except ValueError:
+                    ctx = None
+                if not isinstance(ctx, dict):
+                    ctx = None
+                with svc.tracer.start_from(ctx, "dcompact.request") \
+                        as request:
+                    self._run_request(request, ctx)
+
+            def _run_request(self, request, ctx):
                 n = int(self.headers.get("Content-Length", 0))
                 try:
                     req = json.loads(self.rfile.read(n))
                     job_dir = req["job_dir"]
+                    request.tag(job_dir=os.path.basename(job_dir))
                     with svc._sem:  # one job per chip at a time
-                        import os
-
                         from toplingdb_tpu.compaction import worker
 
                         os.makedirs(job_dir, exist_ok=True)
@@ -312,16 +348,12 @@ class DcompactWorkerService:
                         if params.get("device") != svc.device:
                             params["device"] = svc.device
                             dirty = True
-                        hdr = self.headers.get("X-Tpulsm-Trace")
-                        if hdr and not params.get("trace"):
+                        if ctx is not None and not params.get("trace"):
                             # Header-carried trace context (cross-host
                             # deployments where the submitter wrote params
                             # before sampling): fold into the job.
-                            try:
-                                params["trace"] = json.loads(hdr)
-                                dirty = True
-                            except ValueError:
-                                pass
+                            params["trace"] = ctx
+                            dirty = True
                         if dirty:
                             with open(ppath, "w") as pf:
                                 json.dump(params, pf, indent=1)
@@ -329,10 +361,12 @@ class DcompactWorkerService:
                             lambda: worker.run_job(job_dir))
                     with open(f"{job_dir}/results.json") as f:
                         results = json.load(f)
-                    svc._count(ok=True)
+                    svc._count(ok=True, left_pipeline=bool(
+                        results.get("stats", {}).get("pipeline_exit")))
                     self._reply(200, results)
                 except Exception as e:  # job failure → structured error
                     svc._count(ok=False)
+                    request.tag(error=repr(e)[:200])
                     self._reply(500, {"status": f"{type(e).__name__}: {e}",
                                       "output_files": [], "stats": {}})
 

@@ -42,33 +42,48 @@ def run_job(job_dir: str) -> int:
         from toplingdb_tpu.compaction.resilience import HeartbeatWriter
 
         heartbeat = HeartbeatWriter(job_dir, lease_sec).start()
-    # Cross-process trace propagation: adopt the DB side's context (when
-    # it sampled this compaction), record this worker's spans locally, and
-    # append them to results.json for the primary to stitch.
+    # The worker always records its job: one `dcompact.worker` span and
+    # the spans of every stage below it (ARCHITECTURE.md §2.9.1). Under a
+    # service the handler's `dcompact.request` is open on this thread and
+    # its tracer keeps the trace; a worker process of its own keeps one
+    # itself. The spans go back in results.json only when the submitter
+    # sampled this compaction (`params.trace.sampled`).
     from toplingdb_tpu.utils import telemetry as _tm
 
     ctx = getattr(params, "trace", None)
-    root = None
-    if ctx and ctx.get("sampled"):
-        tracer = _tm.Tracer(sample_every=1, proc="dcompact-worker")
-        root = tracer.start_from(ctx, "dcompact.worker",
-                                 job_id=params.job_id,
-                                 attempt=params.attempt,
-                                 device=params.device)
+    tags = dict(job_id=params.job_id, attempt=params.attempt,
+                device=params.device)
+    above = _tm.current_span()
+    if above is not None and (not ctx or not ctx.get("trace_id")
+                              or ctx["trace_id"] == above.trace_id):
+        root = _tm.span("dcompact.worker", **tags)
+    else:
+        root = _tm.Tracer(proc="dcompact-worker").start_from(
+            ctx, "dcompact.worker", **tags)
     try:
-        return _run_job_inner(job_dir, params, t_enter, waiting_usec)
+        with root:
+            if waiting_usec:
+                # Measured from params.json's mtime, before this process
+                # saw the job: the one back-dated span of a job.
+                _tm.span_event("compaction.queue_wait", waiting_usec)
+            results = _run_job_inner(job_dir, params, t_enter, waiting_usec)
+            root.tag(input_records=results.stats.get("input_records", 0),
+                     pipelined=bool(results.stats.get("pipelined")))
+            with _tm.span("dcompact.results"):
+                if ctx and ctx.get("sampled"):
+                    # The spans still open (this one, the job's, the
+                    # request's) carry their time so far.
+                    results.spans = root._tracer.export_trace(root.trace_id)
+                with open(os.path.join(job_dir, "results.json"), "w") as f:
+                    f.write(results.to_json())
+        return 0
     finally:
-        if root is not None:
-            tracer_ = root._tracer
-            root.finish()
-            _append_result_spans(job_dir,
-                                 tracer_.export_trace(root.trace_id))
         if heartbeat is not None:
             heartbeat.stop()
 
 
 def _run_job_inner(job_dir: str, params, t_enter: float,
-                   waiting_usec: int) -> int:
+                   waiting_usec: int):
     store_mode = _StoreJobMode.maybe(params)
     try:
         return _run_job_body(job_dir, params, t_enter, waiting_usec,
@@ -79,7 +94,8 @@ def _run_job_inner(job_dir: str, params, t_enter: float,
 
 
 def _run_job_body(job_dir: str, params, t_enter: float,
-                  waiting_usec: int, store_mode) -> int:
+                  waiting_usec: int, store_mode):
+    """Runs the job; returns its CompactionResults (run_job writes them)."""
     from toplingdb_tpu.compaction.compaction_job import (
         CompactionStats, build_outputs, surviving_tombstone_fragments,
     )
@@ -100,7 +116,11 @@ def _run_job_body(job_dir: str, params, t_enter: float,
                   "wb") as f:
             f.write(b"\x00" * 4096)
         os._exit(137)
-    t0 = time.time()
+    from toplingdb_tpu.utils import telemetry as _tm
+
+    # Params, options, opening every input, first/last seeks for the
+    # metas: everything before the data plane starts.
+    prepare = _tm.span("compaction.prepare", files=len(params.input_files))
     env = default_env()
     if store_mode is not None:
         # Disaggregated mode: inputs resolve from the shared store by
@@ -190,6 +210,7 @@ def _run_job_body(job_dir: str, params, t_enter: float,
             bottommost=params.bottommost,
             max_output_file_size=params.max_output_file_size,
         )
+        prepare.finish()
         outputs, stats = run_device_compaction(
             env, params.output_dir, icmp, fake_compaction,
             _PathTableCache(readers), topts, params.snapshots,
@@ -205,23 +226,18 @@ def _run_job_body(job_dir: str, params, t_enter: float,
         stats.prepare_time_usec = max(
             0, int((time.time() - t_enter) * 1e6) - stats.work_time_usec)
         stats.waiting_time_usec = waiting_usec
-        from toplingdb_tpu.compaction.compaction_job import emit_phase_spans
-
-        emit_phase_spans(stats)  # worker-side interior, under its root
-        results = CompactionResults(
-            status="ok",
-            output_files=_encode_outputs(outputs, env, params, store_mode),
-            stats=dataclasses.asdict(stats),
-            work_time_usec=stats.work_time_usec,
-        )
-        with open(os.path.join(job_dir, "results.json"), "w") as f:
-            f.write(results.to_json())
-        return 0
+        with _tm.span("compaction.finish"):  # output metas, for the reply
+            return CompactionResults(
+                status="ok",
+                output_files=_encode_outputs(outputs, env, params,
+                                             store_mode),
+                stats=dataclasses.asdict(stats),
+                work_time_usec=stats.work_time_usec,
+            )
 
     # Per-entry path (CPU jobs and exotic comparators): read inputs raw —
     # unsorted for the device stream, host-sorted for the CPU reference.
-    from toplingdb_tpu.utils import telemetry as _tm
-
+    prepare.finish()
     entries = []
     rd = RangeDelAggregator(ucmp)
     readers_l = []
@@ -285,7 +301,7 @@ def _run_job_body(job_dir: str, params, t_enter: float,
             column_family=(getattr(params, "cf_id", 0),
                            getattr(params, "cf_name", "default")),
         )
-    results = CompactionResults(
+    return CompactionResults(
         status="ok",
         output_files=_encode_outputs(outputs, env, params, store_mode),
         stats=dataclasses.asdict(stats),
@@ -294,9 +310,6 @@ def _run_job_body(job_dir: str, params, t_enter: float,
         work_time_usec=max(
             0, int((time.time() - t_enter) * 1e6) - stats.prepare_time_usec),
     )
-    with open(os.path.join(job_dir, "results.json"), "w") as f:
-        f.write(results.to_json())
-    return 0
 
 
 class _StoreJobMode:
@@ -377,23 +390,6 @@ def _encode_outputs(outputs, env, params, store_mode) -> list[dict]:
                 env, os.path.join(params.output_dir, name), m))
         docs.append(d)
     return docs
-
-
-def _append_result_spans(job_dir: str, spans: list) -> None:
-    """Re-open results.json and attach the worker's finished spans (the
-    results were written by the job body before the tracer could close its
-    root). Best-effort: a failed job has no results.json to annotate."""
-    import json
-
-    path = os.path.join(job_dir, "results.json")
-    try:
-        with open(path) as f:
-            results = json.load(f)
-        results["spans"] = spans
-        with open(path, "w") as f:
-            json.dump(results, f, indent=1)
-    except (OSError, ValueError):
-        pass
 
 
 def _merge_operator_by_name(name: str):

@@ -31,6 +31,7 @@ from toplingdb_tpu.table.builder import (
 from toplingdb_tpu.table.properties import TableProperties
 from toplingdb_tpu.utils.status import Corruption, NotSupported
 from toplingdb_tpu.utils import errors as _errors
+from toplingdb_tpu.utils import telemetry
 
 
 # Soft per-native-call output budget for the bulk block builder: bounds the
@@ -628,6 +629,21 @@ class _ColumnarSST:
         feeds the bloom build below) — a dict with
         num_entries/raw_key_size/raw_value_size/num_deletions/
         num_merge_operands/smallest_seqno/largest_seqno."""
+        with telemetry.span("sst.finish_file", file=self.fnum):
+            out = self._finish_blocks(lib, kv, sel, vtypes, seqs,
+                                      tombstones, precomputed)
+        with telemetry.span("sst.sync_close", file=self.fnum,
+                            bytes=self.w.file_size()):
+            self.w.flush()
+            self.w.sync()
+            self.w.close()
+        return out
+
+    def _finish_blocks(self, lib, kv, sel, vtypes, seqs, tombstones,
+                       precomputed):
+        """Everything of finish() but the fsync: pending data blocks,
+        filter, range-del, dictionary, index, properties, metaindex,
+        footer."""
         if self._dict == b"":
             self._train_dict_and_flush()  # small file: train from the lot
         self._drain(wait=True)
@@ -719,9 +735,6 @@ class _ColumnarSST:
         mih = fmt.write_block(self.w, metaindex.finish(), fmt.NO_COMPRESSION)
         ih = fmt.write_block(self.w, iraw, options.compression)
         self.w.append(fmt.Footer(mih, ih).encode())
-        self.w.flush()
-        self.w.sync()
-        self.w.close()
         return props, smallest, largest
 
 
@@ -750,6 +763,9 @@ def write_tables_columnar(env, dbname, new_file_number, icmp, options,
     lib = native.lib()
     if lib is None:
         raise NotSupported("native library unavailable")
+    # The writer's set-up: buffers sized from the inputs, the first
+    # output file created.
+    setup = telemetry.span("sst.open")
     if isinstance(order, np.ndarray):
         # Whole array up front: no copy, no withhold/rebuild of the final
         # block (exhausted from the start).
@@ -839,12 +855,13 @@ def write_tables_columnar(env, dbname, new_file_number, icmp, options,
     else:
         use_section = False
     if use_section and kv.n:
-        # Upper bound over ALL entries (the survivor set streams in).
-        # max(0, ·): under streamed callers the length arrays can still
-        # hold uninitialized garbage (see the max_entry clamp above);
-        # the sec rc==-2 grow loop recovers from an undersized guess.
-        sec_bytes = max(
-            0, int(kv.key_lens.sum()) + int(kv.val_lens.sum()))
+        # Upper bound over ALL entries (the survivor set streams in): the
+        # buffers' own sizes. Not the sum of the length arrays: under
+        # streamed callers the readers are still filling those (see the
+        # max_entry clamp above), fresh pages read 0, and a section buffer
+        # of 64 KB then costs a hundred native calls a chunk where two do
+        # (PERF.md §6, PR 25: `sst.build_data` +3.8-5.9 s a job).
+        sec_bytes = len(kv.key_buf) + len(kv.val_buf)
         # Each native call emits at most ~_SECTION_RUN_BYTES (stopping a run
         # early is free: the next call continues the same file), so the
         # section buffer and the per-call copy stay bounded no matter how
@@ -881,15 +898,27 @@ def write_tables_columnar(env, dbname, new_file_number, icmp, options,
     start = 0
     filled = start_filled      # rows of `order` received so far
     exhausted = start_exhausted
+    # One `pipeline.encode_write` span a consumed chunk (the whole order
+    # when it came as one array); waiting for the next chunk is the
+    # feeder's span, not this one.
+    n_chunks = 0
+    ew = telemetry.NOOP_SPAN
     try:
         cur = _ColumnarSST(env, dbname, new_file_number(), icmp, options,
                            creation_time, column_family, pool)
+        setup.finish()
+        if start_exhausted:
+            ew = telemetry.span("pipeline.encode_write", chunk=0)
         need_fetch = False
         while True:
             if start >= filled or need_fetch:
                 need_fetch = False
                 if not exhausted:
+                    ew.finish()
                     nxt = next(chunks, None)
+                    ew = telemetry.span("pipeline.encode_write",
+                                        chunk=n_chunks)
+                    n_chunks += 1
                     if nxt is None:
                         exhausted = True
                     else:
@@ -922,84 +951,87 @@ def write_tables_columnar(env, dbname, new_file_number, icmp, options,
                         j += 1
                     limit = j
             if use_section:
-                base_size = cur.w.file_size()
-                budget = base_size + _SECTION_RUN_BYTES
-                if can_cut and max_output_file_size < budget:
-                    budget = max_output_file_size
-                if sec_ctype:
-                    rc = lib.tpulsm_build_data_section_c(
-                        p_kbuf, p_koff, p_klen, p_vbuf, p_voff, p_vlen,
-                        p_tro, p_order, start, limit,
-                        options.block_size, options.restart_interval,
-                        sec_ctype, sec_level,
-                        base_size, budget,
-                        p_counts, p_plens, p_rawlens, max_blocks,
-                        p_sec, sec_cap, p_seclen,
-                    )
-                    if rc == -9:
-                        # codec .so unavailable: per-block Python framing
-                        use_section = False
-                        sec_ctype = 0
+                # Native block build + compress of one run of blocks, its
+                # copy out of the section buffer and its append to the file.
+                with telemetry.span("sst.build_data", file=cur.fnum):
+                    base_size = cur.w.file_size()
+                    budget = base_size + _SECTION_RUN_BYTES
+                    if can_cut and max_output_file_size < budget:
+                        budget = max_output_file_size
+                    if sec_ctype:
+                        rc = lib.tpulsm_build_data_section_c(
+                            p_kbuf, p_koff, p_klen, p_vbuf, p_voff, p_vlen,
+                            p_tro, p_order, start, limit,
+                            options.block_size, options.restart_interval,
+                            sec_ctype, sec_level,
+                            base_size, budget,
+                            p_counts, p_plens, p_rawlens, max_blocks,
+                            p_sec, sec_cap, p_seclen,
+                        )
+                        if rc == -9:
+                            # codec .so unavailable: per-block Python framing
+                            use_section = False
+                            sec_ctype = 0
+                            continue
+                    else:
+                        rc = lib.tpulsm_build_data_section(
+                            p_kbuf, p_koff, p_klen, p_vbuf, p_voff, p_vlen,
+                            p_tro, p_order, start, limit,
+                            options.block_size, options.restart_interval,
+                            base_size, budget,
+                            p_counts, p_plens, max_blocks,
+                            p_sec, sec_cap, p_seclen,
+                        )
+                        sec_rawlens[:max(0, int(rc))] = \
+                            sec_plens[:max(0, int(rc))] if rc > 0 else 0
+                    if rc == -2:
+                        sec_cap *= 4
+                        sec_buf = np.empty(sec_cap, dtype=np.uint8)
+                        p_sec = native.np_u8p(sec_buf)
                         continue
-                else:
-                    rc = lib.tpulsm_build_data_section(
-                        p_kbuf, p_koff, p_klen, p_vbuf, p_voff, p_vlen,
-                        p_tro, p_order, start, limit,
-                        options.block_size, options.restart_interval,
-                        base_size, budget,
-                        p_counts, p_plens, max_blocks,
-                        p_sec, sec_cap, p_seclen,
-                    )
-                    sec_rawlens[:max(0, int(rc))] = \
-                        sec_plens[:max(0, int(rc))] if rc > 0 else 0
-                if rc == -2:
-                    sec_cap *= 4
-                    sec_buf = np.empty(sec_cap, dtype=np.uint8)
-                    p_sec = native.np_u8p(sec_buf)
+                    if rc == -3 or rc == -8:
+                        raise NotSupported(
+                            f"native block build unsupported input rc={rc}"
+                        )
+                    if rc <= 0:
+                        raise Corruption(f"native section build failed rc={rc}")
+                    nb = int(rc)
+                    sec_total = int(sec_len[0])
+                    pos = start + sum(int(sec_counts[b]) for b in range(nb))
+                    if not exhausted and pos == filled:
+                        # The final block ended at the chunk boundary — it may
+                        # have been starved, not full. Withhold it until more
+                        # data arrives so block layout matches the
+                        # whole-array build byte-for-byte.
+                        last_cnt = int(sec_counts[nb - 1])
+                        nb -= 1
+                        pos -= last_cnt
+                        sec_total -= int(sec_plens[nb]) + fmt.BLOCK_TRAILER_SIZE
+                        need_fetch = True
+                        if nb == 0:
+                            continue
+                    section = sec_buf[:sec_total].tobytes()
+                    if use_nat_index:
+                        # Index entries defer to ONE native call at finish —
+                        # zero per-block Python on the section path.
+                        cur._idx_order = order
+                        cur._idx_trailer = trailer_override
+                        cur.add_framed_section_arrays(
+                            section, sec_counts, sec_plens, sec_rawlens, nb,
+                            start, entry_key)
+                    else:
+                        blocks = []
+                        bpos = start
+                        for b in range(nb):
+                            cnt = int(sec_counts[b])
+                            blocks.append((int(sec_plens[b]),
+                                           int(sec_rawlens[b]),
+                                           entry_key(bpos),
+                                           entry_key(bpos + cnt - 1), cnt))
+                            bpos += cnt
+                        cur.add_framed_section(section, blocks)
+                    start = pos
                     continue
-                if rc == -3 or rc == -8:
-                    raise NotSupported(
-                        f"native block build unsupported input rc={rc}"
-                    )
-                if rc <= 0:
-                    raise Corruption(f"native section build failed rc={rc}")
-                nb = int(rc)
-                sec_total = int(sec_len[0])
-                pos = start + sum(int(sec_counts[b]) for b in range(nb))
-                if not exhausted and pos == filled:
-                    # The final block ended at the chunk boundary — it may
-                    # have been starved, not full. Withhold it until more
-                    # data arrives so block layout matches the
-                    # whole-array build byte-for-byte.
-                    last_cnt = int(sec_counts[nb - 1])
-                    nb -= 1
-                    pos -= last_cnt
-                    sec_total -= int(sec_plens[nb]) + fmt.BLOCK_TRAILER_SIZE
-                    need_fetch = True
-                    if nb == 0:
-                        continue
-                section = sec_buf[:sec_total].tobytes()
-                if use_nat_index:
-                    # Index entries defer to ONE native call at finish —
-                    # zero per-block Python on the section path.
-                    cur._idx_order = order
-                    cur._idx_trailer = trailer_override
-                    cur.add_framed_section_arrays(
-                        section, sec_counts, sec_plens, sec_rawlens, nb,
-                        start, entry_key)
-                else:
-                    blocks = []
-                    bpos = start
-                    for b in range(nb):
-                        cnt = int(sec_counts[b])
-                        blocks.append((int(sec_plens[b]),
-                                       int(sec_rawlens[b]),
-                                       entry_key(bpos),
-                                       entry_key(bpos + cnt - 1), cnt))
-                        bpos += cnt
-                    cur.add_framed_section(section, blocks)
-                start = pos
-                continue
             rc = lib.tpulsm_build_block(
                 p_kbuf, p_koff, p_klen, p_vbuf, p_voff, p_vlen, p_tro,
                 p_order, start, limit,
@@ -1048,5 +1080,6 @@ def write_tables_columnar(env, dbname, new_file_number, icmp, options,
                 _errors.swallow(reason="sst-abort-cleanup", exc=e)
         raise
     finally:
+        ew.finish()
         if pool is not None:
             pool.shutdown()

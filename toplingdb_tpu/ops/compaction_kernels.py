@@ -666,48 +666,55 @@ def _uniform_shard_core(kb, pkb, starts, min_his, min_los, tomb_hi, tomb_lo,
     iota = jnp.arange(p, dtype=jnp.int32)
     valid = iota < total
 
-    kbp = kb
-    if span > uk_len:
-        kbp = jnp.pad(kbp, ((0, 0), (0, span - uk_len)))
-    kbp = kbp.astype(u32).reshape(p, num_key_words, 4)
-    words = (
-        (kbp[:, :, 0] << 24) | (kbp[:, :, 1] << 16)
-        | (kbp[:, :, 2] << 8) | kbp[:, :, 3]
-    )
-    key_words = jnp.where(valid[:, None], i32(words ^ sign), int32max)
+    # The scopes below name the steps in the device's trace (PERF.md §3:
+    # `kernel.<scope>_ms_per_Mrow`); they are metadata, the program the
+    # compiler builds is the same with and without them.
+    with jax.named_scope("encode_words"):
+        kbp = kb
+        if span > uk_len:
+            kbp = jnp.pad(kbp, ((0, 0), (0, span - uk_len)))
+        kbp = kbp.astype(u32).reshape(p, num_key_words, 4)
+        words = (
+            (kbp[:, :, 0] << 24) | (kbp[:, :, 1] << 16)
+            | (kbp[:, :, 2] << 8) | kbp[:, :, 3]
+        )
+        key_words = jnp.where(valid[:, None], i32(words ^ sign), int32max)
 
-    # Reconstruct full 64-bit packed trailers (seq<<8|type): per-row chunk
-    # id via searchsorted over the chunk starts, then add that chunk's
-    # 64-bit min seqno to the 24-bit delta. Deltas from different chunks
-    # are not comparable; the absolute words are.
-    cid = jnp.searchsorted(starts, iota, side="right") - 1
-    rel = pkb >> 8
-    mlo = min_los[cid]
-    seq_lo = mlo + rel
-    carry = (seq_lo < mlo).astype(u32)
-    seq_hi = min_his[cid] + carry
-    vt0 = pkb & u32(0xFF)
-    packed_hi = (seq_hi << 8) | (seq_lo >> 24)
-    packed_lo = (seq_lo << 8) | vt0
-    inv_hi = jnp.where(valid, i32(~packed_hi ^ sign), int32max)
-    inv_lo = jnp.where(valid, i32(~packed_lo ^ sign), int32max)
-    vtype = jnp.where(valid, vt0.astype(jnp.int32), -1)
-    key_len = jnp.where(valid, jnp.int32(uk_len), int32max)
+        # Reconstruct full 64-bit packed trailers (seq<<8|type): per-row
+        # chunk id via searchsorted over the chunk starts, then add that
+        # chunk's 64-bit min seqno to the 24-bit delta. Deltas from
+        # different chunks are not comparable; the absolute words are.
+        cid = jnp.searchsorted(starts, iota, side="right") - 1
+        rel = pkb >> 8
+        mlo = min_los[cid]
+        seq_lo = mlo + rel
+        carry = (seq_lo < mlo).astype(u32)
+        seq_hi = min_his[cid] + carry
+        vt0 = pkb & u32(0xFF)
+        packed_hi = (seq_hi << 8) | (seq_lo >> 24)
+        packed_lo = (seq_lo << 8) | vt0
+        inv_hi = jnp.where(valid, i32(~packed_hi ^ sign), int32max)
+        inv_lo = jnp.where(valid, i32(~packed_lo ^ sign), int32max)
+        vtype = jnp.where(valid, vt0.astype(jnp.int32), -1)
+        key_len = jnp.where(valid, jnp.int32(uk_len), int32max)
 
-    kw, kl, ih, il, vt, perm = _sort_impl(
-        key_words, key_len, inv_hi, inv_lo, vtype, iota, num_key_words,
-    )
-    if has_tombs:
-        th = tomb_hi[perm]
-        tl = tomb_lo[perm]
-    else:
-        th = tl = jnp.zeros(p, dtype=jnp.uint32)
-    keep, zero_seq, host_resolve, _ = _gc_mask_impl(
-        kw, kl, ih, il, vt, snap_hi, snap_lo, th, tl,
-        num_key_words, bottommost,
-    )
-    out = keep | host_resolve
-    take = jnp.argsort(~out, stable=True)
+    with jax.named_scope("sort"):
+        kw, kl, ih, il, vt, perm = _sort_impl(
+            key_words, key_len, inv_hi, inv_lo, vtype, iota, num_key_words,
+        )
+    with jax.named_scope("gc_mask"):
+        if has_tombs:
+            th = tomb_hi[perm]
+            tl = tomb_lo[perm]
+        else:
+            th = tl = jnp.zeros(p, dtype=jnp.uint32)
+        keep, zero_seq, host_resolve, _ = _gc_mask_impl(
+            kw, kl, ih, il, vt, snap_hi, snap_lo, th, tl,
+            num_key_words, bottommost,
+        )
+    with jax.named_scope("compact"):
+        out = keep | host_resolve
+        take = jnp.argsort(~out, stable=True)
     return {
         "perm": perm, "take": take, "out": out, "zero_seq": zero_seq,
         "host_resolve": host_resolve,
@@ -729,39 +736,43 @@ def _uniform_shard_tail(kb, pkb, starts, min_his, min_los, tomb_hi, tomb_lo,
         has_tombs,
     )
     take = core["take"]
-    po = (
-        jax.lax.bitcast_convert_type(core["perm"][take], u32)
-        | (core["zero_seq"][take].astype(u32) << 23)
-        | (core["host_resolve"][take].astype(u32) << 22)
-    )
-    packed_bytes = jnp.concatenate([
-        (po & u32(0xFF)).astype(jnp.uint8),
-        ((po >> 8) & u32(0xFF)).astype(jnp.uint8),
-        ((po >> 16) & u32(0xFF)).astype(jnp.uint8),
-    ])
-    meta = jnp.stack([
-        jnp.sum(core["out"].astype(jnp.int32)),
-        jnp.any(core["host_resolve"]).astype(jnp.int32),
-    ])
+    with jax.named_scope("compact"):
+        po = (
+            jax.lax.bitcast_convert_type(core["perm"][take], u32)
+            | (core["zero_seq"][take].astype(u32) << 23)
+            | (core["host_resolve"][take].astype(u32) << 22)
+        )
+    with jax.named_scope("pack"):
+        packed_bytes = jnp.concatenate([
+            (po & u32(0xFF)).astype(jnp.uint8),
+            ((po >> 8) & u32(0xFF)).astype(jnp.uint8),
+            ((po >> 16) & u32(0xFF)).astype(jnp.uint8),
+        ])
+        meta = jnp.stack([
+            jnp.sum(core["out"].astype(jnp.int32)),
+            jnp.any(core["host_resolve"]).astype(jnp.int32),
+        ])
     return packed_bytes, meta
 
 
 def _decode_front_coded(plens, sfx, uk_len):
     """Reconstruct the [p, uk_len] u8 key matrix from front-coded uploads
     (shared by the packed-download and block-assembly kernels)."""
-    p = plens.shape[0]
-    pl = plens.astype(jnp.int32)
-    sfx_len = jnp.int32(uk_len) - pl
-    sfx_off = jnp.cumsum(sfx_len) - sfx_len
-    iota = jnp.arange(p, dtype=jnp.int32)
-    col = jnp.arange(uk_len, dtype=jnp.int32)[None, :]
-    # Column j of row i inherits from the LAST row i' <= i with
-    # plen[i'] <= j; chunk starts have plen 0, so inheritance never
-    # crosses a chunk boundary.
-    contrib = jnp.where(pl[:, None] <= col, iota[:, None], jnp.int32(-1))
-    src = jax.lax.cummax(contrib, axis=0)
-    pos = sfx_off[src] + (col - pl[src])
-    return sfx[jnp.clip(pos, 0, sfx.shape[0] - 1)]
+    with jax.named_scope("fc_decode"):
+        p = plens.shape[0]
+        pl = plens.astype(jnp.int32)
+        sfx_len = jnp.int32(uk_len) - pl
+        sfx_off = jnp.cumsum(sfx_len) - sfx_len
+        iota = jnp.arange(p, dtype=jnp.int32)
+        col = jnp.arange(uk_len, dtype=jnp.int32)[None, :]
+        # Column j of row i inherits from the LAST row i' <= i with
+        # plen[i'] <= j; chunk starts have plen 0, so inheritance never
+        # crosses a chunk boundary.
+        contrib = jnp.where(pl[:, None] <= col, iota[:, None],
+                            jnp.int32(-1))
+        src = jax.lax.cummax(contrib, axis=0)
+        pos = sfx_off[src] + (col - pl[src])
+        return sfx[jnp.clip(pos, 0, sfx.shape[0] - 1)]
 
 
 @functools.partial(
@@ -952,6 +963,12 @@ def upload_uniform_shard(chunks, covers=None, front_code=None, device=None):
     else:
         h["ukb"] = put(ukb)
     return h
+
+
+def shard_upload_nbytes(handle) -> int:
+    """Bytes an upload_uniform_shard handle put on the device."""
+    return sum(int(v.nbytes) for v in handle.values()
+               if hasattr(v, "nbytes"))
 
 
 def fused_uniform_shard_start(handle, snapshots: list[int], bottommost: bool):

@@ -32,6 +32,7 @@ from toplingdb_tpu.db.range_del import RangeDelAggregator, RangeTombstone, fragm
 from toplingdb_tpu.ops import compaction_kernels as ck
 from toplingdb_tpu.ops import device_runtime
 from toplingdb_tpu.ops.columnar import ColumnarEntries
+from toplingdb_tpu.utils import telemetry as _tele
 from toplingdb_tpu.utils.status import NotSupported
 
 
@@ -631,18 +632,26 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
                 snapshots, new_file_number, creation_time, pstats,
                 MAX_DEVICE_KEY_BYTES, column_family,
             )
-        except (pl.PipelineIneligible, NotSupported):
-            pass  # serial path decides (and re-raises what it must)
+        except (pl.PipelineIneligible, NotSupported) as e:
+            # The serial path decides (and re-raises what it must); the
+            # job's stats say why it left the pipeline.
+            stats.pipeline_exit = f"{type(e).__name__}: {e}"
         else:
-            outputs = _outputs_from_files(env, pfiles, pkv, pvt, pstats,
-                                          icmp=icmp,
-                                          table_options=table_options)
+            with _tele.span("compaction.finish"):
+                outputs = _outputs_from_files(env, pfiles, pkv, pvt, pstats,
+                                              icmp=icmp,
+                                              table_options=table_options)
+                # The job's columnar buffers (hundreds of MB) go back to
+                # the allocator here, inside the span, not at some return.
+                del pfiles, pkv, pvt, _ptombs
             pstats.work_time_usec = int((time.time() - t0) * 1e6)
             return outputs, pstats
     try:
-        kv, rd, shards, parts = _collect_raw_columnar(
-            compaction, table_cache, icmp, want_uploads=not _host_sort(),
-        )
+        with _tele.span("compaction.input_scan"):
+            kv, rd, shards, parts = _collect_raw_columnar(
+                compaction, table_cache, icmp,
+                want_uploads=not _host_sort(),
+            )
     except NotSupported:
         raise _FallbackToEntries()  # >2GiB columnar buffers etc.
     stats.input_scan_usec = int((time.time() - t0) * 1e6)
@@ -657,6 +666,7 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
     t_fin = time.time()
     mkb = max(4, int(kv.key_lens.max()) - 8) if kv.n else 4
     col = any_complex = None
+    prep = _tele.span("pipeline.chunk_prepare")  # host numpy before upload
     if not _host_sort():
         # Host-sort mode gets seq/vtype from the fused native merge+GC —
         # gathering trailers here would be pure waste at bench scale.
@@ -677,6 +687,7 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
         cover = (None if rd.empty() else _cover_for_parts(
             parts, rd, icmp.user_comparator, snapshots))
         stats.host_compute_usec += int((time.time() - t_cov) * 1e6)
+        prep.finish()
         if not _host_sort():
             from toplingdb_tpu.ops import block_assembly as ba
 
@@ -741,14 +752,15 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
             # places shards round-robin over every chip instead, double-
             # buffered per chip (ops/mesh_compaction.py).
             from toplingdb_tpu.ops import mesh_compaction as mc
-            from toplingdb_tpu.utils import telemetry as _tele
 
             t_up = time.time()
-            finish_shard, _mesh_on = mc.dispatch_shards(
-                shards, cover, snapshots, compaction.bottommost,
-                stats=stats, any_complex=bool(any_complex),
-                trace=_tele.current_handle(),
-            )
+            with _tele.span("pipeline.upload", shards=len(shards)) as up:
+                finish_shard, _mesh_on = mc.dispatch_shards(
+                    shards, cover, snapshots, compaction.bottommost,
+                    stats=stats, any_complex=bool(any_complex),
+                    trace=_tele.current_handle(),
+                )
+                up.tag(h2d_bytes=stats.h2d_bytes)
             # Upload-enqueue span (device_put is async, so this is a lower
             # bound; the blocking download waits below add the rest).
             stats.transfer_time_usec += int((time.time() - t_up) * 1e6)
@@ -767,7 +779,9 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
                 orders, zfs, cxs = [], [], []
                 for s_i, (_chunks, ranges) in enumerate(shards):
                     t_dn = time.time()
-                    o, z, cx, hc = finish_shard(s_i)
+                    with _tele.span("pipeline.merge_gc", shard=s_i,
+                                    device=True):
+                        o, z, cx, hc = finish_shard(s_i)
                     stats.device_wait_usec += int(
                         (time.time() - t_dn) * 1e6)
                     lmap = _ranges_lmap(ranges)
@@ -822,16 +836,19 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
         def _shard_order_chunks():
             for s_i, (_chunks, ranges) in enumerate(shards):
                 t_dn = time.time()
-                o, z, _cx, hc = finish_shard(s_i)
+                with _tele.span("pipeline.merge_gc", shard=s_i,
+                                device=True):
+                    o, z, _cx, hc = finish_shard(s_i)
                 stats.device_wait_usec += int((time.time() - t_dn) * 1e6)
                 if hc:
                     raise _FallbackToEntries()
-                lmap = _ranges_lmap(ranges)
-                order_s = lmap[o]
-                zero_s = order_s[z]
-                trailer_override[zero_s] = \
-                    col.vtype[zero_s].astype(np.int64)
-                seqs[zero_s] = 0
+                with _tele.span("pipeline.unpack", shard=s_i):
+                    lmap = _ranges_lmap(ranges)
+                    order_s = lmap[o]
+                    zero_s = order_s[z]
+                    trailer_override[zero_s] = \
+                        col.vtype[zero_s].astype(np.int64)
+                    seqs[zero_s] = 0
                 yield order_s
 
         order_feed = _shard_order_chunks()
@@ -873,9 +890,10 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
             # Native builder refused (oversized key / restart overflow):
             # the per-entry path handles these (partials already cleaned).
             raise _FallbackToEntries()
-        outputs = _outputs_from_files(env, files, kv, vtypes, stats,
-                                      icmp=icmp,
-                                      table_options=table_options)
+        with _tele.span("compaction.finish"):
+            outputs = _outputs_from_files(env, files, kv, vtypes, stats,
+                                          icmp=icmp,
+                                          table_options=table_options)
     stats.encode_write_usec = int((time.time() - t_wr) * 1e6)
     stats.work_time_usec = int((time.time() - t0) * 1e6)
     return outputs, stats
@@ -905,12 +923,17 @@ def run_device_compaction(env, dbname, icmp, compaction, table_cache,
         raise NotSupported(
             "the device compaction path needs the native library "
             "(toplingdb_tpu/native: g++ missing or the build failed)")
+    _tele.watch_gc()  # the process that holds the chip watches its heap
+    gc_us0, gc_n0 = _tele.gc_totals()
     with device_runtime.count_compiles() as jit:
         outputs, stats = _run_device_compaction(
             env, dbname, icmp, compaction, table_cache, table_options,
             snapshots, merge_operator, compaction_filter, new_file_number,
             creation_time, device_name, blob_resolver, blob_gc,
             column_family)
+    gc_us1, gc_n1 = _tele.gc_totals()
+    stats.gc_pause_usec = gc_us1 - gc_us0
+    stats.gc_collections = gc_n1 - gc_n0
     stats.jit_compiles = jit.compiled
     stats.jit_cache_hits = jit.cache_hits
     stats.jit_compile_usec = int(jit.seconds * 1e6)

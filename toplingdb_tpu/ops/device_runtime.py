@@ -13,7 +13,13 @@ so three things are decided in exactly one place:
     label is never just a label: `CompactionStats.device` routes a job to
     the DCOMPACTION_* tickers, so it has to be what JAX ran on;
   * how compilations are counted (`count_compiles`), so a job can say how
-    many programs it compiled and how many came from the cache.
+    many programs it compiled and how many came from the cache;
+  * that the program's spans reach the profiler. `utils/telemetry.py`
+    imports no JAX (the DB process never loads it); here, in the process
+    that does, `jax.profiler.TraceAnnotation` becomes its mirror: every
+    real span is also an annotation on the span's own thread. Without a
+    profiler session that is one atomic load a span; with one, the spans
+    lie in the trace's host plane beside the device's ops, on one clock.
 """
 
 from __future__ import annotations
@@ -23,6 +29,7 @@ import os
 
 import jax
 
+from toplingdb_tpu.utils import telemetry
 from toplingdb_tpu.utils.status import InvalidArgument, NotSupported
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -34,6 +41,8 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
 # Keep every program, not only the ones that took over a second: a shape
 # bucket a worker has met once is never compiled by the next worker.
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+
+telemetry.set_mirror(jax.profiler.TraceAnnotation)
 
 # Device label -> the platform jax.devices()[0] must report.
 _PLATFORM_OF = {"tpu": "tpu", "cpu-jax": "cpu"}
@@ -88,6 +97,21 @@ class CompileCount:
         return self.requests - self.cache_hits
 
 
+# The count_compiles blocks that are running, innermost last.
+_counting: list[CompileCount] = []
+
+
+def compiles_now() -> tuple[int, int]:
+    """(programs compiled, programs loaded from the persistent cache) so
+    far in the innermost running `count_compiles` block; (0, 0) outside
+    one. A dispatch reads it before and after to say whether it compiled
+    (the span `pipeline.dispatch`)."""
+    if not _counting:
+        return 0, 0
+    c = _counting[-1]
+    return c.compiled, c.cache_hits
+
+
 @contextlib.contextmanager
 def count_compiles():
     """Count XLA compile requests process-wide (the compiling thread is
@@ -105,8 +129,10 @@ def count_compiles():
 
     jax.monitoring.register_event_listener(on_event)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
+    _counting.append(c)
     try:
         yield c
     finally:
+        _counting.remove(c)
         jax.monitoring.unregister_event_listener(on_event)
         jax.monitoring.unregister_event_duration_listener(on_duration)

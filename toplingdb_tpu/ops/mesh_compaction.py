@@ -25,13 +25,13 @@ fails mid-job is WEDGED: its queued shards re-dispatch onto the surviving
 chips (or the default device when none remain) and the job completes with
 the same bytes; the demotion is counted on CompactionStats.mesh_fallbacks
 and visible as a `compaction.mesh.fallback` span event beside the
-per-chip `compaction.mesh.shard` spans in the stitched waterfall.
+`pipeline.merge_gc` spans, whose `chip=` tag names the chip a shard ran
+on, in the stitched waterfall.
 """
 
 from __future__ import annotations
 
 import os
-import time
 
 from toplingdb_tpu.parallel import mesh_plan
 from toplingdb_tpu.utils import errors as _errors
@@ -149,6 +149,8 @@ class MeshShardRun:
             _FAULT_HOOK(s, device)
         h = self._ck.upload_uniform_shard(chunks, self._covers_for(ranges),
                                           device=device)
+        if self._stats is not None:
+            self._stats.h2d_bytes += self._ck.shard_upload_nbytes(h)
         return self._ck.fused_uniform_shard_start(
             h, self._snapshots, self._bottommost)
 
@@ -164,7 +166,7 @@ class MeshShardRun:
                     raise  # even the default device failed: real error
                 self._wedge(device, e)
                 continue  # demote: next surviving chip / default device
-            self._pend[s] = (pending, device, time.time())
+            self._pend[s] = (pending, device)
             return
 
     def _fill(self) -> None:
@@ -179,26 +181,28 @@ class MeshShardRun:
         """Block on shard s's result (order, zero_flags, cx_flags,
         has_complex); re-dispatches the shard on a surviving chip if its
         chip dies under the wait, then refills the window."""
-        pending, device, t_disp = self._pend.pop(s)
+        pending, device = self._pend.pop(s)
         while True:
             try:
                 out = self._ck.fused_uniform_shard_finish(pending)
+                if self._stats is not None:
+                    self._stats.d2h_bytes += sum(
+                        int(a.nbytes) for a in pending)
                 break
             except Exception as e:
                 if device is None:
                     raise
                 self._wedge(device, e)
                 self._dispatch(s)  # re-runs on a healthy chip, same bytes
-                pending, device, t_disp = self._pend.pop(s)
-        # Callers time the blocking wait into stats.device_wait_usec
-        # around finish() itself; only the per-chip span is emitted here.
+                pending, device = self._pend.pop(s)
+        # Callers time the blocking wait into stats.device_wait_usec and
+        # the span `pipeline.merge_gc` around finish() itself; here that
+        # span learns which chip the shard ran on.
         if self._plan is not None:
-            chunks, _ranges = self._shards[s]
-            telemetry.span_event_under(
-                self._trace, "compaction.mesh.shard",
-                (time.time() - t_disp) * 1e6, shard=s,
-                chip=str(device) if device is not None else "default",
-                rows=sum(int(c[3]) for c in chunks))
+            sp = telemetry.current_span()
+            if sp is not None:
+                sp.tag(chip=str(device) if device is not None
+                       else "default")
         self._fill()
         return out
 

@@ -321,63 +321,68 @@ def _scan_file(fi, fp, kv, prog, splitters, stats, stats_mu,
         for s in range(n_shards):
             if prog.stop:
                 return
-            t_sh = time.time() if trace_handle is not None else 0.0
             blo, bhi = fp.groups[s], fp.groups[s + 1]
-            if bhi > blo:
-                w0 = int(fp.block_offs[blo])
-                w1 = int(fp.block_offs[bhi - 1] + fp.block_lens[bhi - 1]) + 5
-                raw = fp.pf.read(w0, w1 - w0)
-                rawb = np.frombuffer(raw, dtype=np.uint8)
-                boffs = np.ascontiguousarray(fp.block_offs[blo:bhi] - w0)
-                blens = np.ascontiguousarray(fp.block_lens[blo:bhi])
-                rc = lib.tpulsm_scan_blocks(
-                    native.np_u8p(rawb), len(rawb),
-                    native.np_i64p(boffs), native.np_i64p(blens), bhi - blo,
-                    1 if fp.verify else 0,
-                    ctypes.cast(kv.key_buf.ctypes.data + fp.k_base + k_used,
-                                _PU8), fp.rk - k_used,
-                    ctypes.cast(kv.val_buf.ctypes.data + fp.v_base + v_used,
-                                _PU8), fp.rv - v_used,
-                    ctypes.cast(kv.key_offs.ctypes.data
-                                + 4 * (fp.n_base + rows), _PI32),
-                    ctypes.cast(kv.key_lens.ctypes.data
-                                + 4 * (fp.n_base + rows), _PI32),
-                    ctypes.cast(kv.val_offs.ctypes.data
-                                + 4 * (fp.n_base + rows), _PI32),
-                    ctypes.cast(kv.val_lens.ctypes.data
-                                + 4 * (fp.n_base + rows), _PI32),
-                    fp.ne - rows, fp.k_base + k_used, fp.v_base + v_used,
-                )
-                if rc == -6:
-                    raise Corruption("block checksum mismatch (pipeline)")
-                if rc == -8:
-                    raise Corruption("block decode failed (pipeline)")
-                if rc < 0:
-                    # -1 codec fallback, -2/-3/-4 capacity disagreements
-                    # with the properties: the serial path covers these.
-                    raise PipelineIneligible(f"native scan rc={rc}")
-                if rc > 0:
-                    last = fp.n_base + rows + int(rc) - 1
-                    k_used = int(kv.key_offs[last]) \
-                        + int(kv.key_lens[last]) - fp.k_base
-                    v_used = int(kv.val_offs[last]) \
-                        + int(kv.val_lens[last]) - fp.v_base
-                rows += int(rc)
-                if rows > fp.ne:
-                    raise PipelineIneligible("more entries than properties")
-            if s < n_shards - 1:
-                nb = _lower_bound(kv, fp.n_base + bound, fp.n_base + rows,
-                                  splitters[s]) - fp.n_base
-                fp.row_bounds[s + 1] = fp.n_base + nb
-                bound = nb
-            if s == n_shards - 1 and (rows != fp.ne or k_used != fp.rk
-                                      or v_used != fp.rv):
-                raise PipelineIneligible("scan totals disagree with props")
-            if trace_handle is not None and bhi > blo:
-                telemetry.span_event_under(
-                    trace_handle, "pipeline.scan",
-                    (time.time() - t_sh) * 1e6, file=fi, shard=s,
-                    blocks=bhi - blo)
+            # One span a file and shard that has blocks, entered here, on
+            # the reader's own thread.
+            with telemetry.span_under(
+                    trace_handle if bhi > blo else None, "pipeline.scan",
+                    file=fi, shard=s, blocks=bhi - blo) as sp:
+                if bhi > blo:
+                    w0 = int(fp.block_offs[blo])
+                    w1 = int(fp.block_offs[bhi - 1]
+                             + fp.block_lens[bhi - 1]) + 5
+                    raw = fp.pf.read(w0, w1 - w0)
+                    sp.tag(bytes=w1 - w0)
+                    rawb = np.frombuffer(raw, dtype=np.uint8)
+                    boffs = np.ascontiguousarray(fp.block_offs[blo:bhi] - w0)
+                    blens = np.ascontiguousarray(fp.block_lens[blo:bhi])
+                    rc = lib.tpulsm_scan_blocks(
+                        native.np_u8p(rawb), len(rawb),
+                        native.np_i64p(boffs), native.np_i64p(blens),
+                        bhi - blo,
+                        1 if fp.verify else 0,
+                        ctypes.cast(kv.key_buf.ctypes.data + fp.k_base
+                                    + k_used, _PU8), fp.rk - k_used,
+                        ctypes.cast(kv.val_buf.ctypes.data + fp.v_base
+                                    + v_used, _PU8), fp.rv - v_used,
+                        ctypes.cast(kv.key_offs.ctypes.data
+                                    + 4 * (fp.n_base + rows), _PI32),
+                        ctypes.cast(kv.key_lens.ctypes.data
+                                    + 4 * (fp.n_base + rows), _PI32),
+                        ctypes.cast(kv.val_offs.ctypes.data
+                                    + 4 * (fp.n_base + rows), _PI32),
+                        ctypes.cast(kv.val_lens.ctypes.data
+                                    + 4 * (fp.n_base + rows), _PI32),
+                        fp.ne - rows, fp.k_base + k_used, fp.v_base + v_used,
+                    )
+                    if rc == -6:
+                        raise Corruption(
+                            "block checksum mismatch (pipeline)")
+                    if rc == -8:
+                        raise Corruption("block decode failed (pipeline)")
+                    if rc < 0:
+                        # -1 codec fallback, -2/-3/-4 capacity disagreements
+                        # with the properties: the serial path covers these.
+                        raise PipelineIneligible(f"native scan rc={rc}")
+                    if rc > 0:
+                        last = fp.n_base + rows + int(rc) - 1
+                        k_used = int(kv.key_offs[last]) \
+                            + int(kv.key_lens[last]) - fp.k_base
+                        v_used = int(kv.val_offs[last]) \
+                            + int(kv.val_lens[last]) - fp.v_base
+                    rows += int(rc)
+                    if rows > fp.ne:
+                        raise PipelineIneligible(
+                            "more entries than properties")
+                if s < n_shards - 1:
+                    nb = _lower_bound(kv, fp.n_base + bound, fp.n_base + rows,
+                                      splitters[s]) - fp.n_base
+                    fp.row_bounds[s + 1] = fp.n_base + nb
+                    bound = nb
+                if s == n_shards - 1 and (rows != fp.ne or k_used != fp.rk
+                                          or v_used != fp.rv):
+                    raise PipelineIneligible(
+                        "scan totals disagree with props")
             prog.mark(fi, s)
         with stats_mu:
             stats.prefetch_hits += fp.pf.hits
@@ -435,16 +440,46 @@ def _ranges_lmap(ranges) -> np.ndarray:
     ])
 
 
-def _put(outq, prog, item) -> None:
-    """Bounded put that gives up once any stage has failed or aborted."""
-    while True:
-        if prog.stop:
-            raise prog.err or PipelineIneligible("pipeline aborted")
-        try:
-            outq.put(item, timeout=0.1)
-            return
-        except Full:
-            continue
+def _put(outq, prog, item, shared) -> None:
+    """Bounded put that gives up once any stage has failed or aborted.
+    A full queue is back-pressure from the writer: that wait is the span
+    `pipeline.wait_writer` and `stall_wait_writer_usec`."""
+    if prog.stop:
+        raise prog.err or PipelineIneligible("pipeline aborted")
+    try:
+        outq.put_nowait(item)
+        return
+    except Full:
+        pass
+    t0 = time.time()
+    try:
+        with telemetry.span_under(shared.trace, "pipeline.wait_writer"):
+            while True:
+                if prog.stop:
+                    raise prog.err or PipelineIneligible("pipeline aborted")
+                try:
+                    outq.put(item, timeout=0.1)
+                    return
+                except Full:
+                    continue
+    finally:
+        shared.stats.stall_wait_writer_usec += int((time.time() - t0) * 1e6)
+
+
+def _wait_scan(prog, shared, s: int) -> None:
+    """Block until every reader has scanned past shard s. When that takes
+    a wait, the device's feeder is starved by the readers: the span
+    `pipeline.wait_scan` and `stall_wait_scan_usec`."""
+    if prog.poll_shard(s):
+        prog.wait_shard(s)  # returns at once; raises if a stage stopped
+        return
+    t0 = time.time()
+    try:
+        with telemetry.span_under(shared.trace, "pipeline.wait_scan",
+                                  shard=s):
+            prog.wait_shard(s)
+    finally:
+        shared.stats.stall_wait_scan_usec += int((time.time() - t0) * 1e6)
 
 
 def _host_compute(kv, files, splitters, prog, outq, shared, snapshots,
@@ -456,40 +491,48 @@ def _host_compute(kv, files, splitters, prog, outq, shared, snapshots,
     n_shards = len(splitters) + 1
     snaps = np.asarray(sorted(snapshots), dtype=np.uint64)
     for s in range(n_shards):
-        prog.wait_shard(s)
-        t0 = time.time()
+        _wait_scan(prog, shared, s)
         ranges = _shard_ranges(files, s)
         if not ranges:
             continue
-        _tsp = telemetry.span_under(shared.trace, "pipeline.merge_gc",
-                                    shard=s)
-        soffs = np.concatenate(
-            [kv.key_offs[lo:hi] for lo, hi in ranges]).astype(np.int64)
-        slens = np.concatenate(
-            [kv.key_lens[lo:hi] for lo, hi in ranges]).astype(np.int64)
-        mx = int(slens.max())
-        if mx - 8 > max_dev_key:
-            raise PipelineIneligible("keys exceed the device budget")
-        rs = np.cumsum([0] + [hi - lo for lo, hi in ranges],
-                       dtype=np.int64)
-        cover = _cover_for_ranges(kv, ranges, frags, snaps)
-        order, zero, _cx, hc, seq_l, vt_l = ck.host_fused_full(
-            kv.key_buf, soffs, slens, max(4, mx - 8), snapshots,
-            bottommost, cover, run_starts=rs,
-        )
-        if hc:
-            raise PipelineIneligible("complex groups present")
-        lmap = _ranges_lmap(ranges)
-        og = lmap[order]
-        shared.seqs[lmap] = seq_l
-        shared.vtypes[lmap] = vt_l
-        zg = og[zero]
-        shared.trailer_override[zg] = shared.vtypes[zg].astype(np.int64)
-        shared.seqs[zg] = 0
-        shared.stats.host_compute_usec += int((time.time() - t0) * 1e6)
-        _tsp.finish()
-        _put(outq, prog, og)
-    _put(outq, prog, _DONE)
+        with telemetry.span_under(shared.trace, "pipeline.merge_gc",
+                                  shard=s):
+            og = _host_merge_gc_shard(ck, kv, ranges, shared, snapshots,
+                                      snaps, bottommost, frags, max_dev_key)
+        _put(outq, prog, og, shared)
+    _put(outq, prog, _DONE, shared)
+
+
+def _host_merge_gc_shard(ck, kv, ranges, shared, snapshots, snaps,
+                         bottommost, frags, max_dev_key):
+    """One shard through the native merge+GC host twin; returns the
+    survivors' global rows in output order."""
+    t0 = time.time()
+    soffs = np.concatenate(
+        [kv.key_offs[lo:hi] for lo, hi in ranges]).astype(np.int64)
+    slens = np.concatenate(
+        [kv.key_lens[lo:hi] for lo, hi in ranges]).astype(np.int64)
+    mx = int(slens.max())
+    if mx - 8 > max_dev_key:
+        raise PipelineIneligible("keys exceed the device budget")
+    rs = np.cumsum([0] + [hi - lo for lo, hi in ranges],
+                   dtype=np.int64)
+    cover = _cover_for_ranges(kv, ranges, frags, snaps)
+    order, zero, _cx, hc, seq_l, vt_l = ck.host_fused_full(
+        kv.key_buf, soffs, slens, max(4, mx - 8), snapshots,
+        bottommost, cover, run_starts=rs,
+    )
+    if hc:
+        raise PipelineIneligible("complex groups present")
+    lmap = _ranges_lmap(ranges)
+    og = lmap[order]
+    shared.seqs[lmap] = seq_l
+    shared.vtypes[lmap] = vt_l
+    zg = og[zero]
+    shared.trailer_override[zg] = shared.vtypes[zg].astype(np.int64)
+    shared.seqs[zg] = 0
+    shared.stats.host_compute_usec += int((time.time() - t0) * 1e6)
+    return og
 
 
 def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
@@ -500,16 +543,21 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
     TPULSM_MESH_COMPACT shards round-robin over every chip instead
     (committed uploads pin each program, ops/mesh_compaction.py) and the
     lookahead widens to UPLOAD_DEPTH per chip; a chip that fails mid-job
-    demotes the remaining shards to the default device."""
+    demotes the remaining shards to the default device.
+
+    This thread feeds the device, so every step of it is a span: whatever
+    the device's trace shows as idle inside a job has the name of what
+    this thread was doing (PERF.md §3)."""
     from toplingdb_tpu.ops import compaction_kernels as ck
+    from toplingdb_tpu.ops import device_runtime
     from toplingdb_tpu.ops import mesh_compaction as mc
     from toplingdb_tpu.parallel import mesh_plan as mp
     from toplingdb_tpu.utils.status import NotSupported
 
     n_shards = len(splitters) + 1
     snaps = np.asarray(sorted(snapshots), dtype=np.uint64)
-    mesh_devs = mc.pipeline_devices(n_shards, stats=shared.stats,
-                                    trace=shared.trace)
+    stats, trace = shared.stats, shared.trace
+    mesh_devs = mc.pipeline_devices(n_shards, stats=stats, trace=trace)
     depth = [mp.UPLOAD_DEPTH * len(mesh_devs) if mesh_devs else 1]
     pendings = []  # (ranges, lmap, pending, s, dev, chunks, covers) | None
 
@@ -518,95 +566,85 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
         # unchanged (same kernels), only placement degrades.
         mesh_devs.clear()
         depth[0] = 1
-        shared.stats.mesh_chips = 1
-        shared.stats.mesh_fallbacks = getattr(
-            shared.stats, "mesh_fallbacks", 0) + 1
-        telemetry.span_event_under(shared.trace, "compaction.mesh.fallback",
+        stats.mesh_chips = 1
+        stats.mesh_fallbacks = getattr(stats, "mesh_fallbacks", 0) + 1
+        telemetry.span_event_under(trace, "compaction.mesh.fallback",
                                    0, reason="chip-wedged",
                                    error=type(exc).__name__)
+
+    def start_one(s, chunks, covers, dev):
+        """H2D enqueue, then jit dispatch (and the compile, when the
+        shape is met for the first time)."""
+        with telemetry.span_under(trace, "pipeline.upload",
+                                  shard=s) as sp:
+            h = ck.upload_uniform_shard(chunks, covers, device=dev)
+            nb = ck.shard_upload_nbytes(h)
+            stats.h2d_bytes += nb
+            sp.tag(h2d_bytes=nb, front_coded="plens" in h)
+        with telemetry.span_under(trace, "pipeline.dispatch",
+                                  shard=s) as sp:
+            c0, h0 = device_runtime.compiles_now()
+            pending = ck.fused_uniform_shard_start(h, snapshots, bottommost)
+            c1, h1 = device_runtime.compiles_now()
+            sp.tag(compiled=c1 - c0, cache_hit=h1 - h0)
+        return pending
 
     def finish_one(item):
         if item is None:
             return
         ranges, lmap, pending, s, dev, chunks, covers = item
         t0 = time.time()
-        try:
-            o, z, _cx, hc = ck.fused_uniform_shard_finish(pending)
-        except Exception as e:
-            if dev is None or isinstance(e, NotSupported):
-                raise
-            _demote(e)  # re-run this shard on the default device
-            pending = ck.fused_uniform_shard_start(
-                ck.upload_uniform_shard(chunks, covers), snapshots,
-                bottommost,
-            )
-            o, z, _cx, hc = ck.fused_uniform_shard_finish(pending)
-        dwait = time.time() - t0
-        shared.stats.device_wait_usec += int(dwait * 1e6)
-        telemetry.span_event_under(shared.trace, "pipeline.merge_gc",
-                                   dwait * 1e6, shard=s, device=True)
-        if dev is not None:
-            telemetry.span_event_under(shared.trace, "compaction.mesh.shard",
-                                       dwait * 1e6, shard=s, chip=str(dev))
+        nb = sum(int(a.nbytes) for a in pending)
+        # Device compute + D2H, as this thread waits for them.
+        with telemetry.span_under(
+                trace, "pipeline.merge_gc", shard=s, device=True,
+                d2h_bytes=nb, **({} if dev is None else {"chip": str(dev)})):
+            try:
+                o, z, _cx, hc = ck.fused_uniform_shard_finish(pending)
+            except Exception as e:
+                if dev is None or isinstance(e, NotSupported):
+                    raise
+                _demote(e)  # re-run this shard on the default device
+                pending = start_one(s, chunks, covers, None)
+                o, z, _cx, hc = ck.fused_uniform_shard_finish(pending)
+        stats.d2h_bytes += nb
+        stats.device_wait_usec += int((time.time() - t0) * 1e6)
         if hc:
             raise PipelineIneligible("complex groups present")
-        og = lmap[o]
-        for lo, hi in ranges:
-            seq_r, vt_r = _range_seq_vtype(kv, lo, hi)
-            shared.seqs[lo:hi] = seq_r
-            shared.vtypes[lo:hi] = vt_r
-        zg = og[z]
-        shared.trailer_override[zg] = shared.vtypes[zg].astype(np.int64)
-        shared.seqs[zg] = 0
-        _put(outq, prog, og)
+        # Host work between the device and the writer.
+        with telemetry.span_under(trace, "pipeline.unpack", shard=s):
+            og = lmap[o]
+            for lo, hi in ranges:
+                seq_r, vt_r = _range_seq_vtype(kv, lo, hi)
+                shared.seqs[lo:hi] = seq_r
+                shared.vtypes[lo:hi] = vt_r
+            zg = og[z]
+            shared.trailer_override[zg] = shared.vtypes[zg].astype(np.int64)
+            shared.seqs[zg] = 0
+        _put(outq, prog, og, shared)
 
     for s in range(n_shards):
-        prog.wait_shard(s)
+        _wait_scan(prog, shared, s)
         ranges = _shard_ranges(files, s)
         if not ranges:
             pendings.append(None)
         else:
             t0 = time.time()
-            chunks = []
-            covers = None if not frags else []
-            klen = None
-            for lo, hi in ranges:
-                lens = kv.key_lens[lo:hi]
-                if int(lens.min()) != int(lens.max()):
-                    raise PipelineIneligible("non-uniform key length")
-                if klen is None:
-                    klen = int(lens[0])
-                elif klen != int(lens[0]):
-                    raise PipelineIneligible("non-uniform key length")
-                if klen - 8 > max_dev_key:
-                    raise PipelineIneligible("keys exceed the device budget")
-                b0 = int(kv.key_offs[lo])
-                chunks.append(ck.prepare_uniform_chunk(
-                    kv.key_buf[b0:b0 + (hi - lo) * klen], hi - lo, klen,
-                ))
-            if frags:
-                cov = _cover_for_ranges(kv, ranges, frags, snaps)
-                covers = []
-                pos = 0
-                for lo, hi in ranges:
-                    covers.append(cov[pos:pos + (hi - lo)])
-                    pos += hi - lo
+            # Host numpy before the upload: trailers stripped, covers.
+            with telemetry.span_under(trace, "pipeline.chunk_prepare",
+                                      shard=s):
+                chunks, covers = _prepare_shard_chunks(
+                    ck, kv, ranges, frags, snaps, max_dev_key)
             dev = mesh_devs[s % len(mesh_devs)] if mesh_devs else None
             try:
-                pending = ck.fused_uniform_shard_start(
-                    ck.upload_uniform_shard(chunks, covers, device=dev),
-                    snapshots, bottommost,
-                )
+                pending = start_one(s, chunks, covers, dev)
             except Exception as e:
                 if dev is None or isinstance(e, NotSupported):
                     raise
                 _demote(e)
                 dev = None
-                pending = ck.fused_uniform_shard_start(
-                    ck.upload_uniform_shard(chunks, covers), snapshots,
-                    bottommost,
-                )
-            shared.stats.transfer_time_usec += int((time.time() - t0) * 1e6)
+                pending = start_one(s, chunks, covers, None)
+            stats.transfer_time_usec += int((time.time() - t0) * 1e6)
             pendings.append((ranges, _ranges_lmap(ranges), pending, s, dev,
                              chunks, covers))
         # keep the lookahead window in flight (one upload serially,
@@ -615,7 +653,38 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
             finish_one(pendings.pop(0))
     while pendings:
         finish_one(pendings.pop(0))
-    _put(outq, prog, _DONE)
+    _put(outq, prog, _DONE, shared)
+
+
+def _prepare_shard_chunks(ck, kv, ranges, frags, snaps, max_dev_key):
+    """(chunks, covers) of one shard for upload_uniform_shard: one prepared
+    uniform chunk a file range, and the covering-tombstone seqnos when the
+    job has range tombstones."""
+    chunks = []
+    klen = None
+    for lo, hi in ranges:
+        lens = kv.key_lens[lo:hi]
+        if int(lens.min()) != int(lens.max()):
+            raise PipelineIneligible("non-uniform key length")
+        if klen is None:
+            klen = int(lens[0])
+        elif klen != int(lens[0]):
+            raise PipelineIneligible("non-uniform key length")
+        if klen - 8 > max_dev_key:
+            raise PipelineIneligible("keys exceed the device budget")
+        b0 = int(kv.key_offs[lo])
+        chunks.append(ck.prepare_uniform_chunk(
+            kv.key_buf[b0:b0 + (hi - lo) * klen], hi - lo, klen,
+        ))
+    if not frags:
+        return chunks, None
+    cov = _cover_for_ranges(kv, ranges, frags, snaps)
+    covers = []
+    pos = 0
+    for lo, hi in ranges:
+        covers.append(cov[pos:pos + (hi - lo)])
+        pos += hi - lo
+    return chunks, covers
 
 
 class _Shared:
@@ -649,76 +718,79 @@ def run_pipelined(env, dbname, icmp, compaction, table_cache, table_options,
         raise PipelineIneligible("pipeline disabled")
     if len(snapshots) > MAX_SNAPSHOTS:
         raise PipelineIneligible("snapshot count exceeds the device cap")
-    readers = [
-        table_cache.get_reader(f.number) for _, f in compaction.all_inputs()
-    ]
-    kv, files, splitters = _build_plan(readers)
-    stats.input_records = kv.n
-
-    rd = RangeDelAggregator(icmp.user_comparator)
-    for r in readers:
-        for b, e in r.range_del_entries():
-            rd.add(RangeTombstone.from_table_entry(b, e))
-    frags = (list(fragment_tombstones(rd.tombstones(),
-                                      icmp.user_comparator))
-             if not rd.empty() else [])
-    tombs = surviving_tombstone_fragments(
-        rd, snapshots, compaction.bottommost, icmp.user_comparator,
-    )
-
-    shared = _Shared()
-    shared.trailer_override = np.full(kv.n, -1, dtype=np.int64)
-    shared.seqs = np.zeros(kv.n, dtype=np.uint64)
-    shared.vtypes = np.zeros(kv.n, dtype=np.int32)
-    shared.stats = stats
-    stats.pipelined = True
     # The compaction root span lives on the ORCHESTRATING thread; stage
     # workers parent their per-shard spans under this exported handle.
-    shared.trace = telemetry.current_handle()
+    trace = telemetry.current_handle()
+    # Everything before a thread starts: the plan (index walk of every
+    # input, splitters, the preallocated buffers), tombstone fragments,
+    # the arrays compute and writer share.
+    with telemetry.span("pipeline.plan"):
+        readers = [
+            table_cache.get_reader(f.number)
+            for _, f in compaction.all_inputs()
+        ]
+        kv, files, splitters = _build_plan(readers)
+        stats.input_records = kv.n
+
+        rd = RangeDelAggregator(icmp.user_comparator)
+        for r in readers:
+            for b, e in r.range_del_entries():
+                rd.add(RangeTombstone.from_table_entry(b, e))
+        frags = (list(fragment_tombstones(rd.tombstones(),
+                                          icmp.user_comparator))
+                 if not rd.empty() else [])
+        tombs = surviving_tombstone_fragments(
+            rd, snapshots, compaction.bottommost, icmp.user_comparator,
+        )
+
+        shared = _Shared()
+        shared.trailer_override = np.full(kv.n, -1, dtype=np.int64)
+        shared.seqs = np.zeros(kv.n, dtype=np.uint64)
+        shared.vtypes = np.zeros(kv.n, dtype=np.int32)
+        shared.stats = stats
+        shared.trace = trace
+    stats.pipelined = True
 
     prog = _Progress(len(files))
     outq: Queue = Queue(maxsize=4)
     stats_mu = ccy.Lock("pipeline.run_pipelined.stats_mu")
 
     t_scan0 = time.time()
-    rthreads = [
-        ccy.spawn(f"pipeline-scan-{fi}", _scan_file, start=False,
-                  args=(fi, fp, kv, prog, splitters, stats,
-                        stats_mu, shared.trace))
-        for fi, fp in enumerate(files)
-    ]
-    from toplingdb_tpu.ops.device_compaction import _host_sort
+    with telemetry.span("pipeline.spawn", readers=len(files)):
+        rthreads = [
+            ccy.spawn(f"pipeline-scan-{fi}", _scan_file, start=False,
+                      args=(fi, fp, kv, prog, splitters, stats,
+                            stats_mu, shared.trace))
+            for fi, fp in enumerate(files)
+        ]
+        from toplingdb_tpu.ops.device_compaction import _host_sort
 
-    compute_fn = _host_compute if _host_sort() else _device_compute
-    cthread = ccy.spawn(
-        "pipeline-compute", _compute_guard, start=False,
-        args=(compute_fn, kv, files, splitters, prog, outq, shared,
-              snapshots, compaction.bottommost, frags, max_dev_key),
-    )
-    for t in rthreads:
-        t.start()
-    cthread.start()
+        compute_fn = _host_compute if _host_sort() else _device_compute
+        cthread = ccy.spawn(
+            "pipeline-compute", _compute_guard, start=False,
+            args=(compute_fn, kv, files, splitters, prog, outq, shared,
+                  snapshots, compaction.bottommost, frags, max_dev_key),
+        )
+        for t in rthreads:
+            t.start()
+        cthread.start()
 
     def chunk_stream():
-        chunk = 0
-        t_resumed = None  # when the writer got control back after a yield
+        # The writer (write_tables_columnar, on this thread) asks for the
+        # next chunk once it has consumed the last: each consumed chunk is
+        # its span `pipeline.encode_write`, each wait here `pipeline.stall`.
         while True:
-            t0 = time.time()
-            if t_resumed is not None:
-                # Time since the previous chunk was handed over = that
-                # chunk's encode+write stage (the writer consumed it
-                # before asking for the next one).
-                telemetry.span_event_under(
-                    shared.trace, "pipeline.encode_write",
-                    (t0 - t_resumed) * 1e6, chunk=chunk)
-                chunk += 1
-            item = outq.get()
-            stats.pipeline_stall_usec += int((time.time() - t0) * 1e6)
+            try:
+                item = outq.get_nowait()
+            except Empty:
+                t0 = time.time()
+                with telemetry.span_under(trace, "pipeline.stall"):
+                    item = outq.get()
+                stats.pipeline_stall_usec += int((time.time() - t0) * 1e6)
             if item is _DONE:
                 return
             if isinstance(item, _Err):
                 raise item.exc
-            t_resumed = time.time()
             yield item
 
     writer = write_tables_columnar
@@ -742,9 +814,10 @@ def run_pipelined(env, dbname, icmp, compaction, table_cache, table_options,
         raise
     stats.encode_write_usec = max(0, int(
         (time.time() - t_wr) * 1e6) - stats.pipeline_stall_usec)
-    for t in rthreads:
-        t.join()
-    cthread.join()
+    with telemetry.span("pipeline.join"):
+        for t in rthreads:
+            t.join()
+        cthread.join()
     if prog.err is not None:
         raise prog.err
     stats.input_scan_usec = int(

@@ -26,10 +26,25 @@ when the current thread carries no sampled trace.
 
 Chrome trace-event JSON export (`chrome_trace`) renders in chrome://tracing
 or Perfetto; the SidePluginRepo serves it at /traces/<db>/<trace_id>.
+
+Two process-wide hooks, both off until a process asks for them:
+
+  mirror      set_mirror(factory): every REAL span (one entered and left
+              where the work happens — roots, span(), span_under()) also
+              enters and leaves `factory(name, **tags)` on the span's own
+              thread. ops/device_runtime.py installs
+              jax.profiler.TraceAnnotation, which puts the program's spans
+              into the profiler's trace beside the device's ops, on one
+              clock. Back-dated span_event*() never reach it. This module
+              itself imports no JAX: the DB process never loads it.
+  gc watch    watch_gc(): a `runtime.gc_pause` span for every collection
+              of generation >= 1, under whatever span the collector
+              interrupted, and process totals for the job counters.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
 import os
 import threading
@@ -41,13 +56,24 @@ from collections import OrderedDict, deque
 
 _tls = threading.local()
 
+# The process-wide mirror of real spans (see the module docstring).
+_mirror = None
+
+
+def set_mirror(factory) -> None:
+    """`factory(name, **tags)` returns a context manager; None turns the
+    mirror off. Spans already open keep the mirror they entered."""
+    global _mirror
+    _mirror = factory
+
 
 class Span:
     """One timed region of one trace. `start_us` is the offset from the
     trace root's start (µs); `dur_us` is filled at finish."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start_us",
-                 "dur_us", "proc", "tags", "_t0", "_trace", "_tracer")
+                 "dur_us", "proc", "tags", "tid", "_t0", "_trace", "_tracer",
+                 "_mirror", "_open")
 
     def __init__(self, name, trace_id, span_id, parent_id, proc, tags):
         self.name = name
@@ -58,12 +84,18 @@ class Span:
         self.tags = tags
         self.start_us = 0
         self.dur_us = 0
+        self.tid = threading.get_ident()  # the thread it was recorded on
         self._t0 = 0.0
         self._trace = None
         self._tracer = None
+        self._mirror = None
+        self._open = False
 
     def tag(self, **kw) -> "Span":
         self.tags.update(kw)
+        m = self._mirror
+        if m is not None and hasattr(m, "set_metadata"):
+            m.set_metadata(**kw)  # tags that came after the span was entered
         return self
 
     def finish(self) -> None:
@@ -82,11 +114,14 @@ class Span:
         return False
 
     def to_dict(self) -> dict:
+        dur = self.dur_us
+        if self._open:  # exported while it runs: its duration so far
+            dur = int((time.monotonic() - self._t0) * 1e6)
         return {
             "name": self.name, "trace_id": self.trace_id,
             "span_id": self.span_id, "parent_id": self.parent_id,
-            "start_us": self.start_us, "dur_us": self.dur_us,
-            "proc": self.proc, "tags": self.tags,
+            "start_us": self.start_us, "dur_us": dur,
+            "proc": self.proc, "tid": self.tid, "tags": self.tags,
         }
 
     @staticmethod
@@ -96,6 +131,7 @@ class Span:
                  d.get("proc", "remote"), dict(d.get("tags") or {}))
         s.start_us = int(d.get("start_us", 0))
         s.dur_us = int(d.get("dur_us", 0))
+        s.tid = int(d.get("tid", 0))
         return s
 
 
@@ -228,6 +264,7 @@ class Tracer:
         tr = Trace(trace_id, sp, int(time.time() * 1e6), now)
         sp._trace = tr
         sp._tracer = self
+        _enter_real(sp)
         # Lock-free registration (dict set/del are GIL-atomic): the lock
         # is reserved for ring retirement, keeping a sampled op cheap.
         self.traces_started += 1
@@ -252,7 +289,11 @@ class Tracer:
 
     # -- child spans ---------------------------------------------------
 
-    def _child(self, parent: Span, name: str, tags: dict) -> Span:
+    def _child(self, parent: Span, name: str, tags: dict,
+               real: bool = True) -> Span:
+        """`real`: entered here and left at finish() (mirrored); False for
+        the back-dated span_event*(), whose duration was measured
+        elsewhere."""
         trace = parent._trace
         sp = Span(name, parent.trace_id, next(self._span_ids),
                   parent.span_id, self.proc, tags)
@@ -262,18 +303,30 @@ class Tracer:
         sp._trace = trace
         sp._tracer = self
         trace.spans.append(sp)  # list.append: GIL-atomic
+        if real:
+            _enter_real(sp)
         return sp
 
     def _finish_span(self, sp: Span) -> None:
-        sp.dur_us = int((time.monotonic() - sp._t0) * 1e6)
+        if not sp._open:
+            return  # finished before (an explicit finish() inside a with)
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] is sp:
             stack.pop()
-        elif stack is not None:
-            try:
-                stack.remove(sp)
-            except ValueError:
-                pass
+        elif stack and sp in stack:
+            # Spans opened under it on this thread and never left (an
+            # exception passed them by) end with it.
+            i = stack.index(sp)
+            above = stack[i + 1:]
+            del stack[i:]
+            for child in reversed(above):
+                self._finish_span(child)
+        sp._open = False
+        m = sp._mirror
+        if m is not None:
+            sp._mirror = None
+            m.__exit__(None, None, None)
+        sp.dur_us = int((time.monotonic() - sp._t0) * 1e6)
         trace = sp._trace
         if trace is not None and trace.root is sp:
             if self.slow_usec and sp.dur_us >= self.slow_usec:
@@ -360,7 +413,7 @@ class Tracer:
             events.append({
                 "name": s.name, "ph": "X", "ts": s.start_us,
                 "dur": max(1, s.dur_us), "pid": s.proc,
-                "tid": s.proc, "args": dict(s.tags),
+                "tid": s.tid or s.proc, "args": dict(s.tags),
             })
         return {
             "traceEvents": events, "displayTimeUnit": "ms",
@@ -387,6 +440,16 @@ class Tracer:
 # Module-level helpers: operate on the CALLING THREAD's active span, so
 # instrumentation deep in the table/ops layers needs no tracer plumbing.
 # ---------------------------------------------------------------------------
+
+
+def _enter_real(sp: Span) -> None:
+    """A real span starts on the calling thread: mark it open and enter
+    the process's mirror, if one is set."""
+    sp._open = True
+    if _mirror is not None:
+        m = _mirror(sp.name, **sp.tags)
+        m.__enter__()
+        sp._mirror = m
 
 
 def current_span() -> Span | None:
@@ -421,7 +484,7 @@ def span_event(name: str, dur_us, **tags) -> None:
     parent = stack[-1]
     # _child pushes nothing onto the tls stack; just close the span out,
     # back-dating its start so the waterfall shows where the time went.
-    sp = parent._tracer._child(parent, name, tags)
+    sp = parent._tracer._child(parent, name, tags, real=False)
     sp.start_us = max(0, sp.start_us - int(dur_us))
     sp.dur_us = int(dur_us)
 
@@ -435,19 +498,62 @@ def current_handle():
 
 def span_under(parent: Span | None, name: str, **tags):
     """Cross-thread child span under an exported handle (NOT the calling
-    thread's tls). NOOP_SPAN when the handle is None."""
+    thread's tls). It becomes the calling thread's active span until it
+    finishes there, so span() below it nests. NOOP_SPAN when the handle
+    is None."""
     if parent is None:
         return NOOP_SPAN
-    return parent._tracer._child(parent, name, tags)
+    sp = parent._tracer._child(parent, name, tags)
+    stack = getattr(_tls, "stack", None)
+    if stack is None:
+        stack = _tls.stack = []
+    stack.append(sp)
+    return sp
 
 
 def span_event_under(parent: Span | None, name: str, dur_us,
                      **tags) -> None:
     if parent is None:
         return
-    sp = parent._tracer._child(parent, name, tags)
+    sp = parent._tracer._child(parent, name, tags, real=False)
     sp.start_us = max(0, sp.start_us - int(dur_us))
     sp.dur_us = int(dur_us)
+
+
+# -- the Python collector ------------------------------------------------
+# gc callbacks run on the thread that triggered the collection, with the
+# GIL held from "start" to "stop": plain module state is enough.
+_gc = {"t0": 0.0, "span": None, "pause_us": 0, "collections": 0}
+
+
+def _on_gc(phase, info) -> None:
+    if info.get("generation", 0) < 1:
+        return  # generation 0 runs all the time and takes microseconds
+    if phase == "start":
+        _gc["t0"] = time.monotonic()
+        _gc["span"] = span("runtime.gc_pause",
+                           generation=info.get("generation", 0))
+    else:
+        _gc["pause_us"] += int((time.monotonic() - _gc["t0"]) * 1e6)
+        _gc["collections"] += 1
+        sp, _gc["span"] = _gc["span"], None
+        if sp is not None:
+            sp.tag(collected=info.get("collected", 0))
+            sp.finish()
+
+
+def watch_gc() -> None:
+    """From now on, in this process: a `runtime.gc_pause` span for every
+    collection of generation >= 1 under the span it interrupted, and the
+    totals gc_totals() returns. Idempotent."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_totals() -> tuple[int, int]:
+    """(microseconds inside collections of generation >= 1, their count)
+    since watch_gc(); (0, 0) before it."""
+    return _gc["pause_us"], _gc["collections"]
 
 
 def inject() -> dict | None:
