@@ -688,41 +688,104 @@ def test_device_columnar_complex_host_twin_parity(tmp_path, monkeypatch):
         tmp_path, monkeypatch, 0)
 
 
+def _uniform_chunks(rng, L, n_chunks, key_of, vtypes=(ValueType.VALUE,),
+                    chunk_rows=None):
+    """Presorted runs of internal keys of one length L, as the pipeline
+    hands them to the upload: (prepared chunks, raw key bytes, rows)."""
+    import numpy as np
+
+    from toplingdb_tpu.ops import compaction_kernels as ck
+
+    raw, chunks = bytearray(), []
+    seq = 1
+    for _ in range(n_chunks):
+        n = chunk_rows or rng.randrange(5, 200)
+        buf = bytearray()
+        for k in sorted(key_of(rng) for _ in range(n)):
+            buf += make_internal_key(k, seq, rng.choice(vtypes))
+            seq += 1
+        chunks.append(ck.prepare_uniform_chunk(
+            np.frombuffer(bytes(buf), np.uint8), n, L))
+        raw += buf
+    return chunks, np.frombuffer(bytes(raw), np.uint8), seq - 1
+
+
 @pytest.mark.parametrize("seed", [31, 32, 33])
-def test_front_coded_upload_parity(seed):
-    """Front-coded uploads (prefix lengths + suffixes, decoded on device
-    with a cummax scan) must produce IDENTICAL survivor streams to the
-    plain full-key upload."""
+def test_plain_upload_matches_host_oracle(seed):
+    """The shard's keys go up as they are; the fused program's survivors,
+    zero-seq flags and complex-group flags equal the host twin's."""
     import numpy as np
 
     from toplingdb_tpu.ops import compaction_kernels as ck
 
     rng = random.Random(seed)
     L = rng.choice([12, 16, 24])  # internal key len (uk_len = L - 8)
-    chunks_raw = []
-    seq = 1
-    for _ in range(rng.randrange(1, 4)):  # chunks = sorted runs
-        n = rng.randrange(5, 200)
-        keys = sorted(
-            b"k%0*d" % (L - 9, rng.randrange(100)) for _ in range(n)
-        )
-        buf = bytearray()
-        for k in keys:
-            buf += make_internal_key(k, seq, ValueType.VALUE)
-            seq += 1
-        chunks_raw.append((np.frombuffer(bytes(buf), np.uint8), n, L))
-    chunks = [ck.prepare_uniform_chunk(b, n, l) for b, n, l in chunks_raw]
-    snaps = sorted(rng.sample(range(1, seq + 1), rng.randrange(0, 3)))
-    outs = []
-    for fc in (False, True):
-        h = ck.upload_uniform_shard(chunks, front_code=fc)
-        assert ("plens" in h) == fc
-        pending = ck.fused_uniform_shard_start(h, snaps, True)
-        outs.append(ck.fused_uniform_shard_finish(pending))
-    o0, z0, c0, h0 = outs[0]
-    o1, z1, c1, h1 = outs[1]
-    assert np.array_equal(o0, o1), "front-coded survivor order differs"
-    assert np.array_equal(z0, z1) and np.array_equal(c0, c1) and h0 == h1
+    chunks, raw, rows = _uniform_chunks(
+        rng, L, rng.randrange(1, 4),
+        lambda r: b"k%0*d" % (L - 9, r.randrange(100)),
+        vtypes=(ValueType.VALUE, ValueType.VALUE, ValueType.DELETION,
+                ValueType.MERGE))
+    snaps = sorted(rng.sample(range(1, rows + 2), rng.randrange(0, 3)))
+    h = ck.upload_uniform_shard(chunks)
+    assert h["ukb"].shape == (h["pkb"].shape[0] * (L - 8),)
+    got = ck.fused_uniform_shard_finish(
+        ck.fused_uniform_shard_start(h, snaps, True))
+    want = ck.host_fused_full(
+        raw, np.arange(rows, dtype=np.int64) * L,
+        np.full(rows, L, dtype=np.int64), L - 8, snaps, True)
+    assert np.array_equal(got[0], want[0]), "survivor order differs"
+    assert np.array_equal(got[1], want[1]), "zero-seq flags differ"
+    assert np.array_equal(got[2], want[2]), "complex flags differ"
+    assert got[3] == want[3] and bool(got[2].any()) == got[3]
+
+
+def test_one_fused_program_a_row_bucket():
+    """The program's shapes come from the row bucket and the key length,
+    never from the keys: a shard of long shared prefixes and a shard of
+    none, same bucket, compile once (the front-coded upload's suffix
+    buffer made a program a power of two of its data-dependent length)."""
+    from toplingdb_tpu.ops import compaction_kernels as ck
+
+    rng = random.Random(7)
+    L = 29  # uk_len 21: a shape no other test brings
+    shared, _, _ = _uniform_chunks(
+        rng, L, 2, lambda r: b"p" * 18 + b"%03d" % r.randrange(1000),
+        chunk_rows=100)
+    distinct, _, _ = _uniform_chunks(
+        rng, L, 2, lambda r: bytes(r.randrange(256) for _ in range(21)),
+        chunk_rows=100)
+    before = ck._fused_uniform_shard_impl._cache_size()
+    sizes = []
+    for chunks in (shared, distinct):
+        h = ck.upload_uniform_shard(chunks)
+        assert h["pkb"].shape == (256,)
+        ck.fused_uniform_shard_finish(
+            ck.fused_uniform_shard_start(h, [], False))
+        sizes.append(ck._fused_uniform_shard_impl._cache_size())
+    assert sizes == [before + 1, before + 1]
+
+
+@pytest.mark.parametrize("with_covers", [False, True])
+def test_shard_upload_bytes_are_rows_times_key_and_word(with_covers):
+    """What goes up: p x (uk_len + 4) bytes of keys and trailer words, the
+    three chunk tables, and two u32 planes when tombstones cover rows."""
+    import numpy as np
+
+    from toplingdb_tpu.ops import compaction_kernels as ck
+
+    rng = random.Random(11)
+    L = 16
+    chunks, _, rows = _uniform_chunks(
+        rng, L, 3, lambda r: b"k%07d" % r.randrange(10 ** 6))
+    covers = None
+    if with_covers:
+        covers = [np.zeros(c[3], dtype=np.uint64) for c in chunks]
+        covers[1][0] = 5
+    h = ck.upload_uniform_shard(chunks, covers)
+    p = ck._next_pow2(rows)
+    want = p * (L - 8 + 4) + 3 * 16 * 4 + (2 * p * 4 if with_covers else 0)
+    assert ck.shard_upload_nbytes(h) == want
+    assert (h["tomb_hi"] is not None) == with_covers
 
 
 def test_host_merge_runs_matches_full_sort():

@@ -199,7 +199,6 @@ def test_unsampled_job_leaves_its_whole_span_set_in_the_ring(
     up = [s for s in trace.spans if s.name == "pipeline.upload"]
     assert sum(s.tags["h2d_bytes"] for s in up) == st["h2d_bytes"]
     assert sum(s.tags["d2h_bytes"] for s in computes) == st["d2h_bytes"]
-    assert {s.tags["front_coded"] for s in up} == {True}
     for key in ("gc_pause_usec", "gc_collections", "stall_wait_scan_usec",
                 "stall_wait_writer_usec"):
         assert st[key] >= 0
@@ -446,12 +445,12 @@ def test_program_spans_are_on_the_profilers_clock(tmp_path, pipelined):
     assert job[0] not in {s[0] for s in spans["pipeline.scan"]}
     assert job[0] not in {s[0] for s in spans["pipeline.merge_gc"]}
     up = spans["pipeline.upload"][0][3]
-    assert up["h2d_bytes"] > 0 and "front_coded" in up
+    assert up["h2d_bytes"] > 0
 
 
 def test_fused_shard_program_names_its_steps():
     """`jax.named_scope` around each step of the fused shard program: the
-    lowered text carries all six, so the device's trace can rank device
+    lowered text carries all five, so the device's trace can rank device
     time by step and not by XLA's fusion numbers."""
     import re
 
@@ -459,17 +458,16 @@ def test_fused_shard_program_names_its_steps():
 
     p = 1024
     snap_hi, snap_lo = ck._split_snapshots([])
-    lowered = ck._fused_uniform_shard_fc_impl.lower(
-        np.zeros(p, np.uint8), np.zeros(2048, np.uint8),
-        np.zeros(p, np.uint32), np.zeros(16, np.int32),
-        np.zeros(16, np.uint32), np.zeros(16, np.uint32),
+    lowered = ck._fused_uniform_shard_impl.lower(
+        np.zeros(p * 8, np.uint8), np.zeros(p, np.uint32),
+        np.zeros(16, np.int32), np.zeros(16, np.uint32),
+        np.zeros(16, np.uint32),
         np.zeros(1, np.uint32), np.zeros(1, np.uint32), snap_hi, snap_lo,
         np.int32(1000), 2, 8, np.bool_(True), False)
     text = lowered.as_text(debug_info=True)
     scopes = set(re.findall(
-        r'"jit\(_fused_uniform_shard_fc_impl\)/(\w+)[/"]', text))
-    assert {"fc_decode", "encode_words", "sort", "gc_mask", "compact",
-            "pack"} <= scopes
+        r'"jit\(_fused_uniform_shard_impl\)/(\w+)[/"]', text))
+    assert {"encode_words", "sort", "gc_mask", "compact", "pack"} <= scopes
 
 
 def test_gc_watch_records_a_pause_under_what_it_interrupted():

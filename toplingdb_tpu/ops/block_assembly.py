@@ -56,13 +56,13 @@ def _log2ceil(n: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "num_key_words", "uk_len", "bottommost", "has_tombs", "front_code",
+    "num_key_words", "uk_len", "bottommost", "has_tombs",
     "R", "B", "max_rec", "ubp", "nbp",
 ))
-def _assemble_blocks_impl(ukb, plens, sfx, pkb, starts, min_his, min_los,
+def _assemble_blocks_impl(ukb, pkb, starts, min_his, min_los,
                           vlens, vflat, tomb_hi, tomb_lo, snap_hi, snap_lo,
                           total, num_key_words, uk_len, bottommost,
-                          has_tombs, front_code, R, B, max_rec, ubp, nbp):
+                          has_tombs, R, B, max_rec, ubp, nbp):
     """Sort + GC + FULL block assembly in one device program.
 
     Returns (out u8[ubp], meta i32[10], bcounts i32[nbp], bpayload i32[nbp],
@@ -77,17 +77,13 @@ def _assemble_blocks_impl(ukb, plens, sfx, pkb, starts, min_his, min_los,
     """
     u32 = jnp.uint32
     i32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
-    if front_code:
-        kb = ck._decode_front_coded(plens, sfx, uk_len)
-    else:
-        p0 = pkb.shape[0]
-        kb = ukb.reshape(p0, uk_len)
+    p = pkb.shape[0]
+    kb = ukb.reshape(p, uk_len)
     core = ck._uniform_shard_core(
         kb, pkb, starts, min_his, min_los, tomb_hi, tomb_lo,
         snap_hi, snap_lo, total, num_key_words, uk_len, bottommost,
         has_tombs,
     )
-    p = pkb.shape[0]
     iota = jnp.arange(p, dtype=jnp.int32)
     K = uk_len + 8
 
@@ -371,16 +367,13 @@ def run_block_assembly(env, dbname, icmp, kv, shard, cover, snapshots,
     has_tombs = h["tomb_hi"] is not None
     t_hi = h["tomb_hi"] if has_tombs else np.zeros(1, dtype=np.uint32)
     t_lo = h["tomb_lo"] if has_tombs else np.zeros(1, dtype=np.uint32)
-    front_code = "plens" in h
-    dummy = np.zeros(1, dtype=np.uint8)
     w = (max(uk_len, 4) + 3) // 4
     (out, meta, bcnt, bpayload, bfirst, blast,
      surv_bitmap) = _assemble_blocks_impl(
-        h.get("ukb", dummy), h.get("plens", dummy), h.get("sfx", dummy),
-        h["pkb"], h["starts"], h["min_his"], h["min_los"],
+        h["ukb"], h["pkb"], h["starts"], h["min_his"], h["min_los"],
         jax.device_put(vlens), jax.device_put(vf), t_hi, t_lo,
         snap_hi, snap_lo, np.int32(h["total"]), w, uk_len,
-        bool(bottommost), has_tombs, front_code, R, B, max_rec, ubp, nbp,
+        bool(bottommost), has_tombs, R, B, max_rec, ubp, nbp,
     )
     for a in (meta, bcnt, bpayload, bfirst, blast, surv_bitmap):
         if hasattr(a, "copy_to_host_async"):

@@ -724,57 +724,6 @@ def _uniform_shard_core(kb, pkb, starts, min_his, min_los, tomb_hi, tomb_lo,
     }
 
 
-def _uniform_shard_tail(kb, pkb, starts, min_his, min_los, tomb_hi, tomb_lo,
-                        snap_hi, snap_lo, total, num_key_words, uk_len,
-                        bottommost, has_tombs):
-    """Packed-download tail: [p, uk_len] u8 key matrix in → packed survivor
-    byte-planes out (see _fused_uniform_shard_impl for the contract)."""
-    u32 = jnp.uint32
-    core = _uniform_shard_core(
-        kb, pkb, starts, min_his, min_los, tomb_hi, tomb_lo,
-        snap_hi, snap_lo, total, num_key_words, uk_len, bottommost,
-        has_tombs,
-    )
-    take = core["take"]
-    with jax.named_scope("compact"):
-        po = (
-            jax.lax.bitcast_convert_type(core["perm"][take], u32)
-            | (core["zero_seq"][take].astype(u32) << 23)
-            | (core["host_resolve"][take].astype(u32) << 22)
-        )
-    with jax.named_scope("pack"):
-        packed_bytes = jnp.concatenate([
-            (po & u32(0xFF)).astype(jnp.uint8),
-            ((po >> 8) & u32(0xFF)).astype(jnp.uint8),
-            ((po >> 16) & u32(0xFF)).astype(jnp.uint8),
-        ])
-        meta = jnp.stack([
-            jnp.sum(core["out"].astype(jnp.int32)),
-            jnp.any(core["host_resolve"]).astype(jnp.int32),
-        ])
-    return packed_bytes, meta
-
-
-def _decode_front_coded(plens, sfx, uk_len):
-    """Reconstruct the [p, uk_len] u8 key matrix from front-coded uploads
-    (shared by the packed-download and block-assembly kernels)."""
-    with jax.named_scope("fc_decode"):
-        p = plens.shape[0]
-        pl = plens.astype(jnp.int32)
-        sfx_len = jnp.int32(uk_len) - pl
-        sfx_off = jnp.cumsum(sfx_len) - sfx_len
-        iota = jnp.arange(p, dtype=jnp.int32)
-        col = jnp.arange(uk_len, dtype=jnp.int32)[None, :]
-        # Column j of row i inherits from the LAST row i' <= i with
-        # plen[i'] <= j; chunk starts have plen 0, so inheritance never
-        # crosses a chunk boundary.
-        contrib = jnp.where(pl[:, None] <= col, iota[:, None],
-                            jnp.int32(-1))
-        src = jax.lax.cummax(contrib, axis=0)
-        pos = sfx_off[src] + (col - pl[src])
-        return sfx[jnp.clip(pos, 0, sfx.shape[0] - 1)]
-
-
 @functools.partial(
     jax.jit, static_argnames=("num_key_words", "uk_len", "has_tombs"),
 )
@@ -795,35 +744,31 @@ def _fused_uniform_shard_impl(ukb, pkb, starts, min_his, min_los,
     bit 22 = complex-group flag) — 3/4 the download of int32 orders — plus
     [count, has_complex]. With has_tombs, tomb_hi/lo carry each local row's
     max covering range-tombstone seqno words."""
+    u32 = jnp.uint32
     p = pkb.shape[0]
-    kb = ukb.reshape(p, uk_len)
-    return _uniform_shard_tail(
-        kb, pkb, starts, min_his, min_los, tomb_hi, tomb_lo,
-        snap_hi, snap_lo, total, num_key_words, uk_len, bottommost,
+    core = _uniform_shard_core(
+        ukb.reshape(p, uk_len), pkb, starts, min_his, min_los, tomb_hi,
+        tomb_lo, snap_hi, snap_lo, total, num_key_words, uk_len, bottommost,
         has_tombs,
     )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("num_key_words", "uk_len", "has_tombs"),
-)
-def _fused_uniform_shard_fc_impl(plens, sfx, pkb, starts, min_his, min_los,
-                                 tomb_hi, tomb_lo, snap_hi, snap_lo, total,
-                                 num_key_words, uk_len, bottommost,
-                                 has_tombs):
-    """Front-coded variant of _fused_uniform_shard_impl: instead of the full
-    [p, uk_len] key bytes, the host uploads per-row shared-prefix lengths
-    (`plens` u8, 0 at chunk starts) + the concatenated suffix bytes
-    (`sfx`) — typically a fraction of the full key bytes for sorted runs.
-    The device reconstructs the key matrix with a cummax scan (source row
-    of each inherited byte column) + one gather, then runs the shared
-    tail. Output is bit-identical to the plain upload (parity-tested)."""
-    kb = _decode_front_coded(plens, sfx, uk_len)
-    return _uniform_shard_tail(
-        kb, pkb, starts, min_his, min_los, tomb_hi, tomb_lo,
-        snap_hi, snap_lo, total, num_key_words, uk_len, bottommost,
-        has_tombs,
-    )
+    take = core["take"]
+    with jax.named_scope("compact"):
+        po = (
+            jax.lax.bitcast_convert_type(core["perm"][take], u32)
+            | (core["zero_seq"][take].astype(u32) << 23)
+            | (core["host_resolve"][take].astype(u32) << 22)
+        )
+    with jax.named_scope("pack"):
+        packed_bytes = jnp.concatenate([
+            (po & u32(0xFF)).astype(jnp.uint8),
+            ((po >> 8) & u32(0xFF)).astype(jnp.uint8),
+            ((po >> 16) & u32(0xFF)).astype(jnp.uint8),
+        ])
+        meta = jnp.stack([
+            jnp.sum(core["out"].astype(jnp.int32)),
+            jnp.any(core["host_resolve"]).astype(jnp.int32),
+        ])
+    return packed_bytes, meta
 
 
 def prepare_uniform_chunk(key_buf: np.ndarray, n: int, key_len: int):
@@ -848,35 +793,15 @@ def prepare_uniform_chunk(key_buf: np.ndarray, n: int, key_len: int):
     return (uk, pk32, min_seq, n, uk_len)
 
 
-# Front-coded uploads: on for uniform keys up to this many bytes unless
-# TPULSM_FRONT_CODE=0. The decode materializes [p, uk_len] int32
-# intermediates (cummax source rows + gather positions), so also cap the
-# total element count — beyond it the transient HBM spike would outweigh
-# the transfer win.
-_FC_MAX_UK_LEN = 32
-_FC_MAX_ELEMS = 64 << 20  # ~256 MB of int32 intermediates
-
-
-def _want_front_code(uk_len: int, total_rows: int) -> bool:
-    import os
-
-    if os.environ.get("TPULSM_FRONT_CODE", "1") == "0":
-        return False
-    return (0 < uk_len <= _FC_MAX_UK_LEN
-            and _next_pow2(max(1, total_rows)) * uk_len <= _FC_MAX_ELEMS)
-
-
-def upload_uniform_shard(chunks, covers=None, front_code=None, device=None):
+def upload_uniform_shard(chunks, covers=None, device=None):
     """Pack one shard's prepared chunks (prepare_uniform_chunk outputs, in
     row order) into device buffers, pad rows to the next power of two, and
     START the host→device transfers (device_put is async): two bulk
-    transfers per shard, not two per chunk.
+    transfers per shard, not two per chunk. The user-key bytes go up as
+    they are, so a shard's program depends on its row bucket and key
+    length only, never on the keys.
     `covers`: optional per-chunk uint64 max-covering-tombstone arrays
     (None = tombstone-free); uploaded as two extra u32 planes.
-    `front_code` (None = auto): upload per-row shared-prefix lengths +
-    suffix bytes instead of full key bytes — sorted runs share long
-    prefixes, so this cuts the dominant H2D transfer; the device
-    reconstructs the exact key matrix (bit-identical results).
     `device` (None = backend default): COMMIT the shard's buffers to one
     specific chip — the fused program carries no pin of its own, so the
     committed inputs decide where it runs (ops/mesh_compaction.py places
@@ -888,11 +813,8 @@ def upload_uniform_shard(chunks, covers=None, front_code=None, device=None):
         raise NotSupported(
             f"shard rows {total} exceed the 24-bit packed-order budget"
         )
-    if front_code is None:
-        front_code = _want_front_code(uk_len, total)
-    if uk_len > 255:
-        front_code = False  # plens is uint8; a longer prefix would wrap
     p = _next_pow2(max(1, total))
+    ukb = np.zeros(p * uk_len, dtype=np.uint8)
     pkb = np.zeros(p, dtype=np.uint32)
     has_tombs = covers is not None and any(
         c is not None and np.any(c) for c in covers
@@ -900,24 +822,9 @@ def upload_uniform_shard(chunks, covers=None, front_code=None, device=None):
     if has_tombs:
         tomb_hi = np.zeros(p, dtype=np.uint32)
         tomb_lo = np.zeros(p, dtype=np.uint32)
-    if front_code:
-        plens = np.zeros(p, dtype=np.uint8)
-        sfx_parts = []
-    else:
-        ukb = np.zeros(p * uk_len, dtype=np.uint8)
     pos = 0
     for ci, (uk, pk32, _mn, n, _l) in enumerate(chunks):
-        if front_code and n:
-            kb2 = uk.reshape(n, uk_len)
-            eq = kb2[1:] == kb2[:-1]
-            pl = np.zeros(n, dtype=np.int32)
-            if n > 1:
-                all_eq = eq.all(axis=1)
-                pl[1:] = np.where(all_eq, uk_len, np.argmin(eq, axis=1))
-            plens[pos:pos + n] = pl.astype(np.uint8)
-            sfx_parts.append(kb2[np.arange(uk_len)[None, :] >= pl[:, None]])
-        elif not front_code:
-            ukb[pos * uk_len:(pos + n) * uk_len] = uk
+        ukb[pos * uk_len:(pos + n) * uk_len] = uk
         pkb[pos:pos + n] = pk32
         if has_tombs and covers[ci] is not None:
             cv = covers[ci]
@@ -943,26 +850,14 @@ def upload_uniform_shard(chunks, covers=None, front_code=None, device=None):
         return jax.device_put(x, device) if device is not None \
             else jax.device_put(x)
 
-    h = {
-        "pkb": put(pkb), "total": total,
+    return {
+        "ukb": put(ukb), "pkb": put(pkb), "total": total,
         "starts": put(starts),
         "min_his": put(min_his),
         "min_los": put(min_los), "uk_len": uk_len,
         "tomb_hi": put(tomb_hi) if has_tombs else None,
         "tomb_lo": put(tomb_lo) if has_tombs else None,
     }
-    if front_code:
-        sfx = (np.concatenate(sfx_parts) if sfx_parts
-               else np.zeros(0, dtype=np.uint8))
-        # Pad-row columns all "contribute themselves" (plen 0), so the
-        # decode's clipped gather needs only a pow2 bucket, not real bytes.
-        sb = np.zeros(_next_pow2(max(8, len(sfx))), dtype=np.uint8)
-        sb[: len(sfx)] = sfx
-        h["plens"] = put(plens)
-        h["sfx"] = put(sb)
-    else:
-        h["ukb"] = put(ukb)
-    return h
 
 
 def shard_upload_nbytes(handle) -> int:
@@ -986,18 +881,11 @@ def fused_uniform_shard_start(handle, snapshots: list[int], bottommost: bool):
     has_tombs = h["tomb_hi"] is not None
     t_hi = h["tomb_hi"] if has_tombs else np.zeros(1, dtype=np.uint32)
     t_lo = h["tomb_lo"] if has_tombs else np.zeros(1, dtype=np.uint32)
-    if "plens" in h:
-        out = _fused_uniform_shard_fc_impl(
-            h["plens"], h["sfx"], h["pkb"], h["starts"], h["min_his"],
-            h["min_los"], t_hi, t_lo, snap_hi, snap_lo,
-            np.int32(h["total"]), w, uk_len, np.bool_(bottommost), has_tombs,
-        )
-    else:
-        out = _fused_uniform_shard_impl(
-            h["ukb"], h["pkb"], h["starts"], h["min_his"], h["min_los"],
-            t_hi, t_lo, snap_hi, snap_lo,
-            np.int32(h["total"]), w, uk_len, np.bool_(bottommost), has_tombs,
-        )
+    out = _fused_uniform_shard_impl(
+        h["ukb"], h["pkb"], h["starts"], h["min_his"], h["min_los"],
+        t_hi, t_lo, snap_hi, snap_lo,
+        np.int32(h["total"]), w, uk_len, np.bool_(bottommost), has_tombs,
+    )
     for a in out:
         if hasattr(a, "copy_to_host_async"):
             a.copy_to_host_async()

@@ -580,7 +580,7 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
             h = ck.upload_uniform_shard(chunks, covers, device=dev)
             nb = ck.shard_upload_nbytes(h)
             stats.h2d_bytes += nb
-            sp.tag(h2d_bytes=nb, front_coded="plens" in h)
+            sp.tag(h2d_bytes=nb)
         with telemetry.span_under(trace, "pipeline.dispatch",
                                   shard=s) as sp:
             c0, h0 = device_runtime.compiles_now()
