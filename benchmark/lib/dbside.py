@@ -41,9 +41,14 @@ class JobStatistics(st.Statistics):
         super().record_compaction(stats)
 
 
+ONE_ATTEMPT = DcompactOptions(max_attempts=1)
+
+
 class TimedFactory(HttpCompactionExecutorFactory):
-    """No fallback to a local compaction, one attempt: a device failure
-    fails the run. Keeps the harness-clock interval of every remote job
+    """No fallback to a local compaction, one attempt (the factory's
+    policy here, and `Options.dcompact` in `options`, from which
+    `execute_resilient` takes its attempts): a device failure fails the
+    run. Keeps the harness-clock interval of every remote job
     (`spans`), and with `capture_dir` the inputs and parameters of each
     (hard links, taken before the DB can delete them)."""
 
@@ -51,7 +56,7 @@ class TimedFactory(HttpCompactionExecutorFactory):
                  dbname: str = "", capture_dir: str = ""):
         super().__init__([url], device=device, allow_fallback=False,
                          min_input_bytes=min_input_bytes,
-                         policy=DcompactOptions(max_attempts=1))
+                         policy=ONE_ATTEMPT)
         self.spans: list[tuple[float, float]] = []
         self.failed = 0                # remote jobs whose execute raised
         self.dbname = dbname
@@ -135,6 +140,7 @@ def options(config: dict, sizes: dict, stats, factory) -> Options:
         level0_file_num_compaction_trigger=lsm["l0_compaction_trigger"],
         num_levels=lsm["num_levels"],
         block_cache=LRUCache(config["block_cache_bytes"]),
+        dcompact=ONE_ATTEMPT,
         **({"statistics": stats} if stats is not None else {}),
         **({"compaction_executor_factory": factory}
            if factory is not None else {}))

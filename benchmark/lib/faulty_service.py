@@ -13,6 +13,10 @@ puts it in the service's place (tests and the control runs only).
                      (job directories r001..r005): runs whose output the
                      reference never reads, because a newer run of the same
                      job is the one kept for it
+  --fault leave-pipeline
+                     every job is refused by the pipelined data plane and
+                     takes the serial program: right answers, off the path
+                     the cell measures (`jobs_left_pipeline`)
 """
 
 from __future__ import annotations
@@ -22,10 +26,19 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from lib import traced_service  # noqa: E402
+from lib import span_service  # noqa: E402
 
 
 def plant(fault: str) -> None:
+    if fault == "leave-pipeline":
+        from toplingdb_tpu.ops import pipeline
+
+        def refusing_run_pipelined(*args, **kw):
+            raise pipeline.PipelineIneligible("refused by the fault")
+
+        pipeline.run_pipelined = refusing_run_pipelined  # looked up per job
+        return
+
     import numpy as np
 
     from toplingdb_tpu.ops import compaction_kernels as ck
@@ -60,12 +73,13 @@ def plant(fault: str) -> None:
 
 
 def main() -> int:
-    _svc, rest = traced_service.build_service(sys.argv[1:])
+    _svc, rest = span_service.build_service(sys.argv[1:])
     if len(rest) != 2 or rest[0] != "--fault":
         raise SystemExit("usage: faulty_service.py <service options> "
-                         "--fault drop-row|drop-5pct|drop-row-early")
+                         "--fault drop-row|drop-5pct|drop-row-early|"
+                         "leave-pipeline")
     plant(rest[1])
-    traced_service.serve_commands({})
+    span_service.serve_commands({})
     return 0
 
 

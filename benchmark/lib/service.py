@@ -22,15 +22,17 @@ STOCK = ["-m", "toplingdb_tpu.compaction.dcompact_service"]
 class Service:
     """`launcher` is the stock entry point (argv after the interpreter) or
     one of the benchmark's own launchers, which take the same options and
-    also obey lines on stdin (see traced_service.py)."""
+    also obey lines on stdin (see span_service.py). `workers` is the
+    configuration's `service.workers`: jobs the service runs at once."""
 
     def __init__(self, launcher: list[str], device: str, chips: int,
-                 workdir: str, env: dict):
+                 workers: int, workdir: str, env: dict):
         self.log = os.path.join(workdir, "service.log")
         cmd = [sys.executable, *launcher, "--device", device,
-               "--port", "0", "--host", "127.0.0.1"]
+               "--port", "0", "--host", "127.0.0.1",
+               "--workers", str(workers)]
         if chips > 1:
-            cmd += ["--chips", str(chips), "--workers", "1"]
+            cmd += ["--chips", str(chips)]
         self._logf = open(self.log, "wb")
         env = dict(env, PYTHONPATH=os.pathsep.join(
             [ROOT] + [p for p in [env.get("PYTHONPATH")] if p]))

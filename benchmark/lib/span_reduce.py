@@ -8,9 +8,12 @@ The program's spans reach the trace by `utils/telemetry.py`'s mirror
 lie in the host plane on the device's clock, one line a thread. Nothing here
 patches the program: the job intervals are its `dcompact.worker` spans.
 
-Two stages, as in `trace_reduce`: `xplane_events` (needs
-jax.profiler.ProfileData; run by the process that holds the chip) turns an
-.xplane.pb into plain lists, and `reduce` turns those into the summary.
+Two stages, so that the arithmetic can be tested on a recorded trace without
+JAX: `xplane_events` (needs jax.profiler.ProfileData; run by the process
+that holds the chip) turns an .xplane.pb into plain lists, and `reduce`
+turns those into the summary the metric readers read. In a CPU rehearsal
+the XLA:CPU client's threads stand in as device 0 (their numbers are never
+reported as a device's).
 
   device operations  [name, start_ns, dur_ns, scope]: `scope` is the step of
                      the program the operation belongs to, read from the
@@ -43,7 +46,7 @@ from lib.trace_reduce import (JOB, TOP, WINDOW_CLOSE, WINDOW_OPEN, _clip,
                               _merge, _short, _total)
 
 REQUEST = "dcompact.request"
-WORKER = "dcompact.worker"
+WORKER = JOB
 SPAN_PREFIXES = ("dcompact.", "compaction.", "pipeline.", "sst.", "runtime.")
 # Spans of the thread that feeds the device (ops/pipeline.py's compute
 # thread; in the serial program the job's own thread does the same work
@@ -201,13 +204,12 @@ def xplane_events(path: str) -> dict:
 
 
 def as_trace_reduce_events(events: dict) -> dict:
-    """The same events as `trace_reduce.reduce` takes them: the program's
-    `dcompact.worker` intervals under the name `bench:job`."""
+    """The same events as `trace_reduce.reduce` takes them: names and
+    times only, of the window's marks and the jobs."""
     return {
         "device_ops": {dev: [op[:3] for op in ops]
                        for dev, ops in events["device_ops"].items()},
-        "host": [[JOB if e[0] == WORKER else e[0], e[1], e[2]]
-                 for e in events["host"]
+        "host": [e[:3] for e in events["host"]
                  if e[0] in (WORKER, WINDOW_OPEN, WINDOW_CLOSE)]}
 
 
@@ -357,6 +359,7 @@ def reduce(events: dict) -> dict:
     out["unattributed_s"] = by_span.get(UNATTRIBUTED, 0.0) / n_dev
     out["device_ops_by_hlo"] = out["device_ops"]
     out["device_ops"] = _top(by_scope)
+    out["device_s_by_scope"] = dict(_top(by_scope, n=64))
 
     # -- the host's own time: self time by span, over the window, a job --
     self_s: dict = {}

@@ -1,59 +1,25 @@
-"""From a profiler trace to numbers: device busy and idle share, device time
-under the job annotations, the top device operations, and the idle gaps
-named by what the host was doing.
+"""From the events of a profiler trace to numbers: device busy and idle
+share, device time inside the jobs, the top device operations, and the idle
+gaps by where in a job they lie.
 
-Two stages, so the arithmetic can be tested on a recorded trace without
-JAX: `xplane_events` (needs jax.profiler.ProfileData; run by the process
-that holds the chip) turns an .xplane.pb into plain lists, and `reduce`
-turns those lists into the summary the metric readers read.
+The plain half of the reduction: it knows a window, job intervals and
+device operations, and nothing of the program's stages. `span_reduce`
+(which also turns an .xplane.pb into the lists read here) starts from its
+summary and names every idle second by the program's span; the tests hold
+the two against each other.
 
-  device operations  events of line "XLA Ops" on planes "/device:TPU:<i>";
-                     in a CPU rehearsal the XLA:CPU client's threads stand
-                     in as device 0 (their numbers are never reported as a
-                     device's).
-  host annotations   `jax.profiler.TraceAnnotation` events of the traced
-                     launcher: "bench:window_open", "bench:window_close"
-                     and one "bench:job" around each `worker.run_job`.
+  device operations  {device: [[name, start_ns, dur_ns], ...]}
+  host events        [[name, start_ns, dur_ns], ...]: the launcher's two
+                     window marks, and the program's own `dcompact.worker`
+                     span around each job (nothing is patched in).
 """
 
 from __future__ import annotations
 
 WINDOW_OPEN = "bench:window_open"
 WINDOW_CLOSE = "bench:window_close"
-JOB = "bench:job"
+JOB = "dcompact.worker"  # the program's span around worker.run_job
 TOP = 10
-
-
-def xplane_events(path: str) -> dict:
-    """{"device_ops": {device: [[name, start_ns, dur_ns], ...]},
-        "host": [[name, start_ns, dur_ns], ...]} from one .xplane.pb."""
-    from jax.profiler import ProfileData
-
-    data = ProfileData.from_file(path)
-    device_ops: dict[str, list] = {}
-    host = []
-    cpu_standin = []
-    for plane in data.planes:
-        if plane.name.startswith("/device:TPU:"):
-            for line in plane.lines:
-                if line.name == "XLA Ops":
-                    device_ops.setdefault(plane.name, []).extend(
-                        [e.name, e.start_ns, e.duration_ns]
-                        for e in line.events)
-        elif plane.name == "/host:CPU":
-            for line in plane.lines:
-                standin = line.name.startswith(("tf_XLAPjRtCpuClient",
-                                                "tf_XLAEigen"))
-                for e in line.events:
-                    if e.name.startswith("bench:"):
-                        host.append([e.name, e.start_ns, e.duration_ns])
-                    elif standin and e.duration_ns > 0 \
-                            and not e.name.startswith("end: "):
-                        cpu_standin.append(
-                            [e.name, e.start_ns, e.duration_ns])
-    if not device_ops and cpu_standin:
-        device_ops["/host:CPU (XLA:CPU threads, a rehearsal)"] = cpu_standin
-    return {"device_ops": device_ops, "host": host}
 
 
 def _merge(intervals):

@@ -7,9 +7,10 @@
 This process is the client and, in a served cell, the DB. It never imports
 JAX (asserted). The chip belongs to one child: with `--trace 0` the stock
 `python -m toplingdb_tpu.compaction.dcompact_service --device tpu`, with
-`--trace 1` the benchmark's `lib/traced_service.py`, which is that service
-plus the profiler. Without a TPU (or with fewer chips than the cell asks
-for) it says what JAX found, prints no result and exits non-zero.
+`--trace 1` the benchmark's `lib/span_service.py`, which is that service
+plus the profiler and nothing patched. Without a TPU (or with fewer chips
+than the cell asks for) it says what JAX found, prints no result and exits
+non-zero.
 `--rehearse-cpu` drives a cell at a tiny size on XLA:CPU for the tests: its
 line says platform cpu and `correct` is false whatever was compared.
 
@@ -104,11 +105,12 @@ class Run:
             launcher = [os.path.join(HERE, "lib", self.args.launcher),
                         *self.args.launcher_arg]
         elif self.args.trace:
-            launcher = [os.path.join(HERE, "lib", "traced_service.py")]
+            launcher = [os.path.join(HERE, "lib", "span_service.py")]
         else:
             launcher = service_mod.STOCK
         self.svc = service_mod.Service(
-            launcher, self.device, self.cell["chips"], self.workdir, env)
+            launcher, self.device, self.cell["chips"],
+            self.config["service"]["workers"], self.workdir, env)
 
     def wait_service(self) -> None:
         try:
@@ -158,6 +160,18 @@ class NoDevice(Exception):
     pass
 
 
+def load_bench(held: str = "") -> dict:
+    """BENCHMARK.json, and with `held` the entries of held/<held>.json too:
+    a cell that was taken out and waits there, for the tests and the
+    builder's chip runs. The driver's command never names one."""
+    bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    if held:
+        entries = load_json(os.path.join(HERE, "held", held + ".json"))
+        for section, more in entries["entries"].items():
+            bench[section] = bench[section] + more
+    return bench
+
+
 def find_cell(bench: dict, name: str) -> dict:
     for cell in bench["workloads"]:
         if cell["name"] == name:
@@ -204,9 +218,10 @@ def main(argv=None) -> int:
     ap.add_argument("--launcher-arg", action="append", default=[],
                     help=argparse.SUPPRESS)
     ap.add_argument("--keep-events", default="", help=argparse.SUPPRESS)
+    ap.add_argument("--held", default="", help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
-    bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    bench = load_bench(args.held)
     cell = find_cell(bench, args.workload)
     config = load_json(os.path.join(ROOT, next(
         c["file"] for c in bench["configs"] if c["name"] == cell["config"])))

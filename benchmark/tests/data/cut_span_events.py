@@ -1,7 +1,8 @@
-"""Cut a kept events file of a `--launcher span_service.py --trace 1` run
-(run.py --keep-events) to its first whole jobs, as `cut_events.py` cuts, and
-store it with what `span_reduce.reduce` makes of it:
-tests/data/span_events.json (its numbers: span_events.md)."""
+"""Cut a kept events file of a `--trace 1` run (run.py --keep-events) to its
+first whole jobs (from the window's opening to 50 ms past the last of
+them), and store it with what `span_reduce.reduce` and, of the same
+events, the plain `trace_reduce.reduce` make of it: tests/data/span_events.json
+(its numbers: span_events.md)."""
 
 import json
 import os
@@ -17,6 +18,8 @@ JOBS = 2
 EXPECTED = ("window_s", "busy_s", "busy_in_jobs_chip_s", "jobs_seen", "job_s",
             "devices", "in_job_idle_s", "unattributed_s", "h2d_s",
             "d2h_wait_s", "h2d_bytes", "d2h_bytes")
+PLAIN = ("window_s", "busy_s", "busy_in_jobs_chip_s", "jobs_seen", "job_s",
+         "devices", "idle_gaps")         # of the plain half, trace_reduce
 
 
 def main(path: str) -> None:
@@ -37,9 +40,11 @@ def main(path: str) -> None:
            "op_stats": ev.get("op_stats", {})}
     summary = sr.reduce(cut)
     expected = {k: summary[k] for k in EXPECTED}
+    plain = tr.reduce(sr.as_trace_reduce_events(cut))
     out = os.path.join(HERE, "span_events.json")
     with open(out, "w") as f:
-        json.dump({"events": cut, "expected": expected}, f)
+        json.dump({"events": cut, "expected": expected,
+                   "expected_plain": {k: plain[k] for k in PLAIN}}, f)
     print(json.dumps({k: v for k, v in summary.items()
                       if k not in ("jobs", "device_ops_by_hlo")}, indent=1))
     print(os.path.getsize(out))

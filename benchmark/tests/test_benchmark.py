@@ -1,11 +1,14 @@
 """The benchmark's own tests (CPU; `python -m pytest benchmark/tests -q`).
 
 They are not part of the repository's tier-1 suite. What they hold:
-the window rule on scripted clocks, the trace reduction on a recorded
-trace, the plain reader and reference against the program's own CPU path,
-a rehearsal of each cell, the no-TPU exit, the control and the fault (an
-answer altered where it is produced), and that a configuration, a traffic
-mix and a per-layer metric are each added as files, with no edit.
+the window rule on scripted clocks, the plain half of the trace reduction
+on a hand-made trace and on a piece of a recorded chip trace, the plain
+reader and reference against the program's own CPU path, a rehearsal of
+each cell (the shipped one, and the job cell that waits in
+held/compact-jobs.json), the no-TPU exit, the control and the faults (an
+answer altered where it is produced; a job off the pipelined data plane),
+and that a configuration, a traffic mix and a per-layer metric are each
+added as files, with no edit.
 """
 
 from __future__ import annotations
@@ -25,21 +28,26 @@ ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 sys.path.insert(0, ROOT)
 
+import run as bench_run  # noqa: E402
 from lib import reference, sst_plain, trace_reduce  # noqa: E402
+from lib import span_reduce  # noqa: E402
 from lib.workload import Workload  # noqa: E402
 
 OVERWRITE = "dbbench-c2-8b20b.overwrite"
-JOBS = "dbbench-c2-8b20b.compact-jobs"
+JOBS = "dbbench-c2-8b20b.compact-jobs"   # held: not in BENCHMARK.json
+HELD = "compact-jobs"
 
 
 def run_cell(workload, *extra, root=ROOT, seconds="2", seed="2147483659",
-             rehearse=True):
+             rehearse=True, scale="0.05"):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""))
     cmd = [sys.executable, os.path.join(root, "benchmark", "run.py"),
            "--workload", workload, "--seed", seed, "--seconds", seconds]
     if rehearse:
-        cmd += ["--rehearse-cpu", "0.05"]
+        cmd += ["--rehearse-cpu", scale]
+    if workload == JOBS:
+        cmd += ["--held", HELD]
     p = subprocess.run(cmd + list(extra), cwd=root, env=env,
                        capture_output=True, text=True, timeout=600)
     lines = p.stdout.strip().splitlines()
@@ -141,19 +149,19 @@ def test_reduce_hand_made_trace():
 
 
 def test_reduce_recorded_trace():
-    """A piece of a chip trace of this benchmark (tests/data/README.md)."""
-    path = os.path.join(HERE, "data", "trace_events.json")
-    with open(path) as f:
+    """A piece of a chip trace of this benchmark (tests/data/README.md),
+    as `span_reduce` hands it to the plain half."""
+    with open(os.path.join(HERE, "data", "span_events.json")) as f:
         rec = json.load(f)
-    s = trace_reduce.reduce(rec["events"])
-    for key, want in rec["expected"].items():
-        got = s[key]
+    s = trace_reduce.reduce(span_reduce.as_trace_reduce_events(rec["events"]))
+    for key, want in rec["expected_plain"].items():
         if isinstance(want, float):
-            assert got == pytest.approx(want, rel=1e-9), key
+            assert s[key] == pytest.approx(want, rel=1e-9), key
         else:
-            assert got == want, key
+            assert s[key] == want, key
     gaps = dict(s["idle_gaps"])
     assert sum(gaps.values()) + s["busy_s"] == pytest.approx(s["window_s"])
+    assert gaps["job: before first op"] > 0 and gaps["job: after last op"] > 0
 
 
 # -- the plain reader and the reference, against the program's CPU path ------
@@ -264,7 +272,7 @@ def test_rehearsal_of_each_cell(workload, trace):
     well_formed(line, per_layer=trace == "1")
     assert all(v <= lim for v, lim in line["compared"].values()), \
         line["compared"]
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    bench = bench_run.load_bench(HELD)
     if trace == "0":
         want = {m["name"] for m in bench["end_to_end"]
                 if workload in m.get("workloads", [workload])}
@@ -276,6 +284,15 @@ def test_rehearsal_of_each_cell(workload, trace):
                 if workload in m["workloads"]
                 and m["source"] != "device_trace"}
         assert set(line["metrics"]) == want
+    if workload == JOBS:
+        assert line["compared"]["jobs_left_pipeline"] == [0, 0]
+        if trace == "1":  # the counters of `job_stats` reached the readers
+            for name in ("plane.scan_wait_share", "runtime.gc_pause_share",
+                         "plane.writer_backpressure_share"):
+                assert line["metrics"][name]["value"] >= 0.0
+            assert line["metrics"]["plane.h2d_bytes_per_row"]["value"] >= 12
+            assert 0 < line["metrics"]["plane.writer_busy_share"]["value"] \
+                < 100
     assert "compared " in p.stderr.strip().splitlines()[-1]
 
 
@@ -291,7 +308,7 @@ def test_outside_the_repo_there_is_no_result(tmp_path):
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     p = subprocess.run(
-        [sys.executable, "benchmark/run.py", "--workload", JOBS, "--seed",
+        [sys.executable, "benchmark/run.py", "--workload", OVERWRITE, "--seed",
          "1", "--seconds", "1", "--trace", "0"], cwd=tmp_path, env=env,
         capture_output=True, text=True, timeout=120)
     assert p.returncode != 0 and p.stdout.strip() == ""
@@ -321,6 +338,23 @@ def test_control_and_fault_an_answer_altered_where_it_is_produced():
     assert c["read_mismatches"][0] + c["reopen_read_mismatches"][0] > 0
 
 
+def test_fault_a_job_off_the_pipelined_data_plane():
+    """Every job refused by the pipelined data plane: the serial program
+    gives the right rows, and the run is still not correct. At a tenth of
+    the cell's size one job of the set is over the plane's row floor (those
+    under it leave by design and are not counted); without the fault the
+    same run reads 0."""
+    fault = ["--launcher", "faulty_service.py", "--launcher-arg=--fault",
+             "--launcher-arg=leave-pipeline"]
+    for extra, left in ((fault, True), ([], False)):
+        _, line = run_cell(JOBS, "--trace", "0", *extra, scale="0.1")
+        c = line["compared"]
+        assert c["rows_wrong"] == [0, 0]
+        assert c["runs_unlike_checked"] == [0, 0]
+        assert (c["jobs_left_pipeline"][0] > 0) == left, c
+        assert c["jobs_left_pipeline"][1] == 0
+
+
 def test_a_config_a_mix_and_a_metric_are_added_as_files(tmp_path):
     """A later PR adds a deployment, a traffic mix and a per-layer metric
     as new files and new entries of BENCHMARK.json; no file that exists is
@@ -343,10 +377,15 @@ def test_a_config_a_mix_and_a_metric_are_added_as_files(tmp_path):
     json.dump(cfg, open(b / "configs" / "dummy-config.json", "w"))
     mix = json.load(open(b / "traffic" / "compact-jobs.json"))
     mix["load_overwrite_share"] = 1.0
+    # A counter that a later program will report, and this one does not.
+    mix["job_stats"] = ["h2d_bytes", "shards_on_chip_3"]
     json.dump(mix, open(b / "traffic" / "dummy-mix.json", "w"))
     json.dump({"reader": "fact", "args": {"of": "rows_out"}},
               open(b / "metrics" / "dummy.rows_out.json", "w"))
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    json.dump({"reader": "ratio", "args": {
+        "num": "sum.shards_on_chip_3", "den": "rows_in"}},
+        open(b / "metrics" / "dummy.chip3_share.json", "w"))
+    bench = bench_run.load_bench(HELD)   # the held entries, pasted back
     bench["configs"].append({
         "name": "dummy-config", "source": "a test",
         "file": "benchmark/configs/dummy-config.json", "reduced": ["keys"],
@@ -360,14 +399,42 @@ def test_a_config_a_mix_and_a_metric_are_added_as_files(tmp_path):
         "name": "dummy.rows_out", "unit": "rows", "better": "higher",
         "source": "program_counter", "layer": "kernels",
         "moves": "compact_MBps", "workloads": ["dummy-config.dummy-mix"]})
+    for name in ("dummy.chip3_share", "plane.h2d_bytes_per_row"):
+        bench["per_layer"].append({
+            **bench["per_layer"][-1], "name": name, "unit": "x"})
     json.dump(bench, open(tmp_path / "BENCHMARK.json", "w"))
     p, line = run_cell("dummy-config.dummy-mix", "--trace", "1",
                        root=str(tmp_path))
     assert p.returncode == 4, p.stderr[-2000:]
     assert line["metrics"]["dummy.rows_out"]["value"] > 0
+    # The counter the service lacks: its metric is left out, nothing fails.
+    assert "dummy.chip3_share" not in line["metrics"]
+    assert line["metrics"]["plane.h2d_bytes_per_row"]["value"] > 0
+    assert line["failed"] == 0 and all(
+        v is not None and v <= lim for v, lim in line["compared"].values())
     after = digests()
     assert {k: v for k, v in after.items() if k in before} == before
     assert set(after) - set(before) == {
         "benchmark/configs/dummy-config.json",
         "benchmark/traffic/dummy-mix.json",
-        "benchmark/metrics/dummy.rows_out.json"}
+        "benchmark/metrics/dummy.rows_out.json",
+        "benchmark/metrics/dummy.chip3_share.json"}
+
+
+def test_the_held_cell_pastes_back():
+    """held/compact-jobs.json holds whole entries of BENCHMARK.json: with
+    them no name is there twice, every metric's cells exist, and every
+    per-layer metric has its file."""
+    bench = bench_run.load_bench(HELD)
+    cells = [c["name"] for c in bench["workloads"]]
+    assert JOBS in cells and JOBS not in [
+        c["name"] for c in bench_run.load_bench()["workloads"]]
+    names = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
+    assert len(set(names)) == len(names) and len(set(cells)) == len(cells)
+    e2e = {m["name"] for m in bench["end_to_end"]}
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert set(m.get("workloads", [])) <= set(cells), m["name"]
+    for m in bench["per_layer"]:
+        assert m["moves"] in e2e
+        assert os.path.exists(os.path.join(
+            BENCH, "metrics", m["name"] + ".json")), m["name"]

@@ -3,8 +3,8 @@
 to program spans on a hand-made trace with a gap under each rule, the
 device time by scope, self time and slow jobs, agreement with
 `trace_reduce.reduce` on the same events (hand-made, and a piece of a
-recorded chip trace of PR 25), and a rehearsal of the job cell through
-`--launcher span_service.py`.
+recorded chip trace of PR 27), and a rehearsal of the job cell through
+`--trace 1`, whose launcher is `span_service.py`.
 """
 
 from __future__ import annotations
@@ -49,7 +49,7 @@ def hand_made():
         span("runtime.gc_pause", 31, 33, 2, generation=2),
         span("pipeline.scan", 12, 14, 3),
     ]
-    ops = [["fusion.2 = u8[4194304] fusion(...)", 20 * S, 10 * S, "fc_decode"],
+    ops = [["fusion.2 = u8[4194304] fusion(...)", 20 * S, 10 * S, "compact"],
            ["fusion.7", 35 * S, 3 * S, "sort"]]
     return {"device_ops": {"/device:TPU:0": ops}, "host": host}
 
@@ -88,7 +88,7 @@ def test_every_idle_second_gets_a_name_by_the_rules():
 def test_device_time_goes_by_scope_and_transfers_by_span():
     out = sr.reduce(hand_made())
     assert dict(out["device_ops"]) == pytest.approx(
-        {"fc_decode": 10, "sort": 3})
+        {"compact": 10, "sort": 3})
     assert out["device_ops_by_hlo"][0][0].startswith("fusion.2")
     assert out["h2d_s"] == pytest.approx(3) and out["h2d_bytes"] == 4096
     assert out["d2h_wait_s"] == pytest.approx(22) and out["d2h_bytes"] == 1024
@@ -103,10 +103,10 @@ def test_device_time_goes_by_scope_and_transfers_by_span():
 
 @pytest.mark.parametrize("name,stats,scope", [
     ("fusion.2 = u8[4194304]{0} fusion(p0), kind=kLoop",
-     {"tf_op": "jit(_fused_uniform_shard_fc_impl)/fc_decode/gather"},
-     "fc_decode"),
+     {"tf_op": "jit(_fused_uniform_shard_impl)/encode_words/gather"},
+     "encode_words"),
     ("sort.3", {"long_name": "x", "hlo_category": "sort", "name":
-                "jit(_fused_uniform_shard_fc_impl)/sort/jit(_sort_impl)/sort"},
+                "jit(_fused_uniform_shard_impl)/sort/jit(_sort_impl)/sort"},
      "sort"),
     ("custom-call.1", {"tf_op":
                        "jit(f)/gc_mask/jit(_gc_mask_impl)/pallas_call/gc_rows"},
@@ -164,10 +164,10 @@ def test_agrees_with_trace_reduce_on_the_hand_made_trace():
 
 
 def test_recorded_chip_trace():
-    """A piece of the trace of one `--launcher span_service.py --trace 1`
-    run of the job cell on a TPU v5e (PR 25, my chip run; data/
-    span_events.md has its numbers): no gap of a job is left without a
-    name, no large device operation without a scope."""
+    """A piece of the trace of one `--trace 1` run of the job cell on a TPU
+    v5e (PR 27, my chip run; data/span_events.md has its numbers): no gap
+    of a job is left without a name, no large device operation without a
+    scope, and the scope that PR 26 deleted is gone."""
     with open(os.path.join(HERE, "data", "span_events.json")) as f:
         rec = json.load(f)
     out = sr.reduce(rec["events"])
@@ -179,6 +179,8 @@ def test_recorded_chip_trace():
     for scope, s in out["device_ops"]:
         if s >= 0.01 * device_s:
             assert "fusion" not in scope, scope
+    assert out["device_ops"][0][0] == "compact"
+    assert "fc_decode" not in out["device_s_by_scope"]
     names = {n for n, _ in out["idle_gaps"]}
     assert names & {"compaction.prepare", "pipeline.plan",
                     "sst.build_data", "sst.sync_close"}
@@ -186,8 +188,7 @@ def test_recorded_chip_trace():
 
 def test_rehearsal_through_the_span_launcher(tmp_path):
     events_path = str(tmp_path / "events.json")
-    p, line = run_cell(JOBS, "--trace", "1", "--launcher", "span_service.py",
-                       "--keep-events", events_path)
+    p, line = run_cell(JOBS, "--trace", "1", "--keep-events", events_path)
     assert p.returncode == 4, p.stderr[-2000:]  # a rehearsal is never a pass
     assert line["compared"]["harness_imported_jax"] == [0, 0]
     gaps = dict(line["breakdown"]["idle_gaps"])
@@ -202,7 +203,7 @@ def test_rehearsal_through_the_span_launcher(tmp_path):
         events = json.load(f)
     names = {e[0] for e in events["host"]}
     assert sr.WORKER in names and sr.REQUEST in names
-    assert trace_reduce.JOB not in names
+    assert "bench:job" not in names
     out = sr.reduce(events)
     assert out["jobs_seen"] >= 1
     agrees_with_trace_reduce(events, out)

@@ -2,10 +2,11 @@
 
 Set-up: this deployment's LSM is loaded (every key once, then a share of
 overwrite draws; in bulk batches, it is not timed) with every compaction
-above the configuration's floor sent to the service; each remote job's input SSTs and parameters are kept (hard
-links). Those jobs are the set, and their run during the load is the
-warm-up: every row bucket the window meets has run once on this service.
-Every job directory the window can use is on disk before it opens.
+above the configuration's floor sent to the service; each remote job's
+input SSTs and parameters are kept (hard links). Those jobs are the set, and
+their run during the load is the warm-up: every row bucket the window meets
+has run once on this service. Every job directory the window can use is on
+disk (the run's scratch directory, under `TMPDIR`) before it opens.
 
 The window opens before a job is posted and closes when the job in flight
 at `--seconds` completes: jobs are replayed round-robin through
@@ -37,10 +38,14 @@ from toplingdb_tpu.db.db import DB
 from lib import dbside, reference
 from lib.workload import KEY_BYTES, RAW_KV_BYTES, VALUE_BYTES, Workload
 
-STAT_KEYS = ("work_time_usec", "input_scan_usec", "encode_write_usec",
-             "device_wait_usec", "jit_compiles", "input_records",
-             "output_records", "mesh_chips", "mesh_fallbacks",
-             "host_compute_usec", "pipelined", "device")
+# What the kind itself compares or derives a fact from: every service
+# reports these. The mix's "job_stats" names further counters of a job's
+# reply; one that a service does not report reads None, and the metrics over
+# it are left out.
+CORE_STATS = ("work_time_usec", "input_scan_usec", "encode_write_usec",
+              "device_wait_usec", "jit_compiles", "input_records",
+              "output_records", "mesh_chips", "mesh_fallbacks",
+              "host_compute_usec", "pipelined", "device")
 PHASES = ("work_time_usec", "input_scan_usec", "device_wait_usec",
           "encode_write_usec")
 
@@ -187,8 +192,10 @@ def drive(run) -> dict:
     ran_out = len(done) >= max_runs and span < run.seconds
 
     # ---- facts for the readers -----------------------------------------
+    stat_keys = CORE_STATS + tuple(
+        k for k in tr.get("job_stats", []) if k not in CORE_STATS)
     per_run = [{"job": j, "wall_s": wall,
-                **{k: res["stats"].get(k) for k in STAT_KEYS}}
+                **{k: res["stats"].get(k) for k in stat_keys}}
                for j, wall, res in done]
     rows_in = sum(jobs[j]["rows"] for j, _, _ in done)
     rows_out = sum(p["output_records"] for p in per_run)
@@ -202,6 +209,14 @@ def drive(run) -> dict:
         device_wait_s=sum(p["device_wait_usec"] for p in per_run) / 1e6,
         jit_compiles=sum(p["jit_compiles"] for p in per_run),
         device_kind=run.dev["kind"])
+    for k in stat_keys:                  # "sum.<counter>" over the window's
+        values = [p[k] for p in per_run]  # jobs, where every job reports it
+        if all(type(v) in (int, float) for v in values):
+            run.facts["sum." + k] = sum(values)
+    left = sorted({p["pipeline_exit"] for p in per_run
+                   if p.get("pipeline_exit")})
+    if left:
+        run.facts["notes"].append(f"jobs left the pipeline: {left}")
 
     # ---- what is compared ------------------------------------------------
     unlike = misreported = off_device = 0
@@ -231,14 +246,23 @@ def drive(run) -> dict:
         f"s: {[round(p['wall_s'], 2) for p in per_run]}")
     run.facts["notes"] += _slow_runs(per_run)
     shutil.rmtree(os.path.join(run.workdir, "runs"), ignore_errors=True)
+    svc_stats = run.svc.get("/stats")
     run.compare("rows_wrong", totals["rows_wrong"])
     run.compare("rows_not_from_seed", totals["rows_not_from_seed"])
     run.compare("records_misreported",
                 totals["records_misreported"] + misreported)
     run.compare("runs_unlike_checked", unlike)
     run.compare("jobs_off_device", off_device + load["jobs_off_device"])
+    # Jobs the service ran off the pipelined data plane, in all its life,
+    # less those that are under the plane's row floor (they leave it by
+    # design: the load's smallest, and every job of a rehearsal).
+    left = svc_stats.get("jobs_left_pipeline")
+    sent = [s.input_records for s in stats.jobs if s.remote] \
+        + [jobs[-1]["rows"]] + [jobs[j]["rows"] for j, _, _ in done]
+    run.compare("jobs_left_pipeline", None if left is None else left - sum(
+        rows < dbside.PIPELINE_FLOOR_ROWS for rows in sent))
     run.compare("remote_job_failures",
-                run.svc.get("/stats")["jobs_failed"] + factory.failed)
+                svc_stats["jobs_failed"] + factory.failed)
     run.compare("jobs_unchecked", int(not keeper.newest))
     run.compare("runs_ran_out", int(ran_out))
     return {"compact_MBps": rows_in * RAW_KV_BYTES / 1e6 / span}
