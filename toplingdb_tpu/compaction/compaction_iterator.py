@@ -15,8 +15,9 @@ snapshot):
     older version remains visible (its job is done).
   * SINGLE_DELETION annihilates together with the single older VALUE it meets
     in the same stripe; an unmatched one is kept (unless bottommost).
-  * MERGE operands fold: chain ending at VALUE → full_merge(value_base);
-    chain ending at DELETION in-stripe or at group end on the bottommost
+  * MERGE operands fold: chain ending at VALUE → full_merge(value_base)
+    (full_merge(None) when a range tombstone covers that base); chain
+    ending at DELETION in-stripe or at group end on the bottommost
     level → full_merge(None); otherwise operands partial-merge into one
     MERGE record (keeping the newest seqno) when the operator allows, else
     pass through unchanged.
@@ -301,7 +302,15 @@ class CompactionIterator:
                         )
                     val = self._blob_resolver(val)
                 ops = list(reversed(operands))
-                if t == ValueType.WIDE_COLUMN_ENTITY:
+                if self._tomb_covers(uk, seq):
+                    # A range tombstone lies between the base and the
+                    # operands above it: the base is deleted, the chain
+                    # folds onto nothing (reference MergeHelper::MergeUntil
+                    # asks ShouldDelete of the base too).
+                    self.num_dropped_tombstone += 1
+                    v = self._merge_op.full_merge(uk, None, ops)
+                    out_t = ValueType.VALUE
+                elif t == ValueType.WIDE_COLUMN_ENTITY:
                     # Entity base: fold against the DEFAULT column, emit
                     # the entity back (reference MergeHelper over
                     # kTypeWideColumnEntity / wide_columns_helper).

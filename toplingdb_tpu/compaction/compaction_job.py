@@ -99,6 +99,17 @@ class CompactionStats:
     gc_collections: int = 0
     stall_wait_scan_usec: int = 0
     stall_wait_writer_usec: int = 0
+    # The columnar planes' work on MERGE operands and range tombstones
+    # (`pipeline.merge_fold`, `pipeline.tombstone_cover`): MERGE rows
+    # among the input, user-key groups that held one, rows of those groups
+    # that folded away, and the wall of the fold; tombstone fragments of
+    # the job's inputs and the wall of mapping them onto rows.
+    merge_operand_rows: int = 0
+    merge_groups: int = 0
+    merge_rows_folded: int = 0
+    merge_fold_usec: int = 0
+    tombstone_fragments: int = 0
+    tombstone_cover_usec: int = 0
 
     def phase_dict(self) -> dict:
         """Non-zero timing phases, seconds — for bench/dcompact reporting.

@@ -146,6 +146,11 @@ class ChipPool:
         }
 
 
+JOB_SUM_COUNTERS = ("merge_operand_rows", "merge_groups",
+                    "merge_rows_folded", "merge_fold_usec",
+                    "tombstone_fragments", "tombstone_cover_usec")
+
+
 class DcompactWorkerService:
     """Hosts job execution: POST /dcompact {"job_dir": ...} → runs the job
     in-process (owning the chip), returns the results JSON. GET /stats for
@@ -175,6 +180,10 @@ class DcompactWorkerService:
         self.jobs_done = 0
         self.jobs_failed = 0
         self.jobs_left_pipeline = 0  # ran, but not on the pipelined plane
+        # Sums over the jobs done of the planes' merge and range-tombstone
+        # counters (CompactionStats): operand rows met, groups and rows
+        # folded, the fold's and the covers' wall.
+        self.job_sums = dict.fromkeys(JOB_SUM_COUNTERS, 0)
         from toplingdb_tpu.utils import telemetry
 
         self.tracer = telemetry.Tracer(proc="dcompact-worker", ring=256)
@@ -215,7 +224,8 @@ class DcompactWorkerService:
                     os.environ[k] = v
             self.pool.release(grant, ok=ok)
 
-    def _count(self, ok: bool, left_pipeline: bool = False) -> None:
+    def _count(self, ok: bool, left_pipeline: bool = False,
+               stats: dict | None = None) -> None:
         with self._counter_mu:
             if ok:
                 self.jobs_done += 1
@@ -223,6 +233,8 @@ class DcompactWorkerService:
                 self.jobs_failed += 1
             if left_pipeline:
                 self.jobs_left_pipeline += 1
+            for k in JOB_SUM_COUNTERS:
+                self.job_sums[k] += int((stats or {}).get(k) or 0)
 
     def start(self, port: int = 0, host: str = "127.0.0.1") -> int:
         svc = self
@@ -246,6 +258,7 @@ class DcompactWorkerService:
                         "jobs_done": svc.jobs_done,
                         "jobs_failed": svc.jobs_failed,
                         "jobs_left_pipeline": svc.jobs_left_pipeline,
+                        **svc.job_sums,
                     }
                     if svc.jax_devices is not None:
                         from toplingdb_tpu.ops import device_runtime
@@ -362,7 +375,8 @@ class DcompactWorkerService:
                     with open(f"{job_dir}/results.json") as f:
                         results = json.load(f)
                     svc._count(ok=True, left_pipeline=bool(
-                        results.get("stats", {}).get("pipeline_exit")))
+                        results.get("stats", {}).get("pipeline_exit")),
+                        stats=results.get("stats"))
                     self._reply(200, results)
                 except Exception as e:  # job failure → structured error
                     svc._count(ok=False)

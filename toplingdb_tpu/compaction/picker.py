@@ -223,67 +223,88 @@ class LeveledCompactionPicker(CompactionPicker):
 
 
 class UniversalCompactionPicker(CompactionPicker):
-    """Size-tiered universal compaction over L0-resident sorted runs
-    (reference compaction_picker_universal.cc). Runs live in L0 (newest
-    first) plus at most one full-keyspace run in the last level."""
+    """Size-tiered universal compaction over sorted runs (reference
+    compaction_picker_universal.cc). The runs, newest first: every L0 file,
+    then the last level when it holds files (one full-keyspace run). A pick
+    merges runs that are neighbours in age, so the output takes their
+    place in that order (L0 sorts by largest sequence).
+
+    In the reference's order: (1) size amplification — all the younger runs
+    against the oldest; past `universal_max_size_amplification_percent`
+    everything merges into the last level; (2) size ratio — from the
+    newest run on, a run's older neighbour joins while the candidates' total
+    size, plus `universal_size_ratio` percent, reaches the neighbour's
+    (PickCompactionToReduceSortedRuns: similar-sized young runs merge, a
+    large old run waits until the young ones have grown to it); (3) with
+    more runs than the trigger and no such neighbours, the newest runs
+    merge whatever their sizes, as many as bring the count back under
+    the trigger."""
 
     def compaction_score(self, version: Version) -> list[tuple[float, int]]:
         n = len(version.files[0])
         return [(n / max(1, self.options.level0_file_num_compaction_trigger), 0)]
 
     def pick_compaction(self, version: Version) -> Compaction | None:
-        runs = [f for f in version.files[0] if not _busy(f)]
-        if len(runs) < self.options.level0_file_num_compaction_trigger:
-            return None
-        if any(_busy(f) for f in version.files[0]):
-            return None
         opts = self.options
-        # 1. Size-amplification trigger: total/newest vs percent.
-        last_level = version.num_levels - 1
-        base = version.files[last_level]
-        younger_bytes = sum(f.file_size for f in runs)
-        base_bytes = sum(f.file_size for f in base)
-        if base and not any(_busy(f) for f in base):
-            if base_bytes > 0 and younger_bytes * 100 >= (
-                opts.universal_max_size_amplification_percent * base_bytes
-            ):
-                smallest, largest = self._key_range(runs + base)
-                return Compaction(
-                    level=0, output_level=last_level, inputs=runs,
-                    output_level_inputs=list(base), bottommost=True,
-                    reason="universal size-amp",
-                    max_output_file_size=2**62,
-                )
-        # 2. Size-ratio trigger: merge a prefix of similar-sized runs
-        # (newest first; runs sorted newest→oldest already).
-        picked = [runs[-1]]
-        total = runs[-1].file_size
-        for f in reversed(runs[:-1]):
-            if total * (100 + opts.universal_size_ratio) >= f.file_size * 100:
-                picked.append(f)
-                total += f.file_size
-            else:
-                break
-        if len(picked) >= opts.universal_min_merge_width:
-            picked = picked[: opts.universal_max_merge_width]
-            picked_set = {f.number for f in picked}
-            inputs = [f for f in version.files[0] if f.number in picked_set]
-            bottom = self._is_bottommost(
-                version, 0, *self._key_range(inputs)
-            ) and len(inputs) == len(version.files[0])
+        l0 = list(version.files[0])
+        trigger = opts.level0_file_num_compaction_trigger
+        if len(l0) < trigger or any(_busy(f) for f in l0):
+            return None
+        base = list(version.files[version.num_levels - 1])
+        if any(_busy(f) for f in base):
+            return None
+        runs = [([f], f.file_size) for f in l0]
+        if base:
+            runs.append((base, sum(f.file_size for f in base)))
+        # 1. Size amplification: the younger runs against the oldest.
+        oldest = runs[-1][1]
+        younger = sum(size for _, size in runs[:-1])
+        if len(runs) > 1 and oldest > 0 and younger * 100 >= (
+                opts.universal_max_size_amplification_percent * oldest):
+            return self._merge(version, l0, base, runs, "universal size-amp",
+                               into_last_level=True)
+        # 2. Size ratio, from the newest run on.
+        width = max(2, opts.universal_min_merge_width)
+        for first in range(len(runs) - 1):
+            n, size = 1, runs[first][1]
+            while (first + n < len(runs)
+                   and n < opts.universal_max_merge_width
+                   and size * (100 + opts.universal_size_ratio)
+                   >= runs[first + n][1] * 100):
+                size += runs[first + n][1]
+                n += 1
+            if n >= width:
+                return self._merge(version, l0, base, runs[first:first + n],
+                                   "universal size-ratio")
+        # 3. Too many runs: the newest merge, whatever their sizes.
+        n = min(len(l0) - trigger + 1, opts.universal_max_merge_width)
+        if n >= 2:
+            return self._merge(version, l0, base, runs[:n],
+                               "universal run-count")
+        return None
+
+    def _merge(self, version, l0, base, picked, reason,
+               into_last_level=False) -> Compaction:
+        """The compaction of `picked`, neighbours in age. With the last
+        level's run among them (or for size amplification) the output goes
+        to the last level and nothing older exists; else it stays in L0,
+        bottommost only when it holds the oldest L0 run and no level
+        beneath overlaps it."""
+        files = [f for run, _ in picked for f in run]
+        with_base = bool(base) and files[-1] is base[-1]
+        inputs = files[:len(files) - len(base)] if with_base else files
+        if with_base or into_last_level:
             return Compaction(
-                level=0, output_level=0, inputs=inputs,
-                bottommost=bottom, reason="universal size-ratio",
+                level=0, output_level=version.num_levels - 1, inputs=inputs,
+                output_level_inputs=base if with_base else [],
+                bottommost=with_base or not base, reason=reason,
                 max_output_file_size=2**62,
             )
-        # 3. Fall back: merge all runs into the last level.
-        if base and any(_busy(f) for f in base):
-            return None
-        smallest, largest = self._key_range(runs + list(base)) if base else self._key_range(runs)
+        bottom = inputs[-1] is l0[-1] and self._is_bottommost(
+            version, 0, *self._key_range(inputs))
         return Compaction(
-            level=0, output_level=last_level, inputs=runs,
-            output_level_inputs=list(base), bottommost=True,
-            reason="universal merge-all", max_output_file_size=2**62,
+            level=0, output_level=0, inputs=inputs, bottommost=bottom,
+            reason=reason, max_output_file_size=2**62,
         )
 
 

@@ -10,6 +10,7 @@ compaction, and yields fragments for writing tombstones into output SSTs.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 from toplingdb_tpu.db import dbformat
@@ -40,38 +41,41 @@ def fragment_tombstones(tombstones: list[RangeTombstone], ucmp) -> list[RangeTom
     """Split overlapping tombstones into non-overlapping fragments, keeping
     for each fragment every distinct seqno whose original tombstone covers it
     (reference range_tombstone_fragmenter.cc). Output sorted by (begin, -seq);
-    only fragments are emitted (empty input → empty output)."""
+    only fragments are emitted (empty input → empty output).
+
+    One sweep over the boundary points in the comparator's order: a
+    tombstone's seqno joins the live set at its begin and leaves it at its
+    end, so n tombstones cost n log n comparisons (a deployment that
+    deletes a range every few thousand writes brings thousands to one
+    compaction)."""
     if not tombstones:
         return []
-    # Collect all boundary points.
+    # Bytewise keys sort as bytes do; any other order goes by compare().
     points = sorted(
         {t.begin for t in tombstones} | {t.end for t in tombstones},
-        key=lambda k: _CmpKey(ucmp, k),
+        key=None if type(ucmp) is dbformat.Comparator
+        else functools.cmp_to_key(ucmp.compare),
     )
+    index = {p: i for i, p in enumerate(points)}
+    opens: list[list[int]] = [[] for _ in points]
+    closes: list[list[int]] = [[] for _ in points]
+    for t in tombstones:
+        if index[t.begin] < index[t.end]:
+            opens[index[t.begin]].append(t.seq)
+            closes[index[t.end]].append(t.seq)
+    live: dict[int, int] = {}  # seqno -> tombstones holding it open
     out: list[RangeTombstone] = []
-    for a, b in zip(points, points[1:]):
-        seqs = sorted(
-            {
-                t.seq
-                for t in tombstones
-                if ucmp.compare(t.begin, a) <= 0 and ucmp.compare(b, t.end) <= 0
-            },
-            reverse=True,
-        )
-        for s in seqs:
-            out.append(RangeTombstone(s, a, b))
+    for i in range(len(points) - 1):
+        for s in closes[i]:
+            if live[s] == 1:
+                del live[s]
+            else:
+                live[s] -= 1
+        for s in opens[i]:
+            live[s] = live.get(s, 0) + 1
+        for s in sorted(live, reverse=True):
+            out.append(RangeTombstone(s, points[i], points[i + 1]))
     return out
-
-
-class _CmpKey:
-    __slots__ = ("ucmp", "k")
-
-    def __init__(self, ucmp, k):
-        self.ucmp = ucmp
-        self.k = k
-
-    def __lt__(self, other):
-        return self.ucmp.compare(self.k, other.k) < 0
 
 
 class RangeDelAggregator:

@@ -793,15 +793,29 @@ def prepare_uniform_chunk(key_buf: np.ndarray, n: int, key_len: int):
     return (uk, pk32, min_seq, n, uk_len)
 
 
+# One row bucket for every shard a deployment's jobs produce: a shard of
+# ROW_BUCKET_FROM..ROW_BUCKET rows pads to ROW_BUCKET, not to its own next
+# power of two. A fused program is minutes of compile on the chip
+# (PERF.md: ~110 s at key length 8, ~195 s at 16) against milliseconds of
+# device time for the pad rows, and which power of two a small job or an
+# uneven shard falls under is chance: a job of 250,000 rows after a
+# thousand of 400,000 must not meet a program of its own. The pipeline cuts
+# its shards to ROW_BUCKET (ops/pipeline.py::SHARD_ROWS).
+ROW_BUCKET = 1 << 19
+ROW_BUCKET_FROM = 1 << 17
+
+
 def upload_uniform_shard(chunks, covers=None, device=None):
     """Pack one shard's prepared chunks (prepare_uniform_chunk outputs, in
-    row order) into device buffers, pad rows to the next power of two, and
+    row order) into device buffers, pad rows to the next power of two
+    (to ROW_BUCKET from ROW_BUCKET_FROM rows on), and
     START the host→device transfers (device_put is async): two bulk
     transfers per shard, not two per chunk. The user-key bytes go up as
     they are, so a shard's program depends on its row bucket and key
     length only, never on the keys.
     `covers`: optional per-chunk uint64 max-covering-tombstone arrays
-    (None = tombstone-free); uploaded as two extra u32 planes.
+    (None = a job without range tombstones); uploaded as two extra u32
+    planes.
     `device` (None = backend default): COMMIT the shard's buffers to one
     specific chip — the fused program carries no pin of its own, so the
     committed inputs decide where it runs (ops/mesh_compaction.py places
@@ -813,12 +827,15 @@ def upload_uniform_shard(chunks, covers=None, device=None):
         raise NotSupported(
             f"shard rows {total} exceed the 24-bit packed-order budget"
         )
-    p = _next_pow2(max(1, total))
+    p = (ROW_BUCKET if ROW_BUCKET_FROM <= total <= ROW_BUCKET
+         else _next_pow2(max(1, total)))
     ukb = np.zeros(p * uk_len, dtype=np.uint8)
     pkb = np.zeros(p, dtype=np.uint32)
-    has_tombs = covers is not None and any(
-        c is not None and np.any(c) for c in covers
-    )
+    # A job whose inputs hold range tombstones runs the tombstone variant
+    # of the program in every shard, covered rows or not: which of the two
+    # programs a shard meets is then a property of the job, and a
+    # deployment that deletes ranges warms one program, not two.
+    has_tombs = covers is not None
     if has_tombs:
         tomb_hi = np.zeros(p, dtype=np.uint32)
         tomb_lo = np.zeros(p, dtype=np.uint32)

@@ -334,58 +334,6 @@ def _collect_raw_columnar(compaction, table_cache, icmp, want_uploads=False):
     return kv, rd, shards, parts
 
 
-def _part_lower_bound(part, key: bytes, lo: int = 0) -> int:
-    """First row of the (sorted) part whose user key >= key."""
-    hi = part.n
-    while lo < hi:
-        mid = (lo + hi) // 2
-        if _part_user_key(part, mid) < key:
-            lo = mid + 1
-        else:
-            hi = mid
-    return lo
-
-
-def _cover_for_parts(parts, rd: RangeDelAggregator, ucmp, snapshots):
-    """Per-ORIGINAL-row (concat order) max covering tombstone seqno,
-    stripe-clamped exactly like _tombstone_cover — computed per sorted
-    input part with interval binary searches (fragments are few, rows are
-    many), so the fused device paths can take tombstone-bearing jobs.
-    Returns uint64[sum(part.n)] or None when there are no tombstones."""
-    frags = list(fragment_tombstones(rd.tombstones(), ucmp))
-    if not frags:
-        return None
-    snaps = np.asarray(sorted(snapshots), dtype=np.uint64)
-    covers = []
-    for part in parts:
-        n = part.n
-        cov = np.zeros(n, dtype=np.uint64)
-        if n:
-            tv = _kv_seq_vtype(part)
-            seqs = tv.seq
-            if len(snaps):
-                idx = np.searchsorted(snaps, seqs, side="left")
-                upper = np.where(
-                    idx < len(snaps),
-                    snaps[np.minimum(idx, len(snaps) - 1)],
-                    np.uint64(dbformat.MAX_SEQUENCE_NUMBER),
-                )
-            else:
-                upper = np.full(n, dbformat.MAX_SEQUENCE_NUMBER,
-                                dtype=np.uint64)
-            for frag in frags:
-                lo = _part_lower_bound(part, frag.begin)
-                hi = _part_lower_bound(part, frag.end, lo)
-                if lo < hi:
-                    t = np.uint64(frag.seq)
-                    sl = slice(lo, hi)
-                    elig = ((t > seqs[sl]) & (t <= upper[sl])
-                            & (t > cov[sl]))
-                    cov[sl] = np.where(elig, t, cov[sl])
-        covers.append(cov)
-    return np.concatenate(covers) if covers else None
-
-
 def _prepare_uniform_shards(parts):
     """Host half of the sharded uniform device path: validate density +
     uniform key length, pick range splitters, slice every part into
@@ -487,17 +435,20 @@ def _patch_kv_values(kv, rows: list[int], vals: list[bytes]) -> None:
         off += len(v)
 
 
-def _resolve_complex_stream(kv, order, cx_flags, trailer_override, seqs,
-                            vtypes, helper):
-    """Fold the complex (MERGE / SINGLE_DELETE) user-key groups the device
-    flagged in the survivor stream through the reference state machine
+def _resolve_complex_mask(kv, order, cx_flags, trailer_override, seqs,
+                          vtypes, helper, patch=_patch_kv_values):
+    """Fold the complex (MERGE / SINGLE_DELETE) user-key groups flagged in
+    the survivor stream through the reference state machine
     (CompactionIterator._process_group, the MergeHelper::MergeUntil role,
     /root/reference/db/merge_helper.h:104) WITHOUT abandoning the columnar
     path: each group's emitted entries overwrite the group's leading rows
-    (trailer/seq/vtype overrides + value replacements appended to kv's
-    side buffer); surplus rows drop out of the order. Returns the filtered
-    order; mutates trailer_override/seqs/vtypes and patches kv in place."""
+    (trailer/seq/vtype overrides + value replacements handed to `patch`);
+    surplus rows drop out. Returns (the stream's keep mask, the number of
+    groups); mutates trailer_override/seqs/vtypes and patches kv in
+    place. One Python call a group: the path of operators without a
+    columnar fold."""
     n_stream = len(order)
+    n_groups = 0
     keep_mask = np.ones(n_stream, dtype=bool)
     repl_rows: list[int] = []
     repl_vals: list[bytes] = []
@@ -531,10 +482,228 @@ def _resolve_complex_stream(kv, order, cx_flags, trailer_override, seqs,
                 repl_vals.append(v)
         for t in range(len(emitted), len(rows)):
             keep_mask[int(pos_list[i + t])] = False
+        n_groups += 1
         i = j
     if repl_rows:
-        _patch_kv_values(kv, repl_rows, repl_vals)
-    return order[keep_mask]
+        patch(kv, repl_rows, repl_vals)
+    return keep_mask, n_groups
+
+
+def _same_key_as_previous(kv, rows: np.ndarray):
+    """bool[m]: rows[i] has the user key of rows[i - 1] ([0] is False), or
+    None when the keys are not of one length in one dense buffer (the
+    per-group resolver compares those). Keys of whole 8-byte words are
+    compared a word at a time, others byte by byte."""
+    lens = kv.key_lens[rows]
+    klen = int(lens[0])
+    r64 = rows.astype(np.int64)
+    if (int(lens.min()) != klen or int(lens.max()) != klen
+            or len(kv.key_buf) < kv.n * klen
+            or not np.array_equal(kv.key_offs[rows], r64 * klen)):
+        return None
+    buf = kv.key_buf[:kv.n * klen]
+    same = np.zeros(len(rows), dtype=bool)
+    if klen % 8 == 0 and buf.ctypes.data % 8 == 0:
+        words = buf.view(np.uint64).reshape(kv.n, klen // 8)
+        eq = np.ones(len(rows) - 1, dtype=bool)
+        for c in range(klen // 8 - 1):  # a strided gather a word
+            w = words[:, c][r64]
+            eq &= w[1:] == w[:-1]
+        same[1:] = eq
+    else:
+        uk = buf.reshape(kv.n, klen)[r64, :klen - 8]
+        same[1:] = (uk[1:] == uk[:-1]).all(axis=1)
+    return same
+
+
+def _gather_values(kv, rows: np.ndarray, fold):
+    """The fold.width-byte values of `rows` as fold.dtype numbers."""
+    W = fold.width
+    vo = kv.val_offs[rows].astype(np.int64)
+    if kv.val_buf.ctypes.data % W == 0 and not (vo % W).any():
+        return kv.val_buf[:len(kv.val_buf) // W * W].view(fold.dtype)[vo // W]
+    return np.ascontiguousarray(
+        kv.val_buf[vo[:, None] + np.arange(W)[None, :]]
+    ).view(fold.dtype).reshape(len(rows))
+
+
+def _scatter_values(kv, rows: np.ndarray, vals: np.ndarray, fold) -> None:
+    """Overwrite the fold.width-byte value slots of `rows` with `vals`."""
+    W = fold.width
+    if not kv.val_buf.flags.writeable:
+        kv.val_buf = kv.val_buf.copy()
+    vo = kv.val_offs[rows].astype(np.int64)
+    if kv.val_buf.ctypes.data % W == 0 and not (vo % W).any():
+        kv.val_buf[:len(kv.val_buf) // W * W].view(fold.dtype)[vo // W] = vals
+    else:
+        kv.val_buf[vo[:, None] + np.arange(W)[None, :]] = \
+            np.ascontiguousarray(vals).view(np.uint8).reshape(len(rows), W)
+
+
+def _fold_complex_columnar(kv, order, cx_flags, cover, trailer_override,
+                           seqs, vtypes, snaps, bottommost, fold, helper,
+                           patch=_patch_kv_values):
+    """Vectorised twin of _resolve_complex_mask for an operator that
+    declares a ColumnarFold: the flagged groups of one survivor stream
+    fold in one segmented reduction, no Python a row or a group.
+
+    `cover[i]` (or None): the stripe-clamped max covering range-tombstone
+    seqno of stream position i, 0 = uncovered. `snaps`: sorted uint64
+    snapshot seqnos. The rules are _process_group's, per (user key,
+    snapshot stripe) segment of rows, newest first: a covered first row
+    drops the segment; a first VALUE or DELETION survives as the device
+    would have decided it; a first MERGE leads a chain of uncovered MERGE
+    rows that folds with the VALUE that ends it (unless a range tombstone
+    covers that base), into a VALUE when a DELETION, a covered row, or —
+    bottommost — the end of the group ends it, else into one MERGE
+    operand; nothing folds across a stripe. The result takes the chain's
+    newest row: its value slot is overwritten in place (same width), its
+    trailer overridden. Groups holding anything else (SINGLE_DELETION,
+    blob or entity bases, a value of another width) go through the
+    per-group resolver, them alone.
+    Returns (keep_mask over the stream, counters dict)."""
+    VT = dbformat.ValueType
+    keep_mask = np.ones(len(order), dtype=bool)
+    pos = np.flatnonzero(cx_flags)
+    m = len(pos)
+    counters = {"groups": 0, "operand_rows": 0, "rows_folded": 0}
+    if m == 0:
+        return keep_mask, counters
+    rows = order[pos]
+    same_key = _same_key_as_previous(kv, rows)
+    vt = vtypes[rows]
+    if same_key is None:
+        counters["operand_rows"] = int((vt == int(VT.MERGE)).sum())
+        keep, counters["groups"] = _resolve_complex_mask(
+            kv, order, cx_flags, trailer_override, seqs, vtypes, helper,
+            patch)
+        counters["rows_folded"] = int(m - keep[pos].sum())
+        return keep, counters
+    W = fold.width
+    new_group = ~same_key
+    if m != len(order):  # flagged runs need not be neighbours
+        new_group[1:] |= pos[1:] != pos[:-1] + 1
+    new_seg = new_group
+    stripe = None
+    if len(snaps):
+        stripe = np.searchsorted(snaps, seqs[rows], side="left")
+        new_seg = new_group.copy()
+        new_seg[1:] |= stripe[1:] != stripe[:-1]
+    seg_start = np.flatnonzero(new_seg)
+    n_seg = len(seg_start)
+    covered = (cover[pos] != 0 if cover is not None
+               else np.zeros(m, dtype=bool))
+    is_merge = vt == int(VT.MERGE)
+    is_value = vt == int(VT.VALUE)
+    odd = ~(is_merge | is_value | (vt == int(VT.DELETION))) | (
+        (is_merge | is_value) & (kv.val_lens[rows] != W))
+    counters["groups"] = int(new_group.sum())
+    counters["operand_rows"] = int(is_merge.sum())
+    row_py = None
+    if odd.any():
+        grp_start = np.flatnonzero(new_group)
+        row_py = np.logical_or.reduceat(odd, grp_start)[
+            np.cumsum(new_group) - 1]
+
+    # Chains: the leading uncovered MERGE rows of a segment, and the row
+    # that ends them. A segment whose first row is no such operand has a
+    # bad row at its head, so none of its rows counts as chain or end.
+    bad = ~(is_merge & ~covered)
+    cb = np.cumsum(bad, dtype=np.int32)
+    head = (cb - bad)[seg_start]  # bad rows before each segment
+    bad_in_seg = cb - np.repeat(
+        head, np.diff(np.append(seg_start, m)))
+    chain = bad_in_seg == 0
+    term = bad & (bad_in_seg == 1) & ~new_seg
+    seg_merges = ~bad[seg_start]
+    seg_has_term = np.add.reduceat(term, seg_start, dtype=np.int32) > 0
+    seg_last_of_group = np.ones(n_seg, dtype=bool)
+    seg_last_of_group[:-1] = new_group[seg_start[1:]]
+
+    # What each segment leaves, at its first row.
+    r0 = rows[seg_start]
+    r0_vt = vt[seg_start]
+    r0_bottom0 = (np.full(n_seg, bool(bottommost)) if stripe is None
+                  else bool(bottommost) & (stripe[seg_start] == 0))
+    to_value = seg_merges & (seg_has_term | (seg_last_of_group
+                                             & bool(bottommost)))
+    emit = ~covered[seg_start] & (
+        (r0_vt == int(VT.VALUE))
+        | ((r0_vt == int(VT.DELETION)) & ~r0_bottom0)
+        | seg_merges)
+    out_vt = np.where(to_value, int(VT.VALUE), r0_vt)
+    zero = emit & r0_bottom0 & (out_vt == int(VT.VALUE))
+    out_seq = np.where(zero, np.uint64(0), seqs[r0])
+    if row_py is not None:
+        emit &= ~row_py[seg_start]
+        seg_merges = seg_merges & ~row_py[seg_start]
+
+    # The fold itself: one segmented reduction over the chains' values
+    # (and the bases they end on), newest first. A chain of one operand
+    # with nothing beneath it reduces to itself.
+    if seg_merges.any():
+        part = chain | (term & is_value & ~covered)
+        if row_py is not None:
+            part &= ~row_py
+        sel = np.flatnonzero(part)
+        starts = np.flatnonzero(new_seg[sel])  # a chain starts its segment
+        sums = fold.reduce(_gather_values(kv, rows[sel], fold),
+                           starts).astype(fold.dtype, copy=False)
+        _scatter_values(kv, r0[seg_merges], sums, fold)
+
+    er = r0[emit]
+    vtypes[er] = out_vt[emit].astype(vtypes.dtype)
+    seqs[er] = out_seq[emit]
+    trailer_override[er] = (
+        (out_seq[emit] << np.uint64(8)) | out_vt[emit].astype(np.uint64)
+    ).astype(np.int64)
+
+    keep_c = np.zeros(m, dtype=bool)
+    keep_c[seg_start[emit]] = True
+    keep_mask[pos] = keep_c
+    if row_py is not None:
+        py_pos = pos[row_py]
+        py_flags = np.zeros(len(order), dtype=bool)
+        py_flags[py_pos] = True
+        keep_mask[py_pos] = _resolve_complex_mask(
+            kv, order, py_flags, trailer_override, seqs, vtypes, helper,
+            patch)[0][py_pos]
+    counters["rows_folded"] = int(m - keep_mask[pos].sum())
+    return keep_mask, counters
+
+
+def fold_complex(kv, order, cx_flags, cover, trailer_override, seqs, vtypes,
+                 icmp, snapshots, bottommost, merge_operator, rd,
+                 blob_resolver, patch=_patch_kv_values):
+    """Resolve the complex groups the device flagged in one survivor
+    stream (a whole job's in the serial program, a shard's in the
+    pipeline): the columnar fold when the operator declares one, else the
+    per-group resolver. Returns (keep mask over the stream, counters)."""
+    helper = CompactionIterator(
+        _EmptyIter(), icmp, snapshots, bottommost_level=bottommost,
+        merge_operator=merge_operator, range_del_agg=rd,
+        blob_resolver=blob_resolver,
+    )
+    fold = (merge_operator.columnar_fold()
+            if merge_operator is not None else None)
+    if fold is not None:
+        return _fold_complex_columnar(
+            kv, order, cx_flags, cover, trailer_override, seqs, vtypes,
+            np.asarray(sorted(snapshots), dtype=np.uint64), bottommost,
+            fold, helper, patch)
+    rows = order[cx_flags]
+    operands = int((vtypes[rows] == int(dbformat.ValueType.MERGE)).sum())
+    keep, groups = _resolve_complex_mask(
+        kv, order, cx_flags, trailer_override, seqs, vtypes, helper, patch)
+    return keep, {"groups": groups, "operand_rows": operands,
+                  "rows_folded": int(len(rows) - keep[cx_flags].sum())}
+
+
+def count_fold(stats, ctr: dict, usec: int) -> None:
+    stats.merge_groups += ctr["groups"]
+    stats.merge_operand_rows += ctr["operand_rows"]
+    stats.merge_rows_folded += ctr["rows_folded"]
+    stats.merge_fold_usec += usec
 
 
 def _verify_columnar_output(env, icmp, table_options, path, kv, vtypes,
@@ -631,6 +800,7 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
                 env, dbname, icmp, compaction, table_cache, table_options,
                 snapshots, new_file_number, creation_time, pstats,
                 MAX_DEVICE_KEY_BYTES, column_family,
+                merge_operator=merge_operator, blob_resolver=blob_resolver,
             )
         except (pl.PipelineIneligible, NotSupported) as e:
             # The serial path decides (and re-raises what it must); the
@@ -684,8 +854,20 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
         # Range tombstones ride the fused kernels as a per-row max-covering
         # seqno side input (stripe-clamped on host; fragments are few).
         t_cov = time.time()
-        cover = (None if rd.empty() else _cover_for_parts(
-            parts, rd, icmp.user_comparator, snapshots))
+        cover = None
+        if not rd.empty():
+            with _tele.span("pipeline.tombstone_cover") as sp:
+                frags = list(fragment_tombstones(rd.tombstones(),
+                                                 icmp.user_comparator))
+                # Per ORIGINAL row (concat order), stripe-clamped: the
+                # pipeline's cover over the parts' row spans.
+                bounds = np.cumsum([0] + [p_.n for p_ in parts])
+                cover = pl._cover_for_ranges(
+                    kv, list(zip(bounds[:-1].tolist(), bounds[1:].tolist())),
+                    frags, np.asarray(sorted(snapshots), dtype=np.uint64))
+                sp.tag(fragments=len(frags))
+            stats.tombstone_fragments = len(frags)
+            stats.tombstone_cover_usec += int((time.time() - t_cov) * 1e6)
         stats.host_compute_usec += int((time.time() - t_cov) * 1e6)
         prep.finish()
         if not _host_sort():
@@ -817,18 +999,18 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
         seqs[zero_orig] = 0
         if has_complex:
             vtypes = vtypes.copy()
-            helper = CompactionIterator(
-                _EmptyIter(), icmp, snapshots,
-                bottommost_level=compaction.bottommost,
-                merge_operator=merge_operator,
-                range_del_agg=None if rd.empty() else rd,
-                blob_resolver=blob_resolver,
-            )
             t_rs = time.time()
-            order = _resolve_complex_stream(
-                kv, order, cx_flags, trailer_override, seqs, vtypes, helper
-            )
+            with _tele.span("pipeline.merge_fold") as sp:
+                keep, ctr = fold_complex(
+                    kv, order, cx_flags,
+                    None if cover is None else cover[order],
+                    trailer_override, seqs, vtypes, icmp, snapshots,
+                    compaction.bottommost, merge_operator,
+                    None if rd.empty() else rd, blob_resolver)
+                order = order[keep]
+                sp.tag(**ctr)
             stats.resolve_usec = int((time.time() - t_rs) * 1e6)
+            count_fold(stats, ctr, stats.resolve_usec)
         order_feed = order
     else:
         # Shard streaming: each chunk's trailers/seqs land just before the

@@ -22,10 +22,18 @@ key lands in exactly one shard), so per-shard GC decisions — snapshot
 stripes, tombstone shadowing, bottommost seqno zeroing — equal the
 global ones and the concatenated survivor stream is byte-identical to
 the serial path's; tests/test_compaction_pipeline.py asserts whole-file
-SST equality. Jobs the pipeline does not cover (complex merge /
-single-delete groups, non-block formats, missing properties, small
-inputs) raise PipelineIneligible and the caller falls back to the serial
-path, which computes the same bytes.
+SST equality. Jobs the pipeline does not cover (non-block formats,
+missing properties, small inputs) raise PipelineIneligible and the caller
+falls back to the serial path, which computes the same bytes.
+
+MERGE operands and single-deletes stay on this plane: the compute stage
+returns the rows of such "complex" user-key groups unreduced and flagged,
+and a fold step between it and the writer resolves them a shard at a time
+(`pipeline.merge_fold`; ops/device_compaction.py::fold_complex) — one
+segmented reduction for an operator that declares a columnar fold
+(uint64add), the per-group state machine for the others. Folded values
+overwrite the chain's newest row in place or land in the slack at the end
+of the value buffer, so the writer's hoisted pointers stay good.
 
 `TPULSM_PIPELINE=0` disables the pipeline; `TPULSM_PIPELINE_SHARDS=N`
 overrides the shard count.
@@ -96,6 +104,15 @@ def pipeline_enabled(table_options=None) -> bool:
     return True
 
 
+# The pipeline's shards are cut to at most this many rows, the kernels' one
+# row bucket (ops/compaction_kernels.py::ROW_BUCKET, to which every shard
+# from a quarter of it on is padded): every shard of every job then meets
+# ONE fused program per key length. A program is minutes of compile on the
+# chip (PERF.md): a job whose shards straddle a power of two must not meet
+# a second one.
+SHARD_ROWS = 1 << 19
+
+
 def _pipeline_shards(total_rows: int) -> int:
     """Pipeline shard count: finer than the serial device sharding (the
     pipeline wants several shards in flight even at ~1M rows)."""
@@ -106,9 +123,15 @@ def _pipeline_shards(total_rows: int) -> int:
         except ValueError:
             pass
     # ~512K rows per shard: small jobs get 2 shards (enough to overlap,
-    # little per-shard dispatch overhead), bench-scale jobs get 16-32.
-    target = 1 << 19
-    s = 1
+    # little per-shard dispatch overhead), bench-scale jobs get 16-32. A
+    # job of up to SHARD_ROWS rows is one shard, which the pipeline leaves
+    # to the serial path. From two shards on the cut leaves a fiftieth of
+    # room: shards are cut at block boundaries and come out uneven by a
+    # few blocks a file.
+    if total_rows <= SHARD_ROWS:
+        return 1
+    target = SHARD_ROWS - SHARD_ROWS // 50
+    s = 2
     while s < 32 and total_rows // s > target:
         s *= 2
     return s
@@ -190,15 +213,69 @@ def _lower_bound(kv, lo: int, hi: int, key: bytes) -> int:
     return lo
 
 
+def _uniform_key_matrix(kv, lo: int, hi: int):
+    """Rows [lo, hi) as an [n, key_len] view of the key buffer when they
+    have one key length and lie densely (a file's rows do); else None."""
+    if hi <= lo:
+        return None
+    lens = kv.key_lens[lo:hi]
+    klen = int(lens[0])
+    b0 = int(kv.key_offs[lo])
+    if (int(lens.min()) != klen or int(lens.max()) != klen
+            or int(kv.key_offs[hi - 1]) != b0 + (hi - 1 - lo) * klen):
+        return None
+    return kv.key_buf[b0:b0 + (hi - lo) * klen].reshape(hi - lo, klen)
+
+
+def _lower_bounds(kv, lo: int, hi: int, keys: list[bytes],
+                  packed: dict | None = None) -> np.ndarray:
+    """_lower_bound of every user key of `keys` among rows [lo, hi), as
+    offsets from lo. Rows of one key length are searched at once: their
+    user keys, zero-padded to whole big-endian 8-byte words, sort as the
+    keys do, so one searchsorted places every boundary; only a boundary
+    whose padded words tie with a row's is bisected key by key. `packed`
+    keeps the padded form of `keys` from one range to the next."""
+    mat = _uniform_key_matrix(kv, lo, hi)
+    if mat is None or not keys:
+        return np.array([_lower_bound(kv, lo, hi, k) - lo for k in keys],
+                        dtype=np.int64)
+    n, ukl = hi - lo, mat.shape[1] - 8
+    nw = max(1, (ukl + 7) // 8)
+    dt = np.dtype([(f"w{i}", ">u8") for i in range(nw)])
+    rows = np.zeros((n, nw * 8), dtype=np.uint8)
+    rows[:, :ukl] = mat[:, :ukl]
+    rows = rows.view(dt).reshape(n)
+    kb = None if packed is None else packed.get(nw)
+    if kb is None:
+        kb = np.zeros((len(keys), nw * 8), dtype=np.uint8)
+        for i, k in enumerate(keys):
+            kb[i, :min(len(k), nw * 8)] = np.frombuffer(
+                k[:nw * 8], dtype=np.uint8)
+        kb = kb.view(dt).reshape(len(keys))
+        if packed is not None:
+            packed[nw] = kb
+    left = np.searchsorted(rows, kb, side="left").astype(np.int64)
+    right = np.searchsorted(rows, kb, side="right")
+    for i in np.flatnonzero(right > left):
+        if len(keys[i]) != ukl:  # equal words, equal length: equal keys
+            left[i] = _lower_bound(kv, lo + int(left[i]), lo + int(right[i]),
+                                   keys[i]) - lo
+    return left
+
+
 def _range_seq_vtype(kv, lo: int, hi: int):
     """(seq u64, vtype i32) for global rows [lo, hi) — generic trailer
     gather (the rows need not be a dense byte span)."""
     import sys
 
-    offs = kv.key_offs[lo:hi].astype(np.int64)
-    lens = kv.key_lens[lo:hi].astype(np.int64)
-    tr_idx = (offs + lens - 8)[:, None] + np.arange(8)[None, :]
-    trailer = np.ascontiguousarray(kv.key_buf[tr_idx])
+    mat = _uniform_key_matrix(kv, lo, hi)
+    if mat is not None:
+        trailer = np.ascontiguousarray(mat[:, -8:])  # a strided view
+    else:
+        offs = kv.key_offs[lo:hi].astype(np.int64)
+        lens = kv.key_lens[lo:hi].astype(np.int64)
+        tr_idx = (offs + lens - 8)[:, None] + np.arange(8)[None, :]
+        trailer = np.ascontiguousarray(kv.key_buf[tr_idx])
     packed = trailer.view(np.uint64).reshape(hi - lo)
     if sys.byteorder == "big":
         packed = packed.byteswap()
@@ -206,10 +283,14 @@ def _range_seq_vtype(kv, lo: int, hi: int):
         (packed & np.uint64(0xFF)).astype(np.int32)
 
 
-def _build_plan(readers):
+def _build_plan(readers, value_slack: bool = False):
     """Validate prealloc eligibility, size the global buffers, pick the
     key-range splitters and each file's per-shard block groups. Returns
-    (kv, files, splitters) or raises PipelineIneligible."""
+    (kv, files, splitters, (slack_lo, slack_hi)) or raises
+    PipelineIneligible. With value_slack the value buffer is allocated
+    with room behind the inputs' values for merge results (untouched pages
+    cost nothing): as much again plus 16 B a row, within the int32
+    budget."""
     import bisect
 
     from toplingdb_tpu.ops.columnar_io import ColumnarKV
@@ -267,9 +348,10 @@ def _build_plan(readers):
         raise PipelineIneligible("inputs too uniform to shard")
     n_shards = len(splitters) + 1
 
+    slack = min(tv + 16 * tn, 0x7FFFFF00 - tv) if value_slack else 0
     kv = ColumnarKV(
         np.empty(tk, dtype=np.uint8), np.empty(tn, dtype=np.int32),
-        np.empty(tn, dtype=np.int32), np.empty(tv, dtype=np.uint8),
+        np.empty(tn, dtype=np.int32), np.empty(tv + slack, dtype=np.uint8),
         np.empty(tn, dtype=np.int32), np.empty(tn, dtype=np.int32),
     )
 
@@ -305,7 +387,7 @@ def _build_plan(readers):
         vb += rv
     if not files:
         raise PipelineIneligible("no non-empty inputs")
-    return kv, files, splitters
+    return kv, files, splitters, (tv, tv + slack)
 
 
 def _scan_file(fi, fp, kv, prog, splitters, stats, stats_mu,
@@ -394,35 +476,44 @@ def _scan_file(fi, fp, kv, prog, splitters, stats, stats_mu,
 
 def _cover_for_ranges(kv, ranges, frags, snaps):
     """Stripe-clamped max covering tombstone seqno per row of the shard's
-    (sorted) per-file ranges, concatenated in range order — the pipeline
-    twin of device_compaction._cover_for_parts."""
+    (sorted) per-file ranges, concatenated in range order (the serial
+    columnar program calls it with its parts' spans). The fragments' boundaries
+    are placed in each range by one search (_lower_bounds) and the rows
+    they cover are judged together, so a job with thousands of fragments
+    pays numpy calls a range, not Python steps a fragment."""
     if not frags:
         return None
+    nf = len(frags)
+    bounds = [f.begin for f in frags] + [f.end for f in frags]
+    fseq = np.array([f.seq for f in frags], dtype=np.uint64)
+    packed: dict = {}
     covs = []
     for lo, hi in ranges:
         n = hi - lo
         cov = np.zeros(n, dtype=np.uint64)
         if n:
-            seqs, _vt = _range_seq_vtype(kv, lo, hi)
-            if len(snaps):
-                idx = np.searchsorted(snaps, seqs, side="left")
-                upper = np.where(
-                    idx < len(snaps),
-                    snaps[np.minimum(idx, len(snaps) - 1)],
-                    np.uint64(dbformat.MAX_SEQUENCE_NUMBER),
-                )
-            else:
-                upper = np.full(n, dbformat.MAX_SEQUENCE_NUMBER,
-                                dtype=np.uint64)
-            for frag in frags:
-                flo = _lower_bound(kv, lo, hi, frag.begin) - lo
-                fhi = _lower_bound(kv, lo + flo, hi, frag.end) - lo
-                if flo < fhi:
-                    t = np.uint64(frag.seq)
-                    sl = slice(flo, fhi)
-                    elig = ((t > seqs[sl]) & (t <= upper[sl])
-                            & (t > cov[sl]))
-                    cov[sl] = np.where(elig, t, cov[sl])
+            at = _lower_bounds(kv, lo, hi, bounds, packed)
+            flo, fhi = at[:nf], at[nf:]
+            hit = np.flatnonzero(fhi > flo)
+            if len(hit):
+                seqs, _vt = _range_seq_vtype(kv, lo, hi)
+                if len(snaps):
+                    idx = np.searchsorted(snaps, seqs, side="left")
+                    upper = np.where(
+                        idx < len(snaps),
+                        snaps[np.minimum(idx, len(snaps) - 1)],
+                        np.uint64(dbformat.MAX_SEQUENCE_NUMBER),
+                    )
+                else:
+                    upper = np.full(n, dbformat.MAX_SEQUENCE_NUMBER,
+                                    dtype=np.uint64)
+                # Every (fragment, covered row) pair, flat.
+                cnt = (fhi - flo)[hit]
+                first = np.repeat(flo[hit] - (np.cumsum(cnt) - cnt), cnt)
+                rows = first + np.arange(int(cnt.sum()))
+                t = np.repeat(fseq[hit], cnt)
+                elig = (t > seqs[rows]) & (t <= upper[rows])
+                np.maximum.at(cov, rows[elig], t[elig])
         covs.append(cov)
     return np.concatenate(covs)
 
@@ -498,13 +589,14 @@ def _host_compute(kv, files, splitters, prog, outq, shared, snapshots,
         with telemetry.span_under(shared.trace, "pipeline.merge_gc",
                                   shard=s):
             og = _host_merge_gc_shard(ck, kv, ranges, shared, snapshots,
-                                      snaps, bottommost, frags, max_dev_key)
+                                      snaps, bottommost, frags, max_dev_key,
+                                      s)
         _put(outq, prog, og, shared)
     _put(outq, prog, _DONE, shared)
 
 
 def _host_merge_gc_shard(ck, kv, ranges, shared, snapshots, snaps,
-                         bottommost, frags, max_dev_key):
+                         bottommost, frags, max_dev_key, s):
     """One shard through the native merge+GC host twin; returns the
     survivors' global rows in output order."""
     t0 = time.time()
@@ -517,20 +609,22 @@ def _host_merge_gc_shard(ck, kv, ranges, shared, snapshots, snaps,
         raise PipelineIneligible("keys exceed the device budget")
     rs = np.cumsum([0] + [hi - lo for lo, hi in ranges],
                    dtype=np.int64)
-    cover = _cover_for_ranges(kv, ranges, frags, snaps)
-    order, zero, _cx, hc, seq_l, vt_l = ck.host_fused_full(
+    cover = _timed_cover(kv, ranges, frags, snaps, shared)
+    order, zero, cx, hc, seq_l, vt_l = ck.host_fused_full(
         kv.key_buf, soffs, slens, max(4, mx - 8), snapshots,
         bottommost, cover, run_starts=rs,
     )
-    if hc:
-        raise PipelineIneligible("complex groups present")
     lmap = _ranges_lmap(ranges)
     og = lmap[order]
     shared.seqs[lmap] = seq_l
     shared.vtypes[lmap] = vt_l
-    zg = og[zero]
+    # A complex row's zero flag is provisional: the fold decides it again.
+    zg = og[zero & ~cx] if hc else og[zero]
     shared.trailer_override[zg] = shared.vtypes[zg].astype(np.int64)
     shared.seqs[zg] = 0
+    if hc:
+        og = _fold_shard(kv, shared, s, og, cx,
+                         None if cover is None else cover[order])
     shared.stats.host_compute_usec += int((time.time() - t0) * 1e6)
     return og
 
@@ -559,7 +653,7 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
     stats, trace = shared.stats, shared.trace
     mesh_devs = mc.pipeline_devices(n_shards, stats=stats, trace=trace)
     depth = [mp.UPLOAD_DEPTH * len(mesh_devs) if mesh_devs else 1]
-    pendings = []  # (ranges, lmap, pending, s, dev, chunks, covers) | None
+    pendings = []  # (ranges, lmap, pending, s, dev, chunks, covers, cov)
 
     def _demote(exc) -> None:
         # Wedged chip: the rest of the job runs single-device; bytes are
@@ -592,7 +686,7 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
     def finish_one(item):
         if item is None:
             return
-        ranges, lmap, pending, s, dev, chunks, covers = item
+        ranges, lmap, pending, s, dev, chunks, covers, cov = item
         t0 = time.time()
         nb = sum(int(a.nbytes) for a in pending)
         # Device compute + D2H, as this thread waits for them.
@@ -600,17 +694,15 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
                 trace, "pipeline.merge_gc", shard=s, device=True,
                 d2h_bytes=nb, **({} if dev is None else {"chip": str(dev)})):
             try:
-                o, z, _cx, hc = ck.fused_uniform_shard_finish(pending)
+                o, z, cx, hc = ck.fused_uniform_shard_finish(pending)
             except Exception as e:
                 if dev is None or isinstance(e, NotSupported):
                     raise
                 _demote(e)  # re-run this shard on the default device
                 pending = start_one(s, chunks, covers, None)
-                o, z, _cx, hc = ck.fused_uniform_shard_finish(pending)
+                o, z, cx, hc = ck.fused_uniform_shard_finish(pending)
         stats.d2h_bytes += nb
         stats.device_wait_usec += int((time.time() - t0) * 1e6)
-        if hc:
-            raise PipelineIneligible("complex groups present")
         # Host work between the device and the writer.
         with telemetry.span_under(trace, "pipeline.unpack", shard=s):
             og = lmap[o]
@@ -618,9 +710,13 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
                 seq_r, vt_r = _range_seq_vtype(kv, lo, hi)
                 shared.seqs[lo:hi] = seq_r
                 shared.vtypes[lo:hi] = vt_r
-            zg = og[z]
+            # A complex row's zero flag is provisional: the fold decides.
+            zg = og[z & ~cx] if hc else og[z]
             shared.trailer_override[zg] = shared.vtypes[zg].astype(np.int64)
             shared.seqs[zg] = 0
+            if hc:
+                og = _fold_shard(kv, shared, s, og, cx,
+                                 None if cov is None else cov[o])
         _put(outq, prog, og, shared)
 
     for s in range(n_shards):
@@ -633,8 +729,8 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
             # Host numpy before the upload: trailers stripped, covers.
             with telemetry.span_under(trace, "pipeline.chunk_prepare",
                                       shard=s):
-                chunks, covers = _prepare_shard_chunks(
-                    ck, kv, ranges, frags, snaps, max_dev_key)
+                chunks, covers, cov = _prepare_shard_chunks(
+                    ck, kv, ranges, frags, snaps, max_dev_key, shared)
             dev = mesh_devs[s % len(mesh_devs)] if mesh_devs else None
             try:
                 pending = start_one(s, chunks, covers, dev)
@@ -646,7 +742,7 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
                 pending = start_one(s, chunks, covers, None)
             stats.transfer_time_usec += int((time.time() - t0) * 1e6)
             pendings.append((ranges, _ranges_lmap(ranges), pending, s, dev,
-                             chunks, covers))
+                             chunks, covers, cov))
         # keep the lookahead window in flight (one upload serially,
         # UPLOAD_DEPTH per chip under the mesh); finish older shards now
         while len(pendings) > depth[0]:
@@ -656,10 +752,78 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
     _put(outq, prog, _DONE, shared)
 
 
-def _prepare_shard_chunks(ck, kv, ranges, frags, snaps, max_dev_key):
-    """(chunks, covers) of one shard for upload_uniform_shard: one prepared
-    uniform chunk a file range, and the covering-tombstone seqnos when the
-    job has range tombstones."""
+def _timed_cover(kv, ranges, frags, snaps, shared):
+    """_cover_for_ranges as the span `pipeline.tombstone_cover` and the
+    counter `tombstone_cover_usec`; None for a job without tombstones."""
+    if not frags:
+        return None
+    t0 = time.time()
+    with telemetry.span_under(shared.trace, "pipeline.tombstone_cover",
+                              fragments=len(frags)):
+        cov = _cover_for_ranges(kv, ranges, frags, snaps)
+    shared.stats.tombstone_cover_usec += int((time.time() - t0) * 1e6)
+    return cov
+
+
+def _fold_shard(kv, shared, s, og, cx, cover):
+    """The fold step between the compute stage and the writer: resolve the
+    complex groups flagged in shard s's survivor stream `og` (global rows;
+    `cx` flags them, `cover` is the stream's covering-tombstone seqnos or
+    None). Returns the stream without the rows that folded away; the
+    survivors' values, types and trailers are patched where the writer
+    will read them."""
+    from toplingdb_tpu.ops import device_compaction as dc
+
+    f = shared.fold
+    t0 = time.time()
+    with telemetry.span_under(shared.trace, "pipeline.merge_fold",
+                              shard=s) as sp:
+        keep, ctr = dc.fold_complex(
+            kv, og, cx, cover, shared.trailer_override, shared.seqs,
+            shared.vtypes, f.icmp, f.snapshots, f.bottommost,
+            f.merge_operator, f.rd, f.blob_resolver, patch=f.patch)
+        sp.tag(**ctr)
+    dc.count_fold(shared.stats, ctr, int((time.time() - t0) * 1e6))
+    return og[keep]
+
+
+class _Fold:
+    """What the fold step needs of the job, and the slack at the end of
+    the value buffer where results of another width than the row they
+    replace are put (the buffer itself must not move: the readers and the
+    writer hold pointers into it)."""
+
+    def __init__(self, icmp, snapshots, bottommost, merge_operator, rd,
+                 blob_resolver, slack_lo: int, slack_hi: int):
+        self.icmp = icmp
+        self.snapshots = snapshots
+        self.bottommost = bottommost
+        self.merge_operator = merge_operator
+        self.rd = rd
+        self.blob_resolver = blob_resolver
+        self._pos = slack_lo
+        self._end = slack_hi
+
+    def patch(self, kv, rows, vals) -> None:
+        for r, v in zip(rows, vals):
+            n = len(v)
+            if n == int(kv.val_lens[r]):
+                off = int(kv.val_offs[r])
+            else:
+                if self._pos + n > self._end:
+                    raise PipelineIneligible(
+                        "merge results outgrew the value buffer's slack")
+                off = self._pos
+                self._pos += n
+                kv.val_offs[r] = off
+                kv.val_lens[r] = n
+            kv.val_buf[off:off + n] = np.frombuffer(v, dtype=np.uint8)
+
+
+def _prepare_shard_chunks(ck, kv, ranges, frags, snaps, max_dev_key, shared):
+    """(chunks, covers, cov) of one shard for upload_uniform_shard: one
+    prepared uniform chunk a file range, and the covering-tombstone seqnos
+    (by chunk, and whole) when the job has range tombstones."""
     chunks = []
     klen = None
     for lo, hi in ranges:
@@ -676,15 +840,15 @@ def _prepare_shard_chunks(ck, kv, ranges, frags, snaps, max_dev_key):
         chunks.append(ck.prepare_uniform_chunk(
             kv.key_buf[b0:b0 + (hi - lo) * klen], hi - lo, klen,
         ))
-    if not frags:
-        return chunks, None
-    cov = _cover_for_ranges(kv, ranges, frags, snaps)
+    cov = _timed_cover(kv, ranges, frags, snaps, shared)
+    if cov is None:
+        return chunks, None, None
     covers = []
     pos = 0
     for lo, hi in ranges:
         covers.append(cov[pos:pos + (hi - lo)])
         pos += hi - lo
-    return chunks, covers
+    return chunks, covers, cov
 
 
 class _Shared:
@@ -692,12 +856,14 @@ class _Shared:
     chunked-order contract of write_tables_columnar) plus the stats and
     the telemetry handle stage workers parent their spans under."""
 
-    __slots__ = ("trailer_override", "seqs", "vtypes", "stats", "trace")
+    __slots__ = ("trailer_override", "seqs", "vtypes", "stats", "trace",
+                 "fold")
 
 
 def run_pipelined(env, dbname, icmp, compaction, table_cache, table_options,
                   snapshots, new_file_number, creation_time, stats,
-                  max_dev_key, column_family=(0, "default")):
+                  max_dev_key, column_family=(0, "default"),
+                  merge_operator=None, blob_resolver=None):
     """Run one compaction through the three-stage pipeline. Returns the
     write_tables_columnar file tuples plus the shared arrays used to
     build output metadata: (files, kv, vtypes, tombs).
@@ -729,7 +895,9 @@ def run_pipelined(env, dbname, icmp, compaction, table_cache, table_options,
             table_cache.get_reader(f.number)
             for _, f in compaction.all_inputs()
         ]
-        kv, files, splitters = _build_plan(readers)
+        # A job that may fold operands gets slack behind its values.
+        kv, files, splitters, slack = _build_plan(
+            readers, value_slack=merge_operator is not None)
         stats.input_records = kv.n
 
         rd = RangeDelAggregator(icmp.user_comparator)
@@ -749,6 +917,10 @@ def run_pipelined(env, dbname, icmp, compaction, table_cache, table_options,
         shared.vtypes = np.zeros(kv.n, dtype=np.int32)
         shared.stats = stats
         shared.trace = trace
+        shared.fold = _Fold(icmp, snapshots, compaction.bottommost,
+                            merge_operator, None if rd.empty() else rd,
+                            blob_resolver, *slack)
+        stats.tombstone_fragments = len(frags)
     stats.pipelined = True
 
     prog = _Progress(len(files))

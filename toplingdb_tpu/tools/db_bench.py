@@ -180,9 +180,15 @@ class Bench:
         import struct
 
         wo = WriteOptions(disable_wal=self.args.disable_wal)
-        for i in range(n):
-            self.db.merge(self.key(self.rng.randrange(self.args.num)),
-                          struct.pack("<Q", 1), wo)
+        batch = self.args.batch_size
+        one = struct.pack("<Q", 1)
+        i = 0
+        while i < n:
+            b = WriteBatch()
+            for _ in range(min(batch, n - i)):
+                b.merge(self.key(self.rng.randrange(self.args.num)), one)
+                i += 1
+            self.db.write(b, wo)
         return n
 
     def bench_fillrandombatch(self, n):

@@ -28,6 +28,29 @@ class MergeOperator:
     def allow_single_operand(self) -> bool:
         return False
 
+    def columnar_fold(self) -> "ColumnarFold | None":
+        """A ColumnarFold when the operator is associative over values of
+        one fixed width with the missing base as its identity, so that a
+        whole shard's operand chains fold in one segmented reduction
+        (ops/device_compaction.py::_fold_complex_columnar); None keeps the
+        per-group calls of full_merge / partial_merge."""
+        return None
+
+
+class ColumnarFold:
+    """What an operator declares for the columnar fold: every value and
+    operand is `width` bytes read as `dtype`, and
+    `reduce(values, starts)[i]` folds values[starts[i]:starts[i+1]] — the
+    same bytes as full_merge(None, those operands) and, with a base among
+    them, as full_merge(base, the rest)."""
+
+    __slots__ = ("width", "dtype", "reduce")
+
+    def __init__(self, width: int, dtype: str, reduce):
+        self.width = width
+        self.dtype = dtype
+        self.reduce = reduce
+
 
 class PutOperator(MergeOperator):
     """Merge == overwrite: last operand wins (reference put.cc)."""
@@ -66,6 +89,12 @@ class UInt64AddOperator(MergeOperator):
         return struct.pack(
             "<Q", (self._dec(left) + self._dec(right)) & 0xFFFFFFFFFFFFFFFF
         )
+
+    def columnar_fold(self):
+        import numpy as np
+
+        # Segment sum of <u8 values; uint64 addition wraps mod 2^64.
+        return ColumnarFold(8, "<u8", np.add.reduceat)
 
 
 class StringAppendOperator(MergeOperator):
