@@ -12,8 +12,16 @@ concurrency plane pins down:
    so the parked writer MUST wake into the post-swap world and
    re-resolve onto the new primary (epoch bump).
 
-Both tests drive the orders with
-`get_sync_point_registry().load_dependency(...)` — no sleeps.
+3. db.py — the flush thread's install vs an unlocked reader and vs the
+   writer's next seal. The install puts the table into the version
+   BEFORE it drops the memtable from `imm` (a reader walks `[mem] + imm`
+   and then the version, with no lock: in the other order it would find
+   a row in neither), all in one hold of `_mutex`, so a seal that arrives
+   meanwhile waits for the hold and queues behind the installed unit.
+
+The first two tests drive the orders with
+`get_sync_point_registry().load_dependency(...)` — no sleeps; the flush
+tests hold the flush thread inside a sync-point callback.
 """
 
 import threading
@@ -148,3 +156,108 @@ def test_fenced_writer_vs_migration_cutover(tmp_path, sync_points):
     finally:
         reg.clear_all()
         r.close()
+
+
+def test_flush_install_reaches_the_version_before_it_leaves_imm(
+        tmp_path, sync_points):
+    """Pinned order: log_and_apply, THEN the drop from imm. A reader runs
+    between the two and must find every row (in both places); with the
+    order reversed it would find them in neither."""
+    reg = sync_points
+    db = DB.open(str(tmp_path / "db"),
+                 Options(create_if_missing=True,
+                         disable_auto_compactions=True))
+    between = threading.Event()
+    reader_done = threading.Event()
+    seen = {}
+
+    def at_drop(_arg):
+        seen["imm"] = len(db.imm)
+        seen["l0"] = len(db.versions.current.files[0])
+        between.set()
+        assert reader_done.wait(timeout=30.0)
+
+    reg.set_callback("FlushJob::BeforeImmDrop", at_drop)
+    reg.enable_processing()
+    got = {}
+
+    def reader():
+        assert between.wait(timeout=30.0)
+        for i in range(200):                    # unlocked point reads
+            got[i] = db.get(b"k%04d" % i)
+        reader_done.set()
+
+    t = threading.Thread(target=reader, name="interleave-reader")
+    t.start()
+    try:
+        for i in range(200):
+            db.put(b"k%04d" % i, b"v%d" % i)
+        db.flush()
+        t.join(timeout=30.0)
+        assert not t.is_alive()
+        assert seen == {"imm": 1, "l0": 1}
+        assert got == {i: b"v%d" % i for i in range(200)}
+        assert not db.imm
+    finally:
+        reader_done.set()
+        reg.clear_all()
+        db.close()
+
+
+def test_seal_arrives_while_the_flush_thread_installs(tmp_path,
+                                                      sync_points):
+    """The flush thread is inside its install (holding _mutex) when the
+    writer fills the next memtable: the seal waits for the hold, then
+    queues its unit behind the installed one. Two L0 files, in seal
+    order, every write readable."""
+    reg = sync_points
+    db = DB.open(str(tmp_path / "db"),
+                 Options(create_if_missing=True, write_buffer_size=16 << 10,
+                         max_write_buffer_number=3,
+                         disable_auto_compactions=True,
+                         statistics=Statistics()))
+    installing = threading.Event()
+    writer_started = threading.Event()
+    first = [True]
+
+    def at_drop(_arg):
+        if first[0]:
+            first[0] = False
+            installing.set()
+            assert writer_started.wait(timeout=30.0)
+            time.sleep(0.05)    # the writer is at _mutex by now
+
+    reg.set_callback("FlushJob::BeforeImmDrop", at_drop)
+    reg.enable_processing()
+    err = []
+
+    def writer():
+        try:
+            assert installing.wait(timeout=30.0)
+            writer_started.set()
+            for i in range(400):                # > one write buffer
+                db.put(b"w%04d" % i, b"x" * 60)
+        except BaseException as e:  # noqa: BLE001
+            err.append(e)
+
+    t = threading.Thread(target=writer, name="interleave-sealer")
+    t.start()
+    try:
+        for i in range(100):
+            db.put(b"k%04d" % i, b"v%d" % i)
+        db.flush(FlushOptions(wait=False))
+        t.join(timeout=30.0)
+        assert not t.is_alive()
+        assert not err, err
+        db.flush()
+        files = sorted(db.versions.current.files[0], key=lambda f: f.number)
+        assert len(files) >= 2
+        assert files[0].largest_seqno == 100    # the first unit, alone
+        assert all(a.largest_seqno < b.smallest_seqno
+                   for a, b in zip(files, files[1:]))
+        assert db.get(b"k0042") == b"v42"
+        assert db.get(b"w0399") == b"x" * 60
+    finally:
+        writer_started.set()
+        reg.clear_all()
+        db.close()

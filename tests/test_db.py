@@ -555,7 +555,10 @@ def test_write_buffer_manager_across_dbs(tmp_path):
             db1.put(b"a%04d" % i, b"x" * 40)
             db2.put(b"b%04d" % i, b"y" * 40)
         # Per-DB write_buffer_size (64MiB) would never flush; the shared
-        # 24KiB budget must have.
+        # 24KiB budget must have. (A sealed memtable becomes a file on the
+        # flush thread: wait for it.)
+        db1.wait_for_compactions()
+        db2.wait_for_compactions()
         flushed = (db1.versions.current.num_files()
                    + db2.versions.current.num_files())
         assert flushed > 0, "shared budget never triggered a flush"
@@ -636,6 +639,7 @@ def test_pause_continue_background_work(tmp_db_path):
         db.pause_background_work()
         for i in range(600):
             db.put(b"key%05d" % i, b"x" * 30)
+        db.flush()  # the sealed memtables are files once this returns
         n_l0 = len(db.versions.current.files[0])
         assert n_l0 >= 2, "L0 should pile up while paused"
         db.continue_background_work()

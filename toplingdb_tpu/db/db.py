@@ -30,7 +30,10 @@ from toplingdb_tpu.db.snapshot import SnapshotList
 from toplingdb_tpu.db.table_cache import TableCache
 from toplingdb_tpu.db.version_edit import VersionEdit
 from toplingdb_tpu.db.version_set import VersionSet
+from toplingdb_tpu.utils.kill_point import test_kill_random
+from toplingdb_tpu.utils.listener import FlushJobInfo, notify
 from toplingdb_tpu.utils.sync_point import sync_point
+from toplingdb_tpu.utils.thread_status import thread_operation
 from toplingdb_tpu.db.write_batch import WriteBatch
 from toplingdb_tpu.env import Env, default_env
 from toplingdb_tpu.options import FlushOptions, Options, ReadOptions, WriteOptions
@@ -38,7 +41,7 @@ from toplingdb_tpu.utils import statistics as _st
 from toplingdb_tpu.utils import telemetry as _tm
 from toplingdb_tpu.table.merging_iterator import MergingIterator
 from toplingdb_tpu.utils.status import (
-    Busy, Corruption, InvalidArgument, IOError_, NotFound,
+    Busy, Corruption, InvalidArgument, IOError_, NoSpace, NotFound,
 )
 
 _DEFAULT_READ = ReadOptions()
@@ -133,6 +136,20 @@ class _CFData:
         self.mem = MemTable(icmp, create_memtable_rep(rep_name),
                             protection_bytes=protection_bytes)
         self.imm: list[MemTable] = []
+
+
+class _FlushUnit:
+    """What one memtable switch sealed: the memtable of every column family
+    that had rows, and the number of the WAL opened at that seal. The flush
+    thread takes units in seal order; once a unit and every older one are in
+    the MANIFEST no WAL below `wal_number` is needed, and `log_number` moves
+    there."""
+
+    __slots__ = ("mems", "wal_number")
+
+    def __init__(self, mems: dict, wal_number: int):
+        self.mems = mems            # cf_id -> MemTable
+        self.wal_number = wal_number
 
 
 class _SeqSnapshot:
@@ -329,6 +346,21 @@ class DB:
 
         self._mt_cv = ccy.Condition(lock=self._mutex)
         self._mt_inflight = 0
+        # Background flush: the writer seals (_switch_memtable) and hands
+        # the sealed unit to ONE flush thread, started at the first seal.
+        # _flush_cv (on _mutex) wakes the thread (a unit queued, resume(),
+        # close) and whoever waits for it: a writer at
+        # max_write_buffer_number, flush(), wait_for_compactions().
+        # _flush_failed is the error the queue's head unit met, with its
+        # traceback: the thread parks on it until resume().
+        self._flush_cv = ccy.Condition(lock=self._mutex)
+        self._flush_queue: "_deque[_FlushUnit]" = _deque()
+        self._flush_thread: threading.Thread | None = None
+        self._flush_failed: tuple | None = None
+        self._flush_stop = False
+        self._memtable_limit_waiters = 0
+        # Explicit flush() calls in flight: write groups wait meanwhile.
+        self._flush_fence = 0
         self._seq_alloc = 0
         self._alloc_ranges: "_deque[list]" = _deque()
         self._alloc_entry: dict[int, list] = {}  # first -> its deque entry
@@ -799,7 +831,13 @@ class DB:
         any_flushed = False
         for cf_id, cfd in self._cfs.items():
             if not cfd.mem.empty():
-                self._flush_memtables([cfd.mem], wal_number=None, cf_id=cf_id)
+                # Inline: nobody writes beside recovery.
+                with self._flush_root_span(1):
+                    built = [self._build_flush_table(cfd.mem, cf_id)]
+                    try:
+                        self._install_flush_tables(built, log_number=None)
+                    finally:
+                        self._release_flush_outputs(built)
                 cfd.mem = self._fresh_memtable()
                 any_flushed = True
         if any_flushed:
@@ -888,7 +926,14 @@ class DB:
             while self._mt_inflight > 0:
                 self._mt_cv.wait(timeout=10.0)
             if any(not c.mem.empty() or c.imm for c in self._cfs.values()):
+                # A unit whose flush failed is tried once more: whatever
+                # stays unflushed is in its WAL and is replayed at open.
+                self._flush_failed = None
+                self._flush_cv.notify_all()
                 self.flush(FlushOptions())
+            # The flush thread ends here; join_all below waits for it.
+            self._flush_stop = True
+            self._flush_cv.notify_all()
             if self._wal is not None:
                 self._wal.sync()
                 self._wal.close()
@@ -1209,15 +1254,7 @@ class DB:
                   and not group[0].opts.disable_wal)
         try:
             with self._mutex:
-                self._check_open()
-                if self._bg_error is not None:
-                    from toplingdb_tpu.utils.status import Severity as _Sev
-
-                    if self._bg_error_severity >= _Sev.HARD_ERROR:
-                        raise IOError_(
-                            f"background error pending (call resume()): "
-                            f"{self._bg_error!r}"
-                        )
+                self._check_writable()
                 first = max(self._seq_alloc,
                             self.versions.last_sequence) + 1
                 seq = first
@@ -1688,28 +1725,34 @@ class DB:
             self.stats.record_tick(
                 st.BYTES_WRITTEN, sum(w.batch.data_size() for w in group)
             )
-        total_mem = sum(
-            c.mem.approximate_memory_usage() for c in self._cfs.values()
-        )
-        wbm = self.options.write_buffer_manager
         self._sync_wbm()
-        if total_mem >= self.options.write_buffer_size or (
-                wbm is not None and wbm.should_flush()
-                and total_mem >= 4096):  # floor: don't thrash tiny DBs
-            self._switch_memtable()
-            self._flush_immutables()
+        if self._memtable_full():
+            self._seal_and_hand_over()
+
+    def _check_writable(self) -> None:
+        """Top of a write group (caller holds _mutex): the DB is open, no
+        hard background error is latched, and no failed flush has left a
+        full memtable with nowhere to go (the group would be written and
+        then have to wait for a flush that does not run). While an explicit
+        flush() runs, the group waits here."""
+        while self._flush_fence:
+            self._flush_cv.wait(timeout=10.0)
+        self._check_open()
+        if self._bg_error is not None:
+            from toplingdb_tpu.utils.status import Severity as _Sev
+
+            if self._bg_error_severity >= _Sev.HARD_ERROR:
+                raise IOError_(
+                    f"background error pending (call resume()): "
+                    f"{self._bg_error!r}"
+                )
+        if (self._flush_failed is not None and self._memtable_full()
+                and not self._memtable_room()):
+            raise self._flush_failure()
 
     def _commit_write_group(self, group: list[_Writer]) -> None:
         with self._mutex:
-            self._check_open()
-            if self._bg_error is not None:
-                from toplingdb_tpu.utils.status import Severity as _Sev
-
-                if self._bg_error_severity >= _Sev.HARD_ERROR:
-                    raise IOError_(
-                        f"background error pending (call resume()): "
-                        f"{self._bg_error!r}"
-                    )
+            self._check_writable()
             first_seq = max(self._seq_alloc, self.versions.last_sequence) + 1
             seq = first_seq
             for w in group:
@@ -1805,16 +1848,9 @@ class DB:
                 if len(group) > 1:
                     self.stats.record_tick(st.WRITE_DONE_BY_OTHER,
                                            len(group) - 1)
-            total_mem = sum(
-                c.mem.approximate_memory_usage() for c in self._cfs.values()
-            )
-            wbm = self.options.write_buffer_manager
             self._sync_wbm()
-            if total_mem >= self.options.write_buffer_size or (
-                    wbm is not None and wbm.should_flush()
-                    and total_mem >= 4096):  # floor: don't thrash tiny DBs
-                self._switch_memtable()
-                self._flush_immutables()
+            if self._memtable_full():
+                self._seal_and_hand_over()
 
     def _sync_wbm(self) -> None:
         """Reconcile this DB's memtable memory with the shared
@@ -1835,12 +1871,76 @@ class DB:
             wbm.free(-delta)
         self._wbm_charged = total
 
-    def _switch_memtable(self) -> None:
-        """Seal every CF's non-empty active memtable and start a new WAL
-        (reference DBImpl::SwitchMemtable; all-CF switching = atomic-flush
-        behavior so log_number can advance safely)."""
-        from toplingdb_tpu.utils.kill_point import test_kill_random
+    def _memtable_full(self) -> bool:
+        """Is it time to seal the active memtables (caller holds _mutex)?"""
+        total_mem = sum(
+            c.mem.approximate_memory_usage() for c in self._cfs.values()
+        )
+        if total_mem >= self.options.write_buffer_size:
+            return True
+        wbm = self.options.write_buffer_manager
+        return (wbm is not None and wbm.should_flush()
+                and total_mem >= 4096)  # floor: don't thrash tiny DBs
 
+    def _memtable_room(self) -> bool:
+        """May the active memtables be sealed with no column family holding
+        more than max_write_buffer_number memtables, the new active one
+        counted (caller holds _mutex)?"""
+        limit = max(1, self.options.max_write_buffer_number - 1)
+        return all(len(c.imm) < limit or c.mem.empty()
+                   for c in self._cfs.values())
+
+    def _flush_failure(self) -> BaseException:
+        """What a caller raises who needs the flush thread while it is
+        parked on a failed unit: the unit's own error, as when the flush
+        ran inline. Every raise starts from the traceback of the failure,
+        or the frames of all the callers so far would pile up on it."""
+        err, tb = self._flush_failed
+        return err.with_traceback(tb)
+
+    def _seal_and_hand_over(self) -> None:
+        """The writer's share of a flush (caller holds _mutex): wait for room
+        under max_write_buffer_number, seal, and leave the sealed unit to
+        the flush thread."""
+        t0 = time.perf_counter()
+        waited = 0.0 if self._memtable_room() \
+            else self._wait_for_memtable_room()
+        if self._closed or all(c.mem.empty() for c in self._cfs.values()):
+            return  # nothing to seal (any more: the wait released _mutex)
+        self._switch_memtable()
+        if self.stats is not None:
+            self.stats.record_in_histogram(
+                _st.MEMTABLE_SEAL_MICROS,
+                (time.perf_counter() - t0 - waited) * 1e6)
+
+    def _wait_for_memtable_room(self) -> float:
+        """The one wait of a put: max_write_buffer_number memtables are
+        unflushed, so the writer waits (the _mutex released meanwhile) for
+        the flush thread to install a unit. A failed flush ends the wait
+        with its error. Returns the seconds waited, which are a stall of
+        state "memtable_limit"."""
+        t0 = time.perf_counter()
+        self._memtable_limit_waiters += 1
+        try:
+            while not self._memtable_room() and not self._closed:
+                if self._flush_failed is not None:
+                    raise self._flush_failure()
+                self._flush_cv.wait(timeout=10.0)
+        finally:
+            self._memtable_limit_waiters -= 1
+            waited = time.perf_counter() - t0
+            self._account_stall("memtable_limit", waited)
+            if self.stats is not None:
+                self.stats.record_tick(_st.STALL_MEMTABLE_LIMIT_MICROS,
+                                       int(waited * 1e6))
+        return waited
+
+    def _switch_memtable(self) -> None:
+        """Seal every CF's non-empty active memtable, start a new WAL and
+        queue the sealed unit for the flush thread (reference
+        DBImpl::SwitchMemtable + MaybeScheduleFlushOrCompaction; all-CF
+        switching = atomic-flush behavior so log_number can advance
+        safely). Caller holds _mutex."""
         # Staged groups insert into the active memtables OUTSIDE _mutex
         # (pipelined/unordered modes): sealing a memtable mid-insert could
         # let the flush miss an already-published entry. Drain them first
@@ -1860,128 +1960,188 @@ class DB:
                 # Final size of the sealed WAL (tracked as 0 at creation).
                 self._sfm.on_add_file(filename.log_file_name(
                     self.dbname, self._wal_number))
-        for cfd in self._cfs.values():
-            if not cfd.mem.empty():
-                cfd.imm.insert(0, cfd.mem)
-                cfd.mem = self._fresh_memtable()
-        self._new_wal()
-
-    def _flush_immutables(self) -> None:
-        flushed = False
+        sealed = {}
         for cf_id, cfd in self._cfs.items():
-            if not cfd.imm:
-                continue
-            mems = list(cfd.imm)
-            self._flush_memtables(mems, wal_number=None, cf_id=cf_id)
-            cfd.imm = []
-            flushed = True
-        if flushed:
-            # Advance log_number only after EVERY CF's data below the current
-            # WAL is durable in SSTs — a crash mid-flush must still replay
-            # the old WALs for the unflushed CFs.
-            self.versions.log_and_apply(VersionEdit(log_number=self._wal_number))
-            self._delete_obsolete_files()
-            self._maybe_schedule_compaction()
-        self._sync_wbm()
+            if not cfd.mem.empty():
+                sealed[cf_id] = cfd.mem
+                # A new list, never an insert: readers walk imm unlocked.
+                cfd.imm = [cfd.mem] + cfd.imm
+                cfd.mem = self._fresh_memtable()
+        try:
+            self._new_wal()
+        finally:
+            if sealed:
+                self._flush_queue.append(
+                    _FlushUnit(sealed, self._wal_number))
+                if self.stats is not None:
+                    self.stats.record_tick(_st.FLUSH_UNITS_HANDED_OVER)
+                if self._flush_thread is None:
+                    self._flush_thread = ccy.spawn(
+                        "db-flush", self._flush_loop, owner=self)
+                self._flush_cv.notify_all()
 
-    def _flush_memtables(self, mems: list[MemTable], wal_number: int | None,
-                         cf_id: int = 0) -> None:
-        from toplingdb_tpu.utils.sync_point import sync_point
+    def _flush_loop(self) -> None:
+        """The DB's one flush thread: sealed units in seal order, so L0
+        files appear oldest first and log_number never passes a WAL that
+        an unflushed unit needs. A unit that fails stays at the head with
+        its memtables in imm (and its rows in their WAL); the thread parks
+        on it until resume() or close() clears _flush_failed."""
+        while True:
+            with self._mutex:
+                while not self._flush_stop and (
+                        not self._flush_queue
+                        or self._flush_failed is not None):
+                    self._flush_cv.wait()
+                if self._flush_stop:
+                    return
+                unit = self._flush_queue[0]
+            try:
+                self._flush_unit(unit)
+            except BaseException as e:  # noqa: BLE001 — latched below
+                with self._mutex:
+                    self._flush_failed = (e, e.__traceback__)
+                    self._flush_cv.notify_all()
+                # A MANIFEST failure in the install carries its own reason
+                # (version_set.log_and_apply) and latches FATAL.
+                self._set_background_error(
+                    e, reason=getattr(e, "_bg_reason", "flush"))
 
+    def _flush_root_span(self, memtables: int):
+        """Flushes are rare and high-value: always traced while a tracer
+        exists (sampling applies to the per-op read/write roots only). The
+        root stays open over the install, so that its flush_finished
+        events carry the trace's id."""
+        return (self.tracer.start("flush", memtables=memtables)
+                if self.tracer is not None else _tm.NOOP_SPAN)
+
+    def _flush_unit(self, unit: _FlushUnit) -> None:
+        """Build the unit's tables with no DB lock held; then, in ONE hold
+        of _mutex, put them into the version, move log_number, and only
+        then drop the memtables from imm: a reader walks [mem] + imm and
+        then the version, unlocked, and must find a row in one of them."""
+        built = []
+        try:
+            with self._flush_root_span(len(unit.mems)):
+                for cf_id, mem in unit.mems.items():
+                    if cf_id in self._cfs:  # else dropped since the seal
+                        built.append(self._build_flush_table(mem, cf_id))
+                with self._mutex:
+                    self._install_flush_tables(built, unit.wal_number)
+                    sync_point("FlushJob::BeforeImmDrop")
+                    for cf_id, mem in unit.mems.items():
+                        cfd = self._cfs.get(cf_id)
+                        if cfd is not None:
+                            cfd.imm = [m for m in cfd.imm if m is not mem]
+                    self._flush_queue.popleft()
+                    if self.stats is not None:
+                        self.stats.record_tick(_st.FLUSH_UNITS_INSTALLED)
+                    self._sync_wbm()
+                    self._flush_cv.notify_all()
+                    self._delete_obsolete_files()
+                    self._maybe_schedule_compaction()
+        finally:
+            self._release_flush_outputs(built)
+
+    def _release_flush_outputs(self, built: list) -> None:
+        """The tables are in the version, or will never be: either way the
+        guard against the obsolete-file sweep has done its work."""
+        with self._mutex:
+            for _cf_id, _meta, numbers, _t0 in built:
+                self._pending_outputs.difference_update(numbers)
+
+    def _build_flush_table(self, mem: MemTable, cf_id: int):
+        """One column family's memtable into one L0 table file; _mutex is
+        taken for the file numbers only. Returns (cf_id, meta or None, the
+        file numbers, guarded in _pending_outputs until
+        _release_flush_outputs, and the start time)."""
         sync_point("FlushJob::Start")
         if self._sfm is not None:
             # Preflight: refuse to START a flush only when even the
             # reserved flush/WAL headroom can't absorb it (flushes may
             # spend the headroom compactions must leave alone, so a
-            # red-pressure DB still drains its memtables). A refusal
-            # latches SOFT no_space — ingest resumes when space frees.
-            est = sum(m.approximate_memory_usage() for m in mems)
+            # red-pressure DB still drains its memtables). The refusal
+            # latches SOFT no_space (_set_background_error re-reasons a
+            # NoSpace) — ingest resumes when space frees.
+            est = mem.approximate_memory_usage()
             if not self._sfm.check_flush(est):
                 if self.stats is not None:
                     self.stats.record_tick(_st.NO_SPACE_PREFLIGHT_BLOCKS, 1)
-                from toplingdb_tpu.utils.status import NoSpace
-
-                err = NoSpace(
+                raise NoSpace(
                     f"flush of ~{est} bytes would breach the disk budget")
-                self._set_background_error(err, reason="no_space")
-                raise err
-        from toplingdb_tpu.utils.thread_status import thread_operation
-
-        with thread_operation("flush", f"cf{cf_id}", self.dbname):
-            self._flush_memtables_inner(mems, wal_number, cf_id)
-
-    def _flush_memtables_inner(self, mems: list[MemTable],
-                               wal_number: int | None, cf_id: int) -> None:
-        # Flushes are rare and high-value: always traced while a tracer
-        # exists (sampling applies to the per-op read/write roots only).
-        _root = (self.tracer.start("flush", cf_id=cf_id,
-                                   memtables=len(mems))
-                 if self.tracer is not None else _tm.NOOP_SPAN)
-        try:
-            self._flush_memtables_traced(mems, wal_number, cf_id)
-        finally:
-            _root.finish()
-
-    def _flush_memtables_traced(self, mems: list[MemTable],
-                                wal_number: int | None, cf_id: int) -> None:
         t0 = time.time()
         if self._seqno_time_dirty:
-            # Every flush path (auto-switch, write-path stall, bg worker)
-            # funnels here off the write hot path: persist pending
-            # seqno-time samples so a crash doesn't lose them and make
-            # all existing data look young after reopen.
+            # Every flush funnels here, off the write hot path: persist
+            # pending seqno-time samples so a crash doesn't lose them and
+            # make all existing data look young after reopen.
             self._save_seqno_time()
-        fnum = self.versions.new_file_number()
-        blob_num = (
-            self.versions.new_file_number()
-            if self.options.enable_blob_files else None
-        )
-        # Guard in-flight outputs (incl. the blob sibling) from obsolete-file
-        # GC until the version edit lands.
-        self._pending_outputs.add(fnum)
-        if blob_num is not None:
-            self._pending_outputs.add(blob_num)
+        with self._mutex:
+            fnum = self.versions.new_file_number()
+            blob_num = (
+                self.versions.new_file_number()
+                if self.options.enable_blob_files else None
+            )
+            # Guard in-flight outputs (incl. the blob sibling) from
+            # obsolete-file GC until the version edit lands.
+            numbers = (fnum,) if blob_num is None else (fnum, blob_num)
+            self._pending_outputs.update(numbers)
         try:
-            with _tm.span("flush.build_table", file_number=fnum):
+            with thread_operation("flush", f"cf{cf_id}", self.dbname), \
+                    _tm.span("flush.build_table", file_number=fnum,
+                             cf_id=cf_id):
                 meta = flush_memtable_to_table(
-                    self.env, self.dbname, fnum, self.icmp, mems,
+                    self.env, self.dbname, fnum, self.icmp, [mem],
                     self.options.table_options_for_level(0),
-                    creation_time=int(time.time()),
+                    creation_time=int(t0),
                     blob_file_number=blob_num,
                     min_blob_size=self.options.min_blob_size,
                     column_family=(cf_id, self.cf_name(cf_id)),
                 )
-            from toplingdb_tpu.utils.kill_point import test_kill_random
-
             test_kill_random("FlushJob::AfterTableWrite")
             if meta is not None:
                 self._stamp_file_checksums([meta])
-            edit = VersionEdit(log_number=wal_number, column_family=cf_id)
-            if meta is not None:
+        except BaseException:
+            with self._mutex:
+                self._pending_outputs.difference_update(numbers)
+            raise
+        return cf_id, meta, numbers, t0
+
+    def _install_flush_tables(self, built: list,
+                              log_number: int | None) -> None:
+        """Put built flush tables into the MANIFEST and the version (caller
+        holds _mutex). `log_number` rides on the last edit, so it moves
+        only when every column family's table is in: a crash before that
+        replays the unit's WALs."""
+        edits = []
+        for cf_id, meta, _numbers, _t0 in built:
+            # An edit for a family dropped since the build would be
+            # discarded whole, the log_number on it too.
+            if meta is not None and cf_id in self.versions.column_families:
+                edit = VersionEdit(column_family=cf_id)
                 edit.add_file(0, meta)
+                edits.append(edit)
+        if log_number is not None:
+            if not edits:
+                edits.append(VersionEdit())
+            edits[-1].log_number = log_number
+        for edit in edits:
             self.versions.log_and_apply(edit)
-        finally:
-            self._pending_outputs.discard(fnum)
-            if blob_num is not None:
-                self._pending_outputs.discard(blob_num)
-        if meta is not None and self._sfm is not None:
-            self._sfm.on_add_file(
-                filename.table_file_name(self.dbname, meta.number),
-                meta.file_size)
-            if blob_num is not None:
-                from toplingdb_tpu.db.blob import blob_file_name
+        for _cf_id, meta, numbers, t0 in built:
+            if meta is None:
+                continue
+            if self._sfm is not None:
+                self._sfm.on_add_file(
+                    filename.table_file_name(self.dbname, meta.number),
+                    meta.file_size)
+                if len(numbers) > 1:
+                    from toplingdb_tpu.db.blob import blob_file_name
 
-                bpath = blob_file_name(self.dbname, blob_num)
-                if self.env.file_exists(bpath):
-                    self._sfm.on_add_file(bpath)
-        if meta is not None:
-            from toplingdb_tpu.utils import statistics as st
-            from toplingdb_tpu.utils.listener import FlushJobInfo, notify
-
+                    bpath = blob_file_name(self.dbname, numbers[1])
+                    if self.env.file_exists(bpath):
+                        self._sfm.on_add_file(bpath)
             if self.stats is not None:
-                self.stats.record_tick(st.FLUSH_WRITE_BYTES, meta.file_size)
+                self.stats.record_tick(_st.FLUSH_WRITE_BYTES, meta.file_size)
                 self.stats.record_in_histogram(
-                    st.FLUSH_TIME_MICROS, (time.time() - t0) * 1e6
+                    _st.FLUSH_TIME_MICROS, (time.time() - t0) * 1e6
                 )
             self.event_logger.log(
                 "flush_finished", file_number=meta.number,
@@ -1995,12 +2155,34 @@ class DB:
                        largest_seqno=meta.largest_seqno,
                    ))
 
+    def _wait_for_flushes(self) -> None:
+        """Return when the flush thread has installed every sealed unit, so
+        imm is empty (caller holds _mutex, released while waiting); raise
+        if the thread is parked on a failed one."""
+        if threading.current_thread() is self._flush_thread:
+            return  # a listener on the flush thread: it would wait for itself
+        while self._flush_queue:
+            if self._flush_failed is not None:
+                raise self._flush_failure()
+            self._flush_cv.wait(timeout=10.0)
+
     def flush(self, fopts: FlushOptions = FlushOptions()) -> None:
+        """Seal what the memtables hold; with fopts.wait (the default),
+        return when every sealed memtable is an L0 file in the version.
+        Write groups are held out meanwhile, as when the flush ran under
+        _mutex: a caller that holds _mutex around flush() (checkpoint,
+        export, ingest) finds the version and last_sequence of one moment,
+        though the wait for the flush thread releases the lock."""
         with self._mutex:
             self._check_open()
-            if any(not c.mem.empty() for c in self._cfs.values()):
-                self._switch_memtable()
-            self._flush_immutables()
+            self._seal_and_hand_over()
+            if fopts.wait:
+                self._flush_fence += 1
+                try:
+                    self._wait_for_flushes()
+                finally:
+                    self._flush_fence -= 1
+                    self._flush_cv.notify_all()
         if self._seqno_time_dirty:
             self._save_seqno_time()  # outside _mutex: best-effort IO
 
@@ -2428,25 +2610,30 @@ class DB:
         """Queryable write-stall state (the sharding router's backpressure
         signal, also exposed as /metrics gauges): the LIVE state derived
         from L0 file counts vs the slowdown/stop triggers — "none",
-        "delayed", or "stopped" — plus cumulative stall totals. `drainable`
-        is False when nothing can reduce L0 (auto compaction off /
-        scheduler paused), in which case writes are never stalled either."""
+        "delayed", or "stopped" — or "memtable_limit" while a writer waits
+        for the flush thread with max_write_buffer_number memtables
+        unflushed; plus cumulative stall totals. `drainable` is False when
+        nothing can reduce L0 (auto compaction off / scheduler paused), in
+        which case writes are never stalled by L0 either."""
         opts = self.options
         n_l0 = self._max_l0_files()
         drainable = not (opts.disable_auto_compactions
                          or self._compaction_scheduler is None
                          or self._compaction_scheduler._paused)
-        if not drainable:
-            state = "none"
-        elif n_l0 >= opts.level0_stop_writes_trigger:
+        if drainable and n_l0 >= opts.level0_stop_writes_trigger:
             state = "stopped"
-        elif n_l0 >= opts.level0_slowdown_writes_trigger:
+        elif drainable and n_l0 >= opts.level0_slowdown_writes_trigger:
             state = "delayed"
+        elif self._memtable_limit_waiters:
+            state = "memtable_limit"
         else:
             state = "none"
         out = dict(self._stall_totals)
         out.update(
             state=state,
+            immutable_memtables=max(
+                (len(c.imm) for c in self._cfs.values()), default=0),
+            memtable_limit=max(1, opts.max_write_buffer_number - 1),
             l0_files=n_l0,
             drainable=drainable,
             slowdown_trigger=opts.level0_slowdown_writes_trigger,
@@ -3459,6 +3646,10 @@ class DB:
             self.versions.log_and_apply(edit)
 
     def wait_for_compactions(self) -> None:
+        """Return when no sealed memtable waits for the flush thread and
+        no compaction is running or pending."""
+        with self._mutex:
+            self._wait_for_flushes()
         if self._compaction_scheduler is not None:
             self._compaction_scheduler.wait_idle()
         if self._bg_error is not None:
@@ -3622,6 +3813,9 @@ class DB:
             self._bg_error = None
             self._bg_error_severity = _Sev.NO_ERROR
             self._bg_error_reason = ""
+            # The flush thread tries its failed unit again.
+            self._flush_failed = None
+            self._flush_cv.notify_all()
         if had is not None:
             if self.stats is not None:
                 self.stats.record_tick(_st.BG_ERROR_RESUMES, 1)
@@ -4089,6 +4283,10 @@ class DB:
             return [filename.log_file_name("", n) for n in nums]
 
     def pause_background_work(self) -> None:
+        """Sealed memtables are flushed first; flushes go on while paused
+        (L0 piles up), compactions do not."""
+        with self._mutex:
+            self._wait_for_flushes()
         if self._compaction_scheduler is not None:
             self._compaction_scheduler.pause()
 
@@ -4242,6 +4440,8 @@ class DB:
                 + sum(m.approximate_memory_usage() for m in c.imm)
                 for c in self._cfs.values()
             ))
+        if name == "tpulsm.num-immutable-mem-table":
+            return str(sum(len(c.imm) for c in self._cfs.values()))
         if name == "tpulsm.num-snapshots":
             return str(self.snapshots.num_live())
         if name == "tpulsm.estimate-live-data-size":

@@ -94,10 +94,11 @@ def _flush_columnar(env, dbname, file_number, icmp, mem, table_options,
     """Single-memtable columnar flush: ONE native export of the whole rep +
     the native block-building SST writer — no per-entry Python. Returns the
     FileMetaData, or None when ineligible (caller uses the iterator path).
-    This is the write-path half of the memtable performance story: without
-    it, flushing a full memtable walks ~10^5 Python iterations while the
-    write group waits (reference FlushJob::WriteLevel0Table's tight C++
-    scan, db/flush_job.cc:833)."""
+    It runs on the DB's flush thread beside the writer (db/db.py
+    `_flush_loop`): the two native calls release the GIL, and what is left
+    in Python here is taken from the writer a switch interval at a time —
+    without this path a flush walks ~10^5 Python iterations (reference
+    FlushJob::WriteLevel0Table's tight C++ scan, db/flush_job.cc:833)."""
     from toplingdb_tpu.db import dbformat as _dbf
 
     if (getattr(table_options, "format", "block") != "block"

@@ -432,6 +432,19 @@ class Bench:
         for cf_id, cfd in self.db._cfs.items():
             print(f"cf{cf_id} mem_entries={cfd.mem.num_entries} "
                   f"imm={len(cfd.imm)}")
+        stats = self.db.stats
+        if stats is not None:
+            from toplingdb_tpu.utils import statistics as st
+
+            seal = stats.get_histogram(st.MEMTABLE_SEAL_MICROS)
+            print(f"flush units handed_over="
+                  f"{stats.get_ticker_count(st.FLUSH_UNITS_HANDED_OVER)} "
+                  f"installed="
+                  f"{stats.get_ticker_count(st.FLUSH_UNITS_INSTALLED)} "
+                  f"memtable_limit_wait_us="
+                  f"{stats.get_ticker_count(st.STALL_MEMTABLE_LIMIT_MICROS)}"
+                  f" seal_us_p50={seal.percentile(50):.0f} "
+                  f"seal_us_max={seal.max:.0f}")
         return 1
 
 

@@ -331,6 +331,9 @@ def load_latest_options(dbname: str, env=None):
 
 
 _BREAKER_STATE_NUM = {"closed": 0, "half_open": 1, "open": 2}
+# DB.write_stall_state()["state"] as a gauge value.
+_STALL_STATE_NUM = {"none": 0, "delayed": 1, "stopped": 2,
+                    "memtable_limit": 3}
 
 
 def _prometheus_gauges(name: str, db) -> str:
@@ -408,8 +411,7 @@ def _prometheus_gauges(name: str, db) -> str:
         if stall_fn is not None:
             stall = stall_fn()
             g("write_stall_state",
-              {"none": 0, "delayed": 1, "stopped": 2}.get(
-                  stall.get("state"), -1))
+              _STALL_STATE_NUM.get(stall.get("state"), -1))
             g("write_stall_l0_files", stall.get("l0_files", 0))
             g("write_stall_micros_total", stall.get("stall_micros", 0))
     except Exception as e:
@@ -463,8 +465,7 @@ def _prometheus_cluster_gauges(name: str, router) -> str:
             g("shard_epoch", row["epoch"], lab)
             g("shard_fenced", int(bool(row.get("fenced"))), lab)
             g("shard_stall_state",
-              {"none": 0, "delayed": 1, "stopped": 2}.get(
-                  row.get("stall"), -1), lab)
+              _STALL_STATE_NUM.get(row.get("stall"), -1), lab)
             if row.get("health") is not None:
                 from toplingdb_tpu.utils.slo import health_num
 

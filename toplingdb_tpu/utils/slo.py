@@ -325,7 +325,7 @@ def health_score(stall_state: str | None = None,
     score = _HEALTH_RANK.get(slo_health, 0)
     if stall_state == "stopped":
         score = max(score, 2)
-    elif stall_state == "delayed":
+    elif stall_state in ("delayed", "memtable_limit"):
         score = max(score, 1)
     if breakers_open > 0 or lag_exceeded:
         score = max(score, 1)

@@ -171,12 +171,20 @@ FLEET_HEARTBEAT_MISSES = "fleet.heartbeat.misses"  # renew attempts that failed
 FLEET_MIGRATIONS_RECOVERED = "fleet.migrations.recovered"  # cross-process recover
 # -- flush / WAL / files ---------------------------------------------
 FLUSH_WRITE_BYTES = "flush.write.bytes"
+# Background flush (db/db.py): sealed units (the memtables of one
+# memtable switch) handed to the DB's flush thread, and units that thread
+# has put into the MANIFEST. Equal when the queue is empty.
+FLUSH_UNITS_HANDED_OVER = "flush.units.handed.over"
+FLUSH_UNITS_INSTALLED = "flush.units.installed"
 NO_FILE_OPENS = "no.file.opens"
 NO_FILE_CLOSES = "no.file.closes"
 NO_FILE_ERRORS = "no.file.errors"
 # -- stalls ----------------------------------------------------------
 STALL_MICROS = "stall.micros"
 WRITE_STALL_COUNT = "write.stall.count"
+# A writer's wait for the flush thread with max_write_buffer_number
+# memtables unflushed; these microseconds are inside stall.micros too.
+STALL_MEMTABLE_LIMIT_MICROS = "stall.memtable.limit.micros"
 # -- transactions ----------------------------------------------------
 TXN_COMMIT = "txn.commit"
 TXN_ROLLBACK = "txn.rollback"
@@ -248,6 +256,10 @@ DCOMPACTION_WAITING_MICROS = "dcompaction.waiting.micros"
 DCOMPACTION_RPC_MICROS = "dcompaction.rpc.micros"
 DCOMPACTION_ATTEMPT_MICROS = "dcompaction.attempt.micros"
 FLUSH_TIME_MICROS = "flush.time.micros"
+# What a flush leaves on the writer's thread: the memtable switch (drain,
+# sync and close the sealed WAL, open the next), less any wait at the
+# memtable limit.
+MEMTABLE_SEAL_MICROS = "memtable.seal.micros"
 SST_READ_MICROS = "sst.read.micros"
 TABLE_OPEN_IO_MICROS = "table.open.io.micros"
 WAL_FILE_SYNC_MICROS = "wal.file.sync.micros"
