@@ -81,7 +81,7 @@ SOURCE_KEYS = 100_000_000      # the source's key count (BASELINE.json config 2)
 DEFAULT_KEYS = 10_000_000      # the smoke's cut of it
 BIG_JOB_ROWS = 1 << 21         # the byte-parity job is at least this large
 # Above this many input rows a job has >= 2 pipeline shards of <= 2^19
-# rows (ops/pipeline.py _pipeline_shards), so it must run pipelined.
+# rows (ops/compaction_kernels.py shard_count), so it must run pipelined.
 PIPELINE_FLOOR_ROWS = (1 << 19) + 1
 EXIT_REHEARSAL = 4             # --rehearse-cpu completed; never a pass
 

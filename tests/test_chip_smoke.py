@@ -146,11 +146,12 @@ def test_a_tpu_job_on_xla_cpu_raises_instead_of_returning_tpu_stats(tmp_path):
 
 def test_a_failing_kernel_raises_and_leaves_the_environment_alone(
         tmp_path, monkeypatch):
-    """Regression for the deleted retry: it used to set TPULSM_PALLAS_GC=0
-    and TPULSM_DEVICE_MERGE=0 in os.environ, clear the jit caches and run
-    the job again, so a Mosaic refusal never reached a caller."""
+    """Regression for the deleted retry: it used to switch the kernels
+    through os.environ, clear the jit caches and run the job again, so a
+    Mosaic refusal never reached a caller."""
     import jax
 
+    from toplingdb_tpu.ops import compaction_kernels as ck
     from toplingdb_tpu.ops import pallas_kernels
     from toplingdb_tpu.ops.device_compaction import run_device_compaction
 
@@ -160,7 +161,8 @@ def test_a_failing_kernel_raises_and_leaves_the_environment_alone(
     def refuse(*_a, **_kw):
         raise MosaicRefusal("unsupported shape cast")
 
-    monkeypatch.setenv("TPULSM_PALLAS_GC", "1")  # the accelerator program
+    # the accelerator's program, on this backend
+    monkeypatch.setattr(ck, "_want_pallas_gc", lambda: True)
     monkeypatch.setattr(pallas_kernels, "gc_rows", refuse)
     env, dbdir, icmp, c, tc, topts = _two_run_job(tmp_path)
     jax.clear_caches()  # the kernel choice is made at trace time

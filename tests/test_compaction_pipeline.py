@@ -15,8 +15,10 @@ from toplingdb_tpu.db.dbformat import (
     ValueType,
     make_internal_key,
 )
+from toplingdb_tpu.ops import pipeline as pl
 
 ICMP = InternalKeyComparator()
+_PIPELINE_ENABLED = pl.pipeline_enabled
 
 
 def _build_runs(env, dbdir, n_total, topts, seed=1, runs=4, first_fnum=21,
@@ -137,10 +139,18 @@ def _sst_bytes(env, dbdir, outs):
 
 
 def _enable_small_pipeline(monkeypatch, shards=4):
-    from toplingdb_tpu.ops import pipeline as pl
+    """A test-sized job is cut into `shards` (the rule would leave it one
+    shard, which the pipeline leaves to the serial path)."""
+    from toplingdb_tpu.ops import compaction_kernels as ck
 
-    monkeypatch.setattr(pl, "MIN_PIPELINE_ROWS", 256)
-    monkeypatch.setenv("TPULSM_PIPELINE_SHARDS", str(shards))
+    monkeypatch.setattr(ck, "shard_count", lambda total_rows: shards)
+
+
+def _pipeline(monkeypatch, on: bool):
+    """Send jobs down the serial path (on=False), or give the choice back
+    to the program."""
+    monkeypatch.setattr(pl, "pipeline_enabled",
+                        _PIPELINE_ENABLED if on else (lambda *_a: False))
 
 
 def _spy_pipeline(monkeypatch):
@@ -190,10 +200,10 @@ def test_pipeline_byte_parity(tmp_path, monkeypatch, codec, mode):
     metas = _build_runs(env, dbdir, n, topts, seed=3, tombstone_file=True)
     snapshots = [n // 3, 2 * n // 3]
 
-    monkeypatch.setenv("TPULSM_PIPELINE", "0")
+    _pipeline(monkeypatch, False)
     out_serial, _ = _run_job(env, dbdir, metas, topts, topts, 1000, snapshots)
     assert not calls
-    monkeypatch.setenv("TPULSM_PIPELINE", "1")
+    _pipeline(monkeypatch, True)
     out_pipe, stats = _run_job(env, dbdir, metas, topts, topts, 2000,
                                snapshots)
     assert calls, "pipeline did not engage"
@@ -225,7 +235,7 @@ def test_pipeline_multi_output_cut_parity(tmp_path, monkeypatch):
     metas = _build_runs(env, dbdir, 20_000, topts, seed=5)
     outs = {}
     for knob in ("0", "1"):
-        monkeypatch.setenv("TPULSM_PIPELINE", knob)
+        _pipeline(monkeypatch, knob == "1")
         tc = TableCache(env, dbdir, ICMP, topts)
         c = Compaction(level=0, output_level=2, inputs=list(metas),
                        bottommost=True, max_output_file_size=64 * 1024)
@@ -341,11 +351,11 @@ def test_pipeline_zip_byte_parity(tmp_path, monkeypatch):
     metas = _build_runs(env, dbdir, n, topts, seed=3, tombstone_file=True)
     snapshots = [n // 3, 2 * n // 3]
 
-    monkeypatch.setenv("TPULSM_PIPELINE", "0")
+    _pipeline(monkeypatch, False)
     out_serial, _ = _run_job(env, dbdir, metas, topts, zip_topts, 1000,
                              snapshots)
     assert not calls
-    monkeypatch.setenv("TPULSM_PIPELINE", "1")
+    _pipeline(monkeypatch, True)
     out_pipe, _ = _run_job(env, dbdir, metas, topts, zip_topts, 2000,
                            snapshots)
     assert calls, "zip job did not ride the pipeline"
@@ -482,19 +492,24 @@ def test_pipeline_soak_acknowledged_writes_survive(monkeypatch, seed):
     """Seeded soak with the pipeline forced on for every compaction
     (tests/test_fault_soak.py's model-checked shape): every acknowledged
     write survives flush+compaction cycles and a clean reopen."""
+    from toplingdb_tpu.compaction.executor import (
+        DeviceCompactionExecutorFactory,
+    )
     from toplingdb_tpu.db.db import DB
     from toplingdb_tpu.options import Options
 
     _enable_small_pipeline(monkeypatch, shards=3)
+    calls = _spy_pipeline(monkeypatch)
     monkeypatch.setenv("TPULSM_HOST_SORT", "1")
-    monkeypatch.setenv("TPULSM_PIPELINE", "1")
     rng = random.Random(seed)
     root = tempfile.mkdtemp(prefix=f"pipesoak{seed}_")
     d = root + "/db"
     model = {}
     try:
-        db = DB.open(d, Options(write_buffer_size=8 * 1024,
-                                level0_file_num_compaction_trigger=3))
+        db = DB.open(d, Options(
+            write_buffer_size=8 * 1024, level0_file_num_compaction_trigger=3,
+            compaction_executor_factory=DeviceCompactionExecutorFactory(
+                device="cpu-jax", allow_fallback=False)))
         for cycle in range(5):
             for _ in range(rng.randrange(150, 400)):
                 k = b"k%04d" % rng.randrange(600)
@@ -515,6 +530,7 @@ def test_pipeline_soak_acknowledged_writes_survive(monkeypatch, seed):
                     if k not in model and db.get(k) is not None]
             assert not gone, (cycle, gone[:3])
         db.close()
+        assert calls, "no compaction ran pipelined"
         with DB.open(d, Options()) as db2:
             bad = [k for k, v in model.items() if db2.get(k) != v]
             assert not bad, bad[:3]

@@ -13,6 +13,7 @@ from toplingdb_tpu.db.dbformat import (
     make_internal_key,
 )
 from toplingdb_tpu.db.range_del import RangeDelAggregator, RangeTombstone
+from toplingdb_tpu.ops import compaction_kernels as ck
 from toplingdb_tpu.ops.device_compaction import device_gc_entries
 from toplingdb_tpu.utils.merge_operator import UInt64AddOperator
 
@@ -472,8 +473,9 @@ def test_host_sort_tombstone_path_byte_parity(tmp_path, monkeypatch):
 
 
 def test_multi_shard_parity(tmp_path, monkeypatch):
-    """TPULSM_DEVICE_SHARDS>1 splits the job into user-key-range shards
-    (per-shard device programs, stitched survivor orders); bytes must equal
+    """The serial branch cuts a job of several shards into user-key-range
+    shards (per-shard device programs, stitched survivor orders); bytes
+    must equal
     the single-shard device path and the CPU path — both uniform-length and
     variable-length keys."""
     from toplingdb_tpu.compaction.compaction_job import run_compaction_to_tables
@@ -481,7 +483,7 @@ def test_multi_shard_parity(tmp_path, monkeypatch):
     from toplingdb_tpu.db.table_cache import TableCache
     from toplingdb_tpu.db.version_edit import FileMetaData
     from toplingdb_tpu.env import default_env
-    from toplingdb_tpu.ops import device_compaction as dc
+    from toplingdb_tpu.ops import pipeline as pl
     from toplingdb_tpu.ops.device_compaction import run_device_compaction
     from toplingdb_tpu.table.builder import TableBuilder, TableOptions
     import os
@@ -489,8 +491,8 @@ def test_multi_shard_parity(tmp_path, monkeypatch):
 
     env = default_env()
     topts = TableOptions(block_size=512)
-    # Shard even the small test inputs.
-    monkeypatch.setattr(dc, "_SHARD_MIN_ROWS", 1)
+    # The serial branch, whatever the shard count.
+    monkeypatch.setattr(pl, "pipeline_enabled", lambda *_a: False)
     for mode, keyfmt in (
         ("uniform", lambda r: b"key%05d" % r.randrange(400)),
         ("varlen", lambda r: b"k%0*d" % (r.randrange(3, 9), r.randrange(400))),
@@ -543,14 +545,15 @@ def test_multi_shard_parity(tmp_path, monkeypatch):
             c = Compaction(level=0, output_level=2, inputs=list(metas),
                            bottommost=True, max_output_file_size=1 << 62)
             if shards:
-                monkeypatch.setenv("TPULSM_DEVICE_SHARDS", str(shards))
+                # Shard even the small test inputs.
+                monkeypatch.setattr(ck, "shard_count",
+                                    lambda total_rows, n=shards: n)
                 outs[shards], _ = run_device_compaction(
                     env, dbdir, ICMP, c, tc, topts, [250, 600],
                     new_file_number=mk(500 + shards * 20), creation_time=7,
                     device_name="cpu-jax",
                 )
             else:
-                monkeypatch.delenv("TPULSM_DEVICE_SHARDS", raising=False)
                 outs[0], _ = run_compaction_to_tables(
                     env, dbdir, ICMP, c, tc, topts, [250, 600],
                     new_file_number=mk(490), creation_time=7,
@@ -582,6 +585,7 @@ def test_device_columnar_complex_tombstones_snapshots(tmp_path, monkeypatch,
     from toplingdb_tpu.db.version_edit import FileMetaData
     from toplingdb_tpu.env import default_env
     from toplingdb_tpu.ops import device_compaction as dc
+    from toplingdb_tpu.ops import pipeline as pl
     from toplingdb_tpu.ops.device_compaction import run_device_compaction
     from toplingdb_tpu.table.builder import TableBuilder, TableOptions
     import toplingdb_tpu.db.filename as fn
@@ -591,11 +595,9 @@ def test_device_columnar_complex_tombstones_snapshots(tmp_path, monkeypatch,
     dbdir = str(tmp_path / f"s{shards}")
     os.makedirs(dbdir)
     rng = random.Random(77 + shards)
-    if shards:
-        monkeypatch.setattr(dc, "_SHARD_MIN_ROWS", 1)
-        monkeypatch.setenv("TPULSM_DEVICE_SHARDS", str(shards))
-    else:
-        monkeypatch.delenv("TPULSM_DEVICE_SHARDS", raising=False)
+    if shards:  # several shards, on the serial branch
+        monkeypatch.setattr(pl, "pipeline_enabled", lambda *_a: False)
+        monkeypatch.setattr(ck, "shard_count", lambda total_rows: shards)
 
     metas = []
     seq = 1

@@ -13,18 +13,64 @@ from toplingdb_tpu.db.db import DB
 from toplingdb_tpu.env import PosixEnv
 from toplingdb_tpu.env.fault_injection import FaultInjectionEnv
 from toplingdb_tpu.options import Options, WriteOptions
+from toplingdb_tpu.utils.status import IOError_
+
+
+def _fail_the_next_manifest_sync(db, fe):
+    """Arm a sync fault around the flush thread's next install: its table
+    is built and synced, the MANIFEST record is appended, and the sync of
+    the MANIFEST fails, which is the one failure resume() refuses."""
+    install = db._install_flush_tables
+
+    def armed(built, log_number):
+        del db._install_flush_tables  # this install only
+        fe.fail_ops = {"sync"}
+        try:
+            return install(built, log_number)
+        finally:
+            fe.fail_ops = set()
+
+    db._install_flush_tables = armed
 
 
 @pytest.mark.parametrize("seed", [0, 3])
 def test_intermittent_io_faults_preserve_acknowledged_writes(seed):
+    """Since the flush runs on its own thread a fault of the window may
+    meet the writer (the put is rejected), the flush or a compaction (a
+    background error that resume() clears) or a MANIFEST write (FATAL:
+    resume() refuses, and the DB is closed and opened again, as the error
+    says). Which of them, timing decides; cycles 1 and 2 make the last two
+    happen in every run. Whatever happened: every acknowledged write reads back, and
+    no rejected write shows while the DB stays open. A reopen after a FATAL
+    error reads the log again, and a write whose sync failed AFTER its
+    append is in the log: it may come back then, once, and is the key's
+    value from there on."""
     rng = random.Random(seed)
     fe = FaultInjectionEnv(PosixEnv())
     root = tempfile.mkdtemp(prefix=f"faultt{seed}_")
     d = root + "/db"
-    db = DB.open(d, Options(write_buffer_size=8 * 1024,
-                            level0_file_num_compaction_trigger=3), env=fe)
+
+    def open_db():
+        return DB.open(d, Options(write_buffer_size=8 * 1024,
+                                  level0_file_num_compaction_trigger=3),
+                       env=fe)
+
+    db = open_db()
     model = {}
+    touched = set()
+    in_log = {}  # key -> rejected values since its last acknowledged write
     wo = WriteOptions(sync=True)
+    resumed = reopened = 0
+
+    def check(where, log_read_again=False):
+        for k in sorted(touched):
+            got = db.get(k)
+            if log_read_again and got in in_log.get(k, ()):
+                model[k] = got
+            assert got == model.get(k), (where, k, got, model.get(k))
+        if log_read_again:
+            in_log.clear()
+
     try:
         for cycle in range(6):
             for _ in range(rng.randrange(50, 200)):
@@ -32,27 +78,53 @@ def test_intermittent_io_faults_preserve_acknowledged_writes(seed):
                 v = b"v%06d" % rng.randrange(10 ** 6)
                 db.put(k, v, wo)
                 model[k] = v
-            fe.fail_ops = {rng.choice(["append", "sync"])}
-            for _ in range(rng.randrange(5, 30)):
-                k = b"k%04d" % rng.randrange(500)
-                v = b"F%06d" % rng.randrange(10 ** 6)
-                try:
-                    db.put(k, v, wo)
-                    model[k] = v  # acknowledged despite faults
-                except Exception:
-                    pass          # rejected: must not take effect
-            fe.fail_ops = set()
+                touched.add(k)
+                in_log.pop(k, None)
+            if cycle == 1:  # the flush thread's table: resume() clears it
+                fe.fail_ops = {"append"}
+                with pytest.raises(IOError_, match="injected append error"):
+                    db.flush()
+                fe.fail_ops = set()
+            elif cycle == 2:  # its MANIFEST sync: resume() refuses
+                _fail_the_next_manifest_sync(db, fe)
+                with pytest.raises(IOError_, match="injected sync error"):
+                    db.flush()
+            else:
+                fe.fail_ops = {rng.choice(["append", "sync"])}
+                for _ in range(rng.randrange(5, 30)):
+                    k = b"k%04d" % rng.randrange(500)
+                    v = b"F%06d" % rng.randrange(10 ** 6)
+                    touched.add(k)
+                    try:
+                        db.put(k, v, wo)
+                        model[k] = v  # acknowledged despite faults
+                        in_log.pop(k, None)
+                    except Exception:
+                        in_log.setdefault(k, set()).add(v)  # rejected
+                fe.fail_ops = set()
+            # What the window left running ends first, in success or in a
+            # latched error: one latched after resume() would stay.
             try:
-                db.resume()
+                db.wait_for_compactions()
             except Exception:
                 pass
+            failed = db.get_property("tpulsm.bg-error-severity") != "NO_ERROR"
+            try:
+                db.resume()
+                resumed += failed
+            except IOError_ as e:
+                assert "reopen the DB" in str(e)
+                check((cycle, "before the reopen"))
+                db.close()
+                db = open_db()
+                reopened += 1
+                check((cycle, "reopened"), log_read_again=True)
             db.wait_for_compactions()
-            bad = [k for k, v in model.items() if db.get(k) != v]
-            assert not bad, (cycle, bad[:3])
+            check(cycle)
+        assert reopened >= 1 and resumed >= 1
         db.close()
-        with DB.open(d, Options()) as db2:  # reopen on the REAL env
-            bad = [k for k, v in model.items() if db2.get(k) != v]
-            assert not bad, bad[:3]
+        with DB.open(d, Options()) as db:  # reopen on the REAL env
+            check("real env")
     finally:
         shutil.rmtree(root, ignore_errors=True)
 

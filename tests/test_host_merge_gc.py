@@ -57,7 +57,7 @@ def _two_pass(key_buf, key_offs, key_lens, snapshots, bottommost, cover,
               run_starts):
     """The pre-fusion reference pipeline (native sort + numpy masks)."""
     s, new_key, seq, vtype = ck.host_sort_with_boundaries(
-        key_buf, key_offs, key_lens, 8, run_starts=run_starts)
+        key_buf, key_offs, key_lens, run_starts=run_starts)
     keep, zero_seq, host_resolve, _ = ck.host_gc_mask(
         new_key, seq[s], vtype[s], snapshots,
         None if cover is None else cover[s], bottommost)

@@ -564,25 +564,38 @@ def test_corruption_soak_zero_wrong_bytes_and_twin_parity(tmp_path):
         d2.close()
 
 
-@pytest.mark.parametrize("knob,value", [("TPULSM_PIPELINE", "1"),
-                                        ("TPULSM_ITER_CHUNK", "1")])
-def test_protected_parity_with_data_planes(tmp_path, monkeypatch, knob,
-                                           value):
+@pytest.mark.parametrize("plane", ["pipeline", "TPULSM_ITER_CHUNK"])
+def test_protected_parity_with_data_planes(tmp_path, monkeypatch, plane):
     """Protection-on runs through the pipelined compaction plane and the
     chunked scan plane must produce byte-identical results to the
     protection-off serial twin (the handoff checks must be pure
     verification, never a behavior change)."""
-    if knob == "TPULSM_PIPELINE":
-        import toplingdb_tpu.ops.pipeline as pl
+    from toplingdb_tpu.compaction.executor import (
+        DeviceCompactionExecutorFactory,
+    )
 
-        monkeypatch.setattr(pl, "MIN_PIPELINE_ROWS", 256)
-        monkeypatch.setenv("TPULSM_PIPELINE_SHARDS", "4")
-    monkeypatch.setenv(knob, value)
+    pipelined = []
+    if plane == "pipeline":
+        from test_compaction_pipeline import (
+            _enable_small_pipeline,
+            _spy_pipeline,
+        )
 
-    def build(dbdir, pb):
-        db = DB.open(dbdir, Options(protection_bytes_per_key=pb,
-                                    write_buffer_size=24 * 1024,
-                                    level0_file_num_compaction_trigger=3))
+        _enable_small_pipeline(monkeypatch)  # four shards a job
+        pipelined = _spy_pipeline(monkeypatch)
+
+    def scan_plane(value):
+        if plane != "pipeline":
+            monkeypatch.setenv(plane, value)
+
+    scan_plane("1")
+
+    def build(dbdir, pb, device):
+        db = DB.open(dbdir, Options(
+            protection_bytes_per_key=pb, write_buffer_size=24 * 1024,
+            level0_file_num_compaction_trigger=3,
+            compaction_executor_factory=DeviceCompactionExecutorFactory(
+                device="cpu-jax", allow_fallback=False) if device else None))
         rng = random.Random(5)
         for i in range(3000):
             db.put(b"p%05d" % rng.randrange(1200),
@@ -591,13 +604,14 @@ def test_protected_parity_with_data_planes(tmp_path, monkeypatch, knob,
         db.compact_range()
         return db
 
-    db_p = build(str(tmp_path / "prot"), 8)
-    monkeypatch.setenv(knob, "0")
-    db_o = build(str(tmp_path / "off"), 0)
+    db_p = build(str(tmp_path / "prot"), 8, plane == "pipeline")
+    assert bool(pipelined) == (plane == "pipeline")
+    scan_plane("0")
+    db_o = build(str(tmp_path / "off"), 0, False)
     try:
-        monkeypatch.setenv(knob, value)
+        scan_plane("1")
         got = dump(db_p)
-        monkeypatch.setenv(knob, "0")
+        scan_plane("0")
         want = dump(db_o)
         assert got == want
         res = db_p.verify_file_checksums()

@@ -102,12 +102,11 @@ def make_job(tmp_path, name="job", runs=3, rows=3000, seq_gap=0, trace=None,
 
 @pytest.fixture
 def pipelined(monkeypatch):
-    """A test-sized job runs pipelined (the row floor would send it to the
-    serial program)."""
-    from toplingdb_tpu.ops import pipeline as pl
+    """A test-sized job runs pipelined (the shard rule would leave it one
+    shard, and so to the serial program)."""
+    from toplingdb_tpu.ops import compaction_kernels as ck
 
-    monkeypatch.setattr(pl, "MIN_PIPELINE_ROWS", 256)
-    monkeypatch.setenv("TPULSM_PIPELINE_SHARDS", "4")
+    monkeypatch.setattr(ck, "shard_count", lambda total_rows: 4)
 
 
 def run_under_request(tracer, job_dir, ctx=None):

@@ -1,8 +1,5 @@
 """CompactFiles / SuggestCompactRange / PromoteL0 (reference db.h manual
-compaction APIs), RemapEnv (env/fs_remap.cc role), and the benchmark
-regression tooling (tools/benchmark.sh + benchmark_compare.sh role)."""
-
-import json
+compaction APIs) and RemapEnv (env/fs_remap.cc role)."""
 
 import pytest
 
@@ -134,27 +131,3 @@ def test_remap_env(tmp_path):
                                               str(tmp_path / "db2")}))
     assert db.get(b"k") == b"v"
     db.close()
-
-
-def test_benchmark_suite_and_compare(tmp_path, capsys):
-    from toplingdb_tpu.tools.benchmark import main as bench_main
-    from toplingdb_tpu.tools.benchmark_compare import main as cmp_main
-
-    out1 = str(tmp_path / "base.json")
-    out2 = str(tmp_path / "new.json")
-    for out in (out1, out2):
-        rc = bench_main([
-            "--suite", "quick", "--num", "2000",
-            "--db", str(tmp_path / "benchdb"), "--out", out,
-        ])
-        assert rc == 0
-        doc = json.loads(open(out).read())
-        assert {r["name"] for r in doc["results"]} == {"fillseq", "readrandom"}
-        assert all(r["ops_per_sec"] > 0 for r in doc["results"])
-    assert cmp_main([out1, out2, "--threshold", "0.01"]) == 0
-    # forge a regression
-    doc = json.loads(open(out2).read())
-    doc["results"][0]["ops_per_sec"] = 1.0
-    open(out2, "w").write(json.dumps(doc))
-    assert cmp_main([out1, out2, "--threshold", "0.85"]) == 1
-    capsys.readouterr()

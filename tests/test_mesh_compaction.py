@@ -23,11 +23,14 @@ from test_compaction_pipeline import (
 from toplingdb_tpu.parallel import mesh_plan
 
 
-def _mesh_env(monkeypatch, on: bool, devices: int = 2):
-    from toplingdb_tpu.ops import device_compaction as dc
+def _mesh_env(monkeypatch, on: bool, devices: int = 2,
+              pipelined: bool = False):
+    """Four shards a job and the mesh knobs; the serial branch unless
+    `pipelined`."""
+    from test_compaction_pipeline import _enable_small_pipeline, _pipeline
 
-    monkeypatch.setattr(dc, "_SHARD_MIN_ROWS", 1)
-    monkeypatch.setenv("TPULSM_DEVICE_SHARDS", "4")
+    _enable_small_pipeline(monkeypatch)
+    _pipeline(monkeypatch, pipelined)
     monkeypatch.setenv("TPULSM_MESH_MIN_ROWS", "1")
     monkeypatch.setenv("TPULSM_MESH_DEVICES", str(devices))
     if on:
@@ -130,12 +133,9 @@ def test_mesh_pipeline_parity(tmp_path, monkeypatch):
     """The pipelined plane's compute stage places shards over the mesh
     too (ops/pipeline.py _device_compute): bytes match the mesh-off
     pipelined run and the mode engages on stats."""
-    from test_compaction_pipeline import _enable_small_pipeline
     from toplingdb_tpu.env import default_env
     from toplingdb_tpu.table.builder import TableOptions
 
-    monkeypatch.setenv("TPULSM_PIPELINE", "1")
-    _enable_small_pipeline(monkeypatch)
     env = default_env()
     dbdir = str(tmp_path)
     topts = TableOptions(block_size=512)
@@ -143,11 +143,12 @@ def test_mesh_pipeline_parity(tmp_path, monkeypatch):
     metas = _build_runs(env, dbdir, n, topts, seed=5, tombstone_file=True)
     snapshots = [n // 3]
 
-    _mesh_env(monkeypatch, on=False)
+    _mesh_env(monkeypatch, on=False, pipelined=True)
     out_ref, _ = _run_job(env, dbdir, metas, topts, topts, 1000, snapshots)
-    _mesh_env(monkeypatch, on=True)
+    _mesh_env(monkeypatch, on=True, pipelined=True)
     out_mesh, stats = _run_job(env, dbdir, metas, topts, topts, 2000,
                                snapshots)
+    assert stats.pipelined, "the job left the pipeline"
     assert stats.mesh_chips == 2, "pipeline mesh placement did not engage"
     assert _sst_bytes(env, dbdir, out_mesh) == \
         _sst_bytes(env, dbdir, out_ref), "pipelined mesh bytes differ"

@@ -178,11 +178,11 @@ def test_pipelined_merge_job(tmp_path, monkeypatch, case, bottommost,
     reference's survivors row by row and, byte for byte, the serial
     columnar program's and the CPU compaction path's."""
     from toplingdb_tpu.env import default_env
+    from toplingdb_tpu.ops import compaction_kernels as ck
     from toplingdb_tpu.ops import pipeline as pl
     from toplingdb_tpu.table.builder import TableOptions
 
-    monkeypatch.setattr(pl, "MIN_PIPELINE_ROWS", 256)
-    monkeypatch.setenv("TPULSM_PIPELINE_SHARDS", "4")
+    monkeypatch.setattr(ck, "shard_count", lambda total_rows: 4)
     if mode == "host":
         monkeypatch.setenv("TPULSM_HOST_SORT", "1")
     else:
@@ -194,7 +194,6 @@ def test_pipelined_merge_job(tmp_path, monkeypatch, case, bottommost,
     runs, tombs = make_rows(case, seed=len(case) * 7 + bottommost)
     metas = write_runs(env, dbdir, topts, runs, tombs)
 
-    monkeypatch.setenv("TPULSM_PIPELINE", "1")
     out_pipe, st = run_job(env, dbdir, metas, topts, 2000, snapshots,
                            bottommost, op)
     assert st.pipelined and st.pipeline_exit == ""
@@ -217,7 +216,7 @@ def test_pipelined_merge_job(tmp_path, monkeypatch, case, bottommost,
     assert ref.rows_wrong(want, got) == 0
     assert st.output_records == len(want[0])
 
-    monkeypatch.setenv("TPULSM_PIPELINE", "0")
+    monkeypatch.setattr(pl, "pipeline_enabled", lambda *_a: False)
     out_serial, st_s = run_job(env, dbdir, metas, topts, 3000, snapshots,
                                bottommost, op)
     assert not st_s.pipelined
@@ -238,11 +237,10 @@ def test_columnar_fold_sends_odd_groups_to_the_per_group_resolver(
     import toplingdb_tpu.db.filename as fn
     from toplingdb_tpu.db.version_edit import FileMetaData
     from toplingdb_tpu.env import default_env
-    from toplingdb_tpu.ops import pipeline as pl
+    from toplingdb_tpu.ops import compaction_kernels as ck
     from toplingdb_tpu.table.builder import TableBuilder, TableOptions
 
-    monkeypatch.setattr(pl, "MIN_PIPELINE_ROWS", 256)
-    monkeypatch.setenv("TPULSM_PIPELINE_SHARDS", "4")
+    monkeypatch.setattr(ck, "shard_count", lambda total_rows: 4)
     monkeypatch.delenv("TPULSM_HOST_SORT", raising=False)
     env = default_env()
     dbdir = str(tmp_path)

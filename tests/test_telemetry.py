@@ -383,13 +383,13 @@ def test_dcompact_http_job_stitches_worker_spans(tmp_path, monkeypatch):
         DcompactWorkerService, HttpCompactionExecutorFactory,
     )
     from toplingdb_tpu.compaction.resilience import DcompactOptions
-    from toplingdb_tpu.ops import pipeline as pl
+    from toplingdb_tpu.ops import compaction_kernels as ck
 
     # Engage the 3-stage pipeline inside the (in-process) worker so the
     # stitched waterfall is of a PIPELINED remote job (the acceptance
-    # shape) — the row floor would route a test-sized job serial.
-    monkeypatch.setattr(pl, "MIN_PIPELINE_ROWS", 256)
-    monkeypatch.setenv("TPULSM_PIPELINE_SHARDS", "4")
+    # shape) — the shard rule would leave a test-sized job one shard, and
+    # so to the serial path.
+    monkeypatch.setattr(ck, "shard_count", lambda total_rows: 4)
     svc = DcompactWorkerService(device="cpu-jax")
     port = svc.start()
     fac = HttpCompactionExecutorFactory(

@@ -1,29 +1,10 @@
-"""Tool coverage: microbench primitives, extra db_bench workloads, and the
+"""Tool coverage: extra db_bench workloads, and the
 SstFileWriter fuzz (reference fuzz/sst_file_writer_fuzzer.cc: random KVs →
 writer → reader must round-trip and survive truncation checks)."""
 
-import json
 import random
-import subprocess
-import sys
 
 import pytest
-
-
-def test_microbench_runs():
-    out = subprocess.run(
-        [sys.executable, "-m", "toplingdb_tpu.tools.microbench", "--n=2000"],
-        capture_output=True, timeout=300, cwd="/root/repo",
-    )
-    assert out.returncode == 0, out.stderr.decode()
-    lines = [json.loads(x) for x in out.stdout.decode().splitlines() if x]
-    names = {r["bench"] for r in lines}
-    assert {"crc32c_1MiB", "memtable_insert", "table_build",
-            "table_scan"} <= names
-    assert all(r["items_per_s"] > 0 for r in lines
-               if "items_per_s" in r)  # *_stats rows carry counters instead
-    assert any(r["bench"] == "persistent_cache_tier_stats"
-               and r["hit_rate"] > 0 for r in lines)
 
 
 def test_db_bench_extra_workloads(tmp_path):

@@ -473,6 +473,12 @@ class CompactionScheduler:
                        elapsed_micros=stats.work_time_usec,
                        device=stats.device, reason=c.reason,
                    ))
+        except BaseException as e:
+            if getattr(e, "_bg_reason", "") == "manifest":
+                # As after a flush (db.py::_flush_unit): the MANIFEST may
+                # name the outputs, so they stay guarded until the next open.
+                pending.clear()
+            raise
         finally:
             with db._mutex:
                 db._pending_outputs.difference_update(pending)

@@ -890,6 +890,8 @@ class DB:
             self._sfm.on_add_file(path, 0)  # grows; resized at switch/close
 
     def close(self) -> None:
+        from toplingdb_tpu.utils.status import Severity as _Sev
+
         self._recover_stop.set()
         if self._integrity_scrubber is not None:
             self._integrity_scrubber.stop()
@@ -925,9 +927,12 @@ class DB:
             # memtables for the final flush to carry them.
             while self._mt_inflight > 0:
                 self._mt_cv.wait(timeout=10.0)
-            if any(not c.mem.empty() or c.imm for c in self._cfs.values()):
+            if (any(not c.mem.empty() or c.imm for c in self._cfs.values())
+                    and self._bg_error_severity < _Sev.FATAL_ERROR):
                 # A unit whose flush failed is tried once more: whatever
                 # stays unflushed is in its WAL and is replayed at open.
+                # Not after a failed MANIFEST write: what that file holds
+                # is not known before it is read again.
                 self._flush_failed = None
                 self._flush_cv.notify_all()
                 self.flush(FlushOptions())
@@ -2039,6 +2044,14 @@ class DB:
                     self._flush_cv.notify_all()
                     self._delete_obsolete_files()
                     self._maybe_schedule_compaction()
+        except BaseException as e:
+            if getattr(e, "_bg_reason", "") == "manifest":
+                # The MANIFEST may hold the record that names these tables
+                # (appended, its sync failed): they stay guarded from the
+                # obsolete-file sweep until the next open decides by the
+                # MANIFEST it recovers.
+                built = []
+            raise
         finally:
             self._release_flush_outputs(built)
 

@@ -358,50 +358,6 @@ class _FISequential(SequentialFile):
         self._base.close()
 
 
-class DelayedReadEnv:
-    """Env wrapper whose random-access reads sleep `delay_sec` first.
-
-    Models device read latency on a page-cache-warm box, where real
-    preads return in microseconds and I/O overlap is unmeasurable: the
-    bench/microbench cold-cache twins run BOTH knob settings of the
-    async read plane (env/async_reads.py) on this env, so the 0/1 ratio
-    isolates ring fan-out + coalescing. Wrapped file handles also make
-    the native get/multiget fast chains ineligible (no raw fd), which
-    keeps the two twins on the same Python walk — the comparison never
-    mixes native-vs-Python with sync-vs-async.
-    """
-
-    def __init__(self, base, delay_sec: float = 0.0002):
-        self.base = base
-        self.delay_sec = delay_sec
-        self.delayed_reads = 0  # benign race: diagnostic counter only
-
-    def new_random_access_file(self, path: str):
-        return _DelayedRandom(self, self.base.new_random_access_file(path))
-
-    def __getattr__(self, name):
-        return getattr(self.base, name)
-
-
-class _DelayedRandom(RandomAccessFile):
-    def __init__(self, env: DelayedReadEnv, base):
-        self._env = env
-        self._base = base
-
-    def read(self, offset, n):
-        import time as _t
-
-        _t.sleep(self._env.delay_sec)
-        self._env.delayed_reads += 1
-        return self._base.read(offset, n)
-
-    def size(self):
-        return self._base.size()
-
-    def close(self):
-        self._base.close()
-
-
 class WalWriterFaultInjector:
     """Seeded fault points for the async WAL writer's submit ring
     (env/env.py AsyncIORing.fault_hook): each executed ring entry draws a

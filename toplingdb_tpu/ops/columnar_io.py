@@ -620,18 +620,12 @@ class _ColumnarSST:
             offset += payload_len + fmt.BLOCK_TRAILER_SIZE
         self.w.append(section)
 
-    def finish(self, lib, kv, sel, vtypes, seqs, tombstones,
-               precomputed=None):
+    def finish(self, lib, kv, sel, vtypes, seqs, tombstones):
         """Write meta blocks + footer; `sel` = the original-index selection
-        of this file's entries (stats/bloom are vectorized over it).
-        `precomputed`: entry stats already reduced elsewhere (the on-device
-        block-assembly path; its sel comes from a survivor bitmap and only
-        feeds the bloom build below) — a dict with
-        num_entries/raw_key_size/raw_value_size/num_deletions/
-        num_merge_operands/smallest_seqno/largest_seqno."""
+        of this file's entries (stats/bloom are vectorized over it)."""
         with telemetry.span("sst.finish_file", file=self.fnum):
             out = self._finish_blocks(lib, kv, sel, vtypes, seqs,
-                                      tombstones, precomputed)
+                                      tombstones)
         with telemetry.span("sst.sync_close", file=self.fnum,
                             bytes=self.w.file_size()):
             self.w.flush()
@@ -639,8 +633,7 @@ class _ColumnarSST:
             self.w.close()
         return out
 
-    def _finish_blocks(self, lib, kv, sel, vtypes, seqs, tombstones,
-                       precomputed):
+    def _finish_blocks(self, lib, kv, sel, vtypes, seqs, tombstones):
         """Everything of finish() but the fsync: pending data blocks,
         filter, range-del, dictionary, index, properties, metaindex,
         footer."""
@@ -659,31 +652,20 @@ class _ColumnarSST:
         if self.pending_last_key is not None:
             succ = icmp.find_short_successor(self.pending_last_key)
             self.index_block.add(succ, self.pending_handle.encode())
-        if precomputed is not None:
-            props.num_entries = precomputed["num_entries"]
-            props.raw_key_size = precomputed["raw_key_size"]
-            props.raw_value_size = precomputed["raw_value_size"]
-            props.num_deletions = precomputed["num_deletions"]
-            props.num_merge_operands = precomputed["num_merge_operands"]
-            props.smallest_seqno = precomputed["smallest_seqno"]
-            props.largest_seqno = precomputed["largest_seqno"]
-            # stats come precomputed; the bloom (below) still builds from
-            # `sel` when the caller materialized one (order-insensitive).
-        else:
-            props.num_entries = n
-            props.raw_key_size = int(kv.key_lens[sel].sum()) if n else 0
-            props.raw_value_size = int(kv.val_lens[sel].sum()) if n else 0
-            vt = vtypes[sel] if n else vtypes[:0]
-            props.num_deletions = int(np.count_nonzero(
-                (vt == int(dbformat.ValueType.DELETION))
-                | (vt == int(dbformat.ValueType.SINGLE_DELETION))
-            ))
-            props.num_merge_operands = int(np.count_nonzero(
-                vt == int(dbformat.ValueType.MERGE)
-            ))
-            sq = seqs[sel] if n else seqs[:0]
-            props.smallest_seqno = int(sq.min()) if n else 0
-            props.largest_seqno = int(sq.max()) if n else 0
+        props.num_entries = n
+        props.raw_key_size = int(kv.key_lens[sel].sum()) if n else 0
+        props.raw_value_size = int(kv.val_lens[sel].sum()) if n else 0
+        vt = vtypes[sel] if n else vtypes[:0]
+        props.num_deletions = int(np.count_nonzero(
+            (vt == int(dbformat.ValueType.DELETION))
+            | (vt == int(dbformat.ValueType.SINGLE_DELETION))
+        ))
+        props.num_merge_operands = int(np.count_nonzero(
+            vt == int(dbformat.ValueType.MERGE)
+        ))
+        sq = seqs[sel] if n else seqs[:0]
+        props.smallest_seqno = int(sq.min()) if n else 0
+        props.largest_seqno = int(sq.max()) if n else 0
 
         meta_entries = []
         metaindex = BlockBuilder(restart_interval=1)

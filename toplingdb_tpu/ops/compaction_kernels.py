@@ -18,7 +18,6 @@ size bucket, not per job. All lanes are 32-bit (TPU-native); 64-bit packed
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -85,14 +84,8 @@ def _next_pow2(n: int) -> int:
 
 def _want_pallas_gc() -> bool:
     """Use the Pallas GC-row kernel inside _gc_mask_impl. Decided at TRACE
-    time (the jit cache does not key on this): default ON for accelerator
-    backends, OFF on cpu (where interpret mode would crawl);
-    TPULSM_PALLAS_GC=1/0 forces. Flip the env var before first use."""
-    import os
-
-    env = os.environ.get("TPULSM_PALLAS_GC", "")
-    if env in ("0", "1"):
-        return env == "1"
+    time (the jit cache does not key on this): ON for accelerator
+    backends, OFF on cpu (where interpret mode would crawl)."""
     return jax.default_backend() != "cpu"
 
 
@@ -245,7 +238,7 @@ def _gc_mask_impl(key_words, key_len, inv_hi, inv_lo, vtype,
 def _sort_gc_compact_tail(key_words, key_len, inv_hi, inv_lo, vtype,
                           snap_hi, snap_lo, num_key_words, bottommost,
                           tomb_hi_orig=None, tomb_lo_orig=None):
-    """Traced tail shared by the fused kernels: sort → GC mask → survivors
+    """Traced tail of the whole-job program: sort → GC mask → survivors
     compacted to the front in sorted order. Rows of complex groups (MERGE /
     SINGLE_DELETE present) are INCLUDED in the output stream, flagged via
     cx_flags, so the host can fold them without abandoning the columnar
@@ -275,77 +268,6 @@ def _sort_gc_compact_tail(key_words, key_len, inv_hi, inv_lo, vtype,
     return order, zero_flags, cx_flags, count, has_complex
 
 
-@functools.partial(jax.jit, static_argnames=("num_key_words", "bottommost"))
-def _fused_sort_gc_impl(key_words, key_len, inv_hi, inv_lo, vtype, idx,
-                        snap_hi, snap_lo, num_key_words, bottommost):
-    """Sort + GC mask in ONE device program (single host round trip for
-    tombstone-free jobs). Returns (order, zero_flags, cx_flags, count,
-    has_complex): order[i] for i < count = original indices of survivors
-    (incl. complex-group rows, flagged) in output order."""
-    return _sort_gc_compact_tail(
-        key_words, key_len, inv_hi, inv_lo, vtype, snap_hi, snap_lo,
-        num_key_words, bottommost,
-    )
-
-
-def fused_sort_gc(padded: dict, snapshots: list[int], bottommost: bool):
-    """Host wrapper for the fused kernel (no range tombstones).
-    Returns (order np[count], zero_flags np[count], cx_flags np[count],
-    has_complex bool)."""
-    if len(snapshots) > MAX_SNAPSHOTS:
-        raise NotSupported(
-            f"device GC supports <= {MAX_SNAPSHOTS} live snapshots"
-        )
-    p = padded["key_words"].shape[0]
-    snap_hi, snap_lo = _split_snapshots(snapshots)
-    idx = np.arange(p, dtype=np.int32)
-    order, zero_flags, cx_flags, count, has_complex = _fused_sort_gc_impl(
-        padded["key_words"], padded["key_len"], padded["inv_hi"],
-        padded["inv_lo"], padded["vtype"], idx, snap_hi, snap_lo,
-        padded["w"], bool(bottommost),
-    )
-    for a in (order, zero_flags, cx_flags, count, has_complex):
-        if hasattr(a, "copy_to_host_async"):
-            a.copy_to_host_async()
-    c = int(count)
-    return (np.asarray(order)[:c], np.asarray(zero_flags)[:c],
-            np.asarray(cx_flags)[:c], bool(has_complex))
-
-
-def host_encode_sort(key_buf: np.ndarray, key_offs: np.ndarray,
-                     key_lens: np.ndarray, max_key_bytes: int):
-    """NumPy half-twin: columnar encode + np.lexsort into internal-key
-    order. Returns (s, words, uk_len, seq, vtype) with s = sorted→original
-    permutation and the UNSORTED per-entry columns."""
-    n = len(key_offs)
-    offs = key_offs.astype(np.int64)
-    lens = key_lens.astype(np.int64)
-
-    seq, vtype = _trailer_seq_vtype(key_buf, key_offs, key_lens)
-    packed = (seq << np.uint64(8)) | vtype.astype(np.uint64)
-    inv = ~packed  # descending seq under an ascending sort
-
-    # Big-endian user-key words, zero-masked past each key's length.
-    w = (max_key_bytes + 3) // 4
-    span = w * 4
-    uk_len = lens - 8
-    idx = offs[:, None] + np.arange(span)[None, :]
-    np.clip(idx, 0, max(len(key_buf) - 1, 0), out=idx)
-    kb = key_buf[idx].astype(np.uint32)
-    kb *= np.arange(span)[None, :] < uk_len[:, None]
-    kbw = kb.reshape(n, w, 4)
-    words = ((kbw[:, :, 0] << 24) | (kbw[:, :, 1] << 16)
-             | (kbw[:, :, 2] << 8) | kbw[:, :, 3])
-
-    # lexsort: LAST column is primary — mirror the device operand order
-    # (key words..., key_len, inv): stable, so duplicate internal keys keep
-    # input order (the device sort has no key ties for distinct seqnos).
-    s = np.lexsort((inv, uk_len) + tuple(
-        words[:, j] for j in range(w - 1, -1, -1)
-    ))
-    return s, words, uk_len, seq, vtype
-
-
 def host_sort_order(key_buf: np.ndarray, key_offs: np.ndarray,
                     key_lens: np.ndarray, run_starts=None):
     """(order, new_key, packed) via the native byte-span comparator —
@@ -373,8 +295,7 @@ def host_sort_order(key_buf: np.ndarray, key_offs: np.ndarray,
     packed = np.full(n, np.uint64(0xFFFFFFFFFFFFFFFF), dtype=np.uint64)
     rc = -1
     if (run_starts is not None and len(run_starts) > 1 and n
-            and hasattr(lib, "tpulsm_merge_runs")
-            and os.environ.get("TPULSM_HOST_MERGE", "1") != "0"):
+            and hasattr(lib, "tpulsm_merge_runs")):
         rs = np.ascontiguousarray(run_starts, dtype=np.int64)
         # Malformed boundaries would leave output rows unmerged (silent
         # corruption) or index past the entry array in C: validate here,
@@ -416,8 +337,7 @@ def host_merge_gc(key_buf, key_offs, key_lens, snapshots, bottommost,
     from toplingdb_tpu import native
 
     lib = native.lib()
-    if (lib is None or not hasattr(lib, "tpulsm_merge_gc_runs")
-            or os.environ.get("TPULSM_HOST_MERGE", "1") == "0"):
+    if lib is None or not hasattr(lib, "tpulsm_merge_gc_runs"):
         return None
     if run_starts is None or len(run_starts) < 2:
         return None
@@ -506,8 +426,9 @@ def host_fused_full(key_buf: np.ndarray, key_offs: np.ndarray,
                     snapshots: list[int], bottommost: bool,
                     cover: np.ndarray | None = None, run_starts=None):
     """Host twin of the fused kernel for accelerator-less deployments
-    (TPULSM_HOST_SORT=1): native/lexsort order + vectorized GC mask —
-    outputs identical to the jax path (parity-tested). `cover`: optional
+    (TPULSM_HOST_SORT=1): native order + vectorized GC mask — outputs
+    identical to the jax path (parity-tested; `max_key_bytes` is the jax
+    twin's argument and unused here). `cover`: optional
     per-ORIGINAL-row uint64 max covering tombstone seqno. Returns
     (order, zero_flags, cx_flags, has_complex, seq, vtype) with seq/vtype
     per ORIGINAL index so callers skip their own trailer gather; `order`
@@ -526,7 +447,7 @@ def host_fused_full(key_buf: np.ndarray, key_offs: np.ndarray,
     if fused is not None:
         return fused
     s, new_key, seq, vtype = host_sort_with_boundaries(
-        key_buf, key_offs, key_lens, max_key_bytes, run_starts=run_starts
+        key_buf, key_offs, key_lens, run_starts=run_starts
     )
     keep, zero_seq, host_resolve, _ = host_gc_mask(
         new_key, seq[s], vtype[s], snapshots,
@@ -539,21 +460,16 @@ def host_fused_full(key_buf: np.ndarray, key_offs: np.ndarray,
     return order, zero_flags, cx_flags, bool(host_resolve.any()), seq, vtype
 
 
-def host_sort_with_boundaries(key_buf, key_offs, key_lens, max_key_bytes,
-                              run_starts=None):
-    """Shared host-path front half: (s, new_key, seq, vtype) — the native
-    comparator when available, else the lexsort twin."""
+def host_sort_with_boundaries(key_buf, key_offs, key_lens, run_starts=None):
+    """Shared host-path front half: (s, new_key, seq, vtype) through the
+    native comparator; NotSupported without the native library."""
     nat = host_sort_order(key_buf, key_offs, key_lens,
                           run_starts=run_starts)
-    if nat is not None:
-        s, new_key, packed = nat
-        seq = packed >> np.uint64(8)
-        vtype = (packed & np.uint64(0xFF)).astype(np.int32)
-    else:
-        s, words, uk_len, seq, vtype = host_encode_sort(
-            key_buf, key_offs, key_lens, max_key_bytes
-        )
-        new_key = _new_key_from_words(words[s], uk_len[s])
+    if nat is None:
+        raise NotSupported("the host twin needs the native sort")
+    s, new_key, packed = nat
+    seq = packed >> np.uint64(8)
+    vtype = (packed & np.uint64(0xFF)).astype(np.int32)
     return s, new_key, seq, vtype
 
 
@@ -566,16 +482,6 @@ def _trailer_seq_vtype(key_buf, key_offs, key_lens):
     for i in range(8):
         packed |= tr[:, i] << np.uint64(8 * i)
     return packed >> np.uint64(8), (packed & np.uint64(0xFF)).astype(np.int32)
-
-
-def _new_key_from_words(skw, slen):
-    n = len(slen)
-    same_key = np.zeros(n, dtype=bool)
-    if n > 1:
-        same_key[1:] = np.all(skw[1:] == skw[:-1], axis=1) & (
-            slen[1:] == slen[:-1]
-        )
-    return ~same_key
 
 
 def _encode_from_bytes(key_buf, key_offs, key_lens, valid, num_key_words):
@@ -641,14 +547,26 @@ def _fused_encode_sort_gc_impl(key_buf, key_lens, valid, tomb_hi, tomb_lo,
 MAX_SHARD_ROWS = 1 << 22
 
 
-def _uniform_shard_core(kb, pkb, starts, min_his, min_los, tomb_hi, tomb_lo,
-                        snap_hi, snap_lo, total, num_key_words, uk_len,
-                        bottommost, has_tombs):
-    """Shared traced core of the uniform-shard kernels: [p, uk_len] u8 key
-    matrix in → sort + GC. Returns a dict of per-SORTED-row arrays
-    (perm, out, zero_seq, host_resolve, take) plus per-ORIGINAL-row
-    packed trailer words, for the packed-download and block-assembly
-    tails to consume.
+@functools.partial(
+    jax.jit, static_argnames=("num_key_words", "uk_len", "has_tombs"),
+)
+def _fused_uniform_shard_impl(ukb, pkb, starts, min_his, min_los,
+                              tomb_hi, tomb_lo,
+                              snap_hi, snap_lo, total, num_key_words, uk_len,
+                              bottommost, has_tombs):
+    """ONE range-shard's encode+sort+GC over ONE uploaded buffer pair:
+    `ukb` = trailer-stripped user-key bytes of every chunk packed
+    contiguously (padded rows zero), `pkb` = one uint32 per row
+    ((seq - chunk_min_seq) << 8 | vtype, deltas < 2^24). Chunk row starts
+    arrive as a small DEVICE array `starts` (pow2-padded with sentinel
+    2^31-1), so per-row chunk ids come from one searchsorted and the jit
+    cache keys only on pow2-padded shapes — arbitrary chunk-size tuples
+    reuse one compilation. TWO bulk host→device transfers per shard.
+    The result is (packed_bytes u8[3p], meta i32[2]): three
+    byte-planes of the 24-bit survivor row ids (bit 23 = zero-seq flag,
+    bit 22 = complex-group flag) — 3/4 the download of int32 orders — plus
+    [count, has_complex]. With has_tombs, tomb_hi/lo carry each local row's
+    max covering range-tombstone seqno words.
 
     The reorder is ONE multi-operand lax.sort whatever the chunk count.
     The chunks are presorted runs, and two cheaper-looking reorders were
@@ -670,7 +588,7 @@ def _uniform_shard_core(kb, pkb, starts, min_his, min_los, tomb_hi, tomb_lo,
     # `kernel.<scope>_ms_per_Mrow`); they are metadata, the program the
     # compiler builds is the same with and without them.
     with jax.named_scope("encode_words"):
-        kbp = kb
+        kbp = ukb.reshape(p, uk_len)
         if span > uk_len:
             kbp = jnp.pad(kbp, ((0, 0), (0, span - uk_len)))
         kbp = kbp.astype(u32).reshape(p, num_key_words, 4)
@@ -715,48 +633,10 @@ def _uniform_shard_core(kb, pkb, starts, min_his, min_los, tomb_hi, tomb_lo,
     with jax.named_scope("compact"):
         out = keep | host_resolve
         take = jnp.argsort(~out, stable=True)
-    return {
-        "perm": perm, "take": take, "out": out, "zero_seq": zero_seq,
-        "host_resolve": host_resolve,
-        "packed_hi": packed_hi, "packed_lo": packed_lo,  # per ORIGINAL row
-        "vtype_orig": vt0.astype(jnp.int32),
-        "valid": valid,
-    }
-
-
-@functools.partial(
-    jax.jit, static_argnames=("num_key_words", "uk_len", "has_tombs"),
-)
-def _fused_uniform_shard_impl(ukb, pkb, starts, min_his, min_los,
-                              tomb_hi, tomb_lo,
-                              snap_hi, snap_lo, total, num_key_words, uk_len,
-                              bottommost, has_tombs):
-    """ONE range-shard's encode+sort+GC over ONE uploaded buffer pair:
-    `ukb` = trailer-stripped user-key bytes of every chunk packed
-    contiguously (padded rows zero), `pkb` = one uint32 per row
-    ((seq - chunk_min_seq) << 8 | vtype, deltas < 2^24). Chunk row starts
-    arrive as a small DEVICE array `starts` (pow2-padded with sentinel
-    2^31-1), so per-row chunk ids come from one searchsorted and the jit
-    cache keys only on pow2-padded shapes — arbitrary chunk-size tuples
-    reuse one compilation. TWO bulk host→device transfers per shard.
-    The result is (packed_bytes u8[3p], meta i32[2]): three
-    byte-planes of the 24-bit survivor row ids (bit 23 = zero-seq flag,
-    bit 22 = complex-group flag) — 3/4 the download of int32 orders — plus
-    [count, has_complex]. With has_tombs, tomb_hi/lo carry each local row's
-    max covering range-tombstone seqno words."""
-    u32 = jnp.uint32
-    p = pkb.shape[0]
-    core = _uniform_shard_core(
-        ukb.reshape(p, uk_len), pkb, starts, min_his, min_los, tomb_hi,
-        tomb_lo, snap_hi, snap_lo, total, num_key_words, uk_len, bottommost,
-        has_tombs,
-    )
-    take = core["take"]
-    with jax.named_scope("compact"):
         po = (
-            jax.lax.bitcast_convert_type(core["perm"][take], u32)
-            | (core["zero_seq"][take].astype(u32) << 23)
-            | (core["host_resolve"][take].astype(u32) << 22)
+            jax.lax.bitcast_convert_type(perm[take], u32)
+            | (zero_seq[take].astype(u32) << 23)
+            | (host_resolve[take].astype(u32) << 22)
         )
     with jax.named_scope("pack"):
         packed_bytes = jnp.concatenate([
@@ -765,8 +645,8 @@ def _fused_uniform_shard_impl(ukb, pkb, starts, min_his, min_los,
             ((po >> 16) & u32(0xFF)).astype(jnp.uint8),
         ])
         meta = jnp.stack([
-            jnp.sum(core["out"].astype(jnp.int32)),
-            jnp.any(core["host_resolve"]).astype(jnp.int32),
+            jnp.sum(out.astype(jnp.int32)),
+            jnp.any(host_resolve).astype(jnp.int32),
         ])
     return packed_bytes, meta
 
@@ -799,10 +679,29 @@ def prepare_uniform_chunk(key_buf: np.ndarray, n: int, key_len: int):
 # (PERF.md: ~110 s at key length 8, ~195 s at 16) against milliseconds of
 # device time for the pad rows, and which power of two a small job or an
 # uneven shard falls under is chance: a job of 250,000 rows after a
-# thousand of 400,000 must not meet a program of its own. The pipeline cuts
-# its shards to ROW_BUCKET (ops/pipeline.py::SHARD_ROWS).
+# thousand of 400,000 must not meet a program of its own.
 ROW_BUCKET = 1 << 19
 ROW_BUCKET_FROM = 1 << 17
+
+
+def shard_count(total_rows: int) -> int:
+    """How many key-range shards a job of `total_rows` input rows is cut
+    into: one up to ROW_BUCKET rows; from two on the count doubles while a
+    shard of an even cut would pass 0.98 x ROW_BUCKET (cuts fall on block
+    or key boundaries and come out uneven by a few blocks a file: the
+    fiftieth is their room), at most 32. The pipeline (ops/pipeline.py::
+    _build_plan) and the serial branch (ops/device_compaction.py::
+    _prepare_uniform_shards) both ask here, and that is the point of the
+    rule living beside ROW_BUCKET: a job of several shards that leaves the
+    pipeline is cut to the same bucket by the serial branch and meets the
+    program its deployment has compiled, not a second one."""
+    if total_rows <= ROW_BUCKET:
+        return 1
+    target = ROW_BUCKET - ROW_BUCKET // 50
+    s = 2
+    while s < 32 and total_rows // s > target:
+        s *= 2
+    return s
 
 
 def upload_uniform_shard(chunks, covers=None, device=None):

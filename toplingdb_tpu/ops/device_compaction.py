@@ -265,32 +265,6 @@ def _part_bounds(part, splitters: list[bytes]) -> list[int]:
     return b
 
 
-def _device_shards(total_rows: int) -> int:
-    """Range-shard count: TPULSM_DEVICE_SHARDS wins; otherwise size shards
-    to ~512K rows (pow2 count, so per-shard padded shapes land in the same
-    compile bucket) up to the 24-bit packed-order budget."""
-    env = os.environ.get("TPULSM_DEVICE_SHARDS")
-    if env:
-        try:
-            return max(1, int(env))
-        except ValueError:
-            pass
-    try:
-        target = max(1 << 16, int(os.environ.get(
-            "TPULSM_SHARD_ROWS", str(1 << 20))))
-    except ValueError:
-        target = 1 << 20
-    s = 1
-    while s < 16 and total_rows // s > target:
-        s *= 2
-    return s
-
-
-# Below this row count a job runs as one shard: the pipeline's transfer/
-# compute overlap cannot recoup the extra per-shard dispatch latency.
-_SHARD_MIN_ROWS = 1 << 18
-
-
 def _collect_raw_columnar(compaction, table_cache, icmp, want_uploads=False):
     """Scan every input file into columnar buffers — in parallel threads
     (the native block decoder runs GIL-free under ctypes). With
@@ -336,8 +310,9 @@ def _collect_raw_columnar(compaction, table_cache, icmp, want_uploads=False):
 
 def _prepare_uniform_shards(parts):
     """Host half of the sharded uniform device path: validate density +
-    uniform key length, pick range splitters, slice every part into
-    per-shard chunks. Returns shards list or None when ineligible."""
+    uniform key length, pick range splitters (as many shards as the
+    pipeline would cut: ck.shard_count), slice every part into per-shard
+    chunks. Returns shards list or None when ineligible."""
     uniform_len = 0
     total_rows = 0
     for part in parts:
@@ -365,11 +340,7 @@ def _prepare_uniform_shards(parts):
     splitters = None
     for part in parts:
         if part.n:
-            n_shards = (
-                _device_shards(total_rows)
-                if total_rows >= _SHARD_MIN_ROWS else 1
-            )
-            splitters = _shard_splitters(part, n_shards)
+            splitters = _shard_splitters(part, ck.shard_count(total_rows))
             break
     shards = [([], []) for _ in range(len(splitters) + 1)]
     row_base = 0
@@ -777,7 +748,6 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
     from toplingdb_tpu.compaction.compaction_job import (
         surviving_tombstone_fragments,
     )
-    from toplingdb_tpu.db.version_edit import FileMetaData
     from toplingdb_tpu.ops.columnar_io import write_tables_columnar
 
     t0 = time.time()
@@ -870,48 +840,6 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
             stats.tombstone_cover_usec += int((time.time() - t_cov) * 1e6)
         stats.host_compute_usec += int((time.time() - t_cov) * 1e6)
         prep.finish()
-        if not _host_sort():
-            from toplingdb_tpu.ops import block_assembly as ba
-
-            if ba.assembly_supported(table_options, kv, shards, any_complex,
-                                     compaction.max_output_file_size,
-                                     col.vtype):
-                # Full block build ON DEVICE: finished payloads come back,
-                # the host only frames + indexes (TPULSM_DEVICE_BLOCKS=1).
-                tombs = surviving_tombstone_fragments(
-                    rd, snapshots, compaction.bottommost,
-                    icmp.user_comparator,
-                )
-                files = ba.run_block_assembly(
-                    env, dbname, icmp, kv, shards[0], cover, snapshots,
-                    compaction.bottommost, table_options, new_file_number,
-                    creation_time, tombs, column_family,
-                )
-                outputs = []
-                pb_ = getattr(table_options, "protection_bytes_per_key", 0)
-                for fnum, path, props, smallest, largest, _sel in files:
-                    if (props.num_entries == 0
-                            and props.num_range_deletions == 0):
-                        env.delete_file(path)
-                        continue
-                    if pb_:
-                        _verify_columnar_output(env, icmp, table_options,
-                                                path, kv, col.vtype, _sel)
-                    meta = FileMetaData(
-                        number=fnum, file_size=env.get_file_size(path),
-                        smallest=smallest, largest=largest,
-                        smallest_seqno=props.smallest_seqno,
-                        largest_seqno=props.largest_seqno,
-                        num_entries=props.num_entries,
-                        num_deletions=props.num_deletions,
-                        num_range_deletions=props.num_range_deletions,
-                    )
-                    outputs.append(meta)
-                    stats.output_bytes += meta.file_size
-                    stats.output_files += 1
-                    stats.output_records += props.num_entries
-                stats.work_time_usec = int((time.time() - t0) * 1e6)
-                return outputs, stats
         if _host_sort():
             import types as _types
 

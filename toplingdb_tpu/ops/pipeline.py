@@ -23,8 +23,8 @@ stripes, tombstone shadowing, bottommost seqno zeroing — equal the
 global ones and the concatenated survivor stream is byte-identical to
 the serial path's; tests/test_compaction_pipeline.py asserts whole-file
 SST equality. Jobs the pipeline does not cover (non-block formats,
-missing properties, small inputs) raise PipelineIneligible and the caller
-falls back to the serial path, which computes the same bytes.
+missing properties, jobs of one shard) raise PipelineIneligible and the
+caller falls back to the serial path, which computes the same bytes.
 
 MERGE operands and single-deletes stay on this plane: the compute stage
 returns the rows of such "complex" user-key groups unreduced and flagged,
@@ -34,15 +34,11 @@ segmented reduction for an operator that declares a columnar fold
 (uint64add), the per-group state machine for the others. Folded values
 overwrite the chain's newest row in place or land in the slack at the end
 of the value buffer, so the writer's hoisted pointers stay good.
-
-`TPULSM_PIPELINE=0` disables the pipeline; `TPULSM_PIPELINE_SHARDS=N`
-overrides the shard count.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
 import threading
 
 from toplingdb_tpu.utils import concurrency as ccy
@@ -73,10 +69,6 @@ class _Err:
 
 _DONE = _Done()
 
-# Below this row estimate the serial path wins: thread startup plus
-# per-shard dispatch overhead cannot be recouped by overlap.
-MIN_PIPELINE_ROWS = 1 << 18
-
 # Reader-stage readahead: shard windows are MBs, so the prefetch buffer
 # runs with a much larger ceiling than the per-iterator default.
 _PF_READAHEAD = 8 << 20
@@ -86,55 +78,16 @@ _PI32 = ctypes.POINTER(ctypes.c_int32)
 
 
 def pipeline_enabled(table_options=None) -> bool:
-    if os.environ.get("TPULSM_PIPELINE", "1") == "0":
-        return False
-    if os.environ.get("TPULSM_DEVICE_BLOCKS") == "1":
-        return False  # on-device block assembly has its own data plane
-    if table_options is not None:
-        f = getattr(table_options, "format", "block")
-        if f == "zip":
-            from toplingdb_tpu.table.zip_table import zip_plane_enabled
+    """The pipeline takes block tables, and zip tables when the native zip
+    data plane is on (scan/merge overlap with the drain-then-encode writer
+    stage: write_tables_zip_columnar collects the chunk feed); other
+    formats consume whole arrays serially."""
+    f = getattr(table_options, "format", "block")
+    if f == "zip":
+        from toplingdb_tpu.table.zip_table import zip_plane_enabled
 
-            # Zip rides the pipeline when the native zip data plane is
-            # on: scan/merge overlap with the drain-then-encode writer
-            # stage (write_tables_zip_columnar collects the chunk feed).
-            return zip_plane_enabled()
-        if f != "block":
-            return False  # other formats consume whole arrays serially
-    return True
-
-
-# The pipeline's shards are cut to at most this many rows, the kernels' one
-# row bucket (ops/compaction_kernels.py::ROW_BUCKET, to which every shard
-# from a quarter of it on is padded): every shard of every job then meets
-# ONE fused program per key length. A program is minutes of compile on the
-# chip (PERF.md): a job whose shards straddle a power of two must not meet
-# a second one.
-SHARD_ROWS = 1 << 19
-
-
-def _pipeline_shards(total_rows: int) -> int:
-    """Pipeline shard count: finer than the serial device sharding (the
-    pipeline wants several shards in flight even at ~1M rows)."""
-    env = os.environ.get("TPULSM_PIPELINE_SHARDS")
-    if env:
-        try:
-            return max(1, int(env))
-        except ValueError:
-            pass
-    # ~512K rows per shard: small jobs get 2 shards (enough to overlap,
-    # little per-shard dispatch overhead), bench-scale jobs get 16-32. A
-    # job of up to SHARD_ROWS rows is one shard, which the pipeline leaves
-    # to the serial path. From two shards on the cut leaves a fiftieth of
-    # room: shards are cut at block boundaries and come out uneven by a
-    # few blocks a file.
-    if total_rows <= SHARD_ROWS:
-        return 1
-    target = SHARD_ROWS - SHARD_ROWS // 50
-    s = 2
-    while s < 32 and total_rows // s > target:
-        s *= 2
-    return s
+        return zip_plane_enabled()
+    return f == "block"
 
 
 class _FilePlan:
@@ -293,6 +246,7 @@ def _build_plan(readers, value_slack: bool = False):
     budget."""
     import bisect
 
+    from toplingdb_tpu.ops import compaction_kernels as ck
     from toplingdb_tpu.ops.columnar_io import ColumnarKV
     from toplingdb_tpu.table import format as fmt
     from toplingdb_tpu.table.prefetch import FilePrefetchBuffer
@@ -329,13 +283,12 @@ def _build_plan(readers, value_slack: bool = False):
         tn += ne
     if tk > 0x7FFFFF00 or tv > 0x7FFFFF00:
         raise PipelineIneligible("inputs exceed the int32 columnar budget")
-    if tn < MIN_PIPELINE_ROWS:
-        raise PipelineIneligible("job below the pipeline row floor")
 
     # Splitters: merged per-file index separator user keys (one per data
     # block, so even index spacing approximates even byte spacing), cut
-    # into n_shards quantiles.
-    n_shards = _pipeline_shards(tn)
+    # into n_shards quantiles. A job of one shard has nothing to overlap
+    # and is left to the serial path.
+    n_shards = ck.shard_count(tn)
     if n_shards < 2:
         raise PipelineIneligible("single-shard job")
     all_seps = sorted(uk for _, _, _, _, uks in infos for uk in uks)

@@ -24,7 +24,7 @@ import re
 import time
 from dataclasses import dataclass, field
 
-# Probe exit codes (bench.py keys on these): 0 = measured, EXIT_SKIP =
+# Probe exit codes: 0 = measured, EXIT_SKIP =
 # environment cannot run the probe (missing backend, too few devices) —
 # NOT a failure, the caller just drops the row; EXIT_FAILURE = the
 # measurement itself broke.
@@ -44,7 +44,7 @@ UPLOAD_DEPTH = 2
 def configure_virtual_devices(n: int, platform: str = "cpu") -> None:
     """Rewrite env so the NEXT jax backend init exposes `n` virtual host
     devices. Must run before jax creates its backend — i.e. at subprocess
-    entry (the probe, microbench) — because the device count is fixed at
+    entry (the probe) — because the device count is fixed at
     backend creation."""
     os.environ["JAX_PLATFORMS"] = platform
     flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",

@@ -10,17 +10,16 @@ Two modes, both printing one JSON line:
   mesh            MEASURED mesh compaction: the same uniform key-range
                   shards through the mesh shard runner
                   (ops/mesh_compaction.py) at 1 chip vs all chips —
-                  strong scaling of one fanned-out job (bench.py promotes
-                  this into compaction_mesh_MBps / mesh_scaling_x).
+                  strong scaling of one fanned-out job.
 
 On a CPU host the devices are virtual
 (--xla_force_host_platform_device_count), so the numbers characterize
 partitioning/dispatch overhead scaling, not chip throughput; the same
 harness runs unchanged on a real multi-chip backend.
 
-Runs in a SUBPROCESS (bench.py invokes `python -m
-toplingdb_tpu.parallel.scaling_probe ...`) because the device count must
-be set before the jax backend exists.
+Runs in a SUBPROCESS (`python -m toplingdb_tpu.parallel.scaling_probe
+...`) because the device count must be set before the jax backend
+exists.
 
 Exit codes: 0 measured; 3 SKIP (environment cannot run the probe — no
 jax backend / too few devices; the caller drops the row); 1 the

@@ -72,7 +72,6 @@ class Bench:
         self.db = DB.open(self.args.db, self.options)
 
     def run(self) -> None:
-        self.results = []  # structured rows for tools/benchmark.py
         for name in self.args.benchmarks.split(","):
             name = name.strip()
             fn = getattr(self, "bench_" + name, None)
@@ -87,11 +86,6 @@ class Bench:
             ops = fn(n)
             dt = time.time() - t0
             ops = ops or n
-            self.results.append({
-                "name": name, "ops": ops, "seconds": round(dt, 4),
-                "ops_per_sec": round(ops / dt, 1),
-                "micros_per_op": round(dt * 1e6 / ops, 3),
-            })
             print(
                 f"{name:<20} : {dt * 1e6 / ops:10.3f} micros/op "
                 f"{ops / dt:12.0f} ops/sec; {dt:8.2f} s"
