@@ -24,9 +24,11 @@ which owns the TPU, runs its compactions.
               rows re-run through the job-dir protocol on the TPU service
               and on a CPU worker, outputs equal as bytes; the DB's
               DCOMPACTION_* tickers and per-job CompactionStats as
-              witnesses that the chip did the work; a second, fresh TPU
-              worker that must compile nothing (persistent compile cache);
-              each Pallas kernel compiled by Mosaic against numpy.
+              witnesses that the chip did the work; a made job whose
+              oldest file holds zeroed rows beside rows written after 2^32,
+              held to the same parity; a fresh TPU worker a job that must
+              compile nothing (persistent compile cache); each Pallas
+              kernel compiled by Mosaic against numpy.
 
 Without a TPU it exits non-zero, says which platform JAX found, and prints
 no result. `--rehearse-cpu` drives the same phases at a tiny size on
@@ -83,6 +85,7 @@ BIG_JOB_ROWS = 1 << 21         # the byte-parity job is at least this large
 # Above this many input rows a job has >= 2 pipeline shards of <= 2^19
 # rows (ops/compaction_kernels.py shard_count), so it must run pipelined.
 PIPELINE_FLOOR_ROWS = (1 << 19) + 1
+SPAN_JOB_ROWS = 600_000        # the sequence-span job: two shards
 EXIT_REHEARSAL = 4             # --rehearse-cpu completed; never a pass
 
 _M1 = np.uint64(0x9E3779B97F4A7C15)
@@ -286,6 +289,14 @@ def worker_result(proc, job_dir: str, log_path: str, timeout: float) -> dict:
 # --------------------------------------------------------------------------
 
 
+def job_params(snapshots=(), **facts) -> dict:
+    return dict(
+        job_id=1, attempt=0, output_dir="", snapshots=list(snapshots),
+        comparator=dbformat.BYTEWISE.name(), merge_operator=None,
+        compaction_filter=None, compression=fmt.SNAPPY_COMPRESSION,
+        block_size=4096, creation_time=1_700_000_000, lease_sec=0.0, **facts)
+
+
 def write_job(job_dir: str, captured: dict, device: str) -> None:
     os.makedirs(os.path.join(job_dir, "out"))
     params = CompactionParams(
@@ -311,6 +322,51 @@ def check_same_bytes(a, b, what: str) -> int:
         check(xa == xb, f"{what}: {name} differs "
                         f"({len(xa)} vs {len(xb)} bytes)")
     return sum(len(x) for _, x in a)
+
+
+def span_job(in_dir: str, rows: int, seed: int) -> dict:
+    """A job a DB of this size never sends: three sorted runs of 8 B keys
+    and 20 B values, `rows` rows in all. The oldest (half the rows) is a
+    bottom-level file of a DB that has taken 2^32 writes: two rows in
+    three zeroed (sequence 0), the others written after 2^24, one after
+    2^32, interleaved, so every file range a shard takes spans the lot.
+    The newer runs overwrite draws of all keys; one snapshot is held."""
+    from toplingdb_tpu.env import default_env
+    from toplingdb_tpu.ops.columnar_io import ColumnarKV, write_tables_columnar
+
+    os.makedirs(in_dir)
+    rng = np.random.default_rng(seed)
+    half, quarter = rows // 2, rows // 4
+    past24, past32 = (1 << 24) + 1, (1 << 32) + 5
+    seqs0 = np.where(rng.random(half) < 2 / 3, 0,
+                     past24 + rng.permutation(half)).astype(np.uint64)
+    seqs0[0] = past32
+    runs = [(np.arange(half, dtype=np.uint64) * np.uint64(2), seqs0)]
+    for base in (half, half + quarter):  # never key 0: its row is newest
+        runs.append((rng.integers(1, rows, quarter, dtype=np.uint64),
+                     np.arange(past24 + base, past24 + base + quarter,
+                               dtype=np.uint64)))
+    fnum = iter(range(1, 100))
+    paths = []
+    for keys, seqs in runs:
+        m = len(keys)
+        s = np.lexsort((~seqs, keys))  # internal-key order: newest first
+        ik = np.empty((m, 16), dtype=np.uint8)
+        ik[:, :8] = Workload.key_bytes(keys[s])
+        ik[:, 8:] = ((seqs[s] << np.uint64(8)) | np.uint64(1)).astype(
+            "<u8").view(np.uint8).reshape(m, 8)  # 1: ValueType.VALUE
+        at = np.arange(m, dtype=np.int32)
+        kv = ColumnarKV(ik.reshape(-1), at * 16, np.full(m, 16, np.int32),
+                        np.tile(ik[:, :10], 2).reshape(-1), at * 20,
+                        np.full(m, 20, np.int32))
+        paths += [f[1] for f in write_tables_columnar(
+            default_env(), in_dir, fnum.__next__,
+            dbformat.InternalKeyComparator(), TableOptions(block_size=4096),
+            kv, at, np.full(m, -1, np.int64), np.ones(m, np.int32), seqs[s],
+            [], creation_time=1)]
+    return {"rows": half + 2 * quarter, "params": job_params(
+        dbname=in_dir, input_files=paths, output_level=6, bottommost=True,
+        max_output_file_size=4 << 20, snapshots=[past24 + half // 2])}
 
 
 # --------------------------------------------------------------------------
@@ -408,15 +464,11 @@ class CapturingFactory(HttpCompactionExecutorFactory):
                 os.link(src, dst)
                 links.append(dst)
             cap["rows"] = rows
-            cap["params"] = dict(
-                job_id=1, attempt=0, dbname=cap["dbname"], output_dir="",
-                input_files=links, output_level=compaction.output_level,
+            cap["params"] = job_params(
+                dbname=cap["dbname"], input_files=links,
+                output_level=compaction.output_level,
                 bottommost=compaction.bottommost,
-                max_output_file_size=compaction.max_output_file_size,
-                snapshots=[], comparator=dbformat.BYTEWISE.name(),
-                merge_operator=None, compaction_filter=None,
-                compression=fmt.SNAPPY_COMPRESSION, block_size=4096,
-                creation_time=1_700_000_000, lease_sec=0.0)
+                max_output_file_size=compaction.max_output_file_size)
         return super().new_executor(compaction)
 
 
@@ -526,18 +578,53 @@ def run(args, rec: Record, workdir: str,
                     wb.put(kb[8 * j:8 * j + 8], vb[20 * j:20 * j + 20])
                 db.write(wb)
 
-    cpu_dir = os.path.join(workdir, "job-cpu")
-    cpu_log = os.path.join(workdir, "worker-cpu.log")
-    cpu_worker = []
+    cpu_worker = {}
 
-    def start_cpu_worker():
-        """The CPU side of the parity job needs no chip and takes about a
+    def start_cpu_worker(tag="job", job=capture):
+        """The CPU side of a parity job needs no chip and takes about a
         minute of per-entry Python: start it as soon as the job has been
         captured, beside the load."""
-        if "params" in capture and not cpu_worker:
-            write_job(cpu_dir, capture, "cpu")
-            cpu_worker.append(spawn_worker(cpu_dir, cpu_env, cpu_log))
-            cleanup.callback(kill, cpu_worker[0])
+        if "params" in job and tag not in cpu_worker:
+            cpu_dir = os.path.join(workdir, f"{tag}-cpu")
+            log = os.path.join(workdir, f"worker-{tag}-cpu.log")
+            write_job(cpu_dir, job, "cpu")
+            w = spawn_worker(cpu_dir, cpu_env, log)
+            cpu_worker[tag] = (w, cpu_dir, log)
+            cleanup.callback(kill, w)
+
+    def parity(ph, tag, job):
+        """`job` through the TPU service and through a CPU worker: outputs
+        equal as bytes; above the pipeline's floor, pipelined to its end
+        with no program of its own. Returns the service's outputs."""
+        ph["rows"] = job["rows"]
+        tpu_dir = os.path.join(workdir, f"{tag}-tpu")
+        write_job(tpu_dir, job, device)
+        start_cpu_worker(tag, job)
+        t0 = time.time()
+        res_tpu = svc.post_job(tpu_dir)
+        ph["tpu_job_s"] = round(time.time() - t0, 2)
+        res_cpu = worker_result(*cpu_worker[tag], timeout=1500)
+        ph["cpu_job_s"] = round(res_cpu["work_time_usec"] / 1e6, 2)
+        out_tpu = job_outputs(tpu_dir, res_tpu)
+        ph["output_files"] = len(out_tpu)
+        ph["output_bytes"] = check_same_bytes(
+            out_tpu, job_outputs(cpu_worker[tag][1], res_cpu),
+            f"{tag}: TPU service vs CPU")
+        st_tpu = ph["tpu_stats"] = {k: res_tpu["stats"][k] for k in (
+            "device", "pipelined", "pipeline_exit", "host_compute_usec",
+            "input_records", "output_records", "jit_compiles",
+            "jit_cache_hits")}
+        check(st_tpu["device"] == device, "service job device")
+        check(res_cpu["stats"]["device"] == "cpu", "cpu job device")
+        check(st_tpu["input_records"] == job["rows"], f"{tag}: input rows")
+        if job["rows"] >= PIPELINE_FLOOR_ROWS:
+            check(st_tpu["pipelined"] and st_tpu["pipeline_exit"] == "",
+                  f"{tag} left the pipeline: {st_tpu['pipeline_exit']!r}")
+            check(st_tpu["host_compute_usec"] == 0,
+                  f"{tag} used the host twin")
+            check(st_tpu["jit_compiles"] == 0,
+                  f"{tag} met a program the load had not compiled")
+        return out_tpu
 
     open_db = []  # the DB while it is open, so a failed phase closes it
 
@@ -580,6 +667,12 @@ def run(args, rec: Record, workdir: str,
         ph.update(run_queries(db, wl, args.seed + 3, gets=2000, mget=1000,
                               scan=min(20_000, n)))
         close_db()
+
+    span = span_job(
+        os.path.join(workdir, "span-inputs"),
+        SPAN_JOB_ROWS if not args.rehearse_cpu else max(4000, n // 16),
+        args.seed + 4)
+    start_cpu_worker("span", span)
 
     with rec.phase("witnesses") as ph:
         t = stats.tickers()
@@ -627,29 +720,11 @@ def run(args, rec: Record, workdir: str,
     with rec.phase("byte_parity_tpu_vs_cpu_worker") as ph:
         check("params" in capture,
               f"no job of >= {big_rows} rows came by to capture")
-        ph["rows"] = capture["rows"]
-        tpu_dir = os.path.join(workdir, "job-tpu")
-        write_job(tpu_dir, capture, device)
-        start_cpu_worker()
-        t0 = time.time()
-        res_tpu = svc.post_job(tpu_dir)
-        ph["tpu_job_s"] = round(time.time() - t0, 2)
-        res_cpu = worker_result(cpu_worker[0], cpu_dir, cpu_log,
-                                timeout=1500)
-        ph["cpu_job_s"] = round(res_cpu["work_time_usec"] / 1e6, 2)
-        out_tpu = job_outputs(tpu_dir, res_tpu)
-        ph["output_files"] = len(out_tpu)
-        ph["output_bytes"] = check_same_bytes(
-            out_tpu, job_outputs(cpu_dir, res_cpu), "TPU service vs CPU")
-        ph["tpu_stats"] = {k: res_tpu["stats"][k] for k in (
-            "device", "pipelined", "host_compute_usec", "input_records",
-            "output_records", "jit_compiles", "jit_cache_hits")}
-        check(res_tpu["stats"]["device"] == device, "service job device")
-        check(res_cpu["stats"]["device"] == "cpu", "cpu job device")
-        if capture["rows"] >= PIPELINE_FLOOR_ROWS:
-            check(res_tpu["stats"]["pipelined"], "parity job not pipelined")
-            check(res_tpu["stats"]["host_compute_usec"] == 0,
-                  "parity job used the host twin")
+        out_tpu = parity(ph, "job", capture)
+
+    with rec.phase("sequence_span_job") as ph:
+        # Zeroed rows beside rows past 2^24 and 2^32 in one file range.
+        out_span = parity(ph, "span", span)
     svc.stop()
 
     if dev["count"] > 1:
@@ -683,23 +758,25 @@ def run(args, rec: Record, workdir: str,
 
     with rec.phase("fresh_worker_compiles_nothing") as ph:
         # The chip is free now: a new worker process takes it and must
-        # find every program of this job in the persistent compile cache.
-        again_dir = os.path.join(workdir, "job-tpu-again")
-        write_job(again_dir, capture, device)
-        log = os.path.join(workdir, "worker-tpu.log")
-        w = spawn_worker(again_dir, env, log)
-        cleanup.callback(kill, w)
-        res = worker_result(w, again_dir, log, timeout=1500)
-        ph.update(jit_compiles=res["stats"]["jit_compiles"],
-                  jit_cache_hits=res["stats"]["jit_cache_hits"],
-                  jit_load_s=round(res["stats"]["jit_compile_usec"] / 1e6,
-                                   2))
-        check_same_bytes(job_outputs(again_dir, res), out_tpu,
-                         "fresh worker vs service")
-        check(res["stats"]["jit_cache_hits"] > 0, "no program requested")
-        check(res["stats"]["jit_compiles"] == 0,
-              f"the fresh worker compiled {res['stats']['jit_compiles']} "
-              "program(s) the service had compiled")
+        # find every program of its job in the persistent compile cache.
+        for tag, job, out in (("job", capture, out_tpu),
+                              ("span", span, out_span)):
+            again_dir = os.path.join(workdir, f"{tag}-tpu-again")
+            write_job(again_dir, job, device)
+            log = os.path.join(workdir, f"worker-{tag}-tpu.log")
+            w = spawn_worker(again_dir, env, log)
+            cleanup.callback(kill, w)
+            res = worker_result(w, again_dir, log, timeout=1500)
+            ph[tag] = dict(
+                jit_compiles=res["stats"]["jit_compiles"],
+                jit_cache_hits=res["stats"]["jit_cache_hits"],
+                jit_load_s=round(res["stats"]["jit_compile_usec"] / 1e6, 2))
+            check_same_bytes(job_outputs(again_dir, res), out,
+                             f"{tag}: fresh worker vs service")
+            check(res["stats"]["jit_cache_hits"] > 0, "no program requested")
+            check(res["stats"]["jit_compiles"] == 0,
+                  f"the fresh worker compiled {res['stats']['jit_compiles']}"
+                  " program(s) the service had compiled")
 
     with rec.phase("pallas_kernels") as ph:
         r = subprocess.run(

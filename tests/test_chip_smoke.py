@@ -39,13 +39,17 @@ def test_smoke_rehearsal_exercises_every_phase_and_is_never_a_pass():
     for name in ("native_build", "service_start", "fillrandom", "overwrite",
                  "compact_range", "queries", "reopen_and_queries",
                  "witnesses", "byte_parity_tpu_vs_cpu_worker",
-                 "fresh_worker_compiles_nothing", "pallas_kernels"):
+                 "sequence_span_job", "fresh_worker_compiles_nothing",
+                 "pallas_kernels"):
         assert name in phases, name
     assert phases["queries"]["gets"] >= 10_000
     assert phases["queries"]["get_misses"] > 0
     assert phases["witnesses"]["dcompaction_fallback_local"] == 0
     assert phases["byte_parity_tpu_vs_cpu_worker"]["output_bytes"] > 0
-    assert phases["fresh_worker_compiles_nothing"]["jit_compiles"] == 0
+    assert phases["sequence_span_job"]["output_bytes"] > 0
+    for tag in ("job", "span"):
+        assert phases["fresh_worker_compiles_nothing"][tag][
+            "jit_compiles"] == 0
     assert record["reduced"], "the cut of the source's scale is stated"
 
 

@@ -729,7 +729,7 @@ def test_plain_upload_matches_host_oracle(seed):
                 ValueType.MERGE))
     snaps = sorted(rng.sample(range(1, rows + 2), rng.randrange(0, 3)))
     h = ck.upload_uniform_shard(chunks)
-    assert h["ukb"].shape == (h["pkb"].shape[0] * (L - 8),)
+    assert h["ukb"].shape == (h["packed_lo"].shape[0] * (L - 8),)
     got = ck.fused_uniform_shard_finish(
         ck.fused_uniform_shard_start(h, snaps, True))
     want = ck.host_fused_full(
@@ -760,7 +760,7 @@ def test_one_fused_program_a_row_bucket():
     sizes = []
     for chunks in (shared, distinct):
         h = ck.upload_uniform_shard(chunks)
-        assert h["pkb"].shape == (256,)
+        assert h["packed_hi"].shape == h["packed_lo"].shape == (256,)
         ck.fused_uniform_shard_finish(
             ck.fused_uniform_shard_start(h, [], False))
         sizes.append(ck._fused_uniform_shard_impl._cache_size())
@@ -769,8 +769,8 @@ def test_one_fused_program_a_row_bucket():
 
 @pytest.mark.parametrize("with_covers", [False, True])
 def test_shard_upload_bytes_are_rows_times_key_and_word(with_covers):
-    """What goes up: p x (uk_len + 4) bytes of keys and trailer words, the
-    three chunk tables, and two u32 planes when tombstones cover rows."""
+    """What goes up: p x (uk_len + 8) bytes of keys and trailer words,
+    nothing a chunk, and two u32 planes when tombstones cover rows."""
     import numpy as np
 
     from toplingdb_tpu.ops import compaction_kernels as ck
@@ -781,13 +781,79 @@ def test_shard_upload_bytes_are_rows_times_key_and_word(with_covers):
         rng, L, 3, lambda r: b"k%07d" % r.randrange(10 ** 6))
     covers = None
     if with_covers:
-        covers = [np.zeros(c[3], dtype=np.uint64) for c in chunks]
+        covers = [np.zeros(c[2], dtype=np.uint64) for c in chunks]
         covers[1][0] = 5
     h = ck.upload_uniform_shard(chunks, covers)
     p = ck._next_pow2(rows)
-    want = p * (L - 8 + 4) + 3 * 16 * 4 + (2 * p * 4 if with_covers else 0)
+    want = p * (L - 8 + 8) + (2 * p * 4 if with_covers else 0)
     assert ck.shard_upload_nbytes(h) == want
     assert (h["tomb_hi"] is not None) == with_covers
+    assert sum(hasattr(v, "nbytes") for v in h.values()) == (
+        5 if with_covers else 3)
+
+
+@pytest.mark.parametrize("with_tombs", [False, True],
+                         ids=["plain", "tombstones"])
+@pytest.mark.parametrize("seed", [41, 42, 43])
+def test_fused_shard_takes_trailers_over_the_whole_56_bits(seed, with_tombs):
+    """The trailer words go up as the keys hold them: rows of ONE chunk
+    whose sequence numbers lie anywhere in 0..2^56-1 (zeroed rows beside
+    rows past 2^24, 2^32 and 2^55), snapshots on both sides of every gap,
+    with and without the tombstone planes, against the host twin."""
+    import numpy as np
+
+    from toplingdb_tpu.ops import compaction_kernels as ck
+
+    rng = random.Random(seed)
+    L, n_chunks = 16, 3
+    edges = [0, 1, (1 << 24) - 1, 1 << 24, (1 << 32) - 1, 1 << 32,
+             (1 << 32) + 5, 1 << 55, (1 << 56) - 1]
+    raw, chunks, all_seqs = bytearray(), [], []
+    for _ in range(n_chunks):
+        n = rng.randrange(40, 120)
+        seqs = set(rng.sample(edges, 5))
+        while len(seqs) < n:
+            seqs.add(rng.randrange(1 << rng.choice([8, 24, 25, 33, 56])))
+        rows = sorted(
+            ((b"k%07d" % rng.randrange(12), s) for s in seqs),
+            key=lambda ks: (ks[0], -ks[1]))  # internal-key order
+        buf = b"".join(
+            make_internal_key(k, s, rng.choice(
+                (ValueType.VALUE, ValueType.VALUE, ValueType.DELETION,
+                 ValueType.MERGE)))
+            for k, s in rows)
+        chunks.append(ck.prepare_uniform_chunk(
+            np.frombuffer(buf, np.uint8), n, L))
+        raw += buf
+        all_seqs += [s for _, s in rows]
+    rows = len(all_seqs)
+    raw = np.frombuffer(bytes(raw), np.uint8)
+    snaps = sorted({(1 << 24) - 2, (1 << 24) + 1, (1 << 32) + 1,
+                    (1 << 55) + 1, rng.choice(all_seqs)})
+    covers = cover = None
+    if with_tombs:
+        cover = np.zeros(rows, dtype=np.uint64)
+        for r in rng.sample(range(rows), rows // 4):
+            # A cover is clamped to its row's snapshot stripe by the host
+            # (host_gc_mask): newer than the row, by one or by as much as
+            # the stripe has room for, or older than it.
+            sq = all_seqs[r]
+            top = min([x for x in snaps if x >= sq] + [(1 << 56) - 1])
+            cover[r] = rng.choice([min(sq + 1, top), top, sq // 2])
+        covers, pos = [], 0
+        for c in chunks:
+            covers.append(cover[pos:pos + c[2]])
+            pos += c[2]
+    bottommost = bool(seed % 2)
+    got = ck.fused_uniform_shard_finish(ck.fused_uniform_shard_start(
+        ck.upload_uniform_shard(chunks, covers), snaps, bottommost))
+    want = ck.host_fused_full(
+        raw, np.arange(rows, dtype=np.int64) * L,
+        np.full(rows, L, dtype=np.int64), L - 8, snaps, bottommost, cover)
+    assert np.array_equal(got[0], want[0]), "survivor order differs"
+    assert np.array_equal(got[1], want[1]), "zero-seq flags differ"
+    assert np.array_equal(got[2], want[2]), "complex flags differ"
+    assert got[3] == want[3]
 
 
 def test_host_merge_runs_matches_full_sort():

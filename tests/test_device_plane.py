@@ -128,6 +128,72 @@ def test_pipeline_exit_on_both_sides_of_the_rule(tmp_path, monkeypatch,
     assert _sst_bytes(env, dbdir, out_dev) == _sst_bytes(env, dbdir, out_cpu)
 
 
+def _mixed_length_keys(n):
+    """n dense internal keys of two lengths: what still reaches the
+    whole-job program (fused_encode_sort_gc) since no job leaves the shard
+    program for its sequence numbers."""
+    import numpy as np
+
+    from toplingdb_tpu.db.dbformat import ValueType, make_internal_key
+
+    keys = [make_internal_key(b"k%0*d" % (7 + 4 * (i % 2), i % 97), i + 1,
+                              ValueType.VALUE) for i in range(n)]
+    lens = np.array([len(k) for k in keys], dtype=np.int64)
+    return (np.frombuffer(b"".join(keys), np.uint8),
+            np.cumsum(lens) - lens, lens)
+
+
+@pytest.mark.parametrize("over", [0, 1])
+def test_whole_job_program_stops_at_the_row_bucket(monkeypatch, over):
+    """The whole-job program pads to the job's own power of two, so it
+    takes a job of up to ROW_BUCKET padded rows and refuses a larger one
+    before anything is traced or compiled (its caller then runs the job
+    per entry). Over a bucket of 256 rows."""
+    import numpy as np
+
+    monkeypatch.setattr(ck, "ROW_BUCKET", 256)
+    kb, ko, kl = _mixed_length_keys(256 + over)
+    before = ck._fused_encode_sort_gc_impl._cache_size()
+    if over:
+        with pytest.raises(NotSupported, match="at most 256 rows, got 257"):
+            ck.fused_encode_sort_gc(kb, ko, kl, 12, [100], True)
+        assert ck._fused_encode_sort_gc_impl._cache_size() == before
+    else:
+        got = ck.fused_encode_sort_gc(kb, ko, kl, 12, [100], True)
+        want = ck.fused_encode_sort_gc_host(kb, ko, kl, 12, [100], True)
+        for a, b in zip(got[:3], want[:3]):
+            assert np.array_equal(a, b)
+        assert len(got[0]) > 0
+
+
+def test_job_of_two_key_lengths_over_the_bucket_runs_per_entry(
+        tmp_path, monkeypatch):
+    """What the refusal leads to: a job of mixed key lengths (no shard
+    program) and more padded rows than the bucket (no whole-job program)
+    is run per entry by the same worker, with the CPU worker's rows."""
+    from test_job_trace import make_job, output_rows, results_of
+    from toplingdb_tpu.compaction import worker
+    from toplingdb_tpu.ops import device_compaction as dc
+
+    monkeypatch.setattr(ck, "ROW_BUCKET", 4096)
+    per_entry = []
+    gc_entries = dc.device_gc_entries
+
+    def spy(entries, *a, **k):
+        per_entry.append(len(entries))
+        return gc_entries(entries, *a, **k)
+
+    monkeypatch.setattr(dc, "device_gc_entries", spy)
+    job_dir = make_job(tmp_path, last_run_key_len=12)
+    assert worker.run_job(job_dir) == 0
+    st = results_of(job_dir)["stats"]
+    assert per_entry == [9000] and not st["pipelined"]
+    cpu_dir = make_job(tmp_path, name="cpu", last_run_key_len=12,
+                       device="cpu")
+    assert worker.run_job(cpu_dir) == 0
+    assert output_rows(job_dir) == output_rows(cpu_dir)
+
+
 # The variables that chose a route or a kernel on the compaction path until
 # PR 30. The serial branch and the host twin stay reachable for tests by
 # replacing pipeline.pipeline_enabled and ck.shard_count, not by a name a

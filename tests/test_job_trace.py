@@ -37,11 +37,14 @@ WAIT_SPANS = {"pipeline.wait_scan", "pipeline.wait_writer", "pipeline.stall"}
 
 
 def make_job(tmp_path, name="job", runs=3, rows=3000, seq_gap=0, trace=None,
-             max_output_file_size=2 ** 62):
+             max_output_file_size=2 ** 62, last_run_key_len=8, snapshots=(),
+             device="cpu-jax"):
     """A job dir with `runs` input SSTs of 8 B keys / 20 B values (half the
     keys overwritten across runs) and its params.json. `seq_gap` is added
     to the sequence numbers of half the last run's rows, so that every
-    chunk of that file spans it."""
+    chunk of that file spans it. `last_run_key_len` other than 8 gives the
+    last run longer keys (the same digits, then '0's): a job of two key
+    lengths, which the pipeline refuses."""
     from toplingdb_tpu.ops.columnar_io import ColumnarKV, write_tables_columnar
 
     env = default_env()
@@ -65,18 +68,19 @@ def make_job(tmp_path, name="job", runs=3, rows=3000, seq_gap=0, trace=None,
         if run == runs - 1:
             seqs[rows // 2:] += np.uint64(seq_gap)
         vts = np.full(rows, int(ValueType.VALUE), dtype=np.uint64)
-        ik = np.empty((rows, 16), dtype=np.uint8)
+        uk = last_run_key_len if run == runs - 1 else 8
+        ik = np.full((rows, uk + 8), ord("0"), dtype=np.uint8)
         for j in range(8):
             ik[:, 7 - j] = (draws // 10 ** j) % 10 + ord("0")
         packed = (seqs << np.uint64(8)) | vts
-        ik[:, 8:] = packed[:, None] >> (np.arange(8) * 8).astype(
+        ik[:, uk:] = packed[:, None] >> (np.arange(8) * 8).astype(
             np.uint64)[None, :] & np.uint64(0xFF)
         s = np.lexsort((np.iinfo(np.int64).max - seqs.view(np.int64), draws))
         vlens = np.full(rows, 20, dtype=np.int32)
         kv = ColumnarKV(
             np.ascontiguousarray(ik[s]).reshape(-1),
-            np.arange(rows, dtype=np.int32) * 16,
-            np.full(rows, 16, dtype=np.int32),
+            np.arange(rows, dtype=np.int32) * (uk + 8),
+            np.full(rows, uk + 8, dtype=np.int32),
             np.full(rows * 20, ord("v"), dtype=np.uint8),
             (np.arange(rows, dtype=np.int32) * 20), vlens,
         )
@@ -90,11 +94,12 @@ def make_job(tmp_path, name="job", runs=3, rows=3000, seq_gap=0, trace=None,
         job_id=1, attempt=0, dbname=in_dir,
         output_dir=os.path.join(job_dir, "out"), input_files=paths,
         output_level=1, bottommost=True,
-        max_output_file_size=max_output_file_size, snapshots=[],
+        max_output_file_size=max_output_file_size,
+        snapshots=list(snapshots),
         comparator=BYTEWISE.name(), merge_operator=None,
         compaction_filter=None, compression=fmt.NO_COMPRESSION,
         block_size=4096, creation_time=1_700_000_000, lease_sec=0.0,
-        device="cpu-jax", trace=trace)
+        device=device, trace=trace)
     with open(os.path.join(job_dir, "params.json"), "w") as f:
         json.dump(params, f)
     return job_dir
@@ -250,12 +255,13 @@ def test_sampled_job_returns_its_spans_in_one_write(tmp_path, pipelined,
 
 
 def test_job_that_leaves_the_pipeline_says_why(tmp_path, pipelined):
-    job_dir = make_job(tmp_path, seq_gap=1 << 24)
+    job_dir = make_job(tmp_path, last_run_key_len=12)
     tracer = tm.Tracer(proc="dcompact-worker")
     assert run_under_request(tracer, job_dir) == 0
     st = results_of(job_dir)["stats"]
     assert st["pipelined"] is False
-    assert st["pipeline_exit"].startswith("NotSupported: chunk seqno span")
+    assert st["pipeline_exit"] == (
+        "PipelineIneligible: non-uniform key length")
     assert st["output_records"] > 0
     (trace,) = tracer.finished()
     names = {s.name for s in trace.spans}
@@ -264,7 +270,55 @@ def test_job_that_leaves_the_pipeline_says_why(tmp_path, pipelined):
             "compaction.finish", "sst.sync_close"} <= names
     errors = [s.tags["error"] for s in trace.spans
               if s.name == "pipeline.chunk_prepare" and "error" in s.tags]
-    assert errors and "NotSupported" in errors[0]
+    assert errors and "PipelineIneligible" in errors[0]
+
+
+def output_rows(job_dir):
+    """Every (internal key, value) of a finished job's outputs, in order."""
+    from toplingdb_tpu.table.factory import open_table
+
+    env = default_env()
+    rows = []
+    for d in results_of(job_dir)["output_files"]:
+        r = open_table(
+            env.new_random_access_file(
+                os.path.join(job_dir, "out", d["path"])),
+            ICMP, TableOptions(block_size=4096,
+                               compression=fmt.NO_COMPRESSION))
+        it = r.new_iterator()
+        it.seek_to_first()
+        rows.extend(it.entries())
+    return rows
+
+
+@pytest.mark.parametrize("gap", [(1 << 24) - 1, 1 << 24, (1 << 32) + 5,
+                                 1 << 55],
+                         ids=["2^24-1", "2^24", "2^32+5", "2^55"])
+def test_a_file_range_of_any_sequence_span_runs_pipelined(
+        tmp_path, pipelined, gap):
+    """The trailers go to the device as they are: every chunk of the last
+    file holds rows on both sides of `gap` sequence numbers, a snapshot
+    lies below the gap and one above it, and the job stays on the
+    pipelined plane with the CPU worker's output, row for row."""
+    snaps = [7000, gap + 8200]  # the last run is 6001..7500, gap+7501..
+    job_dir = make_job(tmp_path, seq_gap=gap, snapshots=snaps)
+    assert worker.run_job(job_dir) == 0
+    st = results_of(job_dir)["stats"]
+    assert st["pipelined"] is True and st["pipeline_exit"] == ""
+    assert st["input_records"] == 9000
+    cpu_dir = make_job(tmp_path, name="cpu", seq_gap=gap, snapshots=snaps,
+                       device="cpu")
+    assert worker.run_job(cpu_dir) == 0
+    assert results_of(cpu_dir)["stats"]["device"] == "cpu"
+    got, want = output_rows(job_dir), output_rows(cpu_dir)
+    assert len(want) == st["output_records"] > 0
+    assert got == want
+    # Survivors of all three stripes: under the lower snapshot (zeroed, the
+    # job is bottommost), between the two, and over the upper one.
+    seqs = [int.from_bytes(k[-8:], "little") >> 8 for k, _ in want]
+    assert any(q == 0 for q in seqs)
+    assert any(7000 < q <= gap + 8200 for q in seqs)
+    assert any(q > gap + 8200 for q in seqs)
 
 
 def test_failed_job_keeps_its_error_in_the_ring(tmp_path):
@@ -359,8 +413,9 @@ def test_service_serves_its_ring(tmp_path, pipelined):
             return json.loads(r.read())
 
     try:
-        for name, gap in (("a", 0), ("b", 1 << 24)):
-            job_dir = make_job(tmp_path, name=name, seq_gap=gap)
+        for name, key_len in (("a", 8), ("b", 12)):
+            job_dir = make_job(tmp_path, name=name,
+                               last_run_key_len=key_len)
             req = urllib.request.Request(
                 url + "/dcompact",
                 data=json.dumps({"job_dir": job_dir}).encode(),
@@ -459,8 +514,7 @@ def test_fused_shard_program_names_its_steps():
     snap_hi, snap_lo = ck._split_snapshots([])
     lowered = ck._fused_uniform_shard_impl.lower(
         np.zeros(p * 8, np.uint8), np.zeros(p, np.uint32),
-        np.zeros(16, np.int32), np.zeros(16, np.uint32),
-        np.zeros(16, np.uint32),
+        np.zeros(p, np.uint32),
         np.zeros(1, np.uint32), np.zeros(1, np.uint32), snap_hi, snap_lo,
         np.int32(1000), 2, 8, np.bool_(True), False)
     text = lowered.as_text(debug_info=True)

@@ -290,9 +290,7 @@ def host_sort_order(key_buf: np.ndarray, key_offs: np.ndarray,
     kb = np.ascontiguousarray(key_buf)
     order = np.empty(n, dtype=np.int32)
     new_key = np.empty(n, dtype=np.uint8)
-    # Sentinel prefill: a stale 6-arg .so would leave packed unwritten —
-    # (seq=MAX, type=0xFF) is not a valid trailer, so survival means stale.
-    packed = np.full(n, np.uint64(0xFFFFFFFFFFFFFFFF), dtype=np.uint64)
+    packed = np.empty(n, dtype=np.uint64)
     rc = -1
     if (run_starts is not None and len(run_starts) > 1 and n
             and hasattr(lib, "tpulsm_merge_runs")):
@@ -320,10 +318,6 @@ def host_sort_order(key_buf: np.ndarray, key_offs: np.ndarray,
         )
     if rc != 0:
         return None
-    if n and packed[0] == np.uint64(0xFFFFFFFFFFFFFFFF):
-        # Old binary ignored packed_out: derive trailers in numpy instead.
-        seq, vtype = _trailer_seq_vtype(kb, offs, lens)
-        packed = (seq << np.uint64(8)) | vtype.astype(np.uint64)
     return order, new_key.astype(bool), packed
 
 
@@ -473,17 +467,6 @@ def host_sort_with_boundaries(key_buf, key_offs, key_lens, run_starts=None):
     return s, new_key, seq, vtype
 
 
-def _trailer_seq_vtype(key_buf, key_offs, key_lens):
-    offs = key_offs.astype(np.int64)
-    lens = key_lens.astype(np.int64)
-    tr_idx = (offs + lens - 8)[:, None] + np.arange(8)[None, :]
-    tr = key_buf[tr_idx].astype(np.uint64)
-    packed = np.zeros(len(offs), dtype=np.uint64)
-    for i in range(8):
-        packed |= tr[:, i] << np.uint64(8 * i)
-    return packed >> np.uint64(8), (packed & np.uint64(0xFF)).astype(np.int32)
-
-
 def _encode_from_bytes(key_buf, key_offs, key_lens, valid, num_key_words):
     """Shared traced encode from raw internal-key bytes: trailer unpack +
     BE user-key word pack, invalid rows masked to the int32max sentinel.
@@ -550,18 +533,16 @@ MAX_SHARD_ROWS = 1 << 22
 @functools.partial(
     jax.jit, static_argnames=("num_key_words", "uk_len", "has_tombs"),
 )
-def _fused_uniform_shard_impl(ukb, pkb, starts, min_his, min_los,
-                              tomb_hi, tomb_lo,
+def _fused_uniform_shard_impl(ukb, packed_hi, packed_lo, tomb_hi, tomb_lo,
                               snap_hi, snap_lo, total, num_key_words, uk_len,
                               bottommost, has_tombs):
-    """ONE range-shard's encode+sort+GC over ONE uploaded buffer pair:
+    """ONE range-shard's encode+sort+GC over THREE uploaded buffers:
     `ukb` = trailer-stripped user-key bytes of every chunk packed
-    contiguously (padded rows zero), `pkb` = one uint32 per row
-    ((seq - chunk_min_seq) << 8 | vtype, deltas < 2^24). Chunk row starts
-    arrive as a small DEVICE array `starts` (pow2-padded with sentinel
-    2^31-1), so per-row chunk ids come from one searchsorted and the jit
-    cache keys only on pow2-padded shapes — arbitrary chunk-size tuples
-    reuse one compilation. TWO bulk host→device transfers per shard.
+    contiguously (padded rows zero), `packed_hi` / `packed_lo` = the two
+    32-bit words of every row's 8-byte trailer (seq << 8 | vtype) as the
+    key holds it, whatever sequence numbers the rows span. The shapes are
+    the row bucket's and the key length's alone, so chunks of any count
+    and size reuse one compilation.
     The result is (packed_bytes u8[3p], meta i32[2]): three
     byte-planes of the 24-bit survivor row ids (bit 23 = zero-seq flag,
     bit 22 = complex-group flag) — 3/4 the download of int32 orders — plus
@@ -580,7 +561,7 @@ def _fused_uniform_shard_impl(ukb, pkb, starts, min_his, min_los,
     sign = u32(_SIGN)
     i32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
     span = num_key_words * 4
-    p = pkb.shape[0]
+    p = packed_lo.shape[0]
     iota = jnp.arange(p, dtype=jnp.int32)
     valid = iota < total
 
@@ -598,19 +579,7 @@ def _fused_uniform_shard_impl(ukb, pkb, starts, min_his, min_los,
         )
         key_words = jnp.where(valid[:, None], i32(words ^ sign), int32max)
 
-        # Reconstruct full 64-bit packed trailers (seq<<8|type): per-row
-        # chunk id via searchsorted over the chunk starts, then add that
-        # chunk's 64-bit min seqno to the 24-bit delta. Deltas from
-        # different chunks are not comparable; the absolute words are.
-        cid = jnp.searchsorted(starts, iota, side="right") - 1
-        rel = pkb >> 8
-        mlo = min_los[cid]
-        seq_lo = mlo + rel
-        carry = (seq_lo < mlo).astype(u32)
-        seq_hi = min_his[cid] + carry
-        vt0 = pkb & u32(0xFF)
-        packed_hi = (seq_hi << 8) | (seq_lo >> 24)
-        packed_lo = (seq_lo << 8) | vt0
+        vt0 = packed_lo & u32(0xFF)
         inv_hi = jnp.where(valid, i32(~packed_hi ^ sign), int32max)
         inv_lo = jnp.where(valid, i32(~packed_lo ^ sign), int32max)
         vtype = jnp.where(valid, vt0.astype(jnp.int32), -1)
@@ -652,25 +621,19 @@ def _fused_uniform_shard_impl(ukb, pkb, starts, min_his, min_los,
 
 
 def prepare_uniform_chunk(key_buf: np.ndarray, n: int, key_len: int):
-    """Host half of the uniform upload: strip the 8-byte trailers from one
-    dense uniform-length key slice; no device traffic. Returns
-    (uk_bytes, pk32, min_seq, n, uk_len). Raises NotSupported when the
-    chunk's seqno span exceeds 24 bits (the uint32 packing budget)."""
+    """Host half of the uniform upload: split one dense uniform-length key
+    slice into its user-key bytes and its 8-byte trailers (seq << 8 |
+    vtype, little-endian) as uint32 words, column 0 the low word; no device
+    traffic. Returns (uk_bytes, trailer_words[n, 2], n, uk_len)."""
     import sys as _sys
 
     kb2 = key_buf[: n * key_len].reshape(n, key_len)
-    tr = np.ascontiguousarray(kb2[:, -8:]).view(np.uint64).reshape(n)
+    tw = np.ascontiguousarray(kb2[:, -8:]).view(np.uint32).reshape(n, 2)
     if _sys.byteorder == "big":
-        tr = tr.byteswap()
-    seq = tr >> np.uint64(8)
-    min_seq = int(seq.min()) if n else 0
-    rel = seq - np.uint64(min_seq)
-    if n and int(rel.max()) >= 1 << 24:
-        raise NotSupported("chunk seqno span exceeds the 24-bit delta budget")
-    pk32 = ((rel << np.uint64(8)) | (tr & np.uint64(0xFF))).astype(np.uint32)
+        tw = tw.byteswap()
     uk_len = key_len - 8
     uk = np.ascontiguousarray(kb2[:, :uk_len]).reshape(-1)
-    return (uk, pk32, min_seq, n, uk_len)
+    return (uk, tw, n, uk_len)
 
 
 # One row bucket for every shard a deployment's jobs produce: a shard of
@@ -708,10 +671,11 @@ def upload_uniform_shard(chunks, covers=None, device=None):
     """Pack one shard's prepared chunks (prepare_uniform_chunk outputs, in
     row order) into device buffers, pad rows to the next power of two
     (to ROW_BUCKET from ROW_BUCKET_FROM rows on), and
-    START the host→device transfers (device_put is async): two bulk
-    transfers per shard, not two per chunk. The user-key bytes go up as
-    they are, so a shard's program depends on its row bucket and key
-    length only, never on the keys.
+    START the host→device transfers (device_put is async): three bulk
+    transfers per shard, not three per chunk. The user-key bytes and the
+    trailer words go up as they are, so a shard's program depends on its
+    row bucket and key length only, never on the keys or their sequence
+    numbers.
     `covers`: optional per-chunk uint64 max-covering-tombstone arrays
     (None = a job without range tombstones); uploaded as two extra u32
     planes.
@@ -719,9 +683,8 @@ def upload_uniform_shard(chunks, covers=None, device=None):
     specific chip — the fused program carries no pin of its own, so the
     committed inputs decide where it runs (ops/mesh_compaction.py places
     shards round-robin over a mesh this way)."""
-    uk_len = chunks[0][4]
-    ns = tuple(int(c[3]) for c in chunks)
-    total = sum(ns)
+    uk_len = chunks[0][3]
+    total = sum(int(c[2]) for c in chunks)
     if total > MAX_SHARD_ROWS:
         raise NotSupported(
             f"shard rows {total} exceed the 24-bit packed-order budget"
@@ -729,7 +692,8 @@ def upload_uniform_shard(chunks, covers=None, device=None):
     p = (ROW_BUCKET if ROW_BUCKET_FROM <= total <= ROW_BUCKET
          else _next_pow2(max(1, total)))
     ukb = np.zeros(p * uk_len, dtype=np.uint8)
-    pkb = np.zeros(p, dtype=np.uint32)
+    packed_hi = np.zeros(p, dtype=np.uint32)
+    packed_lo = np.zeros(p, dtype=np.uint32)
     # A job whose inputs hold range tombstones runs the tombstone variant
     # of the program in every shard, covered rows or not: which of the two
     # programs a shard meets is then a property of the job, and a
@@ -739,38 +703,23 @@ def upload_uniform_shard(chunks, covers=None, device=None):
         tomb_hi = np.zeros(p, dtype=np.uint32)
         tomb_lo = np.zeros(p, dtype=np.uint32)
     pos = 0
-    for ci, (uk, pk32, _mn, n, _l) in enumerate(chunks):
+    for ci, (uk, tw, n, _l) in enumerate(chunks):
         ukb[pos * uk_len:(pos + n) * uk_len] = uk
-        pkb[pos:pos + n] = pk32
+        packed_lo[pos:pos + n] = tw[:, 0]
+        packed_hi[pos:pos + n] = tw[:, 1]
         if has_tombs and covers[ci] is not None:
             cv = covers[ci]
             tomb_hi[pos:pos + n] = (cv >> np.uint64(32)).astype(np.uint32)
             tomb_lo[pos:pos + n] = (cv & np.uint64(0xFFFFFFFF)).astype(
                 np.uint32)
         pos += n
-    mins = np.array([c[2] for c in chunks], dtype=np.uint64)
-    # Chunk starts + per-chunk min seqnos, padded to a pow2 of at least 16
-    # so that the chunk count (one per input file the shard overlaps)
-    # almost never makes a new program: jobs of 1, 2, 4 and 8 chunks per
-    # shard all came by in one db_bench load, and each shape is a compile.
-    nc = _next_pow2(max(16, len(ns)))
-    starts = np.full(nc, 2**31 - 1, dtype=np.int32)
-    starts[: len(ns)] = np.cumsum([0] + list(ns[:-1]), dtype=np.int64)
-    min_his = np.zeros(nc, dtype=np.uint32)
-    min_los = np.zeros(nc, dtype=np.uint32)
-    min_his[: len(ns)] = (mins >> np.uint64(32)).astype(np.uint32)
-    min_los[: len(ns)] = (mins & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-    def put(x):
-        # A committed transfer (device=) pins the downstream jit program to
-        # that chip; the default keeps today's backend-default placement.
-        return jax.device_put(x, device) if device is not None \
-            else jax.device_put(x)
 
+    # A committed transfer (a device) pins the downstream jit program to
+    # that chip; None keeps the backend-default placement.
+    put = functools.partial(jax.device_put, device=device)
     return {
-        "ukb": put(ukb), "pkb": put(pkb), "total": total,
-        "starts": put(starts),
-        "min_his": put(min_his),
-        "min_los": put(min_los), "uk_len": uk_len,
+        "ukb": put(ukb), "packed_hi": put(packed_hi),
+        "packed_lo": put(packed_lo), "total": total, "uk_len": uk_len,
         "tomb_hi": put(tomb_hi) if has_tombs else None,
         "tomb_lo": put(tomb_lo) if has_tombs else None,
     }
@@ -798,8 +747,7 @@ def fused_uniform_shard_start(handle, snapshots: list[int], bottommost: bool):
     t_hi = h["tomb_hi"] if has_tombs else np.zeros(1, dtype=np.uint32)
     t_lo = h["tomb_lo"] if has_tombs else np.zeros(1, dtype=np.uint32)
     out = _fused_uniform_shard_impl(
-        h["ukb"], h["pkb"], h["starts"], h["min_his"], h["min_los"],
-        t_hi, t_lo, snap_hi, snap_lo,
+        h["ukb"], h["packed_hi"], h["packed_lo"], t_hi, t_lo, snap_hi, snap_lo,
         np.int32(h["total"]), w, uk_len, np.bool_(bottommost), has_tombs,
     )
     for a in out:
@@ -842,6 +790,15 @@ def fused_encode_sort_gc(key_buf: np.ndarray, key_offs: np.ndarray,
             f"device GC supports <= {MAX_SNAPSHOTS} live snapshots"
         )
     n = len(key_offs)
+    p = _next_pow2(max(1, n))
+    if p > ROW_BUCKET:
+        # This program pads to the job's own power of two: at 4,194,304
+        # rows the chip compiled it ~400 s and then failed to allocate
+        # (PERF.md, PR 29). The caller runs such a job per entry.
+        raise NotSupported(
+            f"the whole-job program takes at most {ROW_BUCKET} rows, "
+            f"got {n}"
+        )
     # The device derives offsets as an exclusive cumsum of the lengths; that
     # requires the dense end-to-end layout ColumnarKV scans produce.
     if n and (int(key_offs[0]) != 0
@@ -850,7 +807,6 @@ def fused_encode_sort_gc(key_buf: np.ndarray, key_offs: np.ndarray,
                   key_offs[1:], (np.cumsum(key_lens) - key_lens)[1:]
               )):
         raise NotSupported("fused encode requires densely packed key buffers")
-    p = _next_pow2(max(1, n))
     w = (max_key_bytes + 3) // 4
     lens = np.zeros(p, dtype=np.int32)  # pad rows: zero-length (masked)
     valid = np.zeros(p, dtype=bool)

@@ -18,6 +18,7 @@ from __future__ import annotations
 import bisect
 import os
 import time
+import types
 
 import numpy as np
 
@@ -203,38 +204,6 @@ class _FallbackToEntries(Exception):
     semantics (complex groups present)."""
 
 
-def _kv_seq_vtype(kv):
-    """Trailer columns (packed, seq, vtype) from flat buffers — shared by the
-    full columnar encode and the cheap post-fused-run subset."""
-    import sys
-    import types
-
-    n = kv.n
-    offs = kv.key_offs.astype(np.int64)
-    lens = kv.key_lens.astype(np.int64)
-    if n and kv.key_lens.min() == kv.key_lens.max() and len(
-            kv.key_buf) == n * int(lens[0]) and int(offs[0]) == 0 and int(
-            offs[-1]) == (n - 1) * int(lens[0]) and np.array_equal(
-            np.diff(offs), lens[:-1]):
-        # Uniform key length over a dense buffer: the trailers are a strided
-        # view — no [n,8] gather.
-        trailer = np.ascontiguousarray(
-            kv.key_buf.reshape(n, int(lens[0]))[:, -8:]
-        )
-    else:
-        tr_idx = (offs + lens - 8)[:, None] + np.arange(8)[None, :]
-        trailer = np.ascontiguousarray(kv.key_buf[tr_idx])
-    packed = trailer.view(np.uint64).reshape(n)
-    if sys.byteorder == "big":  # trailer bytes on disk are LE
-        packed = packed.byteswap()
-    return types.SimpleNamespace(
-        packed=packed,
-        seq=packed >> np.uint64(8),
-        vtype=(packed & np.uint64(0xFF)).astype(np.int32),
-        n=n,
-    )
-
-
 def _part_user_key(part, i: int) -> bytes:
     o = int(part.key_offs[i])
     return part.key_buf[o: o + int(part.key_lens[i]) - 8].tobytes()
@@ -344,27 +313,24 @@ def _prepare_uniform_shards(parts):
             break
     shards = [([], []) for _ in range(len(splitters) + 1)]
     row_base = 0
-    try:
-        for part in parts:
-            if not part.n:
+    for part in parts:
+        if not part.n:
+            continue
+        bounds = _part_bounds(part, splitters)
+        for s in range(len(bounds) - 1):
+            lo, hi = bounds[s], bounds[s + 1]
+            if lo == hi:
                 continue
-            bounds = _part_bounds(part, splitters)
-            for s in range(len(bounds) - 1):
-                lo, hi = bounds[s], bounds[s + 1]
-                if lo == hi:
-                    continue
-                blo = int(part.key_offs[lo])
-                bhi = int(part.key_offs[hi - 1]) + int(part.key_lens[hi - 1])
-                shards[s][0].append(ck.prepare_uniform_chunk(
-                    part.key_buf[blo:bhi], hi - lo, uniform_len,
-                ))
-                shards[s][1].append((row_base + lo, row_base + hi))
-            row_base += part.n
-    except NotSupported:
-        return None
+            blo = int(part.key_offs[lo])
+            bhi = int(part.key_offs[hi - 1]) + int(part.key_lens[hi - 1])
+            shards[s][0].append(ck.prepare_uniform_chunk(
+                part.key_buf[blo:bhi], hi - lo, uniform_len,
+            ))
+            shards[s][1].append((row_base + lo, row_base + hi))
+        row_base += part.n
     shards = [sh for sh in shards if sh[0]]
     for chunks, _ranges in shards:
-        if sum(c[3] for c in chunks) > ck.MAX_SHARD_ROWS:
+        if sum(c[2] for c in chunks) > ck.MAX_SHARD_ROWS:
             return None  # skewed splitters blew the 24-bit row budget
     return shards or None
 
@@ -810,7 +776,8 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
     if not _host_sort():
         # Host-sort mode gets seq/vtype from the fused native merge+GC —
         # gathering trailers here would be pure waste at bench scale.
-        col = _kv_seq_vtype(kv)
+        seq_a, vt_a = pl._range_seq_vtype(kv, 0, kv.n)
+        col = types.SimpleNamespace(seq=seq_a, vtype=vt_a, n=kv.n)
         _VT = dbformat.ValueType
         any_complex = bool(kv.n) and bool(np.any(
             (col.vtype == int(_VT.MERGE))
@@ -841,8 +808,6 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
         stats.host_compute_usec += int((time.time() - t_cov) * 1e6)
         prep.finish()
         if _host_sort():
-            import types as _types
-
             t_hc = time.time()
             rs = np.cumsum([0] + [p_.n for p_ in parts], dtype=np.int64)
             order, zero_flags, cx_flags, has_complex, seq_a, vt_a = \
@@ -852,7 +817,7 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
                     run_starts=rs,
                 )
             stats.host_compute_usec += int((time.time() - t_hc) * 1e6)
-            col = _types.SimpleNamespace(seq=seq_a, vtype=vt_a, n=kv.n)
+            col = types.SimpleNamespace(seq=seq_a, vtype=vt_a, n=kv.n)
         elif shards is not None:
             # Upload + dispatch through the mesh seam: serial mode uploads
             # every shard up front to the default device (device_put and

@@ -129,7 +129,7 @@ def check_eligibility(shards, any_complex: bool, devices,
     below-row-floor, single-device."""
     if not shards:
         return "no-uniform-shards", 0
-    total = sum(int(c[3]) for chunks, _ranges in shards for c in chunks)
+    total = sum(int(c[2]) for chunks, _ranges in shards for c in chunks)
     if len(shards) < 2:
         return "single-shard", total
     if any_complex:
