@@ -110,6 +110,35 @@ class CompactionStats:
     merge_fold_usec: int = 0
     tombstone_fragments: int = 0
     tombstone_cover_usec: int = 0
+    # The cold format on the job's two ends (table/zip_table.py): inputs
+    # that are ZipTables, their rows, and the wall of decoding them into
+    # the columnar buffers (`pipeline.zip_scan`; the readers run side by
+    # side, so it is a sum over threads). Outputs that are ZipTables,
+    # their file bytes, the raw key and value bytes of their rows, and the
+    # wall of encoding them (`zip.index_build` + `zip.dict_train` +
+    # `zip.encode`), of which the dictionary training alone.
+    zip_input_files: int = 0
+    zip_input_rows: int = 0
+    zip_scan_usec: int = 0
+    zip_output_files: int = 0
+    zip_output_bytes: int = 0
+    zip_output_raw_bytes: int = 0
+    zip_encode_usec: int = 0
+    zip_dict_train_usec: int = 0
+
+    def count_zip_input(self, reader) -> None:
+        """One input file's reader: counted when it is a ZipTable."""
+        if hasattr(reader, "scan_columnar"):
+            self.zip_input_files += 1
+            self.zip_input_rows += reader.n
+
+    def count_zip_output(self, props, file_size: int) -> None:
+        """One finished output file: counted when it is a ZipTable."""
+        if str(props.compression_name).startswith("zip"):
+            self.zip_output_files += 1
+            self.zip_output_bytes += file_size
+            self.zip_output_raw_bytes += (props.raw_key_size
+                                          + props.raw_value_size)
 
     def phase_dict(self) -> dict:
         """Non-zero timing phases, seconds — for bench/dcompact reporting.
@@ -399,6 +428,7 @@ def build_outputs(env, dbname: str, icmp, compaction: Compaction,
         outputs.append(meta)
         stats.output_bytes += meta.file_size
         stats.output_files += 1
+        stats.count_zip_output(props, meta.file_size)
         builder = None
         wfile = None
 
@@ -528,6 +558,7 @@ def _run_subcompactions(env, dbname, icmp, compaction, table_cache,
     rd0 = RangeDelAggregator(ucmp)
     for _, f in compaction.all_inputs():
         r = table_cache.get_reader(f.number)
+        stats.count_zip_input(r)
         for b, e in r.range_del_entries():
             rd0.add(RangeTombstone.from_table_entry(b, e))
     all_frags = surviving_tombstone_fragments(
@@ -615,6 +646,9 @@ def _run_subcompactions(env, dbname, icmp, compaction, table_cache,
         stats.output_records += st.output_records
         stats.output_bytes += st.output_bytes
         stats.output_files += st.output_files
+        stats.zip_output_files += st.zip_output_files
+        stats.zip_output_bytes += st.zip_output_bytes
+        stats.zip_output_raw_bytes += st.zip_output_raw_bytes
         stats.dropped_obsolete += st.dropped_obsolete
         stats.dropped_tombstone += st.dropped_tombstone
         stats.merged_records += st.merged_records

@@ -148,7 +148,11 @@ class ChipPool:
 
 JOB_SUM_COUNTERS = ("merge_operand_rows", "merge_groups",
                     "merge_rows_folded", "merge_fold_usec",
-                    "tombstone_fragments", "tombstone_cover_usec")
+                    "tombstone_fragments", "tombstone_cover_usec",
+                    "zip_input_files", "zip_input_rows", "zip_scan_usec",
+                    "zip_output_files", "zip_output_bytes",
+                    "zip_output_raw_bytes", "zip_encode_usec",
+                    "zip_dict_train_usec")
 
 
 class DcompactWorkerService:

@@ -390,7 +390,8 @@ class SubprocessCompactionExecutor(CompactionExecutor):
             block_size=opts.table_options.block_size,
             creation_time=int(time.time()),
             device=self.device,
-            table_format=getattr(opts.table_options, "format", "block"),
+            table_format=opts.table_options_for_level(
+                compaction.output_level, compaction.bottommost).format,
             prefix_extractor=(
                 opts.table_options.prefix_extractor.name()
                 if getattr(opts.table_options, "prefix_extractor", None)

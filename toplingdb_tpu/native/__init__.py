@@ -468,8 +468,13 @@ def lib() -> ctypes.CDLL | None:
             l.tpulsm_zip_encode_values.argtypes = [
                 u8p, ctypes.c_int64, i64p, i64p, ctypes.c_int64,
                 ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
-                ctypes.c_int32, u8p, ctypes.c_int64, u8p, ctypes.c_int64,
+                u8p, ctypes.c_int64, u8p, ctypes.c_int64,
                 u8p, u8p, i64p,
+            ]
+            l.tpulsm_zip_train_dict.restype = ctypes.c_int64
+            l.tpulsm_zip_train_dict.argtypes = [
+                u8p, ctypes.c_int64, i64p, i64p, ctypes.c_int64,
+                ctypes.c_int32, ctypes.c_int32, u8p, ctypes.c_int64,
             ]
             l.tpulsm_zip_decode_keys.restype = ctypes.c_int64
             l.tpulsm_zip_decode_keys.argtypes = [

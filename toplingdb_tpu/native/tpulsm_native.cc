@@ -5269,66 +5269,100 @@ int64_t tpulsm_zip_encode_keys(
   return cum;
 }
 
-// Value-plane encoder for one zip segment: gathers each VG-entry value
-// group from the columnar value buffer, trains one ZDICT dictionary over
-// every (ngroups//256)-th group (the Python sampling stride), compresses
-// groups >= 32 raw bytes in parallel, and packs payloads ("compress only
-// if strictly smaller" per group, flag bit set) with the u32 offset
-// directory. dict_out must hold max_dict_bytes; flags_out arrives
-// zeroed. out_meta returns [blob_len, dict_len]. Returns the group
-// count, or -1 zstd/ZDICT entry points unavailable (Python fallback),
-// -2 blob_cap/dict_cap too small, -3 invalid offsets or a compressor
-// error.
-int64_t tpulsm_zip_encode_values(
-    const uint8_t* val_buf, int64_t val_buf_len, const int64_t* offs,
-    const int64_t* lens, int64_t n, int32_t vg, int32_t compress,
-    int32_t level, int32_t max_dict_bytes, uint8_t* dict_out,
-    int64_t dict_cap, uint8_t* blob_out, int64_t blob_cap,
-    uint8_t* go_out, uint8_t* flags_out, int64_t* out_meta) {
-  if (n <= 0 || vg <= 0) return -3;
+// Group byte bounds of one zip segment's value plane: gb[g] is where the
+// raw bytes of VG-entry value group g start. False when an offset or a
+// length lies outside val_buf.
+static bool zip_value_group_bounds(const int64_t* offs, const int64_t* lens,
+                                   int64_t n, int32_t vg,
+                                   int64_t val_buf_len,
+                                   std::vector<int64_t>& gb) {
   const int64_t ng = (n + vg - 1) / vg;
-  std::vector<int64_t> gb(ng + 1, 0);
+  gb.assign(ng + 1, 0);
   for (int64_t i = 0; i < n; i++) {
     if (lens[i] < 0 || offs[i] < 0 || lens[i] > val_buf_len ||
         offs[i] > val_buf_len - lens[i])
-      return -3;
+      return false;
     gb[i / vg + 1] += lens[i];
   }
   for (int64_t g = 0; g < ng; g++) gb[g + 1] += gb[g];
+  return true;
+}
+
+static void zip_gather_group(const uint8_t* val_buf, const int64_t* offs,
+                             const int64_t* lens, int64_t n, int32_t vg,
+                             int64_t g, uint8_t* dst) {
+  int64_t e1 = (g + 1) * (int64_t)vg;
+  if (e1 > n) e1 = n;
+  for (int64_t i = g * (int64_t)vg; i < e1; i++) {
+    std::memcpy(dst, val_buf + offs[i], (size_t)lens[i]);
+    dst += lens[i];
+  }
+}
+
+// Dictionary training for one zip segment (the span `zip.dict_train`):
+// one ZDICT dictionary over every (ngroups//256)-th VG-entry value group
+// (the Python sampling stride). Returns the dictionary's length in
+// dict_out, 0 for none (fewer than 8 groups, max_dict_bytes <= 0, or the
+// trainer declined: the groups then compress dictionary-less, the
+// utils/codecs.py contract), -1 ZDICT entry points unavailable (Python
+// fallback), -2 dict_cap too small, -3 invalid offsets.
+int64_t tpulsm_zip_train_dict(
+    const uint8_t* val_buf, int64_t val_buf_len, const int64_t* offs,
+    const int64_t* lens, int64_t n, int32_t vg, int32_t max_dict_bytes,
+    uint8_t* dict_out, int64_t dict_cap) {
+  if (n <= 0 || vg <= 0) return -3;
+  std::vector<int64_t> gb;
+  if (!zip_value_group_bounds(offs, lens, n, vg, val_buf_len, gb)) return -3;
+  const int64_t ng = (int64_t)gb.size() - 1;
+  if (max_dict_bytes <= 0 || ng < 8) return 0;
+  const Codecs& c = codecs();
+  if (!c.zdict_train || !c.zdict_err || !c.zstd_cmp_dict ||
+      !c.zstd_cctx_new || !c.zstd_cctx_free)
+    return -1;
+  if (dict_cap < max_dict_bytes) return -2;
+  int64_t stride = ng / 256;
+  if (stride < 1) stride = 1;
+  std::string sblob;
+  std::vector<size_t> sizes;
+  for (int64_t g = 0; g < ng; g += stride) {
+    size_t base = sblob.size();
+    sblob.resize(base + (size_t)(gb[g + 1] - gb[g]));
+    zip_gather_group(val_buf, offs, lens, n, vg, g, (uint8_t*)&sblob[base]);
+    sizes.push_back((size_t)(gb[g + 1] - gb[g]));
+  }
+  size_t r = c.zdict_train(dict_out, (size_t)max_dict_bytes, sblob.data(),
+                           sizes.data(), (unsigned)sizes.size());
+  return c.zdict_err(r) ? 0 : (int64_t)r;
+}
+
+// Value-plane encoder for one zip segment: gathers each VG-entry value
+// group from the columnar value buffer, compresses groups >= 32 raw bytes
+// in parallel, under dict[0:dict_len] when dict_len > 0
+// (tpulsm_zip_train_dict), and packs payloads ("compress only if strictly
+// smaller" per group, flag bit set) with the u32 offset directory.
+// flags_out arrives zeroed. out_meta returns [blob_len]. Returns the
+// group count, or -1 zstd entry points unavailable (Python fallback), -2
+// blob_cap too small, -3 invalid offsets or a compressor error.
+int64_t tpulsm_zip_encode_values(
+    const uint8_t* val_buf, int64_t val_buf_len, const int64_t* offs,
+    const int64_t* lens, int64_t n, int32_t vg, int32_t compress,
+    int32_t level, const uint8_t* dict, int64_t dict_len, uint8_t* blob_out,
+    int64_t blob_cap, uint8_t* go_out, uint8_t* flags_out,
+    int64_t* out_meta) {
+  if (n <= 0 || vg <= 0 || dict_len < 0) return -3;
+  std::vector<int64_t> gb;
+  if (!zip_value_group_bounds(offs, lens, n, vg, val_buf_len, gb)) return -3;
+  const int64_t ng = (int64_t)gb.size() - 1;
   auto gather = [&](int64_t g, uint8_t* dst) {
-    int64_t e1 = (g + 1) * (int64_t)vg;
-    if (e1 > n) e1 = n;
-    for (int64_t i = g * (int64_t)vg; i < e1; i++) {
-      std::memcpy(dst, val_buf + offs[i], (size_t)lens[i]);
-      dst += lens[i];
-    }
+    zip_gather_group(val_buf, offs, lens, n, vg, g, dst);
   };
   const Codecs& c = codecs();
-  int64_t dlen = 0;
+  const int64_t dlen = compress ? dict_len : 0;
   if (compress) {
     if (!c.zstd_cmp || !c.zstd_bound || !c.zstd_err) return -1;
-    if (max_dict_bytes > 0 && ng >= 8) {
-      if (!c.zdict_train || !c.zdict_err || !c.zstd_cmp_dict ||
-          !c.zstd_cctx_new || !c.zstd_cctx_free)
-        return -1;
-      if (dict_cap < max_dict_bytes) return -2;
-      int64_t stride = ng / 256;
-      if (stride < 1) stride = 1;
-      std::string sblob;
-      std::vector<size_t> sizes;
-      for (int64_t g = 0; g < ng; g += stride) {
-        size_t base = sblob.size();
-        sblob.resize(base + (size_t)(gb[g + 1] - gb[g]));
-        gather(g, (uint8_t*)&sblob[base]);
-        sizes.push_back((size_t)(gb[g + 1] - gb[g]));
-      }
-      size_t r = c.zdict_train(dict_out, (size_t)max_dict_bytes,
-                               sblob.data(), sizes.data(),
-                               (unsigned)sizes.size());
-      // Training failure is NOT an error: the Python path gets b"" and
-      // compresses dictionary-less (utils/codecs.py contract).
-      if (!c.zdict_err(r)) dlen = (int64_t)r;
-    }
+    if (dlen > 0 && (!c.zstd_cmp_dict || !c.zstd_cctx_new ||
+                     !c.zstd_cctx_free))
+      return -1;
   }
   std::vector<std::string> zs(ng);  // "" → raw payload
   if (compress) {
@@ -5359,7 +5393,7 @@ int64_t tpulsm_zip_encode_values(
         z.resize(bound);
         size_t zn = dlen > 0
                         ? c.zstd_cmp_dict(cctx, &z[0], bound, raw.data(),
-                                          (size_t)rsz, dict_out,
+                                          (size_t)rsz, dict,
                                           (size_t)dlen, level)
                         : c.zstd_cmp(&z[0], bound, raw.data(), (size_t)rsz,
                                      level);
@@ -5409,7 +5443,6 @@ int64_t tpulsm_zip_encode_values(
     std::memcpy(go_out + 4 * (g + 1), &v, 4);
   }
   out_meta[0] = cum;
-  out_meta[1] = dlen;
   return ng;
 }
 

@@ -229,12 +229,15 @@ def scan_table_columnar(reader, ref_values: bool = True) -> ColumnarKV:
     stays alive as val_buf, saving the per-entry value memcpy), keys
     copied; compressed files fall back to per-block decompression +
     decode. `ref_values=False` forces the value-copying twin (parity
-    tests)."""
+    tests). A ZipTable goes through its own decoders."""
     lib = native.lib()
     if lib is None:
         raise NotSupported("native library unavailable")
+    if hasattr(reader, "scan_columnar"):
+        return _scan_zip_table_columnar(reader)
     if not hasattr(reader, "new_index_iterator"):
-        raise NotSupported("bulk columnar scan requires the block format")
+        raise NotSupported(
+            "bulk columnar scan requires the block or the zip format")
     raw, block_offs, block_lens, handles = _file_scan_prologue(reader)
     if raw is None:
         return ColumnarKV(
@@ -321,6 +324,19 @@ def scan_table_columnar(reader, ref_values: bool = True) -> ColumnarKV:
     if kv is None:
         raise Corruption("decompressed blocks failed native bulk decode")
     return kv
+
+
+def _scan_zip_table_columnar(reader) -> ColumnarKV:
+    """A whole ZipTable through its native decoders
+    (ZipTableReader.scan_columnar): dense keys from offset 0, values as
+    the decoded groups hold them."""
+    if reader.n and not reader.scan_native_ready():
+        raise NotSupported("zip scan plane unavailable")
+    kb, ko, kl, vb, vo, vl = reader.scan_columnar(0, reader.n)
+    if len(kb) > 0x7FFFFF00 or len(vb) > 0x7FFFFF00:
+        raise NotSupported("input exceeds the int32 columnar budget")
+    return ColumnarKV(kb, ko.astype(np.int32), kl.astype(np.int32),
+                      vb, vo.astype(np.int32), vl.astype(np.int32))
 
 
 def _refvals_decode(lib, raw, block_offs, block_lens, verify):

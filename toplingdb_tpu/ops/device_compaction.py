@@ -50,9 +50,11 @@ def collect_raw_entries(compaction, table_cache, icmp, stats=None):
         for k, v in it.entries():
             entries.append((k, v))
         if stats is not None:
-            h, m = it.prefetch_counts()
-            stats.prefetch_hits += h
-            stats.prefetch_misses += m
+            stats.count_zip_input(r)
+            if hasattr(it, "prefetch_counts"):  # a ZipTable reads no blocks
+                h, m = it.prefetch_counts()
+                stats.prefetch_hits += h
+                stats.prefetch_misses += m
         for b, e in r.range_del_entries():
             rd.add(RangeTombstone.from_table_entry(b, e))
     return entries, rd
@@ -234,9 +236,12 @@ def _part_bounds(part, splitters: list[bytes]) -> list[int]:
     return b
 
 
-def _collect_raw_columnar(compaction, table_cache, icmp, want_uploads=False):
+def _collect_raw_columnar(compaction, table_cache, icmp, want_uploads=False,
+                          stats=None):
     """Scan every input file into columnar buffers — in parallel threads
-    (the native block decoder runs GIL-free under ctypes). With
+    (the native block decoder runs GIL-free under ctypes). Block files
+    and ZipTables, in any mix: a ZipTable's scan is the span
+    `pipeline.zip_scan` and counts into `stats` (zip_input_*). With
     want_uploads, ALSO split the sorted parts into user-key-range shards
     and prepare (host-side, no device traffic yet) each shard's uniform
     chunk columns. Returns (kv, rd, shards, parts) where shards is None
@@ -260,12 +265,30 @@ def _collect_raw_columnar(compaction, table_cache, icmp, want_uploads=False):
     if pre is not None:
         kv, parts = pre
     else:
+        trace = _tele.current_handle()
+        zip_usec = []
+
+        def scan_one(r):
+            if not hasattr(r, "scan_columnar"):
+                return scan_table_columnar(r)
+            t0 = time.time()
+            with _tele.span_under(trace, "pipeline.zip_scan",
+                                  rows=r.n) as sp:
+                part = scan_table_columnar(r)
+                sp.tag(nbytes=len(part.key_buf) + len(part.val_buf))
+            zip_usec.append(int((time.time() - t0) * 1e6))
+            return part
+
         if len(readers) > 1:
             with ThreadPoolExecutor(min(8, len(readers))) as ex:
-                parts = list(ex.map(scan_table_columnar, readers))
+                parts = list(ex.map(scan_one, readers))
         else:
-            parts = [scan_table_columnar(r) for r in readers]
+            parts = [scan_one(r) for r in readers]
         kv = ColumnarKV.concat(parts)
+        if stats is not None:
+            for r in readers:
+                stats.count_zip_input(r)
+            stats.zip_scan_usec += sum(zip_usec)
     rd = RangeDelAggregator(icmp.user_comparator)
     for r in readers:
         for b, e in r.range_del_entries():
@@ -703,6 +726,7 @@ def _outputs_from_files(env, files, kv, vtypes, stats, icmp=None,
         stats.output_bytes += meta.file_size
         stats.output_files += 1
         stats.output_records += props.num_entries
+        stats.count_zip_output(props, meta.file_size)
     return outputs
 
 
@@ -756,7 +780,7 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
         with _tele.span("compaction.input_scan"):
             kv, rd, shards, parts = _collect_raw_columnar(
                 compaction, table_cache, icmp,
-                want_uploads=not _host_sort(),
+                want_uploads=not _host_sort(), stats=stats,
             )
     except NotSupported:
         raise _FallbackToEntries()  # >2GiB columnar buffers etc.
@@ -950,7 +974,7 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
                     creation_time if creation_time is not None
                     else int(time.time()),
                     max_output_file_size=compaction.max_output_file_size,
-                    column_family=column_family,
+                    column_family=column_family, stats=stats,
                 )
             else:
                 files = write_tables_columnar(

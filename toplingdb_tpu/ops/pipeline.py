@@ -9,7 +9,8 @@ bounded three-stage pipeline at user-key-range shard granularity:
   reader   per input file, decode the blocks of one key-range shard per
            native call (windowed preads through a FilePrefetchBuffer),
            writing into a properties-sized preallocated ColumnarKV —
-           independent files scan on parallel threads
+           independent files scan on parallel threads; a ZipTable input
+           decodes the shard's entry range instead (it has no blocks)
   compute  as soon as EVERY file has scanned past shard s, run the
            device (uniform-shard upload + fused kernel) or host-twin
            (native k-way merge + GC) sort+GC over just that shard's rows
@@ -22,9 +23,11 @@ key lands in exactly one shard), so per-shard GC decisions — snapshot
 stripes, tombstone shadowing, bottommost seqno zeroing — equal the
 global ones and the concatenated survivor stream is byte-identical to
 the serial path's; tests/test_compaction_pipeline.py asserts whole-file
-SST equality. Jobs the pipeline does not cover (non-block formats,
-missing properties, jobs of one shard) raise PipelineIneligible and the
-caller falls back to the serial path, which computes the same bytes.
+SST equality. A job reads block files and ZipTables, mixed freely, and
+writes either. Jobs the pipeline does not cover (single_fast or
+dict-compressed block inputs, missing properties, jobs of one shard)
+raise PipelineIneligible and the caller falls back to the serial path,
+which computes the same bytes.
 
 MERGE operands and single-deletes stay on this plane: the compute stage
 returns the rows of such "complex" user-key groups unreduced and flagged,
@@ -78,10 +81,13 @@ _PI32 = ctypes.POINTER(ctypes.c_int32)
 
 
 def pipeline_enabled(table_options=None) -> bool:
-    """The pipeline takes block tables, and zip tables when the native zip
-    data plane is on (scan/merge overlap with the drain-then-encode writer
-    stage: write_tables_zip_columnar collects the chunk feed); other
-    formats consume whole arrays serially."""
+    """Whether the plane WRITES the job's output format: block tables, and
+    zip tables when the native zip data plane is on (scan/merge overlap
+    with the drain-then-encode writer stage: write_tables_zip_columnar
+    collects the chunk feed); other formats consume whole arrays serially.
+    What a job may READ is `_build_plan`'s to say: block files and
+    ZipTables, mixed freely in one job; single_fast and dict-compressed
+    block inputs leave the plane there."""
     f = getattr(table_options, "format", "block")
     if f == "zip":
         from toplingdb_tpu.table.zip_table import zip_plane_enabled
@@ -93,11 +99,14 @@ def pipeline_enabled(table_options=None) -> bool:
 class _FilePlan:
     """Per-input-file scan plan: block handles grouped by shard, the
     file's slice of the preallocated global buffers, and the row bounds
-    of each shard (filled in by the reader as decode progresses)."""
+    of each shard (filled in by the reader as decode progresses). A
+    ZipTable (`zip`) has no blocks: it is planned by entry ranges, so its
+    `groups` are entry ordinals and its row bounds are known with the
+    plan."""
 
     __slots__ = ("reader", "pf", "block_offs", "block_lens", "groups",
                  "ne", "rk", "rv", "n_base", "k_base", "v_base",
-                 "row_bounds", "verify")
+                 "row_bounds", "verify", "zip")
 
 
 class _Progress:
@@ -243,7 +252,16 @@ def _build_plan(readers, value_slack: bool = False):
     PipelineIneligible. With value_slack the value buffer is allocated
     with room behind the inputs' values for merge results (untouched pages
     cost nothing): as much again plus 16 B a row, within the int32
-    budget."""
+    budget.
+
+    Inputs may be block files and ZipTables, in any mix. A block file is
+    planned by block handles (its index separators are its splitter
+    candidates); a ZipTable by entry ranges, `[entry_lower_bound(
+    splitter_i), entry_lower_bound(splitter_i+1))` a shard (group heads
+    are its candidates), its value groups under the file's dictionary as
+    they come. Both fill a slice of the preallocated buffers sized from
+    their TableProperties. single_fast files, and block files compressed
+    under a dictionary, are not planned: the job leaves the plane."""
     import bisect
 
     from toplingdb_tpu.ops import compaction_kernels as ck
@@ -257,7 +275,8 @@ def _build_plan(readers, value_slack: bool = False):
     infos = []
     tk = tv = tn = 0
     for r in readers:
-        if not hasattr(r, "new_index_iterator"):
+        is_zip = hasattr(r, "scan_columnar")
+        if not is_zip and not hasattr(r, "new_index_iterator"):
             raise PipelineIneligible("non-block input format")
         if getattr(r, "_compression_dict", b""):
             raise PipelineIneligible("dict-compressed input")
@@ -268,15 +287,24 @@ def _build_plan(readers, value_slack: bool = False):
             p.raw_value_size)
         if ne < 0 or rk < 0 or rv < 0 or (ne > 0 and rk == 0):
             raise PipelineIneligible("implausible input properties")
-        idx = r.new_index_iterator()
-        idx.seek_to_first()
-        handles = []
-        sep_uks = []
-        for k, enc in idx.entries():
-            handles.append(fmt.BlockHandle.decode_exact(enc))
-            sep_uks.append(dbformat.extract_user_key(k))
-        if ne and not handles:
-            raise PipelineIneligible("entries claimed but no data blocks")
+        if is_zip:
+            if ne != r.n:
+                raise PipelineIneligible("zip entries disagree with props")
+            if ne and not r.scan_native_ready():
+                raise PipelineIneligible("zip scan plane unavailable")
+            handles = None
+            sep_uks = r.split_candidates(r.opts.block_size)
+        else:
+            idx = r.new_index_iterator()
+            idx.seek_to_first()
+            handles = []
+            sep_uks = []
+            for k, enc in idx.entries():
+                handles.append(fmt.BlockHandle.decode_exact(enc))
+                sep_uks.append(dbformat.extract_user_key(k))
+            if ne and not handles:
+                raise PipelineIneligible(
+                    "entries claimed but no data blocks")
         infos.append((ne, rk, rv, handles, sep_uks))
         tk += rk
         tv += rv
@@ -284,17 +312,25 @@ def _build_plan(readers, value_slack: bool = False):
     if tk > 0x7FFFFF00 or tv > 0x7FFFFF00:
         raise PipelineIneligible("inputs exceed the int32 columnar budget")
 
-    # Splitters: merged per-file index separator user keys (one per data
-    # block, so even index spacing approximates even byte spacing), cut
-    # into n_shards quantiles. A job of one shard has nothing to overlap
-    # and is left to the serial path.
+    # Splitters: the per-file candidates (a block file's index separator
+    # user keys, one a data block; a ZipTable's group heads), merged, each
+    # standing for its file's rows a candidate, cut where the rows below
+    # reach a quantile: shards even in ROWS, whatever the formats' mix in
+    # a key range, because a shard past ROW_BUCKET rows meets a second
+    # program. A job of one shard has nothing to overlap and is left to
+    # the serial path.
     n_shards = ck.shard_count(tn)
     if n_shards < 2:
         raise PipelineIneligible("single-shard job")
-    all_seps = sorted(uk for _, _, _, _, uks in infos for uk in uks)
+    all_seps = sorted((uk, ne / len(uks))
+                      for ne, _, _, _, uks in infos for uk in uks)
+    # below[i]: the rows that candidates 0..i-1 stand for.
+    below = np.cumsum([0.0] + [w for _, w in all_seps])
     splitters: list[bytes] = []
-    for t in range(1, n_shards):
-        cand = all_seps[len(all_seps) * t // n_shards]
+    for t in range(1, n_shards if all_seps else 1):
+        at = int(np.searchsorted(below[:-1], below[-1] * t / n_shards,
+                                 side="right")) - 1
+        cand = all_seps[at][0]
         if not splitters or cand > splitters[-1]:
             splitters.append(cand)
     if not splitters:
@@ -315,25 +351,43 @@ def _build_plan(readers, value_slack: bool = False):
             continue
         fp = _FilePlan()
         fp.reader = r
-        fp.pf = FilePrefetchBuffer(r._f, max_readahead=_PF_READAHEAD,
-                                   initial_readahead=_PF_READAHEAD,
-                                   arm_immediately=True)
-        fp.block_offs = np.array([h.offset for h in handles], dtype=np.int64)
-        fp.block_lens = np.array([h.size for h in handles], dtype=np.int64)
-        # Shard s decodes blocks [groups[s], groups[s+1]); the group ends
-        # at (inclusive) the first block whose separator user key reaches
-        # the splitter — that block may straddle it, and its tail rows
-        # belong to the next shard via the row-bound binary search.
-        g = [0]
-        for spl in splitters:
-            g.append(max(g[-1], min(bisect.bisect_left(sep_uks, spl) + 1,
-                                    len(handles))))
-        g.append(len(handles))
-        fp.groups = g
+        fp.zip = handles is None
         fp.ne, fp.rk, fp.rv = ne, rk, rv
         fp.n_base, fp.k_base, fp.v_base = nb, kb, vb
-        fp.row_bounds = [nb] * n_shards + [nb + ne]
-        fp.verify = bool(r.opts.verify_checksums)
+        if fp.zip:
+            # Shard s is entries [groups[s], groups[s+1]): every version
+            # of a user key sorts behind its seek key, so the bound of a
+            # splitter is the first entry of that user key or a later one.
+            fp.pf = fp.block_offs = fp.block_lens = None
+            fp.groups = [0] + [
+                r.entry_lower_bound(dbformat.make_internal_key(
+                    spl, dbformat.MAX_SEQUENCE_NUMBER,
+                    dbformat.VALUE_TYPE_FOR_SEEK))
+                for spl in splitters] + [ne]
+            fp.row_bounds = [nb + e for e in fp.groups]
+            fp.verify = False  # the sections were verified at open
+        else:
+            fp.pf = FilePrefetchBuffer(r._f, max_readahead=_PF_READAHEAD,
+                                       initial_readahead=_PF_READAHEAD,
+                                       arm_immediately=True)
+            fp.block_offs = np.array([h.offset for h in handles],
+                                     dtype=np.int64)
+            fp.block_lens = np.array([h.size for h in handles],
+                                     dtype=np.int64)
+            # Shard s decodes blocks [groups[s], groups[s+1]); the group
+            # ends at (inclusive) the first block whose separator user key
+            # reaches the splitter — that block may straddle it, and its
+            # tail rows belong to the next shard via the row-bound binary
+            # search.
+            g = [0]
+            for spl in splitters:
+                g.append(max(g[-1],
+                             min(bisect.bisect_left(sep_uks, spl) + 1,
+                                 len(handles))))
+            g.append(len(handles))
+            fp.groups = g
+            fp.row_bounds = [nb] * n_shards + [nb + ne]
+            fp.verify = bool(r.opts.verify_checksums)
         files.append(fp)
         nb += ne
         kb += rk
@@ -343,6 +397,50 @@ def _build_plan(readers, value_slack: bool = False):
     return kv, files, splitters, (tv, tv + slack)
 
 
+def _scan_zip_file(fi, fp, kv, prog, stats, stats_mu, trace_handle):
+    """Reader worker of a ZipTable: decode one entry range a shard
+    (ZipTableReader.scan_columnar: native key and value-group decoders)
+    into the file's slice of the global buffers. Each range is the span
+    `pipeline.zip_scan`; their wall sums into `zip_scan_usec`."""
+    k_used = v_used = 0
+    usec = 0
+    for s in range(len(fp.groups) - 1):
+        if prog.stop:
+            return
+        e0, e1 = fp.groups[s], fp.groups[s + 1]
+        if e1 > e0:
+            t0 = time.time()
+            with telemetry.span_under(trace_handle, "pipeline.zip_scan",
+                                      file=fi, shard=s, rows=e1 - e0) as sp:
+                kb, ko, kl, vb, vo, vl = fp.reader.scan_columnar(e0, e1)
+                # The range's value bytes lie densely inside the decoded
+                # groups, from its first row's offset on.
+                v0 = int(vo[0])
+                nk, nv = len(kb), int(vo[-1] + vl[-1]) - v0
+                if k_used + nk > fp.rk or v_used + nv > fp.rv:
+                    raise PipelineIneligible(
+                        "scan totals disagree with props")
+                k0, w0 = fp.k_base + k_used, fp.v_base + v_used
+                r0, r1 = fp.n_base + e0, fp.n_base + e1
+                kv.key_buf[k0:k0 + nk] = kb
+                kv.val_buf[w0:w0 + nv] = vb[v0:v0 + nv]
+                kv.key_offs[r0:r1] = ko + k0
+                kv.key_lens[r0:r1] = kl
+                kv.val_offs[r0:r1] = vo + (w0 - v0)
+                kv.val_lens[r0:r1] = vl
+                k_used += nk
+                v_used += nv
+                sp.tag(nbytes=nk + nv)
+            usec += int((time.time() - t0) * 1e6)
+        prog.mark(fi, s)
+    if k_used != fp.rk or v_used != fp.rv:
+        raise PipelineIneligible("scan totals disagree with props")
+    with stats_mu:
+        stats.count_zip_input(fp.reader)
+        stats.zip_scan_usec += usec
+    prog.finish_file(fi)
+
+
 def _scan_file(fi, fp, kv, prog, splitters, stats, stats_mu,
                trace_handle=None):
     """Reader worker: decode one file shard-by-shard into its slice of the
@@ -350,6 +448,9 @@ def _scan_file(fi, fp, kv, prog, splitters, stats, stats_mu,
     lib = native.lib()
     n_shards = len(splitters) + 1
     try:
+        if fp.zip:
+            _scan_zip_file(fi, fp, kv, prog, stats, stats_mu, trace_handle)
+            return
         rows = 0
         k_used = v_used = 0
         bound = 0  # file-local row bound of the current shard start
@@ -918,11 +1019,11 @@ def run_pipelined(env, dbname, icmp, compaction, table_cache, table_options,
                 raise item.exc
             yield item
 
-    writer = write_tables_columnar
+    writer, counted = write_tables_columnar, {}
     if getattr(table_options, "format", "block") == "zip":
         from toplingdb_tpu.table.zip_table import write_tables_zip_columnar
 
-        writer = write_tables_zip_columnar
+        writer, counted = write_tables_zip_columnar, {"stats": stats}
     t_wr = time.time()
     try:
         out_files = writer(
@@ -931,7 +1032,7 @@ def run_pipelined(env, dbname, icmp, compaction, table_cache, table_options,
             shared.seqs, tombs,
             creation_time if creation_time is not None else int(time.time()),
             max_output_file_size=compaction.max_output_file_size,
-            column_family=column_family,
+            column_family=column_family, **counted,
         )
     except BaseException:
         prog.abort()

@@ -328,7 +328,14 @@ class Options:
 
     def table_options_for_level(self, level: int, bottommost: bool = False):
         """table_options with the per-level codec and bottommost format
-        applied (identity when nothing level-specific is configured)."""
+        applied (identity when nothing level-specific is configured). This
+        is what EVERY builder of a level's files asks: the flush, the local
+        compaction, the in-process device executor, and the remote job,
+        whose `CompactionParams.table_format` is this `.format`. With
+        `bottommost_format="zip"` the device plane writes ZipTables at the
+        last level and reads them back beside block files from the levels
+        above (ARCHITECTURE.md §2.2.1); a file moved into the last level
+        without a merge keeps the format it was built in."""
         eff = self.compression_for_level(level, bottommost)
         fmt_ = self.table_options.format
         if bottommost and self.bottommost_format is not None:

@@ -29,6 +29,7 @@ Options.bottommost_format = "zip".
 from __future__ import annotations
 
 import os
+import time
 
 import numpy as np
 
@@ -46,6 +47,7 @@ from toplingdb_tpu.table.builder import (
 from toplingdb_tpu.table.filter import filter_policy_from_name
 from toplingdb_tpu.table.properties import TableProperties
 from toplingdb_tpu.utils import coding, crc32c
+from toplingdb_tpu.utils import telemetry as _tele
 from toplingdb_tpu.utils.status import Corruption, NotSupported
 from toplingdb_tpu.utils import errors as _errors
 
@@ -69,6 +71,14 @@ _FLAG_META16 = 4  # key meta is u16 pairs (some internal key > 255 bytes)
 GROUP = 16
 # Value mini-group target: ~2KB of raw value bytes per compressed unit.
 VALUE_GROUP_TARGET = 2048
+# One trained dictionary a file is the format's normal case (the value
+# store of the reference's ToplingZipTable is dictionary-compressed by
+# construction): this is its size unless CompressionOptions names one.
+ZIP_DICT_BYTES = 16 << 10
+
+
+def zip_dict_bytes(copts) -> int:
+    return int(getattr(copts, "max_dict_bytes", 0) or 0) or ZIP_DICT_BYTES
 
 
 def zip_plane_enabled() -> bool:
@@ -238,10 +248,10 @@ class ZipTableBuilder:
                     and codecs.available("zstd"))
         groups = [b"".join(self._vals[i:i + vg]) for i in range(0, n, vg)]
         zdict = b""
-        if compress and copts.max_dict_bytes > 0 and len(groups) >= 8:
+        if compress and zip_dict_bytes(copts) > 0 and len(groups) >= 8:
             zdict = codecs.zstd_train_dictionary(
                 groups[:: max(1, len(groups) // 256)] or groups,
-                copts.max_dict_bytes,
+                zip_dict_bytes(copts),
             )
         blob = bytearray()
         go = [0]
@@ -557,6 +567,17 @@ class ZipTableReader:
         return [self.key_at(i)
                 for i in range(0, self.n, step)][:max_anchors]
 
+    def split_candidates(self, block_size: int) -> list[bytes]:
+        """User keys to cut a compaction's key-range shards at
+        (ops/pipeline.py::_build_plan): group heads, one for about
+        `block_size` raw key and value bytes, so that they weigh among a
+        block file's index separators as one data block each."""
+        if not self.n:
+            return []
+        raw = self.properties.raw_key_size + self.properties.raw_value_size
+        step = max(1, (block_size * self.n) // (max(1, raw) * self.G))
+        return [self._head(g)[:-8] for g in range(step - 1, self._nG, step)]
+
     # --- batched data-plane surface (native kernels) ---
 
     def scan_native_ready(self) -> bool:
@@ -842,13 +863,13 @@ class ZipTableIterator:
 
 
 def _zip_encode_segment_native(lib, kv, rows, ko_seg, ov_seg, fvl, K, n, vg,
-                               compress, copts, meta16):
+                               compress, copts, meta16, timing):
     """One output segment through the tpulsm_zip_* kernels. Returns the
     encoded sections (kmeta, ksfx, kgso, vlens, vgo, vblob, vflags, zdict,
     lens32) bit-identical to the numpy encoder below (parity-tested), or
-    None when a kernel declines — the caller then re-encodes in Python."""
+    None when a kernel declines — the caller then re-encodes in Python.
+    The dictionary training's wall is added to timing["dict_train"]."""
     from toplingdb_tpu import native
-    from toplingdb_tpu.utils import telemetry as tele
 
     ko_seg = np.ascontiguousarray(ko_seg, dtype=np.int64)
     ov_seg = np.ascontiguousarray(ov_seg, dtype=np.int64)
@@ -858,7 +879,7 @@ def _zip_encode_segment_native(lib, kv, rows, ko_seg, ov_seg, fvl, K, n, vg,
     sfx_out = np.empty(max(1, sfx_cap), dtype=np.uint8)
     ngk = (n + GROUP - 1) // GROUP
     gso_out = np.empty(4 * ngk, dtype=np.uint8)
-    with tele.span("zip.index_build", rows=n, groups=ngk):
+    with _tele.span("zip.index_build", rows=n, groups=ngk):
         rc = lib.tpulsm_zip_encode_keys(
             native.np_u8p(kv.key_buf), len(kv.key_buf),
             native.np_i64p(ko_seg), n, K, native.np_i64p(ov_seg), GROUP,
@@ -869,20 +890,33 @@ def _zip_encode_segment_native(lib, kv, rows, ko_seg, ov_seg, fvl, K, n, vg,
     voffs = np.ascontiguousarray(kv.val_offs[rows], dtype=np.int64)
     total_v = int(fvl.sum())
     ngv = (n + vg - 1) // vg
-    mdb = int(getattr(copts, "max_dict_bytes", 0) or 0)
+    mdb = zip_dict_bytes(copts) if compress else 0
     lvl = copts.level if copts.level is not None else 3
     dict_out = np.zeros(max(1, mdb), dtype=np.uint8)
     blob_out = np.empty(max(1, total_v), dtype=np.uint8)
     go_out = np.empty(4 * (ngv + 1), dtype=np.uint8)
     flags_out = np.zeros((ngv + 7) // 8, dtype=np.uint8)
-    om = np.zeros(2, dtype=np.int64)
+    om = np.zeros(1, dtype=np.int64)
     vb = kv.val_buf if len(kv.val_buf) else np.zeros(1, dtype=np.uint8)
-    with tele.span("zip.encode", rows=n, groups=ngv,
+    dlen = 0
+    if mdb > 0 and ngv >= 8:
+        t0 = time.time()
+        with _tele.span("zip.dict_train", rows=n, groups=ngv,
+                       dict_bytes=mdb) as sp:
+            dlen = lib.tpulsm_zip_train_dict(
+                native.np_u8p(vb), len(kv.val_buf), native.np_i64p(voffs),
+                native.np_i64p(fvl), n, vg, mdb, native.np_u8p(dict_out),
+                len(dict_out))
+            sp.tag(trained=max(0, int(dlen)))
+        timing["dict_train"] += time.time() - t0
+        if dlen < 0:
+            return None
+    with _tele.span("zip.encode", rows=n, groups=ngv,
                    compress=1 if compress else 0):
         rc2 = lib.tpulsm_zip_encode_values(
             native.np_u8p(vb), len(kv.val_buf), native.np_i64p(voffs),
             native.np_i64p(fvl), n, vg, 1 if compress else 0, int(lvl),
-            mdb, native.np_u8p(dict_out), len(dict_out),
+            native.np_u8p(dict_out), int(dlen),
             native.np_u8p(blob_out), total_v, native.np_u8p(go_out),
             native.np_u8p(flags_out), native.np_i64p(om))
     if rc2 != ngv:
@@ -891,7 +925,7 @@ def _zip_encode_segment_native(lib, kv, rows, ko_seg, ov_seg, fvl, K, n, vg,
     vlens = fvl.astype("<u4" if lens32 else "<u2").tobytes()
     return (meta_out.tobytes(), sfx_out[:rc].tobytes(), gso_out.tobytes(),
             vlens, go_out.tobytes(), blob_out[: int(om[0])].tobytes(),
-            flags_out.tobytes(), dict_out[: int(om[1])].tobytes(),
+            flags_out.tobytes(), dict_out[: int(dlen)].tobytes(),
             lens32)
 
 
@@ -899,14 +933,17 @@ def write_tables_zip_columnar(env, dbname, new_file_number, icmp, options,
                               kv, order, trailer_override, vtypes, seqs,
                               tombstones, creation_time: int,
                               max_output_file_size: int = 2 ** 62,
-                              column_family=(0, "default")):
+                              column_family=(0, "default"), stats=None):
     """Vectorized ZipTable emission from columnar buffers + a survivor
     order — the zip-format counterpart of write_tables_columnar, so device
     compactions emit searchable-compressed bottommost files without a
     per-entry Python loop. Byte-identical to feeding ZipTableBuilder the
     same stream through build_outputs (cut rule included; parity-tested).
     Uniform key length only; raises NotSupported otherwise (callers fall
-    back to the per-entry path)."""
+    back to the per-entry path). `stats` (CompactionStats) gets the wall
+    of the segments' encoding (`zip_encode_usec`: key index, dictionary
+    training, value groups) and of the training alone
+    (`zip_dict_train_usec`)."""
     from toplingdb_tpu import native
     from toplingdb_tpu.db import filename as _fn
     from toplingdb_tpu.utils import codecs
@@ -999,8 +1036,9 @@ def write_tables_zip_columnar(env, dbname, new_file_number, icmp, options,
 
     results = []
     written = []
+    timing = {"encode": 0.0, "dict_train": 0.0}
     try:
-        for fi in range(len(cuts) - 1):
+        def emit_segment(fi):
             lo, hi = cuts[fi], cuts[fi + 1]
             rows = order[lo:hi]
             seg = slice(lo, hi)
@@ -1031,10 +1069,11 @@ def write_tables_zip_columnar(env, dbname, new_file_number, icmp, options,
                 compress = (options.compression != fmt.NO_COMPRESSION
                             and codecs.available("zstd"))
                 enc = None
+                t_enc = time.time()
                 if use_native:
                     enc = _zip_encode_segment_native(
                         lib, kv, rows, ko[seg], ov[seg], fvl, K, n, vg,
-                        compress, copts, meta16)
+                        compress, copts, meta16, timing)
                 if enc is not None:
                     (kmeta, ksfx, kgso_b, vlens, vgo, vblob, vflags_b,
                      zdict, lens32) = enc
@@ -1084,13 +1123,17 @@ def write_tables_zip_columnar(env, dbname, new_file_number, icmp, options,
                         for i in range(len(gb) - 1)
                     ]
                     zdict = b""
-                    if (compress and copts.max_dict_bytes > 0
+                    if (compress and zip_dict_bytes(copts) > 0
                             and len(groups) >= 8):
-                        zdict = codecs.zstd_train_dictionary(
-                            groups[:: max(1, len(groups) // 256)]
-                            or groups,
-                            copts.max_dict_bytes,
-                        )
+                        t_dt = time.time()
+                        with _tele.span("zip.dict_train", rows=n,
+                                        groups=len(groups)):
+                            zdict = codecs.zstd_train_dictionary(
+                                groups[:: max(1, len(groups) // 256)]
+                                or groups,
+                                zip_dict_bytes(copts),
+                            )
+                        timing["dict_train"] += time.time() - t_dt
                     blob = bytearray()
                     go = [0]
                     vflags = bytearray((len(groups) + 7) // 8)
@@ -1124,6 +1167,7 @@ def write_tables_zip_columnar(env, dbname, new_file_number, icmp, options,
                     vgo = np.asarray(go, dtype="<u4").tobytes()
                     vblob = bytes(blob)
                     vflags_b = bytes(vflags)
+                timing["encode"] += time.time() - t_enc
                 if compress:
                     props.compression_name = "zip+zstd"
                 # --- stats ---
@@ -1188,7 +1232,7 @@ def write_tables_zip_columnar(env, dbname, new_file_number, icmp, options,
                     props.largest_seqno = max(props.largest_seqno, frag.seq)
                 rd_raw = rdb.finish()
             if n == 0 and rd_raw is None:
-                continue
+                return
             fnum = new_file_number()
             path = _fn.table_file_name(dbname, fnum)
             w = env.new_writable_file(path)
@@ -1200,6 +1244,16 @@ def write_tables_zip_columnar(env, dbname, new_file_number, icmp, options,
             w.close()
             results.append((fnum, path, props, smallest, largest,
                             rows if n else np.empty(0, np.int64)))
+
+        # The writer stage's span, one an output segment (its children:
+        # zip.index_build, zip.dict_train, zip.encode), as the block
+        # writer's is one a consumed chunk.
+        for fi in range(len(cuts) - 1):
+            with _tele.span("pipeline.encode_write", file=fi):
+                emit_segment(fi)
+        if stats is not None:
+            stats.zip_encode_usec += int(timing["encode"] * 1e6)
+            stats.zip_dict_train_usec += int(timing["dict_train"] * 1e6)
         return results
     except BaseException:
         for p in written:

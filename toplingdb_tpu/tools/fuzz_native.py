@@ -343,6 +343,7 @@ ABI_FUZZ_SYMS = (
     "tpulsm_zip_newkey", "tpulsm_zip_encode_keys",
     "tpulsm_zip_encode_values", "tpulsm_zip_decode_keys",
     "tpulsm_zip_group_decode", "tpulsm_zip_table_handle_new",
+    "tpulsm_zip_train_dict",
 )
 
 _BLOB_NAMES = ("data", "block", "file_buf", "rep", "target",
