@@ -156,6 +156,14 @@ def put_batches(db, kb: bytes, vb: bytes, n: int, per_batch: int) -> None:
         db.write(wb)
 
 
+def stream_ran_out(w: int, per_batch: int, end: int, span: float,
+                   seconds: float) -> bool:
+    """Did a closed-loop window end because the pre-encoded stream did (the
+    next batch would pass `end`) before `seconds` were up? Such a run's
+    writer outran the mix's ceiling: it is not correct (`stream_ran_out`)."""
+    return w + per_batch > end and span < seconds
+
+
 def off_device(want: str, device: str, rows: int, pipelined: bool,
                host_compute_usec: int) -> bool:
     """Did a remote job run anywhere but on the device path of `want`?"""

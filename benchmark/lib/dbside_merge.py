@@ -75,7 +75,8 @@ def options(config: dict, sizes: dict, stats, factory) -> Options:
 class LargestJobFactory(dbside.TimedFactory):
     """The timed factory, also keeping (by hard link, taken before the DB
     can delete them) the inputs and parameters of the largest remote job
-    since `watch()`: the one the reference reads after the window."""
+    begun while `watch()` is on: the one the reference reads after the
+    window."""
 
     def __init__(self, url, device, min_input_bytes, dbname, keep_dir,
                  merge_operator: str):
@@ -86,8 +87,11 @@ class LargestJobFactory(dbside.TimedFactory):
         self.largest = None     # {"rows", "links", "params"}
         self.kept = 0
 
-    def watch(self) -> None:
-        self.watching = True
+    def watch(self, on: bool = True) -> None:
+        """On as the window opens, off as it closes: a job begun later (the
+        reopened DB compacts what it recovers) is not the window's, and
+        keeping it would unlink the kept inputs under the reference."""
+        self.watching = on
 
     def new_executor(self, compaction):
         ex = super().new_executor(compaction)

@@ -75,7 +75,7 @@ class OutputWitness(EventListener):
 
 class ColdJobFactory(dbside_merge.LargestJobFactory):
     """The merge cell's factory (timed; keeps, by hard link, the inputs and
-    parameters of the largest remote job since `watch()`), here keeping
+    parameters of the largest remote job begun under `watch()`), here keeping
     only bottommost jobs that have a ZipTable among their inputs: the one
     the reference reads after the window. It also notes of every
     compaction the DB asks it about (each one that is no trivial move)

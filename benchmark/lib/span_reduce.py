@@ -22,8 +22,9 @@ reported as a device's).
                      the head of the HLO line where the event has neither.
   host events        [name, start_ns, dur_ns, line, tags]: the program's
                      spans (names under `dcompact.`, `compaction.`,
-                     `pipeline.`, `sst.`, `runtime.`) and the launcher's two
-                     `bench:` window marks; `line` numbers the thread.
+                     `pipeline.`, `sst.`, `runtime.`, `zip.`) and the
+                     launcher's two `bench:` window marks; `line` numbers
+                     the thread.
   op_stats           {HLO name: the stats of its first event}: kept with the
                      events for reading one trace by hand; `reduce` does not
                      read it.
@@ -47,7 +48,8 @@ from lib.trace_reduce import (JOB, TOP, WINDOW_CLOSE, WINDOW_OPEN, _clip,
 
 REQUEST = "dcompact.request"
 WORKER = JOB
-SPAN_PREFIXES = ("dcompact.", "compaction.", "pipeline.", "sst.", "runtime.")
+SPAN_PREFIXES = ("dcompact.", "compaction.", "pipeline.", "sst.", "runtime.",
+                 "zip.")
 # Spans of the thread that feeds the device (ops/pipeline.py's compute
 # thread; in the serial program the job's own thread does the same work
 # under the same names).

@@ -31,6 +31,7 @@ import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -280,6 +281,8 @@ def main(argv=None) -> int:
         log(f"device busy by chip: {run.trace_summary['busy_by_device_s']}")
     for note in run.facts.get("notes", []):
         log(note)
+    log(f"harness peak RSS (the pre-encoded stream, the DB, the checks): "
+        f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss} KiB")
     line["compared"] = run.compared  # each number beside its limit: last
     for name, (value, limit) in run.compared.items():
         print(f"compared {name}: {value} (limit {limit})", file=sys.stderr)

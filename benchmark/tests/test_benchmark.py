@@ -34,6 +34,13 @@ from lib import span_reduce  # noqa: E402
 from lib.workload import Workload  # noqa: E402
 
 OVERWRITE = "dbbench-c2-8b20b.overwrite"
+# What every served cell reports of the client, the DB front end, the LSM,
+# the executor boundary and the compiler (the cells' own tests follow it).
+SERVED_METRICS = ("client.put_loop_share", "client.build_us_per_op",
+                  "db.stall_share", "db.write_us_per_op",
+                  "db.write_batch_p50_ms", "db.write_batch_p95_ms",
+                  "db.write_batch_p99_ms", "lsm.write_amp",
+                  "compactor.busy_share", "compile.in_window.serve")
 JOBS = "dbbench-c2-8b20b.compact-jobs"   # held: not in BENCHMARK.json
 HELD = "compact-jobs"
 
@@ -115,6 +122,153 @@ def test_put_window_closes_on_the_first_batch_boundary_after_seconds():
     lat, span, w = puts.write_window(write, kb, vb, 0, 250, 100, 1.0,
                                      clock=clock)
     assert w == 200 and span == pytest.approx(0.6)
+
+
+# -- the streams' ceiling ----------------------------------------------------
+
+CLOSED_LOOP = {"puts": "max_puts_per_s", "merges": "max_operands_per_s",
+               "puts_zip": "max_puts_per_s"}
+# A fixed number, not the ledger's (which moves under the test with every
+# PR): PERF.md section 2 has the rule a `benchmark` issue applies, at least
+# 1.5 times the cell's highest ledger median, as soon as one passes 400,000.
+CEILING = 600_000
+
+
+def closed_loop_mixes():
+    out = []
+    for f in sorted(os.listdir(os.path.join(BENCH, "traffic"))):
+        if f.endswith(".json"):
+            with open(os.path.join(BENCH, "traffic", f)) as fh:
+                mix = json.load(fh)
+            if mix["kind"] in CLOSED_LOOP:
+                out.append((f[:-5], mix))
+    return out
+
+
+@pytest.mark.parametrize("name,mix", [
+    pytest.param(n, m, id=n) for n, m in closed_loop_mixes()])
+def test_every_closed_loop_mix_names_the_one_ceiling(name, mix):
+    key = CLOSED_LOOP[mix["kind"]]
+    assert mix[key] == CEILING, name
+    assert "1.5 times" in mix["notes"][key] and "400,000" in mix["notes"][key]
+    served = [c for c in bench_run.load_bench()["workloads"]
+              if c["traffic"] == name]
+    assert served, name                    # no mix without its cell
+
+
+def test_the_three_closed_loop_mixes_are_there():
+    assert sorted(m["kind"] for _, m in closed_loop_mixes()) == sorted(
+        CLOSED_LOOP)
+
+
+class InstantDB:
+    """A DB whose writes return at once, on a clock that does not move."""
+
+    def __init__(self):
+        self.operands = self.ranges = 0
+
+    def write(self, batch):
+        self.operands += batch.count()
+
+    def delete_range(self, begin, end):
+        self.ranges += 1
+
+
+@pytest.mark.parametrize("kind", ["puts", "merges"])
+def test_a_writer_that_outruns_the_stream_is_told_so(kind):
+    """A write that returns at once consumes the whole stream before
+    `seconds`; the kinds' one rule then says the stream ran out."""
+    from lib import dbside, dbside_merge
+
+    clock, db = Clock(), InstantDB()
+    first, end, per_batch, seconds = 1000, 6000, 500, 40.0
+    if kind == "puts":
+        kb, vb = b"k" * 8 * end, b"v" * 20 * end
+        lat, span, w = load_kind("puts").write_window(
+            db.write, kb, vb, first, end, per_batch, seconds, clock=clock)
+    else:
+        kb, vb = b"k" * 16 * end, b"v" * 8 * end
+        tb = te = b"t" * 16 * 2
+        lat, span, w, t = dbside_merge.merge_window(
+            db, kb, vb, tb, te, [2000, 4000], 0, first, end, per_batch,
+            seconds, clock=clock)
+        assert (t, db.ranges) == (2, 2)
+    assert w == end and db.operands == end - first
+    assert len(lat) == (end - first) // per_batch and span < seconds
+    assert dbside.stream_ran_out(w, per_batch, end, span, seconds)
+    # The window's own close (a batch boundary at or after `seconds`) with
+    # stream to spare is no such run, nor is one that ends on both at once.
+    assert not dbside.stream_ran_out(w - per_batch, per_batch, end, seconds,
+                                     seconds)
+    assert not dbside.stream_ran_out(w, per_batch, end, seconds, seconds)
+
+
+def test_a_starved_mix_is_refused_as_stream_ran_out(tmp_path):
+    """The refusal stays: the overwrite cell on a mix (added as a file)
+    whose stream is a few batches compares `stream_ran_out` 1."""
+    shutil.copytree(BENCH, tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    b = tmp_path / "benchmark"
+    with open(b / "traffic" / "overwrite.json") as f:
+        mix = json.load(f)
+    mix["max_puts_per_s"] = 1000
+    with open(b / "traffic" / "starved.json", "w") as f:
+        json.dump(mix, f)
+    bench = bench_run.load_bench()
+    cell = "dbbench-c2-8b20b.starved"
+    bench["workloads"].append({
+        "name": cell, "config": "dbbench-c2-8b20b", "traffic": "starved",
+        "chips": 1, "why": "a test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if OVERWRITE in m.get("workloads", []):
+            m["workloads"].append(cell)
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    p, line = run_cell(cell, "--trace", "0", root=str(tmp_path))
+    assert p.returncode == 4, p.stderr[-2000:]
+    assert line["compared"]["stream_ran_out"] == [1, 0]
+    assert line["attempted"] == 2000 and "raise max_puts_per_s" in p.stderr
+    assert "compared stream_ran_out: 1 (limit 0)" in p.stderr
+
+
+# -- the front end in units a claim can predict in --------------------------
+
+
+def test_the_two_times_an_operation_add_up_to_the_window():
+    facts = {"window_s": 40.0, "in_write_s": 25.0, "out_of_write_s": 15.0,
+             "window_ops": 12_500_000,
+             "write_batch_s": np.array([0.001, 0.002, 0.003, 0.004, 0.1])}
+    bench = bench_run.load_bench()
+    cell = bench_run.find_cell(bench, OVERWRITE)
+    got = bench_run.read_per_layer(bench, cell, facts, {"write_ops_s"})
+    build = got["client.build_us_per_op"]["value"]
+    write = got["db.write_us_per_op"]["value"]
+    assert build == pytest.approx(1.2) and write == pytest.approx(2.0)
+    assert build + write == pytest.approx(
+        1e6 / (facts["window_ops"] / facts["window_s"]))
+    assert got["db.write_batch_p50_ms"]["value"] == pytest.approx(3.0)
+    assert got["client.put_loop_share"]["value"] == pytest.approx(37.5)
+    # Nothing to read gives nothing, never 0.
+    less = bench_run.read_per_layer(bench, cell, {"window_s": 40.0},
+                                    {"write_ops_s"})
+    assert not {"client.build_us_per_op", "db.write_us_per_op",
+                "db.write_batch_p50_ms"} & set(less)
+
+
+def test_the_front_end_metrics_are_read_in_every_served_cell():
+    bench = bench_run.load_bench()
+    cells = [c["name"] for c in bench["workloads"]]
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    assert len(per_layer) == 18
+    assert not [n for n in per_layer if n.startswith("universal.")
+                or n == "compile.in_window.merge"]
+    for name in SERVED_METRICS:
+        assert per_layer[name]["workloads"] == cells, name
+        assert os.path.exists(os.path.join(BENCH, "metrics", name + ".json"))
+    files = {f[:-5] for f in os.listdir(os.path.join(BENCH, "metrics"))
+             if f.endswith(".json")}
+    held = {m["name"] for m in bench_run.load_bench(HELD)["per_layer"]}
+    assert files == held                   # no metric file without its entry
 
 
 # -- the trace reduction -----------------------------------------------------

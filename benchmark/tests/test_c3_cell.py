@@ -22,16 +22,15 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 sys.path.insert(0, ROOT)
+sys.path.insert(0, HERE)
 
 from lib import reference_merge as ref  # noqa: E402
 from lib.workload_merge import MergeWorkload, key_bytes  # noqa: E402
+from test_benchmark import SERVED_METRICS as SHARED_METRICS  # noqa: E402
 
 CELL = "dbbench-c3-universal-merge.mergerandom"
 NEW_METRICS = ("merge.operand_row_share", "merge.fold_share",
-               "rangedel.cover_share", "universal.stall_share",
-               "universal.compactor_busy_share", "universal.write_amp",
-               "compile.in_window.merge", "universal.put_loop_share",
-               "universal.write_batch_p95_ms", "universal.write_batch_p99_ms")
+               "rangedel.cover_share")
 
 
 def run_cell(*extra, seconds="2", seed="2147483659", scale="0.02"):
@@ -73,8 +72,11 @@ def test_rehearsal_ends_with_every_compared_at_its_limit():
 def test_traced_rehearsal_reports_every_new_metric():
     p, line = run_cell("--trace", "1")
     assert p.returncode == 4, p.stderr[-2000:]
-    for name in NEW_METRICS:
+    assert set(line["metrics"]) == set(NEW_METRICS + SHARED_METRICS)
+    for name in NEW_METRICS + SHARED_METRICS:
         assert isinstance(line["metrics"][name]["value"], float), name
+    assert line["metrics"]["client.build_us_per_op"]["value"] > 0
+    assert line["metrics"]["db.write_us_per_op"]["value"] > 0
     assert line["metrics"]["merge.operand_row_share"]["value"] > 0
     assert line["metrics"]["merge.fold_share"]["value"] > 0
     assert line["metrics"]["rangedel.cover_share"]["value"] > 0

@@ -20,17 +20,15 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 sys.path.insert(0, ROOT)
+sys.path.insert(0, HERE)
 
 from lib import zip_plain  # noqa: E402
 from lib.workload import Workload  # noqa: E402
+from test_benchmark import SERVED_METRICS as SHARED_METRICS  # noqa: E402
 
 CELL = "dbbench-c4-zip-l2plus.overwrite-zip"
 NEW_METRICS = ("zip.input_row_share", "zip.output_byte_share",
                "zip.encode_share", "zip.scan_share", "zip.space_ratio")
-SHARED_METRICS = ("client.put_loop_share", "db.stall_share",
-                  "db.write_batch_p95_ms", "db.write_batch_p99_ms",
-                  "lsm.write_amp", "compactor.busy_share",
-                  "compile.in_window.serve")
 COMPARED = ("read_mismatches", "reopen_read_mismatches", "rows_wrong",
             "records_misreported", "bottommost_outputs_not_zip",
             "fallback_local", "remote_job_failures", "jobs_off_device",

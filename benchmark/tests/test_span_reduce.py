@@ -85,6 +85,45 @@ def test_every_idle_second_gets_a_name_by_the_rules():
         out["window_s"] - out["busy_s"])
 
 
+def test_a_zip_span_inside_the_writer_names_its_own_idle_seconds():
+    """The zip writer's segments are children of `pipeline.encode_write`
+    (the program records `zip.index_build`, `zip.dict_train`, `zip.encode`
+    there): the innermost names the gap, the parent keeps the rest, and
+    the device's own time does not move."""
+    events = hand_made()
+    before = sr.reduce(events)
+    events["host"] += [span("zip.dict_train", 41, 44, 1, files=1),
+                       span("zip.group_decode", 12, 13, 3)]
+    out = sr.reduce(events)
+
+    def in_job(summary):               # every name, not the ten largest
+        gaps = {}
+        for table in summary["idle_by_place"].values():
+            for name, s in table:
+                gaps[name] = gaps.get(name, 0.0) + s
+        return gaps
+
+    gaps, was = in_job(out), in_job(before)
+    assert dict(out["idle_gaps"])["zip.dict_train"] == pytest.approx(3)
+    assert gaps["zip.dict_train"] == pytest.approx(3)
+    assert gaps["pipeline.encode_write"] == pytest.approx(
+        was["pipeline.encode_write"] - 3)
+    assert {k: v for k, v in gaps.items() if k not in (
+        "zip.dict_train", "pipeline.encode_write")} == pytest.approx(
+        {k: v for k, v in was.items() if k != "pipeline.encode_write"})
+    # A reader thread's span (`zip.group_decode` inside `pipeline.scan`)
+    # names no gap: readers do not feed the device. Its self time is kept.
+    assert "zip.group_decode" not in gaps
+    assert out["span_self_s"]["zip.group_decode"] == pytest.approx(1)
+    assert out["span_self_s"]["zip.dict_train"] == pytest.approx(3)
+    assert out["span_self_s"]["pipeline.encode_write"] == pytest.approx(
+        before["span_self_s"]["pipeline.encode_write"] - 3)
+    assert out["device_ops"] == before["device_ops"]
+    assert out["busy_s"] == before["busy_s"]
+    assert sum(gaps.values()) == pytest.approx(out["in_job_idle_s"])
+    assert out["in_job_idle_s"] == before["in_job_idle_s"]
+
+
 def test_device_time_goes_by_scope_and_transfers_by_span():
     out = sr.reduce(hand_made())
     assert dict(out["device_ops"]) == pytest.approx(

@@ -121,9 +121,11 @@ def drive(run) -> dict:
         last_seq = db.latest_sequence_number()
         t1 = t0 + span
         run.window_close()
+        factory.watch(False)
         operands = w - first
         run.attempted = operands
-        ran_out = w + per_batch > n + max_ops and span < run.seconds
+        ran_out = dbside.stream_ran_out(w, per_batch, n + max_ops, span,
+                                        run.seconds)
         if ran_out:
             run.facts["notes"].append(
                 f"the encoded stream of {max_ops} operands ran out after "
@@ -141,11 +143,13 @@ def drive(run) -> dict:
 
         # ---- facts for the readers -------------------------------------
         lat_a = np.asarray(lat)
+        in_write = float(lat_a.sum())
         delta = {k: t_after.get(k, 0) - t_before.get(k, 0) for k in TICKERS}
         busy = _covered(factory.spans[spans_before:], t0, t1)
         run.facts.update(
-            window_s=span, in_write_s=float(lat_a.sum()),
-            write_batch_s=lat_a,
+            window_s=span, in_write_s=in_write,
+            out_of_write_s=span - in_write,
+            window_ops=operands, write_batch_s=lat_a,
             stall_s=delta[st.STALL_MICROS] / 1e6,
             storage_write_bytes=(delta[st.FLUSH_WRITE_BYTES]
                                  + delta[st.COMPACT_WRITE_BYTES]),

@@ -97,7 +97,8 @@ def drive(run) -> dict:
         run.window_close()
         puts = w - n
         run.attempted = puts
-        ran_out = w + per_batch > n + max_puts and span < run.seconds
+        ran_out = dbside.stream_ran_out(w, per_batch, n + max_puts, span,
+                                        run.seconds)
         if ran_out:
             run.facts["notes"].append(
                 f"the encoded stream of {max_puts} puts ran out after "
@@ -111,11 +112,13 @@ def drive(run) -> dict:
 
         # ---- facts for the readers -------------------------------------
         lat_a = np.asarray(lat)
+        in_write = float(lat_a.sum())
         delta = {k: t_after.get(k, 0) - t_before.get(k, 0) for k in TICKERS}
         busy = _covered(factory.spans[spans_before:], t0, t1)
         run.facts.update(
-            window_s=span, in_write_s=float(lat_a.sum()),
-            write_batch_s=lat_a,
+            window_s=span, in_write_s=in_write,
+            out_of_write_s=span - in_write,
+            window_ops=puts, write_batch_s=lat_a,
             stall_s=delta[st.STALL_MICROS] / 1e6,
             storage_write_bytes=(delta[st.FLUSH_WRITE_BYTES]
                                  + delta[st.COMPACT_WRITE_BYTES]),
