@@ -81,6 +81,10 @@ def test_intermittent_io_faults_preserve_acknowledged_writes(seed):
                 touched.add(k)
                 in_log.pop(k, None)
             if cycle == 1:  # the flush thread's table: resume() clears it
+                # A memtable the puts above sealed may still be on the
+                # flush thread: its MANIFEST append would take the fault
+                # (FATAL) in place of this flush's table.
+                db.wait_for_compactions()
                 fe.fail_ops = {"append"}
                 with pytest.raises(IOError_, match="injected append error"):
                     db.flush()

@@ -1,10 +1,17 @@
+import struct
+
+import numpy as np
+import pytest
+
 from toplingdb_tpu.db.dbformat import (
     InternalKeyComparator,
     ValueType,
     make_internal_key,
     split_internal_key,
 )
-from toplingdb_tpu.db.memtable import MemTable
+from toplingdb_tpu.db.memtable import MemTable, create_memtable_rep
+from toplingdb_tpu.db.write_batch import WriteBatch
+from toplingdb_tpu.utils import statistics as st
 
 ICMP = InternalKeyComparator()
 MAXSEQ = 2**56 - 1
@@ -223,3 +230,203 @@ def test_columnar_flush_byte_parity(tmp_path):
     assert meta1.num_range_deletions == 1
     assert meta1.smallest == meta2.smallest
     assert meta1.largest == meta2.largest
+
+
+# ---------------------------------------------------------------------------
+# The native skiplist takes a run of records at a time (SkipList::insert_run:
+# sorted, searches interleaved under prefetch, key and value inline in the
+# node). One property for every entry point: whatever the keys, the values
+# and the length of the run, the list reads back as a plain sorted list.
+# tests/test_write_plane.py drives the fused plane with the same helpers.
+# ---------------------------------------------------------------------------
+
+V, D, M, SD = (ValueType.VALUE, ValueType.DELETION, ValueType.MERGE,
+               ValueType.SINGLE_DELETION)
+RUN_LENGTHS = (1, 2, 15, 16, 17, 500, 5000)
+MAXPACKED = 2**64 - 1
+_PADDED = (b"", b"\0", b"ab", b"ab\0", b"ab\0\0", b"abcdefgh", b"abcdefgh\0",
+           b"abcdefgh\0\0", b"abcdefg", b"abcdefg\0", b"\0\0\0\0\0\0\0\0",
+           b"\0\0\0\0\0\0\0\0\0")
+
+
+def _be8(x) -> bytes:
+    return struct.pack(">Q", int(x))
+
+
+def _shape_k8(rng, n):
+    return [(V, _be8(x), b"v%020d" % i)
+            for i, x in enumerate(rng.integers(0, 4 * n + 8, n))]
+
+
+def _shape_k16_equal_prefix(rng, n):
+    return [(V, b"prefix__" + _be8(x), b"%08d" % i)
+            for i, x in enumerate(rng.integers(0, 4 * n + 8, n))]
+
+
+def _shape_k0to7(rng, n):
+    return [(V, rng.bytes(int(rng.integers(0, 8))), b"v%d" % i)
+            for i in range(n)]
+
+
+def _shape_zero_padded_prefix(rng, n):
+    return [(V, _PADDED[int(rng.integers(0, len(_PADDED)))], b"v%d" % i)
+            for i in range(n)]
+
+
+def _shape_k200(rng, n):
+    return [(V, b"p" * 190 + _be8(x) + b"t" * int(rng.integers(0, 3)),
+             b"v%d" % i)
+            for i, x in enumerate(rng.integers(0, 4 * n + 8, n))]
+
+
+def _shape_empty_values(rng, n):
+    return [(V, _be8(x), b"") for x in rng.integers(0, 4 * n + 8, n)]
+
+
+def _shape_values_over_127(rng, n):
+    return [(V, _be8(x), bytes([i % 251]) * int(rng.integers(128, 400)))
+            for i, x in enumerate(rng.integers(0, 4 * n + 8, n))]
+
+
+def _shape_same_key_3_times(rng, n):
+    keys = rng.integers(0, 4 * n + 8, n // 3 + 1)
+    return [(V, _be8(keys[i % len(keys)]), b"v%d" % i) for i in range(n)]
+
+
+def _shape_deletes_and_merges(rng, n):
+    # Merges stay under half of a batch: the fused plane leaves a
+    # merge-heavy batch to insert_wb.
+    kinds = (V, V, V, D, M, SD, V, D)
+    out = []
+    for i, x in enumerate(rng.integers(0, n + 8, n)):
+        t = kinds[int(rng.integers(0, len(kinds)))]
+        out.append((t, _be8(x), b"" if t in (D, SD) else b"m%d" % i))
+    return out
+
+
+KEY_SHAPES = {f.__name__[len("_shape_"):]: f for f in (
+    _shape_k8, _shape_k16_equal_prefix, _shape_k0to7,
+    _shape_zero_padded_prefix, _shape_k200, _shape_empty_values,
+    _shape_values_over_127, _shape_same_key_3_times,
+    _shape_deletes_and_merges)}
+
+
+def make_ops(shape: str, n: int):
+    return KEY_SHAPES[shape](np.random.default_rng([n, len(shape)]), n)
+
+
+def make_batch(ops, pb: int = 0) -> WriteBatch:
+    wb = WriteBatch(protection_bytes_per_key=pb)
+    add = {V: wb.put, M: wb.merge}
+    for t, k, v in ops:
+        if t in add:
+            add[t](k, v)
+        else:
+            (wb.delete if t == D else wb.single_delete)(k)
+    return wb
+
+
+def sorted_rows(ops, first_seq: int):
+    """The plain list: ((user key, ~(seq<<8|type)), value), in the list's
+    order; a record's sequence is first_seq + its place in the batch."""
+    return sorted(((k, MAXPACKED - ((first_seq + i) << 8 | int(t))), v)
+                  for i, (t, k, v) in enumerate(ops))
+
+
+def check_reads_back(mem: MemTable, want) -> None:
+    """Forward and backward iteration, seek_ge / seek_lt of every key and
+    the flush's export against the plain sorted list."""
+    rep = mem._rep
+    assert len(rep) == len(want)
+    assert list(rep.iter_all()) == want
+    back, pos = [], rep.pos_last()
+    while pos is not None:                      # seek_lt of every key
+        back.append(rep.entry_at(pos))
+        pos = rep.pos_seek_lt(back[-1][0])
+    assert back == want[::-1]
+    first_of = {}
+    for row in want:
+        first_of.setdefault(row[0][0], row)
+    for row in want:
+        (uk, inv), _ = row
+        assert rep.entry_at(rep.pos_seek_ge((uk, inv))) == row
+        assert rep.entry_at(rep.pos_seek_ge((uk, 0))) == first_of[uk]
+    assert rep.pos_seek_lt(want[0][0]) is None
+    kv, seqs, vtypes = mem.export_columnar()
+    got = []
+    for i in range(len(seqs)):
+        ko, kl = int(kv.key_offs[i]), int(kv.key_lens[i])
+        vo, vl = int(kv.val_offs[i]), int(kv.val_lens[i])
+        ik = bytes(kv.key_buf[ko:ko + kl])
+        packed = int(seqs[i]) << 8 | int(vtypes[i])
+        assert ik[-8:] == struct.pack("<Q", packed)
+        got.append(((ik[:-8], MAXPACKED - packed),
+                    bytes(kv.val_buf[vo:vo + vl])))
+    assert got == want
+
+
+def _apply_insert(mem, ops, first_seq, pb):
+    for i, (t, k, v) in enumerate(ops):       # runs of one
+        mem.add(first_seq + i, t, k, v)
+
+
+def _apply_insert_batch(mem, ops, first_seq, pb):
+    """The rep's flat-column entry point by itself (MemTable.add_batch,
+    its caller, leaves batches under four records to add())."""
+    rows = [(k, MAXPACKED - ((first_seq + i) << 8 | int(t)), v)
+            for i, (t, k, v) in enumerate(ops)]
+    kl = np.array([len(r[0]) for r in rows], np.int32)
+    vl = np.array([len(r[2]) for r in rows], np.int32)
+    mem._rep.insert_batch(
+        np.frombuffer(b"".join(r[0] for r in rows) + b"\0", np.uint8),
+        np.cumsum(kl, dtype=np.int64) - kl, kl,
+        np.array([r[1] for r in rows], np.uint64),
+        np.frombuffer(b"".join(r[2] for r in rows) + b"\0", np.uint8),
+        np.cumsum(vl, dtype=np.int64) - vl, vl, len(rows))
+
+
+def _apply_insert_wb(mem, ops, first_seq, pb):
+    assert make_batch(ops, pb).insert_into(mem, first_seq) == len(ops)
+
+
+ENTRY_POINTS = {"insert": (_apply_insert, 0),
+                "insert_batch": (_apply_insert_batch, 0),
+                "insert_wb": (_apply_insert_wb, 0),
+                "insert_wb_prot": (_apply_insert_wb, 8)}
+
+
+def replayed(ops):
+    """The same records with other values: what a WAL replay over a
+    half-flushed memtable hands the list (an exact (key, seq) duplicate)."""
+    return [(t, k, v if t in (D, SD) else b"again-" + v[:40])
+            for t, k, v in ops]
+
+
+@pytest.mark.parametrize("n", RUN_LENGTHS)
+@pytest.mark.parametrize("shape", sorted(KEY_SHAPES))
+@pytest.mark.parametrize("entry", sorted(ENTRY_POINTS))
+def test_run_insert_reads_back_sorted(entry, shape, n):
+    apply, pb = ENTRY_POINTS[entry]
+    stats = st.Statistics()
+    mem = MemTable(ICMP, create_memtable_rep("skiplist"),
+                   protection_bytes=pb, stats=stats)
+    ops = make_ops(shape, n)
+    first_seq = 1000
+    apply(mem, ops, first_seq, pb)
+    check_reads_back(mem, sorted_rows(ops, first_seq))
+    if entry != "insert_batch":         # booked by the MemTable
+        assert mem.num_entries == n
+        assert mem.num_deletes == sum(t in (D, SD) for t, _, _ in ops)
+    if entry.startswith("insert_wb"):   # one wire image, one native call
+        assert stats.get_ticker_count(st.MEMTABLE_INSERT_RECORDS) == n
+        assert stats.get_ticker_count(st.MEMTABLE_INSERT_RUN_RECORDS) == (
+            n if n >= 2 else 0)
+    # An exact (key, seq) duplicate replaces the value; the count stands.
+    again = replayed(ops)
+    apply(mem, again, first_seq, pb)
+    check_reads_back(mem, sorted_rows(again, first_seq))
+    # And a later batch lands among the rows that are there.
+    more = make_ops(shape, min(n, 40) + 1)
+    apply(mem, more, first_seq + n, pb)
+    check_reads_back(mem, sorted(sorted_rows(again, first_seq)
+                                 + sorted_rows(more, first_seq + n)))

@@ -41,6 +41,72 @@ print("SANITIZED_REPLAY_OK")
 """
 
 
+# Four writers hand the skiplist overlapping runs (insert_wb releases the
+# GIL: their sorts, interleaved searches and CAS splices truly overlap)
+# while two readers iterate and seek; afterwards the list holds the union.
+_CONCURRENT_CHILD = r"""
+import struct, threading
+from toplingdb_tpu import native
+from toplingdb_tpu.db.memtable import NativeSkipListRep
+from toplingdb_tpu.db.write_batch import WriteBatch
+
+assert native._SANITIZE == {mode!r}, "sanitize mode did not take"
+assert native.lib() is not None, "sanitized .so failed to build/load"
+MAXP = 2**64 - 1
+rep = NativeSkipListRep()
+WRITERS, BATCHES, PER = 4, 25, 200
+want, images = [], []
+for w in range(WRITERS):
+    for b in range(BATCHES):
+        first = 1 + (w * BATCHES + b) * PER
+        wb = WriteBatch()
+        for i in range(PER):
+            n = (w * 7919 + b * 104729 + i * 31) % 1500   # overlapping keys
+            k = struct.pack(">Q", n) + (b"tail" if n % 3 == 0 else b"")
+            t = 0 if i % 11 == 0 else 1
+            v = b"" if t == 0 else b"w%d-%d-%d" % (w, b, i)
+            (wb.delete(k) if t == 0 else wb.put(k, v))
+            want.append(((k, MAXP - ((first + i) << 8 | t)), v))
+        images.append((w, wb.data(), first))
+done = threading.Event()
+errors = []
+
+def writer(w):
+    try:
+        for ww, data, first in images:
+            if ww == w:
+                assert rep.insert_wb(data, first)[0] == PER
+    except BaseException as e:  # noqa: BLE001
+        errors.append(repr(e))
+
+def reader():
+    try:
+        while not done.is_set():
+            rows = [skey for skey, _ in rep.iter_all()]
+            assert rows == sorted(rows)
+            for skey in rows[::97]:
+                assert rep.entry_at(rep.pos_seek_ge(skey))[0] == skey
+                lt = rep.pos_seek_lt(skey)
+                assert lt is None or rep.entry_at(lt)[0] < skey
+    except BaseException as e:  # noqa: BLE001
+        errors.append(repr(e))
+
+ws = [threading.Thread(target=writer, args=(w,)) for w in range(WRITERS)]
+rs = [threading.Thread(target=reader) for _ in range(2)]
+for t in rs + ws:
+    t.start()
+for t in ws:
+    t.join()
+done.set()
+for t in rs:
+    t.join()
+assert not errors, errors
+assert list(rep.iter_all()) == sorted(want)
+assert len(rep) == WRITERS * BATCHES * PER
+print("SANITIZED_REPLAY_OK")
+"""
+
+
 def _libasan() -> str | None:
     gxx = shutil.which("g++")
     if gxx is None:
@@ -55,12 +121,12 @@ def _libasan() -> str | None:
         else None
 
 
-def _replay(mode: str, env_extra: dict, tmp_path) -> None:
+def _replay(mode: str, env_extra: dict, tmp_path, child=_CHILD) -> None:
     env = dict(os.environ)
     env["TPULSM_NATIVE_SANITIZE"] = mode
     env["JAX_PLATFORMS"] = "cpu"
     env.update(env_extra)
-    src = _CHILD.format(mode=mode, corpus_dir=str(tmp_path))
+    src = child.format(mode=mode, corpus_dir=str(tmp_path))
     proc = subprocess.run(
         [sys.executable, "-c", src], capture_output=True, text=True,
         timeout=600, env=env,
@@ -88,3 +154,16 @@ def test_fuzz_corpus_replay_asan(tmp_path):
 
 def test_fuzz_corpus_replay_ubsan(tmp_path):
     _replay("undefined", {"UBSAN_OPTIONS": "halt_on_error=1"}, tmp_path)
+
+
+@pytest.mark.parametrize("mode", ["asan", "undefined"])
+def test_concurrent_run_inserts_under_sanitizer(mode, tmp_path):
+    if mode == "asan":
+        lib = _libasan()
+        if lib is None:
+            pytest.skip("libasan not found")
+        extra = {"LD_PRELOAD": lib,
+                 "ASAN_OPTIONS": "detect_leaks=0:abort_on_error=1"}
+    else:
+        extra = {"UBSAN_OPTIONS": "halt_on_error=1"}
+    _replay(mode, extra, tmp_path, child=_CONCURRENT_CHILD)

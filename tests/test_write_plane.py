@@ -424,3 +424,56 @@ def test_watermark_bookkeeping_unordered_stress(tmp_path):
         for i in range(300):
             assert db.get(b"u%d-%05d" % (t, i)) == b"x"
     db.close()
+
+
+# -- the fused plane hands the skiplist runs (tests/test_memtable.py) -------
+
+from test_memtable import (  # noqa: E402
+    D, KEY_SHAPES, RUN_LENGTHS, SD, V, check_reads_back, make_batch, make_ops,
+    replayed, sorted_rows)
+
+
+@pytest.mark.parametrize("n", RUN_LENGTHS)
+@pytest.mark.parametrize("shape", sorted(KEY_SHAPES))
+def test_group_commit_run_insert_reads_back_sorted(tmp_path, shape, n):
+    """tpulsm_wb_group_commit's apply loop, a unit a run (a batch of 5000
+    fans out over the ApplyPool on disjoint units): the memtable reads back
+    as the plain sorted list, entries and deletes as note_group_applied
+    books them, the tickers say how much of it arrived in runs."""
+    from toplingdb_tpu.tools import fuzz_native as fz
+
+    stats = st.Statistics()
+    db = DB.open(str(tmp_path / "db"), Options(
+        create_if_missing=True, statistics=stats,
+        write_buffer_size=256 << 20))
+    try:
+        ops = make_ops(shape, n)
+        wb = make_batch(ops)
+        db.write(wb)
+        assert stats.get_ticker_count(st.WRITE_GROUP_NATIVE_COMMITS) == 1
+        first_seq = wb.sequence()
+        mem = db._cfs[0].mem
+        check_reads_back(mem, sorted_rows(ops, first_seq))
+        assert mem.num_entries == n
+        assert mem.num_deletes == sum(t in (D, SD) for t, _, _ in ops)
+        assert stats.get_ticker_count(st.MEMTABLE_INSERT_RECORDS) == n
+        assert stats.get_ticker_count(st.MEMTABLE_INSERT_RUN_RECORDS) == (
+            n if n >= 2 else 0)
+        # The same records again with other values, as a replay would hand
+        # them over (mode 2: validate, then insert): every
+        # one an exact duplicate, replaced in place.
+        again = replayed(ops)
+        assert fz.group_commit_insert(mem._rep, make_batch(again).data(),
+                                      first_seq) == n
+        check_reads_back(mem, sorted_rows(again, first_seq))
+        # Single writes land among them (runs of one).
+        db.put(b"ab", b"one")
+        db.delete(b"ab\0")
+        more = [(V, b"ab", b"one"), (D, b"ab\0", b"")]
+        check_reads_back(mem, sorted(sorted_rows(again, first_seq)
+                                     + sorted_rows(more, first_seq + n)))
+        assert stats.get_ticker_count(st.MEMTABLE_INSERT_RECORDS) == n + 2
+        assert stats.get_ticker_count(st.MEMTABLE_INSERT_RUN_RECORDS) == (
+            n if n >= 2 else 0)
+    finally:
+        db.close()

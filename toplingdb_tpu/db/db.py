@@ -129,12 +129,12 @@ class _CFData:
     __slots__ = ("handle", "mem", "imm")
 
     def __init__(self, handle: ColumnFamilyHandle, icmp, rep_name: str = "vector",
-                 protection_bytes: int = 0):
+                 protection_bytes: int = 0, stats=None):
         from toplingdb_tpu.db.memtable import create_memtable_rep
 
         self.handle = handle
         self.mem = MemTable(icmp, create_memtable_rep(rep_name),
-                            protection_bytes=protection_bytes)
+                            protection_bytes=protection_bytes, stats=stats)
         self.imm: list[MemTable] = []
 
 
@@ -323,7 +323,8 @@ class DB:
         self.default_cf = ColumnFamilyHandle(0, "default")
         self._cfs: dict[int, _CFData] = {
             0: _CFData(self.default_cf, self.icmp, options.memtable_rep,
-                       protection_bytes=self._protection)
+                       protection_bytes=self._protection,
+                       stats=options.statistics)
         }
         from toplingdb_tpu.db.blob import BlobSource
 
@@ -620,7 +621,8 @@ class DB:
             cf_id = self.versions.create_column_family(name)
             h = ColumnFamilyHandle(cf_id, name)
             self._cfs[cf_id] = _CFData(h, self.icmp, self.options.memtable_rep,
-                                       protection_bytes=self._protection)
+                                       protection_bytes=self._protection,
+                                       stats=self.stats)
             return h
 
     def drop_column_family(self, handle: ColumnFamilyHandle) -> None:
@@ -853,13 +855,14 @@ class DB:
                 h = ColumnFamilyHandle(cf_id, st.name)
                 self._cfs[cf_id] = _CFData(h, self.icmp,
                                            self.options.memtable_rep,
-                                           protection_bytes=self._protection)
+                                           protection_bytes=self._protection,
+                                           stats=self.stats)
 
     def _fresh_memtable(self) -> MemTable:
         from toplingdb_tpu.db.memtable import create_memtable_rep
 
         m = MemTable(self.icmp, create_memtable_rep(self.options.memtable_rep),
-                     protection_bytes=self._protection)
+                     protection_bytes=self._protection, stats=self.stats)
         self._mem_id_counter += 1
         m.mem_id = self._mem_id_counter
         return m
@@ -1603,7 +1606,9 @@ class DB:
             for w, rep in zip(group, reps):
                 meta.append((seq, rep, w.batch._prot if pb else None))
                 seq += w.batch._count
-            mem0.note_group_applied(meta, int(out[2]), int(out[3]), rc)
+            mem0.note_group_applied(meta, int(out[2]), int(out[3]), rc,
+                                    insert_ns=int(out[7]),
+                                    runs=[w.batch._count for w in group])
             return rc
 
         if not frame:

@@ -16,8 +16,10 @@ from __future__ import annotations
 
 import bisect
 import threading
+import time
 
 from toplingdb_tpu.utils import concurrency as ccy
+from toplingdb_tpu.utils import statistics as _st
 
 from toplingdb_tpu.db import dbformat
 from toplingdb_tpu.db.dbformat import ValueType
@@ -533,9 +535,10 @@ def create_memtable_rep(name: str) -> MemTableRep:
 class MemTable:
     def __init__(self, icmp: dbformat.InternalKeyComparator,
                  rep: MemTableRep | None = None,
-                 protection_bytes: int = 0):
+                 protection_bytes: int = 0, stats=None):
         self._icmp = icmp
         self._rep = rep if rep is not None else PyVectorRep()
+        self._stats = stats  # the memtable.insert.* tickers, when given
         self._range_dels: list[tuple[int, bytes, bytes]] = []  # (seq, begin, end)
         self._mem_usage = 0
         self._num_entries = 0
@@ -596,6 +599,22 @@ class MemTable:
         return _p.truncate(_p.protect_entry(int(t), user_key, value),
                            self.protection_bytes)
 
+    def _tick_insert(self, ns: int, runs) -> None:
+        """memtable.insert.*: `ns` on the rep's insert, `runs` the record
+        count of each run it was handed (a member batch of a write group,
+        one wire image, one parsed batch). The skiplist takes a run of two
+        or more sorted and interleaved; the trie rep has no such path."""
+        stats = self._stats
+        if stats is None:
+            return
+        engaged = getattr(self._rep, "_nget_mem_kind", None) == 0
+        stats.record_ticks((
+            (_st.MEMTABLE_INSERT_MICROS, (ns + 500) // 1000),
+            (_st.MEMTABLE_INSERT_RECORDS, sum(runs)),
+            (_st.MEMTABLE_INSERT_RUN_RECORDS,
+             sum(n for n in runs if n >= 2) if engaged else 0),
+        ))
+
     def add_encoded(self, first_seq: int, rep: bytes,
                     prots=None, pb: int = 0) -> int | None:
         """Apply a whole WriteBatch wire image in one native call (the
@@ -614,6 +633,7 @@ class MemTable:
         means the caller already verified them."""
         if self._prot is not None and prots is None:
             return None  # nothing to carry: the parsed path computes them
+        t0 = time.perf_counter_ns()
         if prots is not None and pb:
             wbp = getattr(self._rep, "insert_wb_prot", None)
             if wbp is None:
@@ -627,6 +647,7 @@ class MemTable:
         if res is None:
             return None
         count, delta, deletes = res
+        self._tick_insert(time.perf_counter_ns() - t0, (count,))
         with self._lock:
             if self._prot is not None:
                 self._prot_pending.append((first_seq, rep, prots))
@@ -649,13 +670,16 @@ class MemTable:
         return h, kind
 
     def note_group_applied(self, entries_meta, mem_delta: int,
-                           deletes: int, total: int) -> None:
+                           deletes: int, total: int, insert_ns: int = 0,
+                           runs=()) -> None:
         """Bookkeeping for a whole write group the native plane already
         applied straight into the rep (tpulsm_wb_group_commit):
         entries_meta is [(first_seq, rep_bytes, prots_or_None)] per member
         batch — protected members park in _prot_pending exactly like
         add_encoded's wire-image deferral, so flush verification sees the
-        same carried checksums either way."""
+        same carried checksums either way. insert_ns is the plane's own
+        clock on the insert (out[7]), runs the members' record counts."""
+        self._tick_insert(insert_ns, runs)
         with self._lock:
             if self._prot is not None:
                 for fs, rep, prots in entries_meta:
@@ -733,8 +757,10 @@ class MemTable:
              for p in points), np.uint64, m)
         # Outside self._lock: the native rep is internally thread-safe, so
         # concurrent groups' inserts overlap GIL-free.
+        t0 = time.perf_counter_ns()
         rep_batch(keybuf, key_offs, key_lens, invs,
                   valbuf, val_offs, val_lens, m)
+        self._tick_insert(time.perf_counter_ns() - t0, (m,))
         return n
 
     def export_columnar(self):

@@ -1313,6 +1313,11 @@ void tpulsm_bloom_build(
 // Ordering: user_key bytewise ascending, then inv_packed (u64) ascending
 // (inv = ~(seq<<8|type), so newer versions sort first).
 //
+// Every entry point hands the list a RUN of records (SkipList::insert_run;
+// a single insert is the run of one): the run is sorted by the list's own
+// order, searched kRunGroup records at a time with the searches advancing
+// together under prefetch, and linked in in order.
+//
 // Concurrency: inserts are LOCK-FREE (CAS splice per level, the reference's
 // InsertConcurrently shape, memtable/inlineskiplist.h:61) and the batch
 // entry point is called WITHOUT the GIL (ctypes.CDLL), so multiple Python
@@ -1323,18 +1328,39 @@ void tpulsm_bloom_build(
 
 namespace {
 
+// One record of a run: pointers into the caller's buffers (a wire image,
+// flat columns), which outlive the call. A probe is a record without a
+// value. `pfx` is filled by the list.
+struct SLRec {
+  uint64_t pfx;
+  const uint8_t* k;
+  const uint8_t* v;
+  uint64_t inv;
+  uint32_t kl;
+  uint32_t vl;
+};
+
+// One allocation an entry: the header, the tower, then the key bytes and
+// the [u32 len][bytes] value record (the reference's InlineSkipList keeps
+// the key behind the tower the same way). `prefix` is the first 8 key bytes
+// as a big-endian integer, zero-padded, so most comparisons end on the
+// node's first cache line without touching the key.
 struct SLNode {
-  const uint8_t* key;
-  uint32_t key_len;
+  uint64_t prefix;
   uint64_t inv_packed;
-  // Value = pointer to a [u32 len][bytes] arena record; a single atomic so
-  // in-place replace (WAL-replay duplicate) can't tear against readers.
+  // Value = pointer to a [u32 len][bytes] record: into this node until a
+  // WAL-replay duplicate replaces it with a fresh arena record; a single
+  // atomic so the in-place replace can't tear against readers.
   std::atomic<const uint8_t*> val;
-  int height;
-  std::atomic<SLNode*> next[1];  // variable length
+  uint32_t key_len;
+  int32_t height;
+  std::atomic<SLNode*> next[1];  // `height` links, then key, then value
 
   SLNode* nxt(int level, std::memory_order o = std::memory_order_acquire) {
     return next[level].load(o);
+  }
+  const uint8_t* key() const {
+    return reinterpret_cast<const uint8_t*>(next + height);
   }
 };
 
@@ -1372,6 +1398,11 @@ struct Arena {
 };
 
 static const int kMaxHeight = 12;
+// Searches of one run that are in flight together (insert_run): each round
+// loads every live cursor's next pointer and prefetches it, so a group's
+// cache misses overlap. Measured at 8, 16 and 32 on the benchmark's host
+// (CHANGES.md, PR 34).
+static const int kRunGroup = 16;
 
 static uint64_t random_height_seed() {
   static std::atomic<uint64_t> c{0x9E3779B97F4A7C15ULL};
@@ -1385,15 +1416,18 @@ struct SkipList {
   std::atomic<int64_t> count{0};
 
   SkipList() {
-    head = alloc_node(kMaxHeight);
-    head->key = nullptr;
+    head = alloc_node(kMaxHeight, 0, 0);
+    head->prefix = 0;
+    head->inv_packed = 0;
     head->key_len = 0;
+    head->val.store(nullptr, std::memory_order_relaxed);
     for (int i = 0; i < kMaxHeight; i++)
       head->next[i].store(nullptr, std::memory_order_relaxed);
   }
 
-  SLNode* alloc_node(int height) {
-    size_t sz = sizeof(SLNode) + (height - 1) * sizeof(std::atomic<SLNode*>);
+  SLNode* alloc_node(int height, uint32_t kl, uint32_t vl) {
+    size_t sz = sizeof(SLNode) + (height - 1) * sizeof(std::atomic<SLNode*>) +
+                kl + 4 + vl;
     SLNode* n = reinterpret_cast<SLNode*>(arena.alloc(sz));
     n->height = height;
     return n;
@@ -1408,29 +1442,49 @@ struct SkipList {
     return h;
   }
 
-  // <0: a < b (a = node key triple, b = probe)
-  static int cmp(const uint8_t* ak, uint32_t al, uint64_t ainv,
-                 const uint8_t* bk, uint32_t bl, uint64_t binv) {
-    uint32_t m = al < bl ? al : bl;
-    int r = m ? std::memcmp(ak, bk, m) : 0;
-    if (r) return r;
-    if (al != bl) return al < bl ? -1 : 1;
-    if (ainv != binv) return ainv < binv ? -1 : 1;
+  static uint64_t key_prefix(const uint8_t* k, uint32_t kl) {
+    uint64_t w = 0;
+    if (kl >= 8)
+      std::memcpy(&w, k, 8);
+    else if (kl)
+      std::memcpy(&w, k, kl);
+#if __BYTE_ORDER__ == __ORDER_LITTLE_ENDIAN__
+    w = __builtin_bswap64(w);
+#endif
+    return w;
+  }
+
+  static SLRec probe(const uint8_t* k, uint32_t kl, uint64_t inv) {
+    return SLRec{key_prefix(k, kl), k, nullptr, inv, kl, 0};
+  }
+
+  // <0: a < b. Equal prefixes mean the first min(8, shorter) bytes agree
+  // and the longer key's bytes up to 8 are zeros there, so what is left is
+  // the bytes past 8 and then the lengths ("ab" < "ab\0").
+  static int cmp(uint64_t apfx, const uint8_t* ak, uint32_t al, uint64_t ainv,
+                 const SLRec& b) {
+    if (apfx != b.pfx) return apfx < b.pfx ? -1 : 1;
+    uint32_t m = al < b.kl ? al : b.kl;
+    if (m > 8) {
+      int r = std::memcmp(ak + 8, b.k + 8, m - 8);
+      if (r) return r;
+    }
+    if (al != b.kl) return al < b.kl ? -1 : 1;
+    if (ainv != b.inv) return ainv < b.inv ? -1 : 1;
     return 0;
   }
 
-  static int cmp_node(SLNode* a, const uint8_t* k, uint32_t kl, uint64_t inv) {
-    return cmp(a->key, a->key_len, a->inv_packed, k, kl, inv);
+  static int cmp_node(const SLNode* a, const SLRec& b) {
+    return cmp(a->prefix, a->key(), a->key_len, a->inv_packed, b);
   }
 
   // First node with node >= probe; fills prev[] when non-null.
-  SLNode* seek_ge(const uint8_t* k, uint32_t kl, uint64_t inv,
-                  SLNode** prev) {
+  SLNode* seek_ge(const SLRec& b, SLNode** prev) {
     SLNode* x = head;
     int level = max_height.load(std::memory_order_acquire) - 1;
     while (true) {
       SLNode* nxt_ = x->nxt(level);
-      bool go_right = nxt_ && cmp_node(nxt_, k, kl, inv) < 0;
+      bool go_right = nxt_ && cmp_node(nxt_, b) < 0;
       if (go_right) {
         x = nxt_;
       } else {
@@ -1448,48 +1502,42 @@ struct SkipList {
     n->val.store(rec, std::memory_order_release);
   }
 
-  // Returns 1 on fresh insert, 0 on in-place replace of an exact duplicate.
-  // Safe for concurrent callers (CAS splice; duplicates replace the value
-  // atomically — only WAL replay produces them, and that is single-threaded,
-  // but the path is still race-safe).
-  int insert(const uint8_t* k, uint32_t kl, uint64_t inv,
-             const uint8_t* v, uint32_t vl) {
-    SLNode* prev[kMaxHeight];
-    for (int i = 0; i < kMaxHeight; i++) prev[i] = head;
-    SLNode* ge = seek_ge(k, kl, inv, prev);
-    if (ge && cmp_node(ge, k, kl, inv) == 0) {
-      set_val(ge, arena, v, vl);
-      return 0;
-    }
+  // Link one record in, given the prev[] its search found. prev[] may be
+  // stale (a lost race, or a neighbour of the same run linked in since):
+  // every level re-walks right from it. Returns 1 on a fresh insert, 0 on
+  // the in-place replace of an exact duplicate.
+  int splice(const SLRec& r, SLNode** prev) {
     int h = random_height();
     int mh = max_height.load(std::memory_order_relaxed);
     while (h > mh &&
            !max_height.compare_exchange_weak(mh, h,
                                              std::memory_order_relaxed)) {
     }
-    SLNode* n = alloc_node(h);
-    uint8_t* kcopy = arena.alloc(kl);
-    std::memcpy(kcopy, k, kl);
-    n->key = kcopy;
-    n->key_len = kl;
-    n->inv_packed = inv;
-    set_val(n, arena, v, vl);
+    SLNode* n = alloc_node(h, r.kl, r.vl);
+    n->prefix = r.pfx;
+    n->inv_packed = r.inv;
+    n->key_len = r.kl;
+    uint8_t* tail = const_cast<uint8_t*>(n->key());
+    if (r.kl) std::memcpy(tail, r.k, r.kl);
+    tail += r.kl;
+    std::memcpy(tail, &r.vl, 4);
+    if (r.vl) std::memcpy(tail + 4, r.v, r.vl);
+    n->val.store(tail, std::memory_order_relaxed);
     // Splice bottom-up (reference InsertConcurrently): the node becomes
     // reachable at level 0 first; higher levels are shortcuts. Only level 0
     // may observe an exact duplicate (n not yet linked there) — at that
     // point replace-in-place and abandon n entirely.
     for (int i = 0; i < h; i++) {
       while (true) {
-        // prev[i] may be stale after a lost race: re-walk right as needed.
         SLNode* p = prev[i];
         SLNode* nx = p->nxt(i);
-        while (nx && nx != n && cmp_node(nx, k, kl, inv) < 0) {
+        while (nx && nx != n && cmp_node(nx, r) < 0) {
           p = nx;
           nx = p->nxt(i);
         }
-        if (i == 0 && nx && cmp_node(nx, k, kl, inv) == 0) {
+        if (i == 0 && nx && cmp_node(nx, r) == 0) {
           // Concurrent/replayed duplicate: last value wins, atomically.
-          set_val(nx, arena, v, vl);
+          set_val(nx, arena, r.v, r.vl);
           return 0;
         }
         n->next[i].store(nx, std::memory_order_relaxed);
@@ -1503,6 +1551,103 @@ struct SkipList {
     count.fetch_add(1, std::memory_order_relaxed);
     return 1;
   }
+
+  // Up to kRunGroup records, sorted: their searches advance a step a round,
+  // each step's next node prefetched a round before it is compared; then
+  // the nodes go in, in order.
+  int64_t insert_group(const SLRec* recs, int g) {
+    struct Cursor {
+      SLNode* x;
+      SLNode* nx;
+      int level;
+      SLNode* prev[kMaxHeight];
+    };
+    Cursor cur[kRunGroup];
+    int live[kRunGroup];
+    const int top = max_height.load(std::memory_order_acquire) - 1;
+    for (int c = 0; c < g; c++) {
+      for (int i = 0; i < kMaxHeight; i++) cur[c].prev[i] = head;
+      cur[c].x = head;
+      cur[c].level = top;
+      cur[c].nx = head->nxt(top);
+      __builtin_prefetch(cur[c].nx);
+      live[c] = c;
+    }
+    for (int n_live = g; n_live;) {
+      int w = 0;
+      for (int j = 0; j < n_live; j++) {
+        Cursor& cu = cur[live[j]];
+        if (cu.nx && cmp_node(cu.nx, recs[live[j]]) < 0) {
+          cu.x = cu.nx;
+        } else {
+          cu.prev[cu.level] = cu.x;
+          if (cu.level == 0) continue;  // found: nx is the first node >= rec
+          cu.level--;
+        }
+        cu.nx = cu.x->nxt(cu.level);
+        __builtin_prefetch(cu.nx);
+        live[w++] = live[j];
+      }
+      n_live = w;
+    }
+    int64_t fresh = 0;
+    for (int c = 0; c < g; c++) {
+      // A replayed duplicate is met before anything is allocated for it
+      // (only WAL replay produces them, and a replay meets many).
+      SLNode* ge = cur[c].nx;
+      if (ge && cmp_node(ge, recs[c]) == 0)
+        set_val(ge, arena, recs[c].v, recs[c].vl);
+      else
+        fresh += splice(recs[c], cur[c].prev);
+    }
+    return fresh;
+  }
+
+  // THE insert: a run of records at a time. Sorting changes the order of
+  // insertion, never a record's key, sequence, type or value. Returns the
+  // number of fresh inserts (exact duplicates replace the value in place).
+  // Safe for concurrent callers (CAS splice; duplicates replace the value
+  // atomically — only WAL replay produces them, and that is
+  // single-threaded, but the path is still race-safe).
+  int64_t insert_run(SLRec* recs, size_t n) {
+    for (size_t i = 0; i < n; i++)
+      recs[i].pfx = key_prefix(recs[i].k, recs[i].kl);
+    if (n > 1)
+      std::sort(recs, recs + n, [](const SLRec& a, const SLRec& b) {
+        return cmp(a.pfx, a.k, a.kl, a.inv, b) < 0;
+      });
+    int64_t fresh = 0;
+    for (size_t i = 0; i < n; i += kRunGroup)
+      fresh += insert_group(
+          recs + i, (int)(n - i < (size_t)kRunGroup ? n - i : kRunGroup));
+    return fresh;
+  }
+};
+
+// What an entry point parses out of its input goes through here to the
+// list: runs of at most kMaxRun records (a bound on the buffer, not on the
+// batch).
+struct SLRunSink {
+  static const size_t kMaxRun = 4096;
+  SkipList* sl;
+  std::vector<SLRec> recs;
+  int64_t fresh = 0;
+
+  SLRunSink(SkipList* s, size_t expect) : sl(s) {
+    recs.reserve(expect < kMaxRun ? expect : kMaxRun);
+  }
+  void add(const uint8_t* k, uint32_t kl, uint64_t inv, const uint8_t* v,
+           uint32_t vl) {
+    recs.push_back(SLRec{0, k, v, inv, kl, vl});
+    if (recs.size() == kMaxRun) flush();
+  }
+  int64_t flush() {
+    if (!recs.empty()) {
+      fresh += sl->insert_run(recs.data(), recs.size());
+      recs.clear();
+    }
+    return fresh;
+  }
 };
 
 }  // namespace
@@ -1512,7 +1657,8 @@ void tpulsm_skiplist_free(void* h) { delete static_cast<SkipList*>(h); }
 
 int32_t tpulsm_skiplist_insert(void* h, const uint8_t* k, uint32_t kl,
                                uint64_t inv, const uint8_t* v, uint32_t vl) {
-  return static_cast<SkipList*>(h)->insert(k, kl, inv, v, vl);
+  SLRec r{0, k, v, inv, kl, vl};  // the run of one
+  return (int32_t)static_cast<SkipList*>(h)->insert_run(&r, 1);
 }
 
 int64_t tpulsm_skiplist_count(void* h) {
@@ -1528,7 +1674,8 @@ int64_t tpulsm_skiplist_memory(void* h) {
 
 void* tpulsm_skiplist_seek_ge(void* h, const uint8_t* k, uint32_t kl,
                               uint64_t inv) {
-  return static_cast<SkipList*>(h)->seek_ge(k, kl, inv, nullptr);
+  return static_cast<SkipList*>(h)->seek_ge(SkipList::probe(k, kl, inv),
+                                             nullptr);
 }
 
 void* tpulsm_skiplist_first(void* h) {
@@ -1546,7 +1693,7 @@ void* tpulsm_skiplist_seek_lt(void* h, const uint8_t* k, uint32_t kl,
   SkipList* sl = static_cast<SkipList*>(h);
   SLNode* prev[kMaxHeight];
   for (int i = 0; i < kMaxHeight; i++) prev[i] = sl->head;
-  sl->seek_ge(k, kl, inv, prev);
+  sl->seek_ge(SkipList::probe(k, kl, inv), prev);
   return prev[0] == sl->head ? nullptr : prev[0];
 }
 
@@ -1563,7 +1710,7 @@ void* tpulsm_skiplist_last(void* h) {
 void tpulsm_skiplist_node(void* node, const uint8_t** k, uint32_t* kl,
                           uint64_t* inv, const uint8_t** v, uint32_t* vl) {
   SLNode* n = static_cast<SLNode*>(node);
-  *k = n->key;
+  *k = n->key();
   *kl = n->key_len;
   *inv = n->inv_packed;
   const uint8_t* rec = n->val.load(std::memory_order_acquire);
@@ -1581,13 +1728,12 @@ int64_t tpulsm_skiplist_insert_batch(
     void* h, const uint8_t* keybuf, const int64_t* key_offs,
     const int32_t* key_lens, const uint64_t* invs, const uint8_t* valbuf,
     const int64_t* val_offs, const int32_t* val_lens, int64_t n) {
-  SkipList* sl = static_cast<SkipList*>(h);
-  int64_t fresh = 0;
+  SLRunSink sink(static_cast<SkipList*>(h), (size_t)(n > 0 ? n : 0));
   for (int64_t i = 0; i < n; i++) {
-    fresh += sl->insert(keybuf + key_offs[i], (uint32_t)key_lens[i], invs[i],
-                        valbuf + val_offs[i], (uint32_t)val_lens[i]);
+    sink.add(keybuf + key_offs[i], (uint32_t)key_lens[i], invs[i],
+             valbuf + val_offs[i], (uint32_t)val_lens[i]);
   }
-  return fresh;
+  return sink.flush();
 }
 
 // Bulk ordered export of the whole skiplist into flat columnar buffers —
@@ -1635,7 +1781,7 @@ int64_t tpulsm_skiplist_export(
     if (ko + (int64_t)n->key_len + 8 > key_cap || vo + (int64_t)vl > val_cap)
       return -1;
     uint64_t packed = ~n->inv_packed;
-    std::memcpy(key_buf + ko, n->key, n->key_len);
+    std::memcpy(key_buf + ko, n->key(), n->key_len);
     for (int b = 0; b < 8; b++)
       key_buf[ko + n->key_len + b] = (uint8_t)(packed >> (8 * b));
     key_offs[rows] = ko;
@@ -2408,6 +2554,14 @@ int64_t tpulsm_build_data_section_c(
 // image on pass 0 (count header, varint bounds, supported record types),
 // applies on pass 1 through the insert callback. Returns the record
 // count, or -2 (unsupported record: Python path) / -4 (corrupt image).
+// The record count a wire image's header states (0 for an image too short
+// to have one): what a collector reserves, never what it trusts.
+static inline size_t wb_header_count(const uint8_t* rep, int64_t len) {
+  if (len < 12) return 0;
+  return (uint32_t)rep[8] | ((uint32_t)rep[9] << 8) |
+         ((uint32_t)rep[10] << 16) | ((uint32_t)rep[11] << 24);
+}
+
 extern "C++" {
 template <typename InsertFn, typename CheckFn>
 static int64_t wb_wire_apply_chk(const uint8_t* rep, int64_t len,
@@ -2418,8 +2572,7 @@ static int64_t wb_wire_apply_chk(const uint8_t* rep, int64_t len,
                        kWideEntity = 0x16;
   if (len < 12) return -4;
   const uint8_t* end = rep + len;
-  uint32_t hdr_count = (uint32_t)rep[8] | ((uint32_t)rep[9] << 8) |
-                       ((uint32_t)rep[10] << 16) | ((uint32_t)rep[11] << 24);
+  uint32_t hdr_count = (uint32_t)wb_header_count(rep, len);
   for (int pass = 0; pass < 2; pass++) {
     const uint8_t* p = rep + 12;
     uint64_t seq = first_seq;
@@ -2482,12 +2635,15 @@ static int64_t wb_wire_apply(const uint8_t* rep, int64_t len,
 
 int64_t tpulsm_skiplist_insert_wb(void* h, const uint8_t* rep, int64_t len,
                                   uint64_t first_seq, int64_t* out) {
-  SkipList* sl = static_cast<SkipList*>(h);
-  return wb_wire_apply(rep, len, first_seq, out,
-                       [sl](const uint8_t* k, uint32_t kl, uint64_t inv,
-                            const uint8_t* v, uint32_t vl) {
-                         sl->insert(k, kl, inv, v, vl);
-                       });
+  SLRunSink sink(static_cast<SkipList*>(h), wb_header_count(rep, len));
+  int64_t rc = wb_wire_apply(rep, len, first_seq, out,
+                             [&sink](const uint8_t* k, uint32_t kl,
+                                     uint64_t inv, const uint8_t* v,
+                                     uint32_t vl) {
+                               sink.add(k, kl, inv, v, vl);
+                             });
+  sink.flush();
+  return rc;
 }
 
 // ---------------------------------------------------------------------------
@@ -2679,12 +2835,13 @@ int64_t tpulsm_skiplist_insert_wb_prot(void* h, const uint8_t* rep,
                                        int64_t len, uint64_t first_seq,
                                        const uint64_t* prots, int64_t n_prots,
                                        int32_t pb, int64_t* out) {
-  SkipList* sl = static_cast<SkipList*>(h);
+  SLRunSink sink(static_cast<SkipList*>(h), wb_header_count(rep, len));
   int64_t rc = wb_wire_apply_chk(
       rep, len, first_seq, out,
-      [sl](const uint8_t* k, uint32_t kl, uint64_t inv, const uint8_t* v,
-           uint32_t vl) { sl->insert(k, kl, inv, v, vl); },
+      [&sink](const uint8_t* k, uint32_t kl, uint64_t inv, const uint8_t* v,
+              uint32_t vl) { sink.add(k, kl, inv, v, vl); },
       ProtCheck{prots, n_prots, prot_trunc_mask(pb)});
+  sink.flush();
   if (rc >= 0 && rc != n_prots) return -5 - rc;  // carried vector too long
   return rc;
 }
@@ -4626,9 +4783,10 @@ int32_t tpulsm_db_get_kinds(void** mem_handles, const int32_t* mem_kinds,
       rec = v->val.load(std::memory_order_acquire);
     } else {
       SkipList* sl = static_cast<SkipList*>(mem_handles[m]);
-      SLNode* n = sl->seek_ge(ukey, (uint32_t)klen, inv, nullptr);
+      SLNode* n =
+          sl->seek_ge(SkipList::probe(ukey, (uint32_t)klen, inv), nullptr);
       if (!n || n->key_len != (uint32_t)klen ||
-          std::memcmp(n->key, ukey, (size_t)klen) != 0)
+          std::memcmp(n->key(), ukey, (size_t)klen) != 0)
         continue;
       p2 = ~n->inv_packed;
       rec = n->val.load(std::memory_order_acquire);
@@ -4882,9 +5040,7 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
   if (mode & 4) {
     // Caller vouches (see above): counts come from the batch headers.
     for (int64_t b = 0; b < n_batches; b++) {
-      const uint8_t* rep = (const uint8_t*)reps[b];
-      total += (uint32_t)rep[8] | ((uint32_t)rep[9] << 8) |
-               ((uint32_t)rep[10] << 16) | ((uint32_t)rep[11] << 24);
+      total += (int64_t)wb_header_count((const uint8_t*)reps[b], lens[b]);
     }
   }
   // Pass 0: validate every batch — nothing is framed or inserted unless the
@@ -4895,8 +5051,7 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
     if (len < 12) return -4;
     const uint8_t* end = rep + len;
     const uint8_t* p = rep + 12;
-    uint32_t hdr_count = (uint32_t)rep[8] | ((uint32_t)rep[9] << 8) |
-                         ((uint32_t)rep[10] << 16) | ((uint32_t)rep[11] << 24);
+    uint32_t hdr_count = (uint32_t)wb_header_count(rep, len);
     int64_t count = 0;
     while (p < end) {
       uint8_t t = *p++;
@@ -4986,8 +5141,7 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
       for (int64_t b = 0; b < n_batches; b++) {
         const uint8_t* rep = (const uint8_t*)reps[b];
         const uint8_t* end = rep + lens[b];
-        uint32_t cnt = (uint32_t)rep[8] | ((uint32_t)rep[9] << 8) |
-                       ((uint32_t)rep[10] << 16) | ((uint32_t)rep[11] << 24);
+        uint32_t cnt = (uint32_t)wb_header_count(rep, lens[b]);
         if ((int64_t)cnt <= S) {
           units.push_back({rep + 12, end, seq});
           seq += cnt;
@@ -5033,6 +5187,9 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
         const uint8_t* p = units[u].p;
         const uint8_t* end = units[u].end;
         uint64_t seq = units[u].seq;
+        // A unit is one run for the skiplist (its records: pointers into
+        // the members' wire images).
+        SLRunSink sink(sl, sl ? (size_t)S : 0);
         while (p < end) {
           uint8_t t = *p++;
           uint32_t klen, vlen = 0;
@@ -5051,13 +5208,14 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
           }
           uint64_t inv = ~((seq << 8) | (uint64_t)t);
           if (sl)
-            sl->insert(k, klen, inv, v, vlen);
+            sink.add(k, klen, inv, v, vlen);
           else
             trie_insert(tr, k, klen, inv, v, vlen);
           d += (int64_t)klen + vlen + 24;
           if (t == 0x0 || t == 0x7) dl++;
           seq++;
         }
+        sink.flush();
       }
       a_delta.fetch_add(d, std::memory_order_relaxed);
       a_deletes.fetch_add(dl, std::memory_order_relaxed);

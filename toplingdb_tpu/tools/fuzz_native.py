@@ -114,7 +114,36 @@ def _wb_seeds(rng):
             else:
                 wb.put_entity(k, b"\x00WCE1\x01\x00\x02vv")
         seeds.append(wb.data())
+    # A long run: the skiplist sorts it and searches it in groups
+    # (SkipList::insert_run), keys of every length around its 8-byte prefix.
+    wb = WriteBatch()
+    for i in range(rng.randrange(40, 600)):
+        k = (b"k%04d" % rng.randrange(200))[:rng.randrange(0, 7)] \
+            + b"\0" * rng.randrange(0, 12)
+        if i % 5 == 0:
+            wb.delete(k)
+        else:
+            wb.put(k, b"v" * rng.randrange(0, 200))
+    seeds.append(wb.data())
     return seeds
+
+
+def group_commit_insert(rep, data: bytes, first_seq: int):
+    """The fused plane's validate + insert (mode 2) of one wire image into
+    `rep`: the record count, or None when the plane refuses the image (or
+    is not there)."""
+    import ctypes
+
+    from toplingdb_tpu import native
+
+    fn = getattr(native.lib(), "tpulsm_wb_group_commit", None)
+    if fn is None:
+        return None
+    out = (ctypes.c_int64 * 8)()
+    rc = fn(rep._h, rep._nget_mem_kind, (ctypes.c_char_p * 1)(data),
+            (ctypes.c_int64 * 1)(len(data)), 1, first_seq, None, 0, 0, 2, 0,
+            -1, None, 0, out)
+    return rc if rc >= 0 else None
 
 
 def fuzz_wb(rng, runs, corpus: Corpus):
@@ -154,7 +183,25 @@ def fuzz_wb(rng, runs, corpus: Corpus):
                       f"python says {py_count!r}")
                 corpus.maybe_add(data, ("FINDING", it))
                 findings += 1
+            # What went in reads back in the list's order, a row a record
+            # (a batch gives every record a sequence of its own).
+            rows = [skey for skey, _ in rep.iter_all()]
+            if rows != sorted(rows) or len(set(rows)) != count \
+                    or len(rep) != count:
+                print(f"FINDING[wb]: {count} records read back as "
+                      f"{len(rows)} rows, sorted: {rows == sorted(rows)}")
+                corpus.maybe_add(data, ("FINDING", it))
+                findings += 1
             sig = ("ok", min(count, 8))
+        # The fused plane parses the same image with a loop of its own:
+        # it takes what insert_wb takes, and a refusal inserts nothing.
+        twin = type(rep)()
+        g = group_commit_insert(twin, data, 1000)
+        if (g, len(twin)) != ((None, 0) if r is None else (r[0], len(rep))):
+            print(f"FINDING[wb]: insert_wb {r and r[0]!r} / {len(rep)} rows,"
+                  f" the fused plane {g!r} / {len(twin)} rows")
+            corpus.maybe_add(data, ("FINDING", it))
+            findings += 1
         corpus.maybe_add(data, sig)
     return findings
 

@@ -94,6 +94,15 @@ WRITE_GROUP_FOLLOWERS = "write.group.followers"
 WRITE_GROUP_NATIVE_COMMITS = "write.group.native.commits"
 WRITE_GROUP_FALLBACKS = "write.group.fallbacks"
 WRITE_GROUP_FSYNCS_COALESCED = "write.group.fsyncs.coalesced"
+# The memtable insert by itself (db/memtable.py books them): the native
+# rep's own clock around the insert of a write group (the plane's out[7])
+# or of one wire image / parsed batch, the records those calls took, and
+# the records that arrived in a run of two or more, which the skiplist
+# sorts and searches interleaved (SkipList::insert_run): run.records over
+# records is how often that engages, micros over records the layer's cost.
+MEMTABLE_INSERT_MICROS = "memtable.insert.micros"
+MEMTABLE_INSERT_RECORDS = "memtable.insert.records"
+MEMTABLE_INSERT_RUN_RECORDS = "memtable.insert.run.records"
 # -- compaction ------------------------------------------------------
 COMPACT_READ_BYTES = "compact.read.bytes"
 COMPACT_WRITE_BYTES = "compact.write.bytes"
