@@ -126,19 +126,52 @@ class CompactionStats:
     zip_encode_usec: int = 0
     zip_dict_train_usec: int = 0
 
-    def count_zip_input(self, reader) -> None:
-        """One input file's reader: counted when it is a ZipTable."""
-        if hasattr(reader, "scan_columnar"):
+    # SingleFastTables on the job's two ends (table/single_fast.py): inputs
+    # of the format, their rows, and the wall of scanning their entry
+    # ranges into the columnar buffers (`pipeline.sft_scan`; a sum over
+    # reader threads, like `zip_scan_usec`). Outputs of the format, their
+    # rows and file bytes, and the wall of building them on the writer's
+    # thread (`sst.sft_append` + `sst.sft_finish`). Every route counts
+    # files, rows and bytes; the columnar routes also the two walls.
+    sft_input_files: int = 0
+    sft_input_rows: int = 0
+    sft_scan_usec: int = 0
+    sft_output_files: int = 0
+    sft_output_rows: int = 0
+    sft_output_bytes: int = 0
+    sft_build_usec: int = 0
+
+    def count_input(self, reader) -> None:
+        """One input file's reader: counted under its format when the
+        format has counters (a ZipTable, a SingleFastTable)."""
+        plane = getattr(reader, "entry_plane", None)
+        if plane == "zip":
             self.zip_input_files += 1
             self.zip_input_rows += reader.n
+        elif plane == "sft":
+            self.sft_input_files += 1
+            self.sft_input_rows += reader.n
 
-    def count_zip_output(self, props, file_size: int) -> None:
-        """One finished output file: counted when it is a ZipTable."""
+    def count_ranged_scan(self, plane: str, usec: int) -> None:
+        """The wall of scanning entry ranges of a file of `plane` (a
+        reader's `entry_plane`) into the columnar buffers."""
+        if plane == "sft":
+            self.sft_scan_usec += usec
+        else:
+            self.zip_scan_usec += usec
+
+    def count_output(self, table_options, props, file_size: int) -> None:
+        """One finished output file, built under `table_options`: counted
+        when it is a ZipTable or a SingleFastTable."""
         if str(props.compression_name).startswith("zip"):
             self.zip_output_files += 1
             self.zip_output_bytes += file_size
             self.zip_output_raw_bytes += (props.raw_key_size
                                           + props.raw_value_size)
+        elif getattr(table_options, "format", "block") == "single_fast":
+            self.sft_output_files += 1
+            self.sft_output_rows += props.num_entries
+            self.sft_output_bytes += file_size
 
     def phase_dict(self) -> dict:
         """Non-zero timing phases, seconds — for bench/dcompact reporting.
@@ -428,7 +461,7 @@ def build_outputs(env, dbname: str, icmp, compaction: Compaction,
         outputs.append(meta)
         stats.output_bytes += meta.file_size
         stats.output_files += 1
-        stats.count_zip_output(props, meta.file_size)
+        stats.count_output(table_options, props, meta.file_size)
         builder = None
         wfile = None
 
@@ -558,7 +591,7 @@ def _run_subcompactions(env, dbname, icmp, compaction, table_cache,
     rd0 = RangeDelAggregator(ucmp)
     for _, f in compaction.all_inputs():
         r = table_cache.get_reader(f.number)
-        stats.count_zip_input(r)
+        stats.count_input(r)
         for b, e in r.range_del_entries():
             rd0.add(RangeTombstone.from_table_entry(b, e))
     all_frags = surviving_tombstone_fragments(
@@ -649,6 +682,9 @@ def _run_subcompactions(env, dbname, icmp, compaction, table_cache,
         stats.zip_output_files += st.zip_output_files
         stats.zip_output_bytes += st.zip_output_bytes
         stats.zip_output_raw_bytes += st.zip_output_raw_bytes
+        stats.sft_output_files += st.sft_output_files
+        stats.sft_output_rows += st.sft_output_rows
+        stats.sft_output_bytes += st.sft_output_bytes
         stats.dropped_obsolete += st.dropped_obsolete
         stats.dropped_tombstone += st.dropped_tombstone
         stats.merged_records += st.merged_records

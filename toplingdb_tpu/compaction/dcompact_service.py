@@ -146,13 +146,14 @@ class ChipPool:
         }
 
 
-JOB_SUM_COUNTERS = ("merge_operand_rows", "merge_groups",
-                    "merge_rows_folded", "merge_fold_usec",
-                    "tombstone_fragments", "tombstone_cover_usec",
-                    "zip_input_files", "zip_input_rows", "zip_scan_usec",
-                    "zip_output_files", "zip_output_bytes",
-                    "zip_output_raw_bytes", "zip_encode_usec",
-                    "zip_dict_train_usec")
+JOB_SUM_COUNTERS = (
+    "merge_operand_rows", "merge_groups", "merge_rows_folded",
+    "merge_fold_usec", "tombstone_fragments", "tombstone_cover_usec",
+    "zip_input_files", "zip_input_rows", "zip_scan_usec", "zip_output_files",
+    "zip_output_bytes", "zip_output_raw_bytes", "zip_encode_usec",
+    "zip_dict_train_usec", "sft_input_files", "sft_input_rows",
+    "sft_scan_usec", "sft_output_files", "sft_output_rows",
+    "sft_output_bytes", "sft_build_usec")
 
 
 class DcompactWorkerService:
@@ -470,7 +471,10 @@ class HttpCompactionExecutorFactory(CompactionExecutorFactory):
                         raise IOError_(f"worker {url} HTTP {r.status}")
                     r.read()  # results also land in job_dir/results.json
             except OSError as e:
-                raise IOError_(f"dcompact POST to {url} failed: {e}") from e
+                # A 500's body says which exception the job died of.
+                said = e.read()[:400] if hasattr(e, "read") else b""
+                raise IOError_(
+                    f"dcompact POST to {url} failed: {e} {said!r}") from e
 
         ex = SubprocessCompactionExecutor(
             self.device, self.job_root, spawn=spawn, policy=self.policy,

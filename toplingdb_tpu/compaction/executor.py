@@ -166,6 +166,11 @@ class CompactionParams:
     block_size: int
     creation_time: int
     table_format: str = "block"
+    # What else shapes a SingleFastTable: its hash index, and the filter
+    # policy's registry name ("" = no filter; None = not sent, the worker
+    # keeps TableOptions' default — a job an older DB wrote).
+    hash_index: bool = False
+    filter_policy: str | None = None
     # SliceTransform serialized name (utils/slice_transform.py) or None —
     # required when table_format == 'plain' (prefix hash index) and feeds
     # prefix blooms for the other formats.
@@ -392,6 +397,9 @@ class SubprocessCompactionExecutor(CompactionExecutor):
             device=self.device,
             table_format=opts.table_options_for_level(
                 compaction.output_level, compaction.bottommost).format,
+            hash_index=opts.table_options.hash_index,
+            filter_policy=(opts.table_options.filter_policy.name()
+                           if opts.table_options.filter_policy else ""),
             prefix_extractor=(
                 opts.table_options.prefix_extractor.name()
                 if getattr(opts.table_options, "prefix_extractor", None)

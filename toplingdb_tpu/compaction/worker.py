@@ -148,15 +148,15 @@ def _run_job_body(job_dir: str, params, t_enter: float,
         create_compaction_filter(params.compaction_filter)
         if params.compaction_filter else None
     )
-    from toplingdb_tpu.utils.table_properties_collector import (
-        create_collector_factory,
-    )
-
+    from toplingdb_tpu.table.filter import filter_policy_from_name
     from toplingdb_tpu.utils.slice_transform import slice_transform_from_name
-
+    from toplingdb_tpu.utils.table_properties_collector import (
+        create_collector_factory)
     topts = TableOptions(
         block_size=params.block_size, compression=params.compression,
-        format=getattr(params, "table_format", "block"),
+        format=params.table_format, hash_index=params.hash_index,
+        **({} if params.filter_policy is None else {
+            "filter_policy": filter_policy_from_name(params.filter_policy)}),
         prefix_extractor=(
             slice_transform_from_name(params.prefix_extractor)
             if getattr(params, "prefix_extractor", None) else None

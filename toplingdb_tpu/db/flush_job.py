@@ -92,7 +92,9 @@ def _verify_flush_tombstones(memtables, pb) -> None:
 def _flush_columnar(env, dbname, file_number, icmp, mem, table_options,
                     tombstones, creation_time, column_family):
     """Single-memtable columnar flush: ONE native export of the whole rep +
-    the native block-building SST writer — no per-entry Python. Returns the
+    the native SST writer of the level's format (block tables and
+    SingleFastTables: `write_tables_columnar` hands the latter to
+    table/single_fast.py) — no per-entry Python. Returns the
     FileMetaData, or None when ineligible (caller uses the iterator path).
     It runs on the DB's flush thread beside the writer (db/db.py
     `_flush_loop`): the two native calls release the GIL, and what is left
@@ -101,7 +103,9 @@ def _flush_columnar(env, dbname, file_number, icmp, mem, table_options,
     FlushJob::WriteLevel0Table's tight C++ scan, db/flush_job.cc:833)."""
     from toplingdb_tpu.db import dbformat as _dbf
 
-    if (getattr(table_options, "format", "block") != "block"
+    if (getattr(table_options, "format", "block") not in ("block",
+                                                           "single_fast")
+            or getattr(table_options, "auto_sort", False)
             or getattr(table_options, "index_type", "binary") != "binary"
             or getattr(table_options, "properties_collector_factories", None)
             or getattr(table_options, "prefix_extractor", None) is not None

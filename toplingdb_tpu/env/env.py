@@ -450,6 +450,26 @@ class _PosixWritable(WritableFile):
         return self._size
 
 
+def _pread_full(fd: int, n: int, offset: int) -> bytes:
+    """pread until n bytes or the end of the file. One pread may return
+    fewer (POSIX allows it; a 16 MB read of a whole SingleFastTable came
+    back short on the chip's host under memory pressure: PERF.md §6, PR
+    35), and a reader that takes the short buffer for the file finds its
+    footer in the middle of the data."""
+    data = os.pread(fd, n, offset)
+    if not data or len(data) >= n:
+        return data
+    parts = [data]
+    got = len(data)
+    while got < n:
+        more = os.pread(fd, n - got, offset + got)
+        if not more:
+            break
+        parts.append(more)
+        got += len(more)
+    return b"".join(parts)
+
+
 class _PosixRandomAccess(RandomAccessFile):
     def __init__(self, path: str):
         try:
@@ -464,12 +484,12 @@ class _PosixRandomAccess(RandomAccessFile):
         lvl = _stats_mod.perf_level
         if lvl >= 2:
             t0 = time.perf_counter()
-            data = os.pread(self._f.fileno(), n, offset)
+            data = _pread_full(self._f.fileno(), n, offset)
             ctx = _stats_mod.iostats_context()
             ctx.read_nanos += int((time.perf_counter() - t0) * 1e9)
             ctx.bytes_read += len(data)
             return data
-        data = os.pread(self._f.fileno(), n, offset)
+        data = _pread_full(self._f.fileno(), n, offset)
         if lvl:
             _stats_mod.iostats_context().bytes_read += len(data)
         return data

@@ -501,6 +501,31 @@ def lib() -> ctypes.CDLL | None:
             ]
         except AttributeError:
             pass
+        try:
+            # SingleFastTable data plane (table/single_fast.py): the
+            # entry-range scan into columnar slots, the region builder and
+            # the hash index of the columnar writer.
+            u32p = ctypes.POINTER(ctypes.c_uint32)
+            l.tpulsm_sft_scan.restype = ctypes.c_int64
+            l.tpulsm_sft_scan.argtypes = [
+                u8p, ctypes.c_int64, u32p, ctypes.c_int64, ctypes.c_int64,
+                ctypes.c_int64, u8p, ctypes.c_int64, u8p, ctypes.c_int64,
+                i32p, i32p, i32p, i32p, ctypes.c_int64, ctypes.c_int64,
+                i64p,
+            ]
+            l.tpulsm_sft_append.restype = ctypes.c_int64
+            l.tpulsm_sft_append.argtypes = [
+                u8p, i32p, i32p, u8p, i32p, i32p, i64p, i32p,
+                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+                ctypes.c_int64, ctypes.c_int64, u8p, ctypes.c_int64, i64p,
+                u32p, u32p,
+            ]
+            l.tpulsm_sft_hash_index.restype = ctypes.c_int64
+            l.tpulsm_sft_hash_index.argtypes = [
+                u8p, i32p, i32p, i32p, ctypes.c_int64, u32p, ctypes.c_int64,
+            ]
+        except AttributeError:
+            pass
         _lib = l
         return _lib
 

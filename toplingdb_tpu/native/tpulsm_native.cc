@@ -5794,4 +5794,146 @@ int64_t tpulsm_zip_group_decode(
   return raw_offs[g1 - g0];
 }
 
+
+// ---------------------------------------------------------------------------
+// SingleFastTable data plane (table/single_fast.py): the flat region
+// [varint klen | varint vlen | ikey | value]* under a fixed32 offset array,
+// scanned by entry range into columnar buffers and built from them.
+// ---------------------------------------------------------------------------
+
+// Decode entries [e0, e1) of a resident SingleFastTable image into columnar
+// slots: keys are copied to key_out; values are copied to val_out, or, when
+// val_out is null, referenced where they lie (val_offs = val_base + the
+// value's offset in `data`). The image comes from a file, so every offset
+// and length is checked against the region. used[0], used[1] receive the
+// key and value bytes the range holds. Returns the rows decoded, or -2 an
+// output too small, -3 a malformed region or range.
+int64_t tpulsm_sft_scan(
+    const uint8_t* data, int64_t data_len, const uint32_t* offsets, int64_t n,
+    int64_t e0, int64_t e1, uint8_t* key_out, int64_t key_cap,
+    uint8_t* val_out, int64_t val_cap, int32_t* key_offs, int32_t* key_lens,
+    int32_t* val_offs, int32_t* val_lens, int64_t key_base, int64_t val_base,
+    int64_t* used) {
+  if (n < 0 || e0 < 0 || e0 > e1 || e1 > n || data_len < 0) return -3;
+  const uint8_t* end = data + data_len;
+  int64_t ku = 0, vu = 0;
+  for (int64_t i = e0; i < e1; i++) {
+    uint32_t off;  // the array lies where the file put it: no alignment
+    std::memcpy(&off, reinterpret_cast<const uint8_t*>(offsets) + 4 * i, 4);
+    if (off >= (uint64_t)data_len) return -3;
+    uint32_t klen, vlen;
+    const uint8_t* p = get_varint32(data + off, end, &klen);
+    if (!p) return -3;
+    p = get_varint32(p, end, &vlen);
+    if (!p || klen < 8 || (uint64_t)klen + vlen > (uint64_t)(end - p))
+      return -3;
+    if (ku + klen > key_cap) return -2;
+    int64_t voff = val_out ? vu : (int64_t)(p + klen - data);
+    if (val_out && vu + vlen > val_cap) return -2;
+    if (key_base + ku > 0x7fffffff || val_base + voff > 0x7fffffff) return -2;
+    std::memcpy(key_out + ku, p, klen);
+    if (val_out) std::memcpy(val_out + vu, p + klen, vlen);
+    int64_t r = i - e0;
+    key_offs[r] = (int32_t)(key_base + ku);
+    key_lens[r] = (int32_t)klen;
+    val_offs[r] = (int32_t)(val_base + voff);
+    val_lens[r] = (int32_t)vlen;
+    ku += klen;
+    vu += vlen;
+  }
+  used[0] = ku;
+  used[1] = vu;
+  return e1 - e0;
+}
+
+// Append a run of columnar entries, order[start..limit), to a
+// SingleFastTable region: the bytes SingleFastTableBuilder._add_sorted
+// appends, with trailer_override as in tpulsm_build_block. region_base is
+// the region's length before the run; offs_out[i] receives each entry's
+// offset in the region, *crc is extended over the bytes written. The run
+// stops before an entry that begins a new user key once the region has
+// reached max_file_size (build_outputs' cut rule: the caller starts the
+// next file there; order[start - 1] is read when start > file_start), or
+// when `out` is full. out_len[0] receives the bytes written, out_len[1]
+// whether the cut rule stopped the run. Returns the entries consumed (0: cut before the
+// first), or -2 when not even one fits `out`, -3 a key shorter than its
+// trailer, -7 the region would pass its fixed32 offsets' 4 GiB.
+int64_t tpulsm_sft_append(
+    const uint8_t* key_buf, const int32_t* key_offs, const int32_t* key_lens,
+    const uint8_t* val_buf, const int32_t* val_offs, const int32_t* val_lens,
+    const int64_t* trailer_override, const int32_t* order, int64_t start,
+    int64_t limit, int64_t file_start, int64_t region_base,
+    int64_t max_file_size, uint8_t* out, int64_t out_cap, int64_t* out_len,
+    uint32_t* offs_out, uint32_t* crc) {
+  int64_t used = 0, cut = 0;
+  int64_t i = start;
+  for (; i < limit; i++) {
+    int32_t e = order[i];
+    uint32_t klen = (uint32_t)key_lens[e];
+    uint32_t vlen = (uint32_t)val_lens[e];
+    if (klen < 8) return -3;
+    const uint8_t* k = key_buf + key_offs[e];
+    if (i > file_start && region_base + used >= max_file_size) {
+      int32_t pe = order[i - 1];
+      uint32_t pl = (uint32_t)key_lens[pe];
+      if (pl != klen ||
+          std::memcmp(key_buf + key_offs[pe], k, klen - 8) != 0) {
+        cut = 1;
+        break;
+      }
+    }
+    int64_t need = (int64_t)varint32_len(klen) + varint32_len(vlen) +
+                   klen + vlen;
+    if (region_base + used + (int64_t)klen + vlen + 10 > 0xFFFFFF00LL)
+      return -7;  // the builder refuses here too: offsets are fixed32
+    if (used + need > out_cap) {
+      if (i == start) return -2;
+      break;
+    }
+    offs_out[i - start] = (uint32_t)(region_base + used);
+    uint8_t* p = put_varint32(out + used, klen);
+    p = put_varint32(p, vlen);
+    std::memcpy(p, k, klen);
+    if (trailer_override[e] >= 0) {
+      uint64_t t = (uint64_t)trailer_override[e];
+      for (int b = 0; b < 8; b++) p[klen - 8 + b] = (t >> (8 * b)) & 0xff;
+    }
+    std::memcpy(p + klen, val_buf + val_offs[e], vlen);
+    used += need;
+  }
+  *crc = tpulsm_crc32c_extend(*crc, out, (size_t)used);
+  out_len[0] = used;
+  out_len[1] = cut;
+  return i - start;
+}
+
+// The SingleFastTable hash index over a file's entries order[0..n):
+// open-addressed xxh64 buckets (nb a power of two, zeroed by the caller),
+// each 1 + the ordinal of the NEWEST version of one user key, as
+// SingleFastTableBuilder._hash_index_block fills them. Returns the keys
+// placed, or -3 a key shorter than its trailer.
+int64_t tpulsm_sft_hash_index(
+    const uint8_t* key_buf, const int32_t* key_offs, const int32_t* key_lens,
+    const int32_t* order, int64_t n, uint32_t* buckets, int64_t nb) {
+  if (nb <= 0 || (nb & (nb - 1)) || n >= nb) return -3;
+  const uint64_t mask = (uint64_t)nb - 1;
+  const uint8_t* prev = nullptr;
+  uint32_t prev_len = 0;
+  int64_t placed = 0;
+  for (int64_t i = 0; i < n; i++) {
+    int32_t e = order[i];
+    if (key_lens[e] < 8) return -3;
+    uint32_t ul = (uint32_t)key_lens[e] - 8;
+    const uint8_t* uk = key_buf + key_offs[e];
+    if (prev && prev_len == ul && std::memcmp(prev, uk, ul) == 0) continue;
+    prev = uk;
+    prev_len = ul;
+    uint64_t h = tpulsm_xxh64(uk, ul, 0) & mask;
+    while (buckets[h]) h = (h + 1) & mask;
+    buckets[h] = (uint32_t)(i + 1);
+    placed++;
+  }
+  return placed;
+}
+
 }  // extern "C"

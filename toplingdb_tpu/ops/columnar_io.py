@@ -104,7 +104,7 @@ def _file_scan_prologue(reader):
     return raw, block_offs, block_lens, handles
 
 
-def scan_tables_columnar_prealloc(readers):
+def scan_tables_columnar_prealloc(readers, ranged_scan=None):
     """Scan EVERY input file into ONE preallocated pair of columnar
     buffers, sized exactly from each file's TableProperties
     (raw_key_size/raw_value_size/num_entries) — the fused native call
@@ -116,14 +116,20 @@ def scan_tables_columnar_prealloc(readers):
     ZERO-COPY per-file view (buffer slices + rebased offsets) with the
     layout the shard/cover helpers expect — or None when ineligible
     (native/symbol missing, props absent or wrong, exotic codec, >int32
-    buffers); the caller then uses the per-file scan + concat path."""
+    buffers); the caller then uses the per-file scan + concat path. Block
+    files and files read by entry ranges (ZipTables, SingleFastTables: one
+    `scan_into` over every entry, run under `ranged_scan(reader, scan)`
+    when the caller books such scans), in any mix."""
     lib = native.lib()
     if lib is None or not hasattr(lib, "tpulsm_scan_blocks"):
         return None
     infos = []
     tk = tv = tn = 0
     for r in readers:
-        if not hasattr(r, "new_index_iterator"):
+        ranged = getattr(r, "entry_plane", None)  # no blocks: entry ranges
+        if not ranged and not hasattr(r, "new_index_iterator"):
+            return None
+        if ranged and not r.scan_native_ready():
             return None
         if getattr(r, "_compression_dict", b""):
             # Dict-compressed frames need the stored dictionary; the
@@ -149,6 +155,7 @@ def scan_tables_columnar_prealloc(readers):
     key_lens = np.empty(tn, dtype=np.int32)
     val_offs = np.empty(tn, dtype=np.int32)
     val_lens = np.empty(tn, dtype=np.int32)
+    kv = ColumnarKV(key_buf, key_offs, key_lens, val_buf, val_offs, val_lens)
 
     bases = []
     kb = vb = nb = 0
@@ -166,6 +173,15 @@ def scan_tables_columnar_prealloc(readers):
         if ne == 0:
             return 0
         n_base, k_base, v_base = bases[i]
+        if getattr(r, "entry_plane", None):
+            if r.n != ne:
+                return -100
+
+            def scan(r):
+                return r.scan_into(0, ne, kv, n_base, k_base, v_base, rk, rv)
+
+            used = ranged_scan(r, scan) if ranged_scan else scan(r)
+            return ne if used == (rk, rv) else -100
         raw, b_offs, b_lens, _handles = _file_scan_prologue(r)
         if raw is None:
             return -100
@@ -207,7 +223,6 @@ def scan_tables_columnar_prealloc(readers):
             # Capacity/entry-count disagreement with the properties, codec
             # fallback, or a dict frame: use the compatible path.
             return None
-    kv = ColumnarKV(key_buf, key_offs, key_lens, val_buf, val_offs, val_lens)
     parts = []
     for i, (ne, rk, rv) in enumerate(infos):
         n_base, k_base, v_base = bases[i]
@@ -229,15 +244,16 @@ def scan_table_columnar(reader, ref_values: bool = True) -> ColumnarKV:
     stays alive as val_buf, saving the per-entry value memcpy), keys
     copied; compressed files fall back to per-block decompression +
     decode. `ref_values=False` forces the value-copying twin (parity
-    tests). A ZipTable goes through its own decoders."""
+    tests). A ZipTable or a SingleFastTable goes through its own
+    decoders (the readers' `scan_columnar`)."""
     lib = native.lib()
     if lib is None:
         raise NotSupported("native library unavailable")
-    if hasattr(reader, "scan_columnar"):
-        return _scan_zip_table_columnar(reader)
+    if getattr(reader, "entry_plane", None):
+        return _scan_entry_ranged_columnar(reader)
     if not hasattr(reader, "new_index_iterator"):
-        raise NotSupported(
-            "bulk columnar scan requires the block or the zip format")
+        raise NotSupported("bulk columnar scan requires the block, the zip "
+                           "or the single_fast format")
     raw, block_offs, block_lens, handles = _file_scan_prologue(reader)
     if raw is None:
         return ColumnarKV(
@@ -326,12 +342,13 @@ def scan_table_columnar(reader, ref_values: bool = True) -> ColumnarKV:
     return kv
 
 
-def _scan_zip_table_columnar(reader) -> ColumnarKV:
-    """A whole ZipTable through its native decoders
-    (ZipTableReader.scan_columnar): dense keys from offset 0, values as
-    the decoded groups hold them."""
+def _scan_entry_ranged_columnar(reader) -> ColumnarKV:
+    """A whole ZipTable or SingleFastTable through its native decoders
+    (the reader's `scan_columnar` over every entry): dense keys from
+    offset 0; values as the decoded groups hold them (zip) or where they
+    lie in the resident image (single_fast)."""
     if reader.n and not reader.scan_native_ready():
-        raise NotSupported("zip scan plane unavailable")
+        raise NotSupported(f"{reader.entry_plane} scan plane unavailable")
     kb, ko, kl, vb, vo, vl = reader.scan_columnar(0, reader.n)
     if len(kb) > 0x7FFFFF00 or len(vb) > 0x7FFFFF00:
         raise NotSupported("input exceeds the int32 columnar budget")
@@ -741,7 +758,7 @@ def write_tables_columnar(env, dbname, new_file_number, icmp, options,
                           trailer_override: np.ndarray, vtypes: np.ndarray,
                           seqs: np.ndarray, tombstones, creation_time: int,
                           max_output_file_size: int = 2 ** 62,
-                          column_family=(0, "default")):
+                          column_family=(0, "default"), stats=None):
     """Build output SSTs from `kv` entries in `order`, byte-identical to
     TableBuilder fed the same stream through build_outputs — including the
     output-cutting rule (cut at a user-key boundary once the file's written
@@ -757,10 +774,23 @@ def write_tables_columnar(env, dbname, new_file_number, icmp, options,
     pipeline: shard s's survivors stream into SSTs while shard s+1 is still
     computing/downloading). Chunks must be key-range-ordered with no user
     key spanning a chunk boundary, and the caller may update
-    trailer_override/seqs rows for a chunk any time before yielding it."""
+    trailer_override/seqs rows for a chunk any time before yielding it.
+
+    `options.format == "single_fast"` is handed, arguments and all, to the
+    format's own writer (table/single_fast.py::write_tables_sft_columnar:
+    no blocks to cut, so it appends each chunk as it arrives); `stats`
+    (CompactionStats) is for that writer's `sft_build_usec`."""
     lib = native.lib()
     if lib is None:
         raise NotSupported("native library unavailable")
+    if getattr(options, "format", "block") == "single_fast":
+        from toplingdb_tpu.table.single_fast import write_tables_sft_columnar
+
+        return write_tables_sft_columnar(
+            env, dbname, new_file_number, icmp, options, kv, order,
+            trailer_override, vtypes, seqs, tombstones, creation_time,
+            max_output_file_size=max_output_file_size,
+            column_family=column_family, stats=stats)
     # The writer's set-up: buffers sized from the inputs, the first
     # output file created.
     setup = telemetry.span("sst.open")

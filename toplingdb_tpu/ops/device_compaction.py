@@ -50,7 +50,7 @@ def collect_raw_entries(compaction, table_cache, icmp, stats=None):
         for k, v in it.entries():
             entries.append((k, v))
         if stats is not None:
-            stats.count_zip_input(r)
+            stats.count_input(r)
             if hasattr(it, "prefetch_counts"):  # a ZipTable reads no blocks
                 h, m = it.prefetch_counts()
                 stats.prefetch_hits += h
@@ -239,9 +239,9 @@ def _part_bounds(part, splitters: list[bytes]) -> list[int]:
 def _collect_raw_columnar(compaction, table_cache, icmp, want_uploads=False,
                           stats=None):
     """Scan every input file into columnar buffers — in parallel threads
-    (the native block decoder runs GIL-free under ctypes). Block files
-    and ZipTables, in any mix: a ZipTable's scan is the span
-    `pipeline.zip_scan` and counts into `stats` (zip_input_*). With
+    (the native decoders run GIL-free under ctypes). Block files, ZipTables
+    and SingleFastTables, in any mix: a whole-file scan of the latter two is
+    `pipeline.zip_scan` / `pipeline.sft_scan`, counted in `stats`. With
     want_uploads, ALSO split the sorted parts into user-key-range shards
     and prepare (host-side, no device traffic yet) each shard's uniform
     chunk columns. Returns (kv, rd, shards, parts) where shards is None
@@ -261,34 +261,34 @@ def _collect_raw_columnar(compaction, table_cache, icmp, want_uploads=False,
     readers = [
         table_cache.get_reader(f.number) for _, f in compaction.all_inputs()
     ]
-    pre = scan_tables_columnar_prealloc(readers)
-    if pre is not None:
-        kv, parts = pre
-    else:
-        trace = _tele.current_handle()
-        zip_usec = []
+    trace = _tele.current_handle()
+    walls = []  # (entry_plane, usec) of each entry-ranged input's scan
 
-        def scan_one(r):
-            if not hasattr(r, "scan_columnar"):
-                return scan_table_columnar(r)
-            t0 = time.time()
-            with _tele.span_under(trace, "pipeline.zip_scan",
-                                  rows=r.n) as sp:
-                part = scan_table_columnar(r)
-                sp.tag(nbytes=len(part.key_buf) + len(part.val_buf))
-            zip_usec.append(int((time.time() - t0) * 1e6))
-            return part
+    def scan_one(r, scan=scan_table_columnar):
+        """One input whole; an entry-ranged one under its span and wall."""
+        plane = getattr(r, "entry_plane", None)
+        if not plane:
+            return scan(r)
+        t0 = time.time()
+        with _tele.span_under(trace, f"pipeline.{plane}_scan",
+                              rows=r.n) as sp:
+            part = scan(r)
+            sp.tag(nbytes=int(r.properties.raw_key_size
+                              + r.properties.raw_value_size))
+        walls.append((plane, int((time.time() - t0) * 1e6)))
+        return part
 
-        if len(readers) > 1:
-            with ThreadPoolExecutor(min(8, len(readers))) as ex:
-                parts = list(ex.map(scan_one, readers))
-        else:
-            parts = [scan_one(r) for r in readers]
-        kv = ColumnarKV.concat(parts)
-        if stats is not None:
-            for r in readers:
-                stats.count_zip_input(r)
-            stats.zip_scan_usec += sum(zip_usec)
+    pre = scan_tables_columnar_prealloc(readers, scan_one)
+    if pre is None:  # a ZipTable among the inputs, or props that disagree
+        with ThreadPoolExecutor(max(1, min(8, len(readers)))) as ex:
+            parts = list(ex.map(scan_one, readers))
+        pre = ColumnarKV.concat(parts), parts
+    kv, parts = pre
+    # Booked here, on one thread: the scans ran side by side.
+    for r in readers if stats is not None else ():
+        stats.count_input(r)
+    for plane, usec in walls if stats is not None else ():
+        stats.count_ranged_scan(plane, usec)
     rd = RangeDelAggregator(icmp.user_comparator)
     for r in readers:
         for b, e in r.range_del_entries():
@@ -726,7 +726,7 @@ def _outputs_from_files(env, files, kv, vtypes, stats, icmp=None,
         stats.output_bytes += meta.file_size
         stats.output_files += 1
         stats.output_records += props.num_entries
-        stats.count_zip_output(props, meta.file_size)
+        stats.count_output(table_options, props, meta.file_size)
     return outputs
 
 
@@ -864,12 +864,12 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
             # bound; the blocking download waits below add the rest).
             stats.transfer_time_usec += int((time.time() - t_up) * 1e6)
             if not any_complex and \
-                    getattr(table_options, "format", "block") in ("block",
-                                                                  "zip"):
+                    getattr(table_options, "format", "block") in (
+                        "block", "zip", "single_fast"):
                 # STREAM each shard's survivors straight into the SST
-                # writer — block building overlaps the remaining shards'
-                # compute + download. (The zip writer drains the feed,
-                # overlapping shard compute with its own encode setup.)
+                # writer — block or single_fast building overlaps the
+                # remaining shards' compute + download. (The zip writer
+                # drains the feed, then encodes.)
                 streamed = True
             else:
                 # Complex groups must fold BEFORE the writer hoists its
@@ -983,7 +983,7 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
                     creation_time if creation_time is not None
                     else int(time.time()),
                     max_output_file_size=compaction.max_output_file_size,
-                    column_family=column_family,
+                    column_family=column_family, stats=stats,
                 )
         except NotSupported:
             # Native builder refused (oversized key / restart overflow):
@@ -1047,8 +1047,8 @@ def _run_device_compaction(env, dbname, icmp, compaction, table_cache,
     if (compaction_filter is None
             and (blob_gc is None or not blob_gc.active)
             and not getattr(table_options, "properties_collector_factories", None)
-            and getattr(table_options, "format", "block") in ("block",
-                                                                "zip")
+            and getattr(table_options, "format", "block") in (
+                "block", "zip", "single_fast")
             and getattr(table_options, "index_type", "binary") == "binary"
             and icmp.user_comparator.name() == dbformat.BYTEWISE.name()):
         try:

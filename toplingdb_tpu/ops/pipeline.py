@@ -9,8 +9,11 @@ bounded three-stage pipeline at user-key-range shard granularity:
   reader   per input file, decode the blocks of one key-range shard per
            native call (windowed preads through a FilePrefetchBuffer),
            writing into a properties-sized preallocated ColumnarKV —
-           independent files scan on parallel threads; a ZipTable input
-           decodes the shard's entry range instead (it has no blocks)
+           independent files scan on parallel threads; a ZipTable or a
+           SingleFastTable decodes the shard's ENTRY RANGE (no blocks):
+           any reader that offers `entry_plane`, `split_candidates`,
+           `entry_lower_bound`, `scan_native_ready` and `scan_into` is
+           planned so, under the span `pipeline.<entry_plane>_scan`
   compute  as soon as EVERY file has scanned past shard s, run the
            device (uniform-shard upload + fused kernel) or host-twin
            (native k-way merge + GC) sort+GC over just that shard's rows
@@ -23,11 +26,11 @@ key lands in exactly one shard), so per-shard GC decisions — snapshot
 stripes, tombstone shadowing, bottommost seqno zeroing — equal the
 global ones and the concatenated survivor stream is byte-identical to
 the serial path's; tests/test_compaction_pipeline.py asserts whole-file
-SST equality. A job reads block files and ZipTables, mixed freely, and
-writes either. Jobs the pipeline does not cover (single_fast or
-dict-compressed block inputs, missing properties, jobs of one shard)
-raise PipelineIneligible and the caller falls back to the serial path,
-which computes the same bytes.
+SST equality. A job reads block files, ZipTables and SingleFastTables,
+mixed freely, and writes any of the three. Jobs the pipeline does not
+cover (plain, cuckoo or dict-compressed block inputs, missing properties,
+jobs of one shard) raise PipelineIneligible and the caller falls back to
+the serial path, which computes the same bytes.
 
 MERGE operands and single-deletes stay on this plane: the compute stage
 returns the rows of such "complex" user-key groups unreduced and flagged,
@@ -81,32 +84,32 @@ _PI32 = ctypes.POINTER(ctypes.c_int32)
 
 
 def pipeline_enabled(table_options=None) -> bool:
-    """Whether the plane WRITES the job's output format: block tables, and
-    zip tables when the native zip data plane is on (scan/merge overlap
-    with the drain-then-encode writer stage: write_tables_zip_columnar
-    collects the chunk feed); other formats consume whole arrays serially.
-    What a job may READ is `_build_plan`'s to say: block files and
-    ZipTables, mixed freely in one job; single_fast and dict-compressed
-    block inputs leave the plane there."""
+    """Whether the plane WRITES the job's output format: block tables and
+    SingleFastTables (both stream a chunk at a time), and zip tables when
+    the native zip data plane is on (write_tables_zip_columnar drains the
+    chunk feed, then encodes); other formats consume whole arrays serially.
+    What a job may READ is `_build_plan`'s to say: block files, ZipTables
+    and SingleFastTables, mixed freely in one job; plain, cuckoo and
+    dict-compressed block inputs leave the plane there."""
     f = getattr(table_options, "format", "block")
     if f == "zip":
         from toplingdb_tpu.table.zip_table import zip_plane_enabled
 
         return zip_plane_enabled()
-    return f == "block"
+    return f in ("block", "single_fast")
 
 
 class _FilePlan:
     """Per-input-file scan plan: block handles grouped by shard, the
     file's slice of the preallocated global buffers, and the row bounds
     of each shard (filled in by the reader as decode progresses). A
-    ZipTable (`zip`) has no blocks: it is planned by entry ranges, so its
-    `groups` are entry ordinals and its row bounds are known with the
-    plan."""
+    ZipTable or a SingleFastTable (`ranged`: the reader's `entry_plane`)
+    has no blocks: it is planned by entry ranges, so its `groups` are
+    entry ordinals and its row bounds are known with the plan."""
 
     __slots__ = ("reader", "pf", "block_offs", "block_lens", "groups",
                  "ne", "rk", "rv", "n_base", "k_base", "v_base",
-                 "row_bounds", "verify", "zip")
+                 "row_bounds", "verify", "ranged")
 
 
 class _Progress:
@@ -254,14 +257,16 @@ def _build_plan(readers, value_slack: bool = False):
     cost nothing): as much again plus 16 B a row, within the int32
     budget.
 
-    Inputs may be block files and ZipTables, in any mix. A block file is
-    planned by block handles (its index separators are its splitter
-    candidates); a ZipTable by entry ranges, `[entry_lower_bound(
-    splitter_i), entry_lower_bound(splitter_i+1))` a shard (group heads
-    are its candidates), its value groups under the file's dictionary as
-    they come. Both fill a slice of the preallocated buffers sized from
-    their TableProperties. single_fast files, and block files compressed
-    under a dictionary, are not planned: the job leaves the plane."""
+    Inputs may be block files, ZipTables and SingleFastTables, in any mix.
+    A block file is planned by block handles (its index separators are its
+    splitter candidates); a reader that bounds and scans ENTRY RANGES (its
+    `entry_plane` names it) by `[entry_lower_bound(splitter_i),
+    entry_lower_bound(splitter_i+1))` a shard, `split_candidates` its
+    candidates. All fill a slice of the preallocated buffers sized from
+    their TableProperties. plain and cuckoo files, and block files
+    compressed under a dictionary, are not planned: the job leaves.
+    Each candidate stands for its file's rows a candidate (PR 32), so the
+    shards stay even in ROWS whatever the formats' mix in a key range."""
     import bisect
 
     from toplingdb_tpu.ops import compaction_kernels as ck
@@ -275,8 +280,8 @@ def _build_plan(readers, value_slack: bool = False):
     infos = []
     tk = tv = tn = 0
     for r in readers:
-        is_zip = hasattr(r, "scan_columnar")
-        if not is_zip and not hasattr(r, "new_index_iterator"):
+        ranged = getattr(r, "entry_plane", None)
+        if not ranged and not hasattr(r, "new_index_iterator"):
             raise PipelineIneligible("non-block input format")
         if getattr(r, "_compression_dict", b""):
             raise PipelineIneligible("dict-compressed input")
@@ -287,11 +292,11 @@ def _build_plan(readers, value_slack: bool = False):
             p.raw_value_size)
         if ne < 0 or rk < 0 or rv < 0 or (ne > 0 and rk == 0):
             raise PipelineIneligible("implausible input properties")
-        if is_zip:
+        if ranged:
             if ne != r.n:
-                raise PipelineIneligible("zip entries disagree with props")
+                raise PipelineIneligible(ranged + " entries disagree with props")
             if ne and not r.scan_native_ready():
-                raise PipelineIneligible("zip scan plane unavailable")
+                raise PipelineIneligible(ranged + " scan plane unavailable")
             handles = None
             sep_uks = r.split_candidates(r.opts.block_size)
         else:
@@ -313,7 +318,7 @@ def _build_plan(readers, value_slack: bool = False):
         raise PipelineIneligible("inputs exceed the int32 columnar budget")
 
     # Splitters: the per-file candidates (a block file's index separator
-    # user keys, one a data block; a ZipTable's group heads), merged, each
+    # user keys, one a data block; an entry-ranged file's own), merged, each
     # standing for its file's rows a candidate, cut where the rows below
     # reach a quantile: shards even in ROWS, whatever the formats' mix in
     # a key range, because a shard past ROW_BUCKET rows meets a second
@@ -351,10 +356,10 @@ def _build_plan(readers, value_slack: bool = False):
             continue
         fp = _FilePlan()
         fp.reader = r
-        fp.zip = handles is None
+        fp.ranged = getattr(r, "entry_plane", None)
         fp.ne, fp.rk, fp.rv = ne, rk, rv
         fp.n_base, fp.k_base, fp.v_base = nb, kb, vb
-        if fp.zip:
+        if fp.ranged:
             # Shard s is entries [groups[s], groups[s+1]): every version
             # of a user key sorts behind its seek key, so the bound of a
             # splitter is the first entry of that user key or a later one.
@@ -365,7 +370,7 @@ def _build_plan(readers, value_slack: bool = False):
                     dbformat.VALUE_TYPE_FOR_SEEK))
                 for spl in splitters] + [ne]
             fp.row_bounds = [nb + e for e in fp.groups]
-            fp.verify = False  # the sections were verified at open
+            fp.verify = False  # the file was verified at open
         else:
             fp.pf = FilePrefetchBuffer(r._f, max_readahead=_PF_READAHEAD,
                                        initial_readahead=_PF_READAHEAD,
@@ -397,11 +402,16 @@ def _build_plan(readers, value_slack: bool = False):
     return kv, files, splitters, (tv, tv + slack)
 
 
-def _scan_zip_file(fi, fp, kv, prog, stats, stats_mu, trace_handle):
-    """Reader worker of a ZipTable: decode one entry range a shard
-    (ZipTableReader.scan_columnar: native key and value-group decoders)
-    into the file's slice of the global buffers. Each range is the span
-    `pipeline.zip_scan`; their wall sums into `zip_scan_usec`."""
+def _scan_ranged_file(fi, fp, kv, prog, stats, stats_mu, trace_handle):
+    """Reader worker of a file planned by entry ranges (a ZipTable, a
+    SingleFastTable): decode one range a shard into the file's slice of
+    the global buffers (the reader's `scan_into`: its native decoders).
+    Each range is the span `pipeline.zip_scan` / `pipeline.sft_scan`;
+    their wall sums into `zip_scan_usec` / `sft_scan_usec` (readers run
+    side by side, a thread a file, so it is a sum over threads). The row
+    bounds of every shard were fixed by the plan, so `mark` follows each
+    range at once; the totals are held against the properties that sized
+    the file's slice."""
     k_used = v_used = 0
     usec = 0
     for s in range(len(fp.groups) - 1):
@@ -410,24 +420,14 @@ def _scan_zip_file(fi, fp, kv, prog, stats, stats_mu, trace_handle):
         e0, e1 = fp.groups[s], fp.groups[s + 1]
         if e1 > e0:
             t0 = time.time()
-            with telemetry.span_under(trace_handle, "pipeline.zip_scan",
-                                      file=fi, shard=s, rows=e1 - e0) as sp:
-                kb, ko, kl, vb, vo, vl = fp.reader.scan_columnar(e0, e1)
-                # The range's value bytes lie densely inside the decoded
-                # groups, from its first row's offset on.
-                v0 = int(vo[0])
-                nk, nv = len(kb), int(vo[-1] + vl[-1]) - v0
-                if k_used + nk > fp.rk or v_used + nv > fp.rv:
-                    raise PipelineIneligible(
-                        "scan totals disagree with props")
-                k0, w0 = fp.k_base + k_used, fp.v_base + v_used
-                r0, r1 = fp.n_base + e0, fp.n_base + e1
-                kv.key_buf[k0:k0 + nk] = kb
-                kv.val_buf[w0:w0 + nv] = vb[v0:v0 + nv]
-                kv.key_offs[r0:r1] = ko + k0
-                kv.key_lens[r0:r1] = kl
-                kv.val_offs[r0:r1] = vo + (w0 - v0)
-                kv.val_lens[r0:r1] = vl
+            with telemetry.span_under(
+                    trace_handle, f"pipeline.{fp.ranged}_scan",
+                    file=fi, shard=s, rows=e1 - e0) as sp:
+                # NotSupported when the range outgrows what the properties
+                # left of the file's slice: the serial path takes the job.
+                nk, nv = fp.reader.scan_into(
+                    e0, e1, kv, fp.n_base + e0, fp.k_base + k_used,
+                    fp.v_base + v_used, fp.rk - k_used, fp.rv - v_used)
                 k_used += nk
                 v_used += nv
                 sp.tag(nbytes=nk + nv)
@@ -436,8 +436,8 @@ def _scan_zip_file(fi, fp, kv, prog, stats, stats_mu, trace_handle):
     if k_used != fp.rk or v_used != fp.rv:
         raise PipelineIneligible("scan totals disagree with props")
     with stats_mu:
-        stats.count_zip_input(fp.reader)
-        stats.zip_scan_usec += usec
+        stats.count_input(fp.reader)
+        stats.count_ranged_scan(fp.ranged, usec)
     prog.finish_file(fi)
 
 
@@ -448,8 +448,8 @@ def _scan_file(fi, fp, kv, prog, splitters, stats, stats_mu,
     lib = native.lib()
     n_shards = len(splitters) + 1
     try:
-        if fp.zip:
-            _scan_zip_file(fi, fp, kv, prog, stats, stats_mu, trace_handle)
+        if fp.ranged:
+            _scan_ranged_file(fi, fp, kv, prog, stats, stats_mu, trace_handle)
             return
         rows = 0
         k_used = v_used = 0
@@ -1019,7 +1019,7 @@ def run_pipelined(env, dbname, icmp, compaction, table_cache, table_options,
                 raise item.exc
             yield item
 
-    writer, counted = write_tables_columnar, {}
+    writer, counted = write_tables_columnar, {"stats": stats}
     if getattr(table_options, "format", "block") == "zip":
         from toplingdb_tpu.table.zip_table import write_tables_zip_columnar
 

@@ -413,7 +413,7 @@ class _SSTSource:
         memo = self._fmemo.get(meta.number)
         if memo is None:
             reader = self._tc.get_reader(meta.number)
-            if hasattr(reader, "scan_columnar"):
+            if getattr(reader, "entry_plane", None) == "zip":
                 # Zip table: served natively through scan_columnar, no
                 # index/prefetch machinery (sections are resident).
                 if not reader.scan_native_ready():
@@ -960,7 +960,7 @@ def make_scan_plane(mems, l0_files, level_runs, table_cache, icmp,
     # them): reject known-bad formats now instead of bailing later.
     for f in l0_files:
         r = table_cache.get_reader(f.number)
-        if hasattr(r, "scan_columnar"):
+        if getattr(r, "entry_plane", None) == "zip":
             if not r.scan_native_ready():
                 if stats is not None:
                     stats.record_tick(_stats_mod.ZIP_PLANE_FALLBACKS)

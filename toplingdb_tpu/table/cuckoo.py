@@ -131,6 +131,7 @@ class CuckooTableReader(SingleFastTableReader):
     probe at most two buckets."""
 
     FOOTER_MAGIC = fmt.CUCKOO_MAGIC
+    entry_plane = None  # not planned by entry ranges: leaves the plane
 
     def _load_hash_index(self) -> None:
         hh = self._meta_handles.get(METAINDEX_CUCKOO_INDEX)

@@ -5,6 +5,10 @@ table/table_factory.cc:18-40, and the adaptive reader, table/adaptive/ in
 /root/reference): builders are chosen by `TableOptions.format`; readers are
 dispatched by footer magic, so a DB can hold a mix of formats (e.g.
 single_fast at L0/L1, block at L2+) and always open every file.
+
+On the device data plane (ops/pipeline.py, ops/device_compaction.py) a job
+reads and writes block, zip and single_fast files, mixed freely; plain and
+cuckoo files are read and written by the per-entry path only.
 """
 
 from __future__ import annotations

@@ -88,6 +88,7 @@ class PlainTableBuilder(SingleFastTableBuilder):
 
 class PlainTableReader(SingleFastTableReader):
     FOOTER_MAGIC = fmt.PLAIN_MAGIC
+    entry_plane = None  # not planned by entry ranges: leaves the plane
 
     def _load_hash_index(self) -> None:
         self._hash_buckets = None

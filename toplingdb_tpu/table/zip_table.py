@@ -358,6 +358,7 @@ class ZipTableReader:
     directory stay resident, value groups decompress lazily (cached)."""
 
     FOOTER_MAGIC = fmt.ZIP_MAGIC
+    entry_plane = "zip"  # planned by entry ranges: `pipeline.zip_scan`, `zip_*`
 
     def __init__(self, rfile, icmp: InternalKeyComparator,
                  options: TableOptions | None = None, block_cache=None,
@@ -684,6 +685,30 @@ class ZipTableReader:
         val_lens = np.ascontiguousarray(ls[e0 - first: e1 - first])
         return (key_out, key_offs, key_lens, val_out[:vcap], val_offs,
                 val_lens)
+
+    def scan_into(self, e0: int, e1: int, kv, row0: int, k0: int, v0: int,
+                  k_cap: int, v_cap: int) -> tuple[int, int]:
+        """scan_columnar(e0, e1) laid into the columnar buffers `kv`: rows
+        from `row0`, key bytes from `k0`, value bytes from `v0`, at most
+        `k_cap` and `v_cap` of them (NotSupported beyond). Returns the
+        (key, value) bytes the range holds."""
+        kb, ko, kl, vb, vo, vl = self.scan_columnar(e0, e1)
+        if not len(ko):
+            return 0, 0
+        # The range's value bytes lie densely inside the decoded groups,
+        # from its first row's offset on.
+        w0 = int(vo[0])
+        nk, nv = len(kb), int(vo[-1] + vl[-1]) - w0
+        if nk > k_cap or nv > v_cap:
+            raise NotSupported("zip scan: the range outgrows its buffers")
+        r1 = row0 + len(ko)
+        kv.key_buf[k0:k0 + nk] = kb
+        kv.val_buf[v0:v0 + nv] = vb[w0:w0 + nv]
+        kv.key_offs[row0:r1] = ko + k0
+        kv.key_lens[row0:r1] = kl
+        kv.val_offs[row0:r1] = vo + (v0 - w0)
+        kv.val_lens[row0:r1] = vl
+        return nk, nv
 
     def native_get_handle(self, smallest_uk: bytes, largest_uk: bytes):
         """Handle for the native point-read engine. Unlike the block

@@ -391,6 +391,9 @@ ABI_FUZZ_SYMS = (
     "tpulsm_zip_encode_values", "tpulsm_zip_decode_keys",
     "tpulsm_zip_group_decode", "tpulsm_zip_table_handle_new",
     "tpulsm_zip_train_dict",
+    # SingleFastTable entry-range scan: the image and its offset array
+    # come from a file, every offset and varint is checked against them.
+    "tpulsm_sft_scan",
 )
 
 _BLOB_NAMES = ("data", "block", "file_buf", "rep", "target",
@@ -418,6 +421,8 @@ _DERIVED_ELEMS = {
     ("tpulsm_zip_decode_keys", "key_lens"): lambda v: v["e1"] - v["e0"],
     ("tpulsm_zip_group_decode", "raw_offs"):
         lambda v: v["g1"] - v["g0"] + 1,
+    **{("tpulsm_sft_scan", a): lambda v: v["e1"] - v["e0"]
+       for a in ("key_offs", "key_lens", "val_offs", "val_lens")},
 }
 
 # Ranges for scalars whose default 0..3 draw would pin a kernel in its
@@ -525,8 +530,9 @@ def shapes_from_contract(rng, sym, sigs, bindings, rows, data=b""):
         else:
             # Untrusted index/length array: straddle the valid range.
             hi = max(len(data), 2)
+            # (an unsigned array takes the negatives wrapped: far out)
             arr = np.array([rng.randrange(-4, 2 * hi)
-                            for _ in range(max(n, 1))], dt)
+                            for _ in range(max(n, 1))], np.int64).astype(dt)
         keepalive.append(arr)
         args.append(ctypes.cast(arr.ctypes.data, ctypes.POINTER(ct)))
     return args, keepalive
