@@ -107,6 +107,55 @@ print("SANITIZED_REPLAY_OK")
 """
 
 
+# The deferred put encoder (fastget.c wb_put_values) under the sanitizer:
+# the wire-image parity corpus of tests/test_write_batch.py, then single
+# settles whose PyByteArray_Resize carries the image across 2^16 and 2^20
+# bytes, each against the Python encode of the same records.
+_ENCODER_CHILD = r"""
+import random, sys
+import pytest
+from toplingdb_tpu import native
+from toplingdb_tpu.db import write_batch as wbm
+from toplingdb_tpu.db.write_batch import WriteBatch
+
+assert native._SANITIZE == {mode!r}, "sanitize mode did not take"
+fn = native.wb_put_values()
+assert native.lib() is not None and fn is not None, \
+    "sanitized .so failed to build/load"
+sys.path.insert(0, "tests")
+import test_write_batch as twb
+
+for pb in (0, 8):
+    for seed in range(4):
+        with pytest.MonkeyPatch.context() as mp:
+            twb.test_deferred_put_wire_image_parity(mp, "native", pb, seed)
+
+rng = random.Random(7)
+
+
+def image(pairs, enc, split):
+    wbm._enc_fn = enc
+    b = WriteBatch()
+    for i, (k, v) in enumerate(pairs):
+        if i == split:
+            b.data_size()  # settle: the next one grows from here
+        b.put(k, v)
+    return b.data()
+
+
+for edge in (1 << 16, 1 << 20):
+    pairs, size = [], 12
+    while size < edge + 4096:
+        k = rng.randbytes(rng.randrange(1, 40))
+        v = rng.randbytes(rng.choice((0, 20, 127, 128, 3000, 40000)))
+        pairs.append((k, v))
+        size += 1 + 5 + len(k) + len(v)
+    for split in (0, len(pairs) // 2, len(pairs) - 1):
+        assert image(pairs, fn, split) == image(pairs, None, split)
+print("SANITIZED_REPLAY_OK")
+"""
+
+
 def _libasan() -> str | None:
     gxx = shutil.which("g++")
     if gxx is None:
@@ -167,3 +216,16 @@ def test_concurrent_run_inserts_under_sanitizer(mode, tmp_path):
     else:
         extra = {"UBSAN_OPTIONS": "halt_on_error=1"}
     _replay(mode, extra, tmp_path, child=_CONCURRENT_CHILD)
+
+
+@pytest.mark.parametrize("mode", ["asan", "undefined"])
+def test_deferred_put_encoder_under_sanitizer(mode, tmp_path):
+    if mode == "asan":
+        lib = _libasan()
+        if lib is None:
+            pytest.skip("libasan not found")
+        extra = {"LD_PRELOAD": lib,
+                 "ASAN_OPTIONS": "detect_leaks=0:abort_on_error=1"}
+    else:
+        extra = {"UBSAN_OPTIONS": "halt_on_error=1"}
+    _replay(mode, extra, tmp_path, child=_ENCODER_CHILD)

@@ -477,3 +477,126 @@ def test_group_commit_run_insert_reads_back_sorted(tmp_path, shape, n):
             n if n >= 2 else 0)
     finally:
         db.close()
+
+
+# -- deferred puts reach the plane as today's wire images -------------------
+
+class _EagerBatch(WriteBatch):
+    """Today's encode: every put through _add_record at once."""
+
+    def put(self, key, value, cf=0):
+        from toplingdb_tpu.db.dbformat import ValueType
+
+        self._add_record(ValueType.VALUE, cf, key, value)
+
+
+def _crash_copy(d):
+    """Copies the open DB's directory as a crash would leave it (close
+    flushes the memtable and empties the WAL); returns its WAL bytes."""
+    shutil.copytree(d, d + "-crash")
+    return _wal_bytes(d + "-crash")
+
+
+def _deferred_db(d, pb=0, **kw):
+    stats = st.Statistics()
+    return DB.open(d, Options(create_if_missing=True, statistics=stats,
+                              protection_bytes_per_key=pb, **kw)), stats
+
+
+@pytest.mark.parametrize("pb", [0, 8])
+def test_deferred_batch_native_commit_and_replay(tmp_path, pb):
+    """A batch whose puts are still pending at DB.write commits through the
+    native group plane (not the fallback), ticks every put as deferred, and
+    reads back after close and WAL replay; the WAL holds today's bytes."""
+    wals = {}
+    for cls in (WriteBatch, _EagerBatch):
+        d = str(tmp_path / cls.__name__)
+        db, stats = _deferred_db(d, pb)
+        n = 0
+        for i in range(0, 600, 50):
+            b = cls(protection_bytes_per_key=pb)
+            for j in range(i, i + 50):
+                b.put(b"k%06d" % j, b"v" * (j % 200))
+            n += 50
+            assert bool(b._pend) == (cls is WriteBatch)
+            db.write(b, WriteOptions(sync=True))
+        assert stats.get_ticker_count(st.WRITE_GROUP_NATIVE_COMMITS) == 12
+        assert stats.get_ticker_count(st.WRITE_GROUP_FALLBACKS) == 0
+        assert stats.get_ticker_count(st.NUMBER_KEYS_WRITTEN) == n
+        assert stats.get_ticker_count(st.WRITE_BATCH_DEFERRED_RECORDS) == (
+            n if cls is WriteBatch else 0)
+        wals[cls] = _crash_copy(d)
+        db.close()
+        for path in (d, d + "-crash"):  # after close; after WAL replay
+            with DB.open(path, Options(protection_bytes_per_key=pb)) as r:
+                for j in range(600):
+                    assert r.get(b"k%06d" % j) == b"v" * (j % 200)
+    assert wals[WriteBatch] == wals[_EagerBatch]
+    assert any(wals[WriteBatch].values())
+
+
+def test_deferred_group_frames_todays_wal_record(tmp_path):
+    """Three pending batches led as ONE group frame one WAL record, byte
+    for byte the record the same batches encoded at once frame."""
+    import threading
+    import time
+
+    wals = {}
+    for cls in (WriteBatch, _EagerBatch):
+        d = str(tmp_path / cls.__name__)
+        db, stats = _deferred_db(d, pb=8)
+        snapshot = db._snapshot_group
+
+        def snap(leader, db=db, snapshot=snapshot):
+            deadline = time.monotonic() + 30
+            while len(db._writers) < 3 and time.monotonic() < deadline:
+                time.sleep(0.001)
+            return snapshot(leader)
+
+        db._snapshot_group = snap
+        batches = []
+        for w in range(3):
+            b = cls(protection_bytes_per_key=8)
+            for j in range(40):
+                b.put(b"g%d-%04d" % (w, j), b"x" * (j * 7 % 150))
+            batches.append(b)
+        ts = []
+        for w, b in enumerate(batches):
+            t = threading.Thread(target=db.write, args=(b,))
+            t.start()
+            ts.append(t)
+            while len(db._writers) < w + 1 and t.is_alive():
+                time.sleep(0.001)  # queue in order: w0 leads, w1, w2
+        for t in ts:
+            t.join(60)
+        assert stats.get_ticker_count(st.WRITE_GROUP_LED) == 1
+        assert stats.get_ticker_count(st.WRITE_GROUP_FOLLOWERS) == 2
+        assert stats.get_ticker_count(st.WRITE_GROUP_NATIVE_COMMITS) == 1
+        assert stats.get_ticker_count(st.WRITE_BATCH_DEFERRED_RECORDS) == (
+            120 if cls is WriteBatch else 0)
+        db.flush_wal(sync=True)
+        wals[cls] = _crash_copy(d)
+        db.close()
+        with DB.open(d + "-crash", Options(protection_bytes_per_key=8)) as r:
+            assert r.get(b"g2-0039") == b"x" * (39 * 7 % 150)
+    assert wals[WriteBatch] == wals[_EagerBatch]
+    assert any(wals[WriteBatch].values())
+
+
+def test_deferred_ticker_silent_for_merges(tmp_path):
+    """A merge-only stream never takes the deferred path."""
+    import struct
+
+    from toplingdb_tpu.utils.merge_operator import UInt64AddOperator
+
+    db, stats = _deferred_db(str(tmp_path / "m"),
+                             merge_operator=UInt64AddOperator())
+    for i in range(10):
+        b = WriteBatch()
+        for j in range(20):
+            b.merge(b"c%03d" % j, struct.pack("<Q", 1))
+        db.write(b)
+    assert stats.get_ticker_count(st.NUMBER_KEYS_WRITTEN) == 200
+    assert stats.get_ticker_count(st.WRITE_BATCH_DEFERRED_RECORDS) == 0
+    assert struct.unpack("<Q", db.get(b"c007"))[0] == 10
+    db.close()

@@ -1084,6 +1084,12 @@ class DB:
         if batch.is_empty():
             return self.versions.last_sequence  # trivially-satisfied token
         self._check_open()  # fail fast before any stall sleep
+        if batch._pend:
+            batch._settle()  # the wire image, in the writer's own thread
+        stats = self.stats
+        if batch._n_deferred and stats is not None:
+            stats.record_tick(_st.WRITE_BATCH_DEFERRED_RECORDS,
+                              batch._n_deferred)
         if self._protection:
             wp = self._write_plane
             if wp is None:
@@ -1111,7 +1117,6 @@ class DB:
             root = tracer.start("db.write", records=batch.count(),
                                 bytes=batch.data_size(),
                                 sync=bool(opts.sync))
-        stats = self.stats
         if stats is None and tracer is None:
             return self._write_impl(batch, opts, on_sequenced)
         # time/_st are module-level imports: no per-call import

@@ -16,6 +16,12 @@ sequence numbers.
 Column families: non-default-CF records use the CF-prefixed record types
 (0x80 | base_type) followed by a varint32 column family id — the same scheme
 as the reference's kTypeColumnFamily* records.
+
+Deferred puts: a default-CF put of two exact `bytes` is kept as a (key,
+value) reference (immutable, so it cannot change after the put) and the
+pending run is encoded in ONE native pass (native fastget.c wb_put_values)
+when the wire image is first read, or before any other record is appended.
+Every reader of `_rep` settles first; `_count` is always current.
 """
 
 from __future__ import annotations
@@ -69,6 +75,31 @@ def _native_protect(rep: bytes, pb: int, strip_cf: bool):
     return np.frombuffer(out, dtype=np.uint64)
 
 
+_VALUE = int(ValueType.VALUE)
+_ENC_UNRESOLVED = object()
+_enc_fn = _ENC_UNRESOLVED  # None once resolved-absent (Python encode)
+
+
+def _encode_values(rep: bytearray, pend: list) -> int:
+    """Appends a VALUE record per pending (key, value) to rep and empties
+    pend; returns how many. One native call, or the same bytes in Python."""
+    global _enc_fn
+    fn = _enc_fn
+    if fn is _ENC_UNRESOLVED:
+        from toplingdb_tpu import native
+
+        fn = _enc_fn = native.wb_put_values()
+    if fn is not None:
+        return fn(rep, pend)
+    for k, v in pend:
+        rep.append(_VALUE)
+        coding.put_length_prefixed_slice(rep, k)
+        coding.put_length_prefixed_slice(rep, v)
+    n = len(pend)
+    pend.clear()
+    return n
+
+
 def _prot_eq(a, b) -> bool:
     """Value equality of two protection vectors (list or uint64 ndarray)."""
     if type(a) is list and type(b) is list:
@@ -103,6 +134,11 @@ class WriteBatch:
         # interiors as the oracle (ISSUE 7 fallback matrix).
         self._has_wide = False
         self._n_merge = 0
+        # Deferred default-CF puts not yet in _rep (see module doc), and
+        # how many records that path has encoded since the last clear()
+        # (DB.write ticks it as write.batch.deferred.records).
+        self._pend: list = []
+        self._n_deferred = 0
         if data is not None:
             if len(data) < HEADER_SIZE:
                 raise Corruption("write batch header too small")
@@ -121,7 +157,11 @@ class WriteBatch:
     # -- mutation -------------------------------------------------------
 
     def put(self, key: bytes, value: bytes, cf: int = 0) -> None:
-        self._add_record(ValueType.VALUE, cf, key, value)
+        if cf == 0 and type(key) is bytes and type(value) is bytes:
+            self._pend.append((key, value))
+            self._count += 1
+        else:
+            self._add_record(ValueType.VALUE, cf, key, value)
 
     def delete(self, key: bytes, cf: int = 0) -> None:
         self._add_record(ValueType.DELETION, cf, key)
@@ -144,10 +184,14 @@ class WriteBatch:
         self._add_record(ValueType.RANGE_DELETION, cf, begin, end)
 
     def put_log_data(self, blob: bytes) -> None:
+        if self._pend:
+            self._settle()
         self._rep.append(ValueType.LOG_DATA)
         coding.put_length_prefixed_slice(self._rep, blob)
 
     def _add_record(self, t: ValueType, cf: int, *slices: bytes) -> None:
+        if self._pend:
+            self._settle()
         rep = self._rep
         if t == ValueType.MERGE:
             self._n_merge += 1
@@ -170,8 +214,14 @@ class WriteBatch:
                 coding.put_length_prefixed_slice(rep, s)
         self._count += 1
 
+    def _settle(self) -> None:
+        """Encodes the deferred puts into _rep (every _rep reader first)."""
+        self._n_deferred += _encode_values(self._rep, self._pend)
+
     def clear(self) -> None:
         self._rep = bytearray(HEADER_SIZE)
+        self._pend = []
+        self._n_deferred = 0
         self._simple = True
         self._count = 0
         self._prot_n = 0
@@ -182,6 +232,10 @@ class WriteBatch:
 
     def append_from(self, other: "WriteBatch") -> None:
         """Group-commit helper: append other's records to self."""
+        if self._pend:
+            self._settle()
+        if other._pend:
+            other._settle()
         self._rep += other._rep[HEADER_SIZE:]
         self._count += other.count()
         self._simple = self._simple and other._simple
@@ -281,11 +335,15 @@ class WriteBatch:
         self._count = n
 
     def data(self) -> bytes:
+        if self._pend:
+            self._settle()
         # The header count is maintained lazily; patch it on export.
         self._rep[8:12] = coding.encode_fixed32(self._count)
         return bytes(self._rep)
 
     def data_size(self) -> int:
+        if self._pend:
+            self._settle()
         return len(self._rep)
 
     def is_empty(self) -> bool:
@@ -304,6 +362,8 @@ class WriteBatch:
 
     def entries_cf(self):
         """Yields (cf_id, value_type, key, value_or_none)."""
+        if self._pend:
+            self._settle()
         rep = self._rep
         off = HEADER_SIZE
         n = 0

@@ -642,6 +642,14 @@ def fastmultiget():
     return getattr(_fastget_mod, "multiget", None)
 
 
+def wb_put_values():
+    """The C-extension deferred put encoder (fastget.c wb_put_values), or
+    None when unavailable: WriteBatch then encodes in Python."""
+    if fastget() is None:
+        return None
+    return getattr(_fastget_mod, "wb_put_values", None)
+
+
 def fastget():
     """The C-extension fast path for tpulsm_getctx_get (fastget.c), or
     None when unavailable (missing Python headers / toolchain): callers

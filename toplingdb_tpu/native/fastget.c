@@ -12,6 +12,11 @@
  *   False  native fallback (the Python state machine must run)
  * The GIL is released around the native chain walk, matching the ctypes
  * path's concurrency (ctx is per-thread).
+ *
+ * wb_put_values is the write side's one native pass: WriteBatch.put keeps
+ * default-CF records as (key, value) references and the batch's wire
+ * image is encoded here when it is first read (db/write_batch.py _settle).
+ * It needs no bound symbols.
  */
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
@@ -236,6 +241,82 @@ static PyObject* fg_get(PyObject* self, PyObject* const* args,
   Py_RETURN_FALSE; /* fallback: run the Python chain */
 }
 
+static inline Py_ssize_t varint32_len(uint32_t v) {
+  Py_ssize_t n = 1;
+  while (v >= 0x80) {
+    v >>= 7;
+    n++;
+  }
+  return n;
+}
+
+static inline char* put_varint32(char* p, uint32_t v) {
+  while (v >= 0x80) {
+    *p++ = (char)(v | 0x80);
+    v >>= 7;
+  }
+  *p++ = (char)v;
+  return p;
+}
+
+/* wb_put_values(rep: bytearray, pending: list[tuple[bytes, bytes]]) -> int
+ * Appends one default-CF VALUE record per pair to rep in ONE resize +
+ * memcpy pass: 0x01 | varint32 klen | key | varint32 vlen | value, byte
+ * for byte what WriteBatch._add_record writes (lengths masked to 32 bits
+ * like coding.encode_varint32). Empties `pending` and returns how many
+ * records it encoded. The GIL is held throughout: nothing else can touch
+ * the list or the bytearray between the sizing and the copy. */
+static PyObject* fg_wb_put_values(PyObject* self, PyObject* const* args,
+                                  Py_ssize_t nargs) {
+  (void)self;
+  if (nargs != 2 || !PyByteArray_CheckExact(args[0]) ||
+      !PyList_CheckExact(args[1])) {
+    PyErr_SetString(PyExc_TypeError,
+                    "wb_put_values(rep: bytearray, pending: list)");
+    return NULL;
+  }
+  PyObject* rep = args[0];
+  PyObject* pend = args[1];
+  Py_ssize_t n = PyList_GET_SIZE(pend);
+  Py_ssize_t add = 0;
+  for (Py_ssize_t i = 0; i < n; i++) {
+    PyObject* kv = PyList_GET_ITEM(pend, i);
+    if (!PyTuple_CheckExact(kv) || PyTuple_GET_SIZE(kv) != 2 ||
+        !PyBytes_CheckExact(PyTuple_GET_ITEM(kv, 0)) ||
+        !PyBytes_CheckExact(PyTuple_GET_ITEM(kv, 1))) {
+      PyErr_SetString(PyExc_TypeError,
+                      "pending records must be (bytes, bytes) pairs");
+      return NULL;
+    }
+    Py_ssize_t kl = PyBytes_GET_SIZE(PyTuple_GET_ITEM(kv, 0));
+    Py_ssize_t vl = PyBytes_GET_SIZE(PyTuple_GET_ITEM(kv, 1));
+    Py_ssize_t rec = 1 + varint32_len((uint32_t)kl) + kl +
+                     varint32_len((uint32_t)vl) + vl;
+    if (rec > PY_SSIZE_T_MAX - add) return PyErr_NoMemory();
+    add += rec;
+  }
+  Py_ssize_t old = PyByteArray_GET_SIZE(rep);
+  if (add > PY_SSIZE_T_MAX - old) return PyErr_NoMemory();
+  if (PyByteArray_Resize(rep, old + add) < 0) return NULL;
+  char* p = PyByteArray_AS_STRING(rep) + old;
+  for (Py_ssize_t i = 0; i < n; i++) {
+    PyObject* kv = PyList_GET_ITEM(pend, i);
+    PyObject* k = PyTuple_GET_ITEM(kv, 0);
+    PyObject* v = PyTuple_GET_ITEM(kv, 1);
+    Py_ssize_t kl = PyBytes_GET_SIZE(k);
+    Py_ssize_t vl = PyBytes_GET_SIZE(v);
+    *p++ = 0x01; /* ValueType.VALUE */
+    p = put_varint32(p, (uint32_t)kl);
+    memcpy(p, PyBytes_AS_STRING(k), (size_t)kl);
+    p += kl;
+    p = put_varint32(p, (uint32_t)vl);
+    memcpy(p, PyBytes_AS_STRING(v), (size_t)vl);
+    p += vl;
+  }
+  if (PyList_SetSlice(pend, 0, n, NULL) < 0) return NULL;
+  return PyLong_FromSsize_t(n);
+}
+
 static PyMethodDef fg_methods[] = {
     {"bind", fg_bind, METH_VARARGS,
      "bind(native_so_path): resolve the getctx symbols"},
@@ -243,6 +324,9 @@ static PyMethodDef fg_methods[] = {
      "get(ctx_addr, key, snap_seq) -> bytes | None | False"},
     {"multiget", (PyCFunction)(void (*)(void))fg_multiget, METH_FASTCALL,
      "multiget(ctx_addr, keys, snap_seq) -> (results, counters) | None"},
+    {"wb_put_values", (PyCFunction)(void (*)(void))fg_wb_put_values,
+     METH_FASTCALL,
+     "wb_put_values(rep, pending) -> n: encode deferred default-CF puts"},
     {NULL, NULL, 0, NULL},
 };
 

@@ -926,6 +926,7 @@ class WriteUnpreparedTransaction(WritePreparedTransaction):
         if self._spill_off is None:
             return self.wbwi.batch
         full = self.wbwi.batch
+        full._settle()  # the spill offset is a byte offset into _rep
         part = WriteBatch()
         part._rep = bytearray(part._rep[:HEADER_SIZE])
         part._rep += full._rep[self._spill_off:]
@@ -948,7 +949,7 @@ class WriteUnpreparedTransaction(WritePreparedTransaction):
         self._txn_db._wp_journal(self, finalized=False)  # intent first
         self._txn_db._wp_write_batch(self, pending)
         self._txn_db._wp_journal(self, finalized=False)  # record the range
-        self._spill_off = len(self.wbwi.batch._rep)
+        self._spill_off = self.wbwi.batch.data_size()
         self._spill_count = self.wbwi.batch.count()
 
     def put(self, key: bytes, value: bytes) -> None:

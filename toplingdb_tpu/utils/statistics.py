@@ -94,6 +94,10 @@ WRITE_GROUP_FOLLOWERS = "write.group.followers"
 WRITE_GROUP_NATIVE_COMMITS = "write.group.native.commits"
 WRITE_GROUP_FALLBACKS = "write.group.fallbacks"
 WRITE_GROUP_FSYNCS_COALESCED = "write.group.fsyncs.coalesced"
+# Records that WriteBatch.put kept as references and encoded in one native
+# pass when the batch was first read (db/write_batch.py _settle): ticked by
+# DB.write once a batch; over number.keys.written, how often that engages.
+WRITE_BATCH_DEFERRED_RECORDS = "write.batch.deferred.records"
 # The memtable insert by itself (db/memtable.py books them): the native
 # rep's own clock around the insert of a write group (the plane's out[7])
 # or of one wire image / parsed batch, the records those calls took, and
