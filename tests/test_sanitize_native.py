@@ -43,12 +43,16 @@ print("SANITIZED_REPLAY_OK")
 
 # Four writers hand the skiplist overlapping runs (insert_wb releases the
 # GIL: their sorts, interleaved searches and CAS splices truly overlap)
-# while two readers iterate and seek; afterwards the list holds the union.
+# while two readers iterate and seek; a fifth commits 500-record groups
+# through the fused plane, whose apply shares each group's sorted run with
+# the apply pool's worker (on a multi-core run the worker must take some);
+# afterwards the list holds the union.
 _CONCURRENT_CHILD = r"""
-import struct, threading
+import ctypes, os, struct, threading
 from toplingdb_tpu import native
 from toplingdb_tpu.db.memtable import NativeSkipListRep
 from toplingdb_tpu.db.write_batch import WriteBatch
+from toplingdb_tpu.tools import fuzz_native as fz
 
 assert native._SANITIZE == {mode!r}, "sanitize mode did not take"
 assert native.lib() is not None, "sanitized .so failed to build/load"
@@ -68,11 +72,27 @@ for w in range(WRITERS):
             (wb.delete(k) if t == 0 else wb.put(k, v))
             want.append(((k, MAXP - ((first + i) << 8 | t)), v))
         images.append((w, wb.data(), first))
+GROUP = 500
+groups = []
+for g in range(10):
+    first = 1 + WRITERS * BATCHES * PER + g * GROUP
+    wb = WriteBatch()
+    for i in range(GROUP):
+        k = struct.pack(">Q", (g * 6151 + i * 389) % 1500) + b"g"
+        wb.put(k, b"g%d-%d" % (g, i))
+        want.append(((k, MAXP - ((first + i) << 8 | 1)), b"g%d-%d" % (g, i)))
+    groups.append((wb.data(), first))
 done = threading.Event()
-errors = []
+errors, by_worker = [], []
 
 def writer(w):
     try:
+        if w == WRITERS:  # the fused plane's two-thread apply
+            out = (ctypes.c_int64 * 11)()
+            for data, first in groups:
+                assert fz.group_commit_insert(rep, data, first, out) == GROUP
+                by_worker.append(out[10])
+            return
         for ww, data, first in images:
             if ww == w:
                 assert rep.insert_wb(data, first)[0] == PER
@@ -91,7 +111,7 @@ def reader():
     except BaseException as e:  # noqa: BLE001
         errors.append(repr(e))
 
-ws = [threading.Thread(target=writer, args=(w,)) for w in range(WRITERS)]
+ws = [threading.Thread(target=writer, args=(w,)) for w in range(WRITERS + 1)]
 rs = [threading.Thread(target=reader) for _ in range(2)]
 for t in rs + ws:
     t.start()
@@ -102,7 +122,8 @@ for t in rs:
     t.join()
 assert not errors, errors
 assert list(rep.iter_all()) == sorted(want)
-assert len(rep) == WRITERS * BATCHES * PER
+assert len(rep) == WRITERS * BATCHES * PER + len(groups) * GROUP
+assert (sum(by_worker) > 0) == (len(os.sched_getaffinity(0)) >= 2), by_worker
 print("SANITIZED_REPLAY_OK")
 """
 

@@ -318,6 +318,9 @@ def test_native_interior_spans_when_plane_available(tmp_path):
         names = {s.name for s in wt.spans}
         if db._write_plane:  # plane resolved: interiors must surface
             assert "native.memtable_insert" in names
+            # 50 records stay on the writer's thread (under kPairApply).
+            ins = [s for s in wt.spans if s.name == "native.memtable_insert"]
+            assert ins[0].tags["threads"] == 1
     finally:
         db.close()
 

@@ -479,6 +479,200 @@ def test_group_commit_run_insert_reads_back_sorted(tmp_path, shape, n):
         db.close()
 
 
+# -- a group's sorted run shared with the apply pool's worker ---------------
+
+def _native_const(name: str) -> int:
+    """A constant of native/tpulsm_native.cc (`static const ... = <int>;`)."""
+    import re
+
+    with open(os.path.join(REPO, "toplingdb_tpu", "native",
+                           "tpulsm_native.cc")) as f:
+        m = re.search(r"static const \w+ %s = (\d+);" % name, f.read())
+    return int(m.group(1))
+
+
+PAIR_APPLY = _native_const("kPairApply")
+MAX_RUN = _native_const("kMaxRun")
+# Around a chunk (64 records), the cut, a run (kMaxRun) and the trie's 512.
+GROUP_SIZES = sorted({1, 31, 64, 65, 255, PAIR_APPLY - 1, PAIR_APPLY, 500, 511,
+                      512, 4097, 2 * MAX_RUN + PAIR_APPLY + 3})
+MULTI_CORE = len(os.sched_getaffinity(0)) >= 2
+
+
+def _plane_out():
+    import ctypes
+
+    return (ctypes.c_int64 * 11)()
+
+
+@pytest.mark.parametrize("n", GROUP_SIZES)
+@pytest.mark.parametrize("shape", ["k8", "same_key_3_times",
+                                   "deletes_and_merges"])
+@pytest.mark.parametrize("kind", ["skiplist", "trie"])
+def test_group_apply_matches_insert_wb(kind, shape, n):
+    """The fused plane's apply. Skiplist: a group's run of kPairApply
+    records or more is sorted once and inserted by chunks by the calling
+    thread and the pool's worker. Trie: a group of 512 records or more is
+    cut into record ranges over the pool. Either rep holds exactly what its
+    one-thread insert_wb makes of the same image: every (user key, seq,
+    type, value), the count, the byte delta and the delete count; a replay
+    of the same records (every one an exact duplicate) replaces the values
+    in place on both."""
+    from toplingdb_tpu.db.memtable import NativeSkipListRep, NativeTrieRep
+    from toplingdb_tpu.tools import fuzz_native as fz
+
+    rep = {"skiplist": NativeSkipListRep, "trie": NativeTrieRep}[kind]
+    cut = PAIR_APPLY if kind == "skiplist" else 512
+    first_seq = 7000
+    ops = make_ops(shape, n)
+    one, two = rep(), rep()
+    for records in (ops, replayed(ops)):
+        data = make_batch(records).data()
+        count, delta, deletes = one.insert_wb(data, first_seq)
+        out = _plane_out()
+        assert fz.group_commit_insert(two, data, first_seq, out) == n == count
+        assert (out[2], out[3]) == (delta, deletes)
+        assert deletes == sum(t in (D, SD) for t, _, _ in records)
+        assert 0 <= out[10] <= n
+        if n < cut:
+            assert out[10] == 0
+        assert len(two) == len(one) == n
+        rows = list(two.iter_all())
+        assert rows == list(one.iter_all()) == sorted_rows(records, first_seq)
+
+
+def test_group_apply_on_two_threads_under_readers():
+    """Readers iterate and seek while 500-record groups go in on two
+    threads: every row a reader meets is a whole record that was written
+    (no half-linked node), in the list's order; afterwards the list holds
+    every group, and the worker took records."""
+    import threading
+
+    from toplingdb_tpu.db.memtable import NativeSkipListRep
+    from toplingdb_tpu.tools import fuzz_native as fz
+
+    rep = NativeSkipListRep()
+    groups, want = [], {}
+    seq = 1
+    for g in range(40):
+        ops = make_ops("same_key_3_times", 500 + g)[:500]
+        groups.append((make_batch(ops).data(), seq))
+        for (uk, inv), v in sorted_rows(ops, seq):
+            want[(uk, inv)] = v
+        seq += len(ops)
+    done = threading.Event()
+    errors, worker_records = [], []
+
+    def writer():
+        try:
+            out = _plane_out()
+            for data, first in groups:
+                assert fz.group_commit_insert(rep, data, first, out) == 500
+                worker_records.append(out[10])
+        except BaseException as e:  # noqa: BLE001
+            errors.append(repr(e))
+        finally:
+            done.set()
+
+    def reader():
+        try:
+            while not done.is_set():
+                rows = list(rep.iter_all())
+                assert rows == sorted(rows)
+                for skey, v in rows:
+                    assert want[skey] == v
+                for skey, v in rows[::53]:
+                    assert rep.entry_at(rep.pos_seek_ge(skey)) == (skey, v)
+        except BaseException as e:  # noqa: BLE001
+            errors.append(repr(e))
+
+    ts = [threading.Thread(target=reader) for _ in range(2)]
+    ts.append(threading.Thread(target=writer))
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(120)
+        assert not t.is_alive()
+    assert not errors, errors
+    assert list(rep.iter_all()) == sorted(want.items())
+    assert (sum(worker_records) > 0) == MULTI_CORE
+
+
+def test_concurrent_group_applies_do_not_queue_for_the_pool():
+    """Two leaders apply 300-record groups at once (the staged write modes
+    insert outside the DB's mutex): a group that finds the pool busy with
+    the other's job goes on its own thread instead of waiting for it, and
+    the list holds every record of both exactly once."""
+    import threading
+
+    from toplingdb_tpu.db.memtable import NativeSkipListRep
+    from toplingdb_tpu.tools import fuzz_native as fz
+
+    rep = NativeSkipListRep()
+    per, rounds = 300, 60
+    work = {w: [] for w in range(2)}
+    want = []
+    seq = 1
+    for r in range(rounds):
+        for w in range(2):
+            ops = [(V, b"w%d-%08d" % (w, (r * 7919 + i * 104729) % 10**8),
+                    b"v%d.%d" % (r, i)) for i in range(per)]
+            work[w].append((make_batch(ops).data(), seq))
+            want += sorted_rows(ops, seq)
+            seq += per
+    start = threading.Barrier(2)
+    errors, by_worker = [], []
+
+    def leader(w):
+        try:
+            out = _plane_out()
+            start.wait()
+            for data, first in work[w]:
+                assert fz.group_commit_insert(rep, data, first, out) == per
+                by_worker.append(out[10])
+        except BaseException as e:  # noqa: BLE001
+            errors.append(repr(e))
+
+    ts = [threading.Thread(target=leader, args=(w,)) for w in range(2)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(120)
+        assert not t.is_alive()
+    assert not errors, errors
+    assert len(rep) == len(want) == 2 * per * rounds
+    assert list(rep.iter_all()) == sorted(want)
+    assert len(by_worker) == 2 * rounds
+    assert all(0 <= x <= per for x in by_worker)
+
+
+@pytest.mark.parametrize("n", [10, 500])
+def test_parallel_records_ticker(tmp_path, n):
+    """memtable.insert.parallel.records books out[10]: it ticks on
+    500-put batches (the worker takes chunks) and stays 0 on 10-put
+    batches, which stay on the writer's thread."""
+    stats = st.Statistics()
+    db = DB.open(str(tmp_path / "db"), Options(
+        create_if_missing=True, statistics=stats,
+        write_buffer_size=256 << 20))
+    try:
+        for i in range(100):
+            b = WriteBatch()
+            for j in range(n):
+                b.put(b"%08d" % ((i * 7919 + j * 104729) % 10**8), b"v" * 20)
+            db.write(b)
+            if n < PAIR_APPLY or stats.get_ticker_count(
+                    st.MEMTABLE_INSERT_PARALLEL_RECORDS):
+                break
+        par = stats.get_ticker_count(st.MEMTABLE_INSERT_PARALLEL_RECORDS)
+        records = stats.get_ticker_count(st.MEMTABLE_INSERT_RECORDS)
+        assert records == (i + 1) * n
+        assert (par > 0) == (n >= PAIR_APPLY and MULTI_CORE)
+        assert par <= records
+    finally:
+        db.close()
+
+
 # -- deferred puts reach the plane as today's wire images -------------------
 
 class _EagerBatch(WriteBatch):

@@ -1603,8 +1603,9 @@ class DB:
         # telemetry plane's window into the GIL-released interior without
         # any per-record Python overhead. out[8..9]: the call's entry and
         # exit on time.monotonic_ns()'s clock (older .so builds leave all
-        # five 0, and then nothing is booked).
-        out = (ctypes.c_int64 * 10)()
+        # five 0, and then nothing is booked). out[10]: the records the
+        # native apply pool's worker inserted beside this thread.
+        out = (ctypes.c_int64 * 11)()
 
         def run(mode, block_off=0, log_no=-1, wal_ptr=None, cap=0):
             rc = fn(gh[0], gh[1], rep_arr, len_arr, n, first_seq, prot_ptr,
@@ -1641,9 +1642,11 @@ class DB:
             rc = run(2 | (4 if validated else 8 if fill else 0))
             if rc < 0:  # only reachable from the unvalidated single call
                 return None
+            parallel = int(out[10])
             if out[7]:
                 _tm.span_event("native.memtable_insert", out[7] // 1000,
-                               records=total)
+                               records=total,
+                               threads=2 if parallel else 1)
             if fill and not validated:
                 adopt_filled()
             seq = first_seq
@@ -1653,7 +1656,8 @@ class DB:
                 seq += w.batch._count
             mem0.note_group_applied(meta, int(out[2]), int(out[3]), rc,
                                     insert_ns=int(out[7]),
-                                    runs=[w.batch._count for w in group])
+                                    runs=[w.batch._count for w in group],
+                                    parallel=parallel)
             return rc
 
         if not frame:

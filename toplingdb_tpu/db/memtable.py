@@ -599,11 +599,12 @@ class MemTable:
         return _p.truncate(_p.protect_entry(int(t), user_key, value),
                            self.protection_bytes)
 
-    def _tick_insert(self, ns: int, runs) -> None:
+    def _tick_insert(self, ns: int, runs, parallel: int = 0) -> None:
         """memtable.insert.*: `ns` on the rep's insert, `runs` the record
         count of each run it was handed (a member batch of a write group,
-        one wire image, one parsed batch). The skiplist takes a run of two
-        or more sorted and interleaved; the trie rep has no such path."""
+        one wire image, one parsed batch), `parallel` the records the
+        native apply pool's worker inserted. The skiplist takes a run of
+        two or more sorted and interleaved; the trie rep has no such path."""
         stats = self._stats
         if stats is None:
             return
@@ -613,6 +614,7 @@ class MemTable:
             (_st.MEMTABLE_INSERT_RECORDS, sum(runs)),
             (_st.MEMTABLE_INSERT_RUN_RECORDS,
              sum(n for n in runs if n >= 2) if engaged else 0),
+            (_st.MEMTABLE_INSERT_PARALLEL_RECORDS, parallel),
         ))
 
     def add_encoded(self, first_seq: int, rep: bytes,
@@ -671,15 +673,16 @@ class MemTable:
 
     def note_group_applied(self, entries_meta, mem_delta: int,
                            deletes: int, total: int, insert_ns: int = 0,
-                           runs=()) -> None:
+                           runs=(), parallel: int = 0) -> None:
         """Bookkeeping for a whole write group the native plane already
         applied straight into the rep (tpulsm_wb_group_commit):
         entries_meta is [(first_seq, rep_bytes, prots_or_None)] per member
         batch — protected members park in _prot_pending exactly like
         add_encoded's wire-image deferral, so flush verification sees the
         same carried checksums either way. insert_ns is the plane's own
-        clock on the insert (out[7]), runs the members' record counts."""
-        self._tick_insert(insert_ns, runs)
+        clock on the insert (out[7]), runs the members' record counts,
+        parallel the records the pool's worker inserted (out[10])."""
+        self._tick_insert(insert_ns, runs, parallel)
         with self._lock:
             if self._prot is not None:
                 for fs, rep, prots in entries_meta:

@@ -1404,6 +1404,11 @@ static const int kMaxHeight = 12;
 // cache misses overlap. Measured at 8, 16 and 32 on the benchmark's host
 // (CHANGES.md, PR 34).
 static const int kRunGroup = 16;
+// Records of a sorted run taken at a time (SkipList::insert_chunk): the
+// unit a thread takes from a run that two threads share, and the unit
+// whose nodes come out of one arena reservation. 2, 4 and 8 kRunGroups
+// measured alike on the benchmark's host (PERF.md §6).
+static const size_t kApplyChunk = 4 * kRunGroup;
 
 static uint64_t random_height_seed() {
   static std::atomic<uint64_t> c{0x9E3779B97F4A7C15ULL};
@@ -1426,10 +1431,16 @@ struct SkipList {
       head->next[i].store(nullptr, std::memory_order_relaxed);
   }
 
-  SLNode* alloc_node(int height, uint32_t kl, uint32_t vl) {
+  // A node's bytes, rounded as Arena::alloc rounds a request.
+  static size_t node_bytes(int height, uint32_t kl, uint32_t vl) {
     size_t sz = sizeof(SLNode) + (height - 1) * sizeof(std::atomic<SLNode*>) +
                 kl + 4 + vl;
-    SLNode* n = reinterpret_cast<SLNode*>(arena.alloc(sz));
+    return (sz + 7) & ~size_t(7);
+  }
+
+  SLNode* alloc_node(int height, uint32_t kl, uint32_t vl) {
+    SLNode* n = reinterpret_cast<SLNode*>(
+        arena.alloc(node_bytes(height, kl, vl)));
     n->height = height;
     return n;
   }
@@ -1503,18 +1514,18 @@ struct SkipList {
     n->val.store(rec, std::memory_order_release);
   }
 
-  // Link one record in, given the prev[] its search found. prev[] may be
-  // stale (a lost race, or a neighbour of the same run linked in since):
-  // every level re-walks right from it. Returns 1 on a fresh insert, 0 on
-  // the in-place replace of an exact duplicate.
-  int splice(const SLRec& r, SLNode** prev) {
-    int h = random_height();
+  // Link one record in as node n of height h (its bytes reserved by the
+  // caller), given the prev[] its search found. prev[] may be stale (a
+  // lost race, or a neighbour of the same run linked in since): every
+  // level re-walks right from it. Returns 1 on a fresh insert, 0 on the
+  // in-place replace of an exact duplicate (n then stays unused).
+  int splice(const SLRec& r, SLNode** prev, SLNode* n, int h) {
     int mh = max_height.load(std::memory_order_relaxed);
     while (h > mh &&
            !max_height.compare_exchange_weak(mh, h,
                                              std::memory_order_relaxed)) {
     }
-    SLNode* n = alloc_node(h, r.kl, r.vl);
+    n->height = h;
     n->prefix = r.pfx;
     n->inv_packed = r.inv;
     n->key_len = r.kl;
@@ -1549,14 +1560,15 @@ struct SkipList {
         prev[i] = p;  // retry from the rescanned position
       }
     }
-    count.fetch_add(1, std::memory_order_relaxed);
     return 1;
   }
 
   // Up to kRunGroup records, sorted: their searches advance a step a round,
   // each step's next node prefetched a round before it is compared; then
-  // the nodes go in, in order.
-  int64_t insert_group(const SLRec* recs, int g) {
+  // the nodes go in, in order, record c as the next node_bytes(h[c], ...)
+  // of *mem. The bytes of a node not linked in are added to *unused.
+  int64_t insert_group(const SLRec* recs, int g, const int* h, uint8_t** mem,
+                       size_t* unused) {
     struct Cursor {
       SLNode* x;
       SLNode* nx;
@@ -1593,34 +1605,73 @@ struct SkipList {
     }
     int64_t fresh = 0;
     for (int c = 0; c < g; c++) {
-      // A replayed duplicate is met before anything is allocated for it
-      // (only WAL replay produces them, and a replay meets many).
+      SLNode* n = reinterpret_cast<SLNode*>(*mem);
+      size_t nb = node_bytes(h[c], recs[c].kl, recs[c].vl);
+      *mem += nb;
+      // A replayed duplicate is met before its node is touched (only WAL
+      // replay produces them, and a replay meets many).
       SLNode* ge = cur[c].nx;
+      int f = 0;
       if (ge && cmp_node(ge, recs[c]) == 0)
         set_val(ge, arena, recs[c].v, recs[c].vl);
       else
-        fresh += splice(recs[c], cur[c].prev);
+        f = splice(recs[c], cur[c].prev, n, h[c]);
+      if (!f) *unused += nb;
+      fresh += f;
     }
     return fresh;
   }
 
-  // THE insert: a run of records at a time. Sorting changes the order of
-  // insertion, never a record's key, sequence, type or value. Returns the
-  // number of fresh inserts (exact duplicates replace the value in place).
-  // Safe for concurrent callers (CAS splice; duplicates replace the value
-  // atomically — only WAL replay produces them, and that is
-  // single-threaded, but the path is still race-safe).
-  int64_t insert_run(SLRec* recs, size_t n) {
+  // At most kApplyChunk sorted records: their heights are drawn first and
+  // their nodes carved out of ONE arena reservation, so two threads that
+  // share a run take the arena's lock once a chunk, not once a node (and
+  // bump `count` once a chunk). The bytes of nodes not linked in (exact
+  // duplicates) leave `handed`, which then reads as a node-at-a-time
+  // allocation would.
+  int64_t insert_chunk(const SLRec* recs, size_t n) {
+    int h[kApplyChunk];
+    size_t bytes = 0;
+    for (size_t i = 0; i < n; i++) {
+      h[i] = random_height();
+      bytes += node_bytes(h[i], recs[i].kl, recs[i].vl);
+    }
+    uint8_t* mem = arena.alloc(bytes);
+    size_t unused = 0;
+    int64_t fresh = 0;
+    for (size_t i = 0; i < n; i += kRunGroup)
+      fresh += insert_group(
+          recs + i, (int)(n - i < (size_t)kRunGroup ? n - i : kRunGroup),
+          h + i, &mem, &unused);
+    if (unused) arena.handed.fetch_sub(unused, std::memory_order_relaxed);
+    count.fetch_add(fresh, std::memory_order_relaxed);
+    return fresh;
+  }
+
+  // Sorts a run by the list's own order. Sorting changes the order of
+  // insertion, never a record's key, sequence, type or value.
+  static void sort_run(SLRec* recs, size_t n) {
     for (size_t i = 0; i < n; i++)
       recs[i].pfx = key_prefix(recs[i].k, recs[i].kl);
     if (n > 1)
       std::sort(recs, recs + n, [](const SLRec& a, const SLRec& b) {
         return cmp(a.pfx, a.k, a.kl, a.inv, b) < 0;
       });
+  }
+
+  // THE insert: a run of records at a time, sorted, then a chunk after the
+  // other. Returns the number of fresh inserts (exact duplicates replace
+  // the value in place). Safe for concurrent callers (CAS splice;
+  // duplicates replace the value atomically — only WAL replay produces
+  // them, and that is single-threaded, but the path is still race-safe).
+  int64_t insert_run(SLRec* recs, size_t n) {
+    sort_run(recs, n);
+    return insert_sorted(recs, n);
+  }
+
+  int64_t insert_sorted(const SLRec* recs, size_t n) {
     int64_t fresh = 0;
-    for (size_t i = 0; i < n; i += kRunGroup)
-      fresh += insert_group(
-          recs + i, (int)(n - i < (size_t)kRunGroup ? n - i : kRunGroup));
+    for (size_t i = 0; i < n; i += kApplyChunk)
+      fresh += insert_chunk(recs + i, std::min(kApplyChunk, n - i));
     return fresh;
   }
 };
@@ -4831,19 +4882,92 @@ extern "C++" {
 #include <condition_variable>
 namespace {
 
+// Records of a write group's sorted run from which its skiplist apply
+// takes the apply pool's worker beside the calling thread (where the
+// process may run on two CPUs or more). On the benchmark's host the
+// two-thread insert call beat the one-thread one from 192 records on
+// (192: 54-70 us against 74-75; 128: 50-51 against 46-55). End to end, at
+// 500-record groups, the two threads beat the same path on one thread by
+// 5.3-6.5% in write rate over four same-seed pairs (PERF.md §6).
+static const int64_t kPairApply = 256;
+// How long the caller of a pool job spins for a started worker's last
+// chunk before it parks on cv_done: a chunk is 25-35 us of inserts, and
+// parking instead costs the caller a wake-up (a 500-record group's apply
+// 219 us against 267 without the spin on an 8-core x86 host).
+static const int64_t kDoneSpinNs = 100000;
+// How long a worker woken ahead of a job (ApplyPool::prewake: the frame
+// call of a group whose insert will be shared) spins for it before it
+// parks again: the frame call, the caller's WAL append and the insert
+// call's parse and sort lie between (30-100 us). On the benchmark's host a
+// parked worker woken by the insert call itself came too late to take a
+// fair share (frame to insert of 500 records 290-296 us against 265-276
+// with the pre-wake; PERF.md §6).
+static const int64_t kArmSpinNs = 300000;
+
+static inline void cpu_pause() {
+#if defined(__x86_64__) || defined(__i386__)
+  __builtin_ia32_pause();
+#endif
+}
+
+// Spins until done() holds or ns nanoseconds have passed.
+template <typename Done>
+static void spin_until(Done&& done, int64_t ns) {
+  const auto t0 = std::chrono::steady_clock::now();
+  for (int i = 1; !done(); i++) {
+    cpu_pause();
+    if ((i & 63) == 0 && std::chrono::steady_clock::now() - t0 >
+                             std::chrono::nanoseconds(ns))
+      return;
+  }
+}
+
+// Parses the wire-image record at *pp (its batch ends at end) and moves *pp
+// past it; fn(t, k, klen, inv, v, vlen) for a counted record (LOG_DATA is
+// not counted), inv from seq. False on a malformed record.
+template <typename Fn>
+static inline bool wb_next_record(const uint8_t** pp, const uint8_t* end,
+                                  uint64_t seq, Fn&& fn) {
+  const uint8_t* p = *pp;
+  uint8_t t = *p++;
+  uint32_t klen, vlen = 0;
+  p = get_varint32(p, end, &klen);
+  if (!p) return false;
+  const uint8_t* k = p;
+  p += klen;
+  const uint8_t* v = p;
+  if (t == 0x1 || t == 0x2 || t == 0x16) {  // VALUE / MERGE / WIDE
+    p = get_varint32(p, end, &vlen);
+    if (!p) return false;
+    v = p;
+    p += vlen;
+  }
+  *pp = p;
+  if (t != 0x3) fn(t, k, klen, ~((seq << 8) | (uint64_t)t), v, vlen);
+  return true;
+}
+
 // Persistent worker pool for the group-apply phase: per-group
 // std::thread spawns cost ~30-50us — more than the insert work of a
 // typical group — so the write plane keeps a small lazily-grown pool
 // alive for the process. One job runs at a time (run_mu): the caller
 // publishes a shared closure, k workers plus the caller execute it, the
-// caller waits for all k. Workers idle on a condvar between groups.
+// caller waits for all k (try_run: or finds the pool busy and runs
+// nothing). Workers idle on a condvar between groups.
+// A job is a loop that takes work from a shared cursor until none is left,
+// so once the caller's own pass returns, all the work is taken: a worker
+// that has not started by then is owed nothing and its slot is withdrawn
+// (a late wake costs the caller no wait), and the caller spins a bounded
+// while for a started worker's last piece before it parks.
 struct ApplyPool {
   std::mutex run_mu;  // serializes whole jobs
   std::mutex mu;
   std::condition_variable cv_work, cv_done;
   const std::function<void()>* fn = nullptr;
-  uint64_t gen = 0;
-  int want = 0, started = 0, done_count = 0;
+  std::atomic<uint64_t> gen{0};  // written under mu, spun on bare
+  bool armed = false;            // a job is about to come (prewake)
+  int want = 0, started = 0;
+  std::atomic<int> done_count{0};  // written under mu, spun on bare
   bool shutdown = false;
   std::vector<std::thread> ths;
 
@@ -4865,9 +4989,20 @@ struct ApplyPool {
     std::unique_lock<std::mutex> lk(mu);
     for (;;) {
       cv_work.wait(lk, [&] {
-        return shutdown || (gen != seen && started < want);
+        return shutdown || armed || (gen != seen && started < want);
       });
       if (shutdown) return;
+      if (!(gen != seen && started < want)) {
+        // Woken ahead of a job: wait for it awake a bounded while. The job
+        // of the current gen, if any, has nothing left to claim.
+        armed = false;
+        seen = gen;
+        lk.unlock();
+        spin_until([&] { return gen.load(std::memory_order_acquire) != seen; },
+                   kArmSpinNs);
+        lk.lock();
+        continue;
+      }
       seen = gen;
       if (started >= want) continue;
       started++;
@@ -4875,14 +5010,16 @@ struct ApplyPool {
       lk.unlock();
       (*f)();
       lk.lock();
-      if (++done_count == want) cv_done.notify_all();
+      if (done_count.fetch_add(1, std::memory_order_release) + 1 == want) {
+        lk.unlock();
+        cv_done.notify_all();
+        lk.lock();
+      }
     }
   }
 
-  // Runs f on min(k, pool) workers concurrently with the caller.
-  void run(const std::function<void()>& f, int k) {
-    std::lock_guard<std::mutex> job(run_mu);
-    std::unique_lock<std::mutex> lk(mu);
+  // Grows the pool to k workers (fewer where threads cannot be made).
+  void grow(int k) {
     while ((int)ths.size() < k) {
       try {
         ths.emplace_back([this] { worker(); });
@@ -4890,6 +5027,40 @@ struct ApplyPool {
         break;  // pid limits: run with what we have
       }
     }
+  }
+
+  // Wakes one worker ahead of a job about to come, so its wake-up overlaps
+  // what the caller does before run(); it waits awake up to kArmSpinNs.
+  void prewake() {
+    {
+      std::lock_guard<std::mutex> lk(mu);
+      grow(1);
+      if (ths.empty()) return;
+      armed = true;
+    }
+    cv_work.notify_one();
+  }
+
+  // Runs f on min(k, pool) workers concurrently with the caller.
+  void run(const std::function<void()>& f, int k) {
+    std::lock_guard<std::mutex> job(run_mu);
+    run_locked(f, k);
+  }
+
+  // run(f, k) where no other job holds the pool; false at once, f not run,
+  // where one does (the staged write modes apply groups outside the DB's
+  // mutex, so two leaders' applies may meet here).
+  bool try_run(const std::function<void()>& f, int k) {
+    std::unique_lock<std::mutex> job(run_mu, std::try_to_lock);
+    if (!job.owns_lock()) return false;
+    run_locked(f, k);
+    return true;
+  }
+
+ private:
+  void run_locked(const std::function<void()>& f, int k) {
+    std::unique_lock<std::mutex> lk(mu);
+    grow(k);
     if ((int)ths.size() < k) k = (int)ths.size();
     if (k <= 0) {
       lk.unlock();
@@ -4899,19 +5070,41 @@ struct ApplyPool {
     fn = &f;
     want = k;
     started = 0;
-    done_count = 0;
-    gen++;
-    cv_work.notify_all();
+    armed = false;
+    done_count.store(0, std::memory_order_relaxed);
+    gen.fetch_add(1, std::memory_order_release);
     lk.unlock();
+    if (k == 1)
+      cv_work.notify_one();
+    else
+      cv_work.notify_all();
     f();  // caller participates
     lk.lock();
-    cv_done.wait(lk, [&] { return done_count == want; });
+    want = started;  // withdraw the slots no worker has claimed
+    const int w = want;
+    if (done_count.load(std::memory_order_acquire) != w) {
+      lk.unlock();
+      spin_until(
+          [&] { return done_count.load(std::memory_order_acquire) == w; },
+          kDoneSpinNs);
+      lk.lock();
+    }
+    cv_done.wait(lk, [&] {
+      return done_count.load(std::memory_order_relaxed) == want;
+    });
   }
 };
 
 static ApplyPool& apply_pool() {
   static ApplyPool p;
   return p;
+}
+
+// The CPUs the process may run on: a system call (35-60 us on the
+// benchmark's host), asked once.
+static size_t apply_cpus() {
+  static const size_t n = effective_cpus();
+  return n;
 }
 
 struct GcPiece {
@@ -5022,8 +5215,9 @@ static int64_t gc_frame_merged(GcCursor& cur, int64_t total_len,
 // out[5..7]=interior phase timings in ns (validate / WAL frame / memtable
 // insert) for the telemetry plane, out[8..9]=this call's entry and exit
 // stamps on steady_clock (Linux CLOCK_MONOTONIC, the clock of Python's
-// time.monotonic_ns(); out[9] is written on every return) — the caller
-// must size out >= 10.
+// time.monotonic_ns(); out[9] is written on every return), out[10]=the
+// records the apply pool's workers inserted (0 where the calling thread
+// applied the group alone) — the caller must size out >= 11.
 // Returns total counted records, or -2 (unsupported record: Python path),
 // -3 (wal_cap too small), -4 (corrupt image), -5 - i (protection mismatch
 // at group record index i).
@@ -5055,6 +5249,7 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
     }
   } exit_stamp{out};
   out[8] = t_entry_ns;
+  out[10] = 0;
   int64_t total = 0;
   if (mode & 4) {
     // Caller vouches (see above): counts come from the batch headers.
@@ -5116,6 +5311,13 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
   int64_t merged_len = 12;
   for (int64_t b = 0; b < n_batches; b++) merged_len += lens[b] - 12;
   int64_t wal_len = 0, new_bo = block_offset;
+  if ((mode & 3) == 1 && mem && mem_kind == 0 && total >= kPairApply &&
+      apply_cpus() >= 2) {
+    // The frame call of a group whose insert will be shared: wake the
+    // pool's worker now, so that its wake-up overlaps the framing, the
+    // caller's WAL append and the insert call's parse and sort.
+    apply_pool().prewake();
+  }
   if (mode & 1) {
     uint8_t hdr12[12];
     for (int i = 0; i < 8; i++) hdr12[i] = (uint8_t)(first_seq >> (8 * i));
@@ -5138,116 +5340,183 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
     SkipList* sl = mem_kind == 0 ? static_cast<SkipList*>(mem) : nullptr;
     TrieRep* tr = mem_kind == 1 ? static_cast<TrieRep*>(mem) : nullptr;
     if (!sl && !tr) return -2;
-    // Work units: contiguous record ranges with a known start seq — one
-    // per small batch, plus INTRA-batch splits for large batches (a quick
-    // varint walk, ~10x cheaper than the inserts it parallelizes), so
-    // even a single-batch group fans out across the ApplyPool. Both
-    // native reps take concurrent inserts (CAS splice / per-stripe
-    // mutexes) and records are order-independent (distinct seqnos), so
-    // unit order does not matter.
-    struct GcUnit {
-      const uint8_t* p;
-      const uint8_t* end;
-      uint64_t seq;
-    };
-    size_t nt_max = std::min(effective_cpus(), (size_t)8);
-    int64_t S = total / (int64_t)(2 * nt_max);
-    if (S < 256) S = 256;
-    std::vector<GcUnit> units;
-    units.reserve((size_t)(total / S + n_batches + 1));
-    {
+    const std::thread::id caller = std::this_thread::get_id();
+    std::atomic<int64_t> by_workers{0};
+    if (sl) {
+      // The group's records as runs of at most kMaxRun (the bound on the
+      // buffer), each sorted ONCE in the list's order, then inserted a
+      // chunk at a time. A run of kPairApply records or more is shared
+      // with the pool's worker through one cursor: the group's frame call
+      // has woken the worker already, and the job starts before the caller
+      // parses and sorts the run, so the worker's wake overlaps both; the
+      // caller then starts on chunk 0, the worker takes chunks
+      // from where the cursor stands when it is up, and neighbouring chunks
+      // are neighbouring key ranges.
+      std::vector<SLRec> run;
+      run.reserve((size_t)std::min<int64_t>(total, SLRunSink::kMaxRun));
+      int64_t b = 0;
       uint64_t seq = first_seq;
-      for (int64_t b = 0; b < n_batches; b++) {
-        const uint8_t* rep = (const uint8_t*)reps[b];
-        const uint8_t* end = rep + lens[b];
-        uint32_t cnt = (uint32_t)wb_header_count(rep, lens[b]);
-        if ((int64_t)cnt <= S) {
-          units.push_back({rep + 12, end, seq});
-          seq += cnt;
+      const uint8_t* p = nullptr;
+      const uint8_t* end = nullptr;
+      auto fill = [&]() {  // the next run: parsed, then sorted
+        run.clear();
+        while (run.size() < SLRunSink::kMaxRun) {
+          if (p == end) {
+            if (b == n_batches) break;
+            p = (const uint8_t*)reps[b] + 12;
+            end = (const uint8_t*)reps[b] + lens[b];
+            b++;
+            continue;
+          }
+          const uint8_t* q = p;
+          if (!wb_next_record(&q, end, seq, [&](uint8_t t, const uint8_t* k,
+                                                uint32_t klen, uint64_t inv,
+                                                const uint8_t* v,
+                                                uint32_t vlen) {
+                run.push_back(SLRec{0, k, v, inv, klen, vlen});
+                delta += (int64_t)klen + vlen + 24;
+                if (t == 0x0 || t == 0x7) deletes++;
+                seq++;
+              }))
+            q = end;  // validated earlier; defensive
+          p = q;
+        }
+        SkipList::sort_run(run.data(), run.size());
+      };
+      for (int64_t left = total; left > 0;
+           left -= (int64_t)SLRunSink::kMaxRun) {
+        if (left < kPairApply || apply_cpus() < 2) {
+          fill();
+          sl->insert_sorted(run.data(), run.size());
           continue;
         }
-        const uint8_t* p = rep + 12;
-        const uint8_t* ustart = p;
-        uint64_t useq = seq;
-        int64_t in_unit = 0;
-        while (p < end) {
-          uint8_t t = *p++;
-          uint32_t klen, vlen;
-          p = get_varint32(p, end, &klen);
-          if (!p) break;  // validated earlier; defensive
-          p += klen;
-          if (t == 0x1 || t == 0x2 || t == 0x16) {
-            p = get_varint32(p, end, &vlen);
-            if (!p) break;
-            p += vlen;
-          } else if (t == 0x3) {
-            continue;
+        // A cache line each: a worker that spins on `ready` must not share
+        // one with what the caller writes while it parses.
+        struct alignas(64) Ready {
+          std::atomic<bool> v{false};
+        } ready;
+        struct alignas(64) Cursor {
+          std::atomic<size_t> v{0};
+        } next;
+        std::function<void()> take = [&]() {
+          const bool mine = std::this_thread::get_id() == caller;
+          if (mine) {
+            fill();
+            ready.v.store(true, std::memory_order_release);
+          } else {
+            while (!ready.v.load(std::memory_order_acquire)) cpu_pause();
           }
-          in_unit++;
-          seq++;
-          if (in_unit >= S) {
-            units.push_back({ustart, p, useq});
-            ustart = p;
-            useq = seq;
-            in_unit = 0;
+          const size_t n = run.size();
+          int64_t took = 0;
+          for (;;) {
+            size_t c = next.v.fetch_add(kApplyChunk,
+                                        std::memory_order_relaxed);
+            if (c >= n) break;
+            size_t m = std::min(kApplyChunk, n - c);
+            sl->insert_chunk(run.data() + c, m);
+            took += (int64_t)m;
           }
+          if (!mine) by_workers.fetch_add(took, std::memory_order_relaxed);
+        };
+        if (!apply_pool().try_run(take, 1)) {
+          // Another group's apply holds the pool: this one does not queue
+          // behind it, and goes on the caller's thread alone.
+          fill();
+          sl->insert_sorted(run.data(), run.size());
         }
-        if (p > ustart) units.push_back({ustart, p, useq});
       }
-    }
-    std::atomic<int64_t> a_delta{0}, a_deletes{0};
-    std::atomic<size_t> next_unit{0};
-    size_t n_units = units.size();
-    auto apply = [&]() {
-      int64_t d = 0, dl = 0;
-      for (;;) {
-        size_t u = next_unit.fetch_add(1, std::memory_order_relaxed);
-        if (u >= n_units) break;
-        const uint8_t* p = units[u].p;
-        const uint8_t* end = units[u].end;
-        uint64_t seq = units[u].seq;
-        // A unit is one run for the skiplist (its records: pointers into
-        // the members' wire images).
-        SLRunSink sink(sl, sl ? (size_t)S : 0);
-        while (p < end) {
-          uint8_t t = *p++;
-          uint32_t klen, vlen = 0;
-          p = get_varint32(p, end, &klen);
-          if (!p) break;  // validated earlier; defensive
-          const uint8_t* k = p;
-          p += klen;
-          const uint8_t* v = p;
-          if (t == 0x1 || t == 0x2 || t == 0x16) {
-            p = get_varint32(p, end, &vlen);
-            if (!p) break;
-            v = p;
-            p += vlen;
-          } else if (t == 0x3) {
-            continue;
-          }
-          uint64_t inv = ~((seq << 8) | (uint64_t)t);
-          if (sl)
-            sink.add(k, klen, inv, v, vlen);
-          else
-            trie_insert(tr, k, klen, inv, v, vlen);
-          d += (int64_t)klen + vlen + 24;
-          if (t == 0x0 || t == 0x7) dl++;
-          seq++;
-        }
-        sink.flush();
-      }
-      a_delta.fetch_add(d, std::memory_order_relaxed);
-      a_deletes.fetch_add(dl, std::memory_order_relaxed);
-    };
-    size_t nt = 1;
-    if (n_units > 1 && total >= 512) nt = std::min(nt_max, n_units);
-    if (nt > 1) {
-      apply_pool().run(apply, (int)nt - 1);
     } else {
-      apply();
+      // Trie work units: contiguous record ranges with a known start seq —
+      // one per small batch, plus INTRA-batch splits for large batches (a
+      // quick varint walk, ~10x cheaper than the inserts it parallelizes),
+      // so even a single-batch group fans out across the ApplyPool (the
+      // trie's per-stripe mutexes take concurrent inserts).
+      // fn(t, k, klen, inv, v, vlen) for every counted record of [p, end),
+      // the first at sequence seq.
+      auto each_record = [](const uint8_t* p, const uint8_t* end,
+                            uint64_t seq, auto&& fn) {
+        while (p < end && wb_next_record(&p, end, seq, [&](auto&&... r) {
+                 fn(r...);
+                 seq++;
+               })) {
+        }
+      };
+      struct GcUnit {
+        const uint8_t* p;
+        const uint8_t* end;
+        uint64_t seq;
+      };
+      size_t nt_max = std::min(apply_cpus(), (size_t)8);
+      int64_t S = total / (int64_t)(2 * nt_max);
+      if (S < 256) S = 256;
+      std::vector<GcUnit> units;
+      units.reserve((size_t)(total / S + n_batches + 1));
+      {
+        uint64_t seq = first_seq;
+        for (int64_t b = 0; b < n_batches; b++) {
+          const uint8_t* rep = (const uint8_t*)reps[b];
+          const uint8_t* end = rep + lens[b];
+          uint32_t cnt = (uint32_t)wb_header_count(rep, lens[b]);
+          if ((int64_t)cnt <= S) {
+            units.push_back({rep + 12, end, seq});
+            seq += cnt;
+            continue;
+          }
+          const uint8_t* p = rep + 12;
+          const uint8_t* ustart = p;
+          uint64_t useq = seq;
+          int64_t in_unit = 0;
+          while (p < end) {
+            bool counted = false;
+            if (!wb_next_record(&p, end, seq,
+                                [&](auto&&...) { counted = true; }))
+              break;  // validated earlier; defensive
+            if (!counted) continue;
+            in_unit++;
+            seq++;
+            if (in_unit >= S) {
+              units.push_back({ustart, p, useq});
+              ustart = p;
+              useq = seq;
+              in_unit = 0;
+            }
+          }
+          if (p > ustart) units.push_back({ustart, p, useq});
+        }
+      }
+      std::atomic<int64_t> a_delta{0}, a_deletes{0};
+      std::atomic<size_t> next_unit{0};
+      size_t n_units = units.size();
+      std::function<void()> apply = [&]() {
+        int64_t d = 0, dl = 0, took = 0;
+        for (;;) {
+          size_t u = next_unit.fetch_add(1, std::memory_order_relaxed);
+          if (u >= n_units) break;
+          each_record(units[u].p, units[u].end, units[u].seq,
+                      [&](uint8_t t, const uint8_t* k, uint32_t klen,
+                          uint64_t inv, const uint8_t* v, uint32_t vlen) {
+                        trie_insert(tr, k, klen, inv, v, vlen);
+                        d += (int64_t)klen + vlen + 24;
+                        if (t == 0x0 || t == 0x7) dl++;
+                        took++;
+                      });
+        }
+        a_delta.fetch_add(d, std::memory_order_relaxed);
+        a_deletes.fetch_add(dl, std::memory_order_relaxed);
+        if (std::this_thread::get_id() != caller)
+          by_workers.fetch_add(took, std::memory_order_relaxed);
+      };
+      size_t nt = 1;
+      if (n_units > 1 && total >= 512) nt = std::min(nt_max, n_units);
+      if (nt > 1) {
+        apply_pool().run(apply, (int)nt - 1);
+      } else {
+        apply();
+      }
+      delta = a_delta.load();
+      deletes = a_deletes.load();
     }
-    delta = a_delta.load();
-    deletes = a_deletes.load();
+    out[10] = by_workers.load();
   }
   out[0] = wal_len;
   out[1] = new_bo;

@@ -125,13 +125,21 @@ def _wb_seeds(rng):
         else:
             wb.put(k, b"v" * rng.randrange(0, 200))
     seeds.append(wb.data())
+    # db_bench's batch: 500 records, which the fused plane's apply shares
+    # between the calling thread and the apply pool's worker.
+    wb = WriteBatch()
+    for i in range(500):
+        k = b"%08d" % rng.randrange(10**8)
+        (wb.delete(k) if i % 11 == 0 else wb.put(k, rng.randbytes(20)))
+    seeds.append(wb.data())
     return seeds
 
 
-def group_commit_insert(rep, data: bytes, first_seq: int):
+def group_commit_insert(rep, data: bytes, first_seq: int, out=None):
     """The fused plane's validate + insert (mode 2) of one wire image into
     `rep`: the record count, or None when the plane refuses the image (or
-    is not there)."""
+    is not there). `out`, when given, is the plane's `c_int64 * 11` out
+    array (out[10]: the records the apply pool's worker inserted)."""
     import ctypes
 
     from toplingdb_tpu import native
@@ -139,7 +147,8 @@ def group_commit_insert(rep, data: bytes, first_seq: int):
     fn = getattr(native.lib(), "tpulsm_wb_group_commit", None)
     if fn is None:
         return None
-    out = (ctypes.c_int64 * 10)()
+    if out is None:
+        out = (ctypes.c_int64 * 11)()
     rc = fn(rep._h, rep._nget_mem_kind, (ctypes.c_char_p * 1)(data),
             (ctypes.c_int64 * 1)(len(data)), 1, first_seq, None, 0, 0, 2, 0,
             -1, None, 0, out)

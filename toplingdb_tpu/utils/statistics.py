@@ -84,9 +84,13 @@ WRITE_BATCH_DEFERRED_RECORDS = "write.batch.deferred.records"
 # the records that arrived in a run of two or more, which the skiplist
 # sorts and searches interleaved (SkipList::insert_run): run.records over
 # records is how often that engages, micros over records the layer's cost.
+# parallel.records: the records of a write group that the native apply
+# pool's worker inserted beside the writer's thread (the plane's out[10]);
+# over records, the worker's share (0 where a group stays on one thread).
 MEMTABLE_INSERT_MICROS = "memtable.insert.micros"
 MEMTABLE_INSERT_RECORDS = "memtable.insert.records"
 MEMTABLE_INSERT_RUN_RECORDS = "memtable.insert.run.records"
+MEMTABLE_INSERT_PARALLEL_RECORDS = "memtable.insert.parallel.records"
 # The writer's own clocks inside DB.write (db/db.py; one record_ticks an
 # outer call, beside db.write.micros): the thread's CPU time; the sum over
 # the group's native write-plane calls of exit minus entry (out[8..9]); and
