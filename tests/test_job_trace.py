@@ -38,13 +38,13 @@ WAIT_SPANS = {"pipeline.wait_scan", "pipeline.wait_writer", "pipeline.stall"}
 
 def make_job(tmp_path, name="job", runs=3, rows=3000, seq_gap=0, trace=None,
              max_output_file_size=2 ** 62, last_run_key_len=8, snapshots=(),
-             device="cpu-jax"):
+             device="cpu-jax", seed=7):
     """A job dir with `runs` input SSTs of 8 B keys / 20 B values (half the
     keys overwritten across runs) and its params.json. `seq_gap` is added
     to the sequence numbers of half the last run's rows, so that every
     chunk of that file spans it. `last_run_key_len` other than 8 gives the
     last run longer keys (the same digits, then '0's): a job of two key
-    lengths, which the pipeline refuses."""
+    lengths, which the pipeline refuses. `seed` draws the keys."""
     from toplingdb_tpu.ops.columnar_io import ColumnarKV, write_tables_columnar
 
     env = default_env()
@@ -53,7 +53,7 @@ def make_job(tmp_path, name="job", runs=3, rows=3000, seq_gap=0, trace=None,
     os.makedirs(in_dir)
     os.makedirs(os.path.join(job_dir, "out"))
     topts = TableOptions(block_size=4096, compression=fmt.NO_COMPRESSION)
-    rng = np.random.default_rng(7)
+    rng = np.random.default_rng(seed)
     counter = [0]
 
     def alloc():

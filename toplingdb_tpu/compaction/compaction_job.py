@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import bisect
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 from toplingdb_tpu.utils import concurrency as ccy
 from toplingdb_tpu.db import dbformat, filename
@@ -73,6 +73,12 @@ class CompactionStats:
     mesh_chips: int = 0
     mesh_shards: int = 0
     mesh_fallbacks: int = 0
+    # Placement (JAX device ids): the chips a dcompact service granted the
+    # job, and those its shard programs ran on (the device of each shard's
+    # committed upload), each sorted once. A job of a service without a
+    # pool has no grant.
+    devices_granted: list = field(default_factory=list)
+    devices_used: list = field(default_factory=list)
     # SST payload bytes that crossed the job transport (storage/: 0 when
     # the worker resolved inputs from the shared store and published its
     # outputs back — the job shipped only metadata).
@@ -140,6 +146,12 @@ class CompactionStats:
     sft_output_rows: int = 0
     sft_output_bytes: int = 0
     sft_build_usec: int = 0
+
+    def note_device(self, device_id: int) -> None:
+        """A shard program ran on JAX device `device_id`."""
+        if device_id not in self.devices_used:
+            self.devices_used.append(device_id)
+            self.devices_used.sort()
 
     def count_input(self, reader) -> None:
         """One input file's reader: counted under its format when the

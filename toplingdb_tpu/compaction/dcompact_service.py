@@ -21,12 +21,19 @@ before it prints "listening" and exits non-zero on a mismatch; /health and
 
 Pod-level packing (`--chips N`): the worker host owns N chips; each chip
 is a failure domain behind its own circuit breaker (PR 1's
-WorkerHealthRegistry reused with "chip:<i>" keys). Jobs are admitted with
-as many healthy free chips as the pool can grant (chip-count-aware
-admission) and run the mesh plane sized to the grant; a wedged chip
-demotes later jobs to fewer chips — down to single-chip/local when every
-breaker is open — instead of stalling the queue. Per-chip queue depths
-ride /metrics beside the existing dcompact gauges.
+WorkerHealthRegistry reused with "chip:<i>" keys). A job runs on the chips
+its grant names and on no other: `--workers W` jobs run at once, and the
+pool grants each `N // W` healthy free chips, the idle ones in rotation.
+`--chips 4 --workers 4` is the fan-out of the reference's pod (one
+CompactionJob a chip, four at once); `--chips 4 --workers 1` meshes one
+job's key-range shards over the four (ops/mesh_compaction.py). The granted
+`jax.Device`s go down the call chain as an argument (`worker.run_job(...,
+devices=)`), never through the environment, so jobs side by side cannot
+see each other's grant. A wedged chip drops out of later grants (fewer
+chips, down to the backend's default device when every breaker is open)
+instead of stalling the queue. Per-chip queue depths, hold times and job
+counts ride /metrics and /stats; `/stats` also carries the time integral
+of the jobs holding a grant and the wait for one, for window deltas.
 """
 
 from __future__ import annotations
@@ -49,77 +56,100 @@ from toplingdb_tpu.utils.status import InvalidArgument, IOError_
 
 
 class ChipPool:
-    """Per-chip work queues + chip-count-aware job admission for one
-    worker host. `admit()` targets the least-loaded healthy chips and
-    gang-waits for them; a chip that wedges while a job queues is dropped
-    from the grant (fewer-chip demotion), and a grant that times out
-    takes whatever subset is free NOW — so a dead device degrades
-    throughput, never progress. Chip health is the SAME breaker machinery
-    the DB side uses for worker URLs, keyed "chip:<i>", so
-    record_failure/record_success from finished jobs open and re-close
-    chips exactly like remote workers."""
+    """Chip grants for one worker host. `admit()` waits for `per_job`
+    healthy free chips (fewer when fewer are healthy: demotion) and takes
+    the free ones in rotation, so jobs that arrive one at a time still
+    spread over every chip; a wait that times out takes whatever subset is
+    free NOW, so a dead device degrades throughput, never progress. Chip
+    health is the SAME breaker machinery the DB side uses for worker URLs,
+    keyed "chip:<i>", so record_failure/record_success from finished jobs
+    open and re-close chips exactly like remote workers.
 
-    def __init__(self, chips: int, policy=None):
+    Counters, cumulative in µs of the monotonic clock and brought up to
+    date at every grant, release and read: `jobs_running_usec` (the time
+    integral of the number of jobs holding a grant), `grant_wait_usec`
+    (admit's waits), per chip `chip_held_usec` and `chip_jobs`. With
+    one-chip grants the chips' hold times add up to `jobs_running_usec`."""
+
+    def __init__(self, chips: int, per_job: int | None = None, policy=None):
         from toplingdb_tpu.compaction.resilience import (
             DcompactOptions, WorkerHealthRegistry,
         )
 
         self.chips = ["chip:%d" % i for i in range(max(1, chips))]
+        self.per_job = (len(self.chips) if per_job is None
+                        else max(1, min(per_job, len(self.chips))))
         self.health = WorkerHealthRegistry(policy or DcompactOptions())
         self._cv = ccy.Condition("dcompact_service.ChipPool._cv")
+        # Chips held by a job; a chip's /metrics queue depth (a waiting
+        # job targets no chip, so it is 0 or 1).
         self._busy: set[str] = set()
-        # Granted-but-unreleased + queued-targeting counts per chip — the
-        # /metrics queue-depth gauge.
-        self._depth = {c: 0 for c in self.chips}
+        self._turn = 0       # where the next tie between idle chips starts
+        self._holders = 0    # jobs holding a grant
+        self._t_us = _now_us()
+        self.jobs_running_usec = 0
+        self.grant_wait_usec = 0
+        self.chip_held_usec = {c: 0 for c in self.chips}
+        self.chip_jobs = {c: 0 for c in self.chips}
+
+    def index(self, chip: str) -> int:
+        return self.chips.index(chip)
 
     def _healthy(self) -> list[str]:
         return [c for c in self.chips if self.health.breaker(c).allow()]
 
-    def _pick_targets(self, want: int) -> list[str]:
-        healthy = self._healthy()
-        healthy.sort(key=lambda c: self._depth[c])
-        return healthy[: max(0, want)]
+    def _advance(self) -> int:
+        """Bring the integrals up to now (under the pool lock)."""
+        now = _now_us()
+        dt = now - self._t_us
+        self._t_us = now
+        self.jobs_running_usec += self._holders * dt
+        for c in self._busy:
+            self.chip_held_usec[c] += dt
+        return now
 
     def admit(self, want: int | None = None,
               timeout: float = 30.0) -> list[str]:
-        """Block until the targeted chips are free; returns the granted
-        chip list (possibly smaller than `want` — demotion), or [] when no
-        healthy chip exists (caller runs local/serial)."""
-        want = len(self.chips) if want is None else max(1, want)
+        """Block until `want` (default `per_job`) healthy chips are free;
+        returns the granted chip list (smaller when fewer are healthy, or
+        when the wait timed out), or [] when no healthy chip is free (the
+        caller runs on the backend's default device)."""
+        want = self.per_job if want is None else max(1, want)
+        t0 = _now_us()
         deadline = time.monotonic() + max(0.0, timeout)
+        n = len(self.chips)
         with self._cv:
-            target = self._pick_targets(want)
-            for c in target:
-                self._depth[c] += 1
             while True:
-                healthy = set(self._healthy())
-                alive = [c for c in target if c in healthy]
-                if len(alive) < len(target):
-                    # Wedged while queued: demote to the survivors.
-                    for c in set(target) - set(alive):
-                        self._depth[c] -= 1
-                    target = alive
-                if not target:
-                    return []
-                free = [c for c in target if c not in self._busy]
-                if len(free) == len(target):
-                    self._busy.update(target)
-                    return list(target)
+                healthy = self._healthy()
+                free = sorted(
+                    (c for c in healthy if c not in self._busy),
+                    key=lambda c: (self.index(c) - self._turn) % n)
+                if len(free) >= min(want, len(healthy)):
+                    grant = free[:want]
+                    break
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
-                    # Take what is free NOW rather than stall the job.
-                    for c in set(target) - set(free):
-                        self._depth[c] -= 1
-                    self._busy.update(free)
-                    return list(free)
+                    grant = free[:want]  # what is free NOW, maybe nothing
+                    break
                 self._cv.wait(min(0.05, remaining))
+            now = self._advance()
+            self.grant_wait_usec += now - t0
+            if grant:
+                self._holders += 1
+                self._turn = (self.index(grant[-1]) + 1) % n
+            for c in grant:
+                self._busy.add(c)
+                self.chip_jobs[c] += 1
+            return grant
 
     def release(self, grant: list[str], ok: bool = True,
                 failed_chips=()) -> None:
         with self._cv:
+            self._advance()
+            if grant:
+                self._holders -= 1
             for c in grant:
                 self._busy.discard(c)
-                self._depth[c] -= 1
             self._cv.notify_all()
         # Health updates OUTSIDE the pool lock: the registry/breaker locks
         # rank below (after) the pool's in the §2.10.1 order, but release
@@ -132,18 +162,35 @@ class ChipPool:
 
     def queue_depths(self) -> dict[str, int]:
         with self._cv:
-            return dict(self._depth)
+            return {c: int(c in self._busy) for c in self.chips}
+
+    def counters(self) -> dict:
+        """The cumulative counters, up to date, for /stats."""
+        with self._cv:
+            self._advance()
+            return {"jobs_running_usec": self.jobs_running_usec,
+                    "grant_wait_usec": self.grant_wait_usec,
+                    "chip_held_usec": [self.chip_held_usec[c]
+                                       for c in self.chips],
+                    "chip_jobs": [self.chip_jobs[c] for c in self.chips]}
 
     def snapshot(self) -> dict:
         with self._cv:
-            depths = dict(self._depth)
+            self._advance()
             busy = set(self._busy)
+            held = dict(self.chip_held_usec)
+            jobs = dict(self.chip_jobs)
         health = self.health.snapshot()
         return {
-            c: {"queue_depth": depths[c], "busy": c in busy,
-                "state": health.get(c, {}).get("state", "closed")}
+            c: {"queue_depth": int(c in busy), "busy": c in busy,
+                "state": health.get(c, {}).get("state", "closed"),
+                "held_usec": held[c], "jobs": jobs[c]}
             for c in self.chips
         }
+
+
+def _now_us() -> int:
+    return time.monotonic_ns() // 1000
 
 
 JOB_SUM_COUNTERS = (
@@ -192,41 +239,48 @@ class DcompactWorkerService:
         from toplingdb_tpu.utils import telemetry
 
         self.tracer = telemetry.Tracer(proc="dcompact-worker", ring=256)
-        # Pod-level packing: chips > 0 builds the per-chip admission pool;
-        # 0 is the one-chip host (every job on the default device).
-        self.pool = ChipPool(chips) if chips > 0 else None
+        # Pod-level packing: chips > 0 builds the per-chip grant pool, each
+        # job granted chips // workers of them; 0 is the one-chip host
+        # (every job on the backend's default device).
+        self.pool = (ChipPool(chips, per_job=chips // max(1, max_workers))
+                     if chips > 0 else None)
+        # chip:<i> of the pool is JAX's device i (None: a host job runs no
+        # device program).
+        self._chip_devices = None
+        if self.pool is not None and device != "cpu":
+            import jax
 
-    def _run_with_chips(self, run) -> int:
-        """Admit chips for one job, size the mesh plane to the grant via
-        env, run, and feed the outcome back into the chip breakers. Only
-        the grant's LENGTH travels: the mesh plane takes the first n of
-        jax.devices(), whichever chips were granted, and the export is
-        process-wide — so the supported shape is --workers 1 with every
-        chip healthy (ROADMAP S5 has the placement finding). Outputs are
-        byte-identical at any count; the admission ledger itself is
-        race-free under the pool lock."""
+            from toplingdb_tpu.ops import compaction_kernels
+
+            self._chip_devices = jax.devices()[:len(self.pool.chips)]
+            # The chips share each program: the first dispatch of one
+            # compiles it for every chip at once, not chip after chip.
+            compaction_kernels.share_programs(self._chip_devices)
+
+    def _run_job(self, job_dir: str) -> None:
+        """Run one job, on the chips of its grant when the host has a pool:
+        the span `dcompact.grant` is the wait for them, the granted
+        `jax.Device`s go to `worker.run_job` as its argument, and the
+        outcome feeds the chip breakers. Outputs are the same bytes on any
+        chip and beside any other job."""
+        from toplingdb_tpu.compaction import worker
+        from toplingdb_tpu.utils import telemetry
+
         if self.pool is None:
-            return run()
-        grant = self.pool.admit()
-        saved = {k: os.environ.get(k)
-                 for k in ("TPULSM_MESH_COMPACT", "TPULSM_MESH_DEVICES")}
-        if len(grant) > 1:
-            os.environ["TPULSM_MESH_COMPACT"] = "1"
-            os.environ["TPULSM_MESH_DEVICES"] = str(len(grant))
-        else:
-            # 0/1 healthy chips: run local/serial, never half-meshed.
-            os.environ.pop("TPULSM_MESH_COMPACT", None)
+            worker.run_job(job_dir)
+            return
+        with telemetry.span("dcompact.grant") as sp:
+            grant = self.pool.admit()
+            chips = [self.pool.index(c) for c in grant]
+            sp.tag(chips=",".join(map(str, chips)))
+        devices = None
+        if self._chip_devices is not None and chips:
+            devices = [self._chip_devices[i] for i in chips]
         ok = False
         try:
-            rc = run()
+            worker.run_job(job_dir, devices=devices)
             ok = True
-            return rc
         finally:
-            for k, v in saved.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
             self.pool.release(grant, ok=ok)
 
     def _count(self, ok: bool, left_pipeline: bool = False,
@@ -272,6 +326,7 @@ class DcompactWorkerService:
                             device_runtime.device_memory()
                     if svc.pool is not None:
                         body["chips"] = svc.pool.snapshot()
+                        body.update(svc.pool.counters())
                     if self.path != "/stats":
                         # Every thread of this process by CPU and run-queue
                         # wait: a /proc walk, so only when asked for.
@@ -318,6 +373,9 @@ class DcompactWorkerService:
                              lambda s: int(s["busy"])),
                             ("dcompact_chip_wedged",
                              lambda s: int(s["state"] != "closed")),
+                            ("dcompact_chip_held_usec",
+                             lambda s: s["held_usec"]),
+                            ("dcompact_chip_jobs", lambda s: s["jobs"]),
                         ):
                             m = f"tpulsm_{metric}"
                             lines.append(f"# TYPE {m} gauge")
@@ -359,9 +417,7 @@ class DcompactWorkerService:
                     req = json.loads(self.rfile.read(n))
                     job_dir = req["job_dir"]
                     request.tag(job_dir=os.path.basename(job_dir))
-                    with svc._sem:  # one job per chip at a time
-                        from toplingdb_tpu.compaction import worker
-
+                    with svc._sem:  # --workers jobs at a time
                         os.makedirs(job_dir, exist_ok=True)
                         # The worker owns the device: override the submitted
                         # params' device with this service's.
@@ -381,8 +437,7 @@ class DcompactWorkerService:
                         if dirty:
                             with open(ppath, "w") as pf:
                                 json.dump(params, pf, indent=1)
-                        rc = svc._run_with_chips(
-                            lambda: worker.run_job(job_dir))
+                        svc._run_job(job_dir)
                     with open(f"{job_dir}/results.json") as f:
                         results = json.load(f)
                     svc._count(ok=True, left_pipeline=bool(
@@ -404,6 +459,10 @@ class DcompactWorkerService:
         if self._server is not None:
             self._server.shutdown()
             self._server = None
+        if self._chip_devices is not None:
+            from toplingdb_tpu.ops import compaction_kernels
+
+            compaction_kernels.share_programs(())
 
 
 class HttpCompactionExecutorFactory(CompactionExecutorFactory):
@@ -496,10 +555,13 @@ def main(argv=None) -> int:
     ap.add_argument("--host", default="0.0.0.0",
                     help="bind address (cross-host deployments need non-loopback)")
     ap.add_argument("--device", default="cpu")
-    ap.add_argument("--workers", type=int, default=1)
+    ap.add_argument("--workers", type=int, default=1,
+                    help="jobs run at once; with --chips each is granted "
+                         "chips // workers chips")
     ap.add_argument("--chips", type=int, default=0,
-                    help="chips this host owns; >0 enables pod-level "
-                         "packing (per-chip queues + mesh-sized jobs)")
+                    help="chips this host owns; >0 grants each job its own "
+                         "chips (one a job at --workers N, all of them "
+                         "meshed at --workers 1)")
     args = ap.parse_args(argv)
     svc = DcompactWorkerService(args.device, args.workers,
                                 chips=args.chips)

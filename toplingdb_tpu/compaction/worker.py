@@ -20,7 +20,10 @@ import time
 import traceback
 
 
-def run_job(job_dir: str) -> int:
+def run_job(job_dir: str, devices=None) -> int:
+    """Run the job in `job_dir`. `devices`: the `jax.Device`s a dcompact
+    service granted the job (None: the backend's default device); a device
+    job runs its programs on them and on no other chip."""
     from toplingdb_tpu.compaction.executor import CompactionParams
 
     t_enter = time.time()
@@ -53,6 +56,8 @@ def run_job(job_dir: str) -> int:
     ctx = getattr(params, "trace", None)
     tags = dict(job_id=params.job_id, attempt=params.attempt,
                 device=params.device)
+    if devices:
+        tags["chip"] = ",".join(str(d.id) for d in devices)
     above = _tm.current_span()
     if above is not None and (not ctx or not ctx.get("trace_id")
                               or ctx["trace_id"] == above.trace_id):
@@ -66,7 +71,8 @@ def run_job(job_dir: str) -> int:
                 # Measured from params.json's mtime, before this process
                 # saw the job: the one back-dated span of a job.
                 _tm.span_event("compaction.queue_wait", waiting_usec)
-            results = _run_job_inner(job_dir, params, t_enter, waiting_usec)
+            results = _run_job_inner(job_dir, params, t_enter, waiting_usec,
+                                     devices)
             root.tag(input_records=results.stats.get("input_records", 0),
                      pipelined=bool(results.stats.get("pipelined")))
             with _tm.span("dcompact.results"):
@@ -83,18 +89,18 @@ def run_job(job_dir: str) -> int:
 
 
 def _run_job_inner(job_dir: str, params, t_enter: float,
-                   waiting_usec: int):
+                   waiting_usec: int, devices=None):
     store_mode = _StoreJobMode.maybe(params)
     try:
         return _run_job_body(job_dir, params, t_enter, waiting_usec,
-                             store_mode)
+                             store_mode, devices)
     finally:
         if store_mode is not None:
             store_mode.cleanup()
 
 
 def _run_job_body(job_dir: str, params, t_enter: float,
-                  waiting_usec: int, store_mode):
+                  waiting_usec: int, store_mode, devices=None):
     """Runs the job; returns its CompactionResults (run_job writes them)."""
     from toplingdb_tpu.compaction.compaction_job import (
         CompactionStats, build_outputs, surviving_tombstone_fragments,
@@ -219,6 +225,7 @@ def _run_job_body(job_dir: str, params, t_enter: float,
             device_name=params.device, blob_resolver=blob_source.get,
             column_family=(getattr(params, "cf_id", 0),
                            getattr(params, "cf_name", "default")),
+            devices=devices,
         )
         stats.input_files = len(params.input_files)
         stats.input_bytes = sum(

@@ -18,6 +18,7 @@ size bucket, not per job. All lanes are 32-bit (TPU-native); 64-bit packed
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ import numpy as np
 
 from toplingdb_tpu.db.dbformat import ValueType
 from toplingdb_tpu.ops import device_runtime  # noqa: F401  (compile cache)
+from toplingdb_tpu.utils import concurrency as ccy
 from toplingdb_tpu.utils.status import NotSupported
 
 _SIGN = 0x80000000
@@ -731,6 +733,72 @@ def shard_upload_nbytes(handle) -> int:
                if hasattr(v, "nbytes"))
 
 
+def shard_device(handle):
+    """The device an upload_uniform_shard handle's buffers live on: where
+    the shard's program runs."""
+    return next(iter(handle["ukb"].devices()))
+
+
+# Chips of one process that share every fused program (a dcompact service's
+# pool): the first dispatch of a program on one of them starts the same
+# program on zeros on each of the others, each on a thread of its own, so a
+# host of four chips waits for the compiles side by side, not chip after
+# chip; a dispatch that finds its chip's compile under way waits for it.
+# Every chip still runs an executable of its own (the device assignment is
+# part of the program), made once a process, or loaded from the persistent
+# cache.
+_shared = {"devices": (), "met": {}}
+_shared_mu = ccy.Lock("compaction_kernels.share_programs._shared_mu")
+
+
+def share_programs(devices) -> None:
+    """The chips whose programs are compiled together (none: each chip
+    compiles when it first meets a program)."""
+    with _shared_mu:
+        _shared["devices"] = tuple(devices)
+        _shared["met"] = {}
+
+
+def _await_shared_compile(h, snap_hi, bottommost) -> None:
+    devices = _shared["devices"]
+    if len(devices) < 2:
+        return
+    dev = shard_device(h)
+    key = (h["ukb"].shape, h["uk_len"], h["tomb_hi"] is not None,
+           snap_hi.shape)
+    with _shared_mu:
+        met = _shared["met"].get(key)
+        if met is None:
+            met = _shared["met"][key] = {}
+            for d in devices:
+                if d != dev:
+                    done = threading.Event()
+                    met[d.id] = done
+                    ccy.spawn(f"tpulsm-warm-{d.id}", _warm_program,
+                              owner=_shared,
+                              args=(h, snap_hi, bottommost, d, done))
+        done = met.get(dev.id)
+    if done is not None:
+        done.wait()
+
+
+def _warm_program(h, snap_hi, bottommost, device, done) -> None:
+    """Run the program of shard handle `h`'s shape on zeros on `device`."""
+    from toplingdb_tpu.utils import errors as _errors
+
+    try:
+        put = functools.partial(jax.device_put, device=device)
+        z = {k: (put(np.zeros(v.shape, v.dtype)) if hasattr(v, "shape")
+                 else v) for k, v in h.items()}
+        out = _dispatch_fused(z, np.zeros_like(snap_hi),
+                              np.zeros_like(snap_hi), bottommost)
+        jax.block_until_ready(out)
+    except Exception as e:  # the job's own dispatch compiles then
+        _errors.swallow(reason="shared-compile", exc=e)
+    finally:
+        done.set()
+
+
 def fused_uniform_shard_start(handle, snapshots: list[int], bottommost: bool):
     """Dispatch one shard's fused program over an upload_uniform_shard
     handle; enqueues the D2H copies so results stream back as the program
@@ -739,21 +807,25 @@ def fused_uniform_shard_start(handle, snapshots: list[int], bottommost: bool):
         raise NotSupported(
             f"device GC supports <= {MAX_SNAPSHOTS} live snapshots"
         )
-    h = handle
     snap_hi, snap_lo = _split_snapshots(snapshots)
+    _await_shared_compile(handle, snap_hi, bottommost)
+    out = _dispatch_fused(handle, snap_hi, snap_lo, bottommost)
+    for a in out:
+        if hasattr(a, "copy_to_host_async"):
+            a.copy_to_host_async()
+    return out
+
+
+def _dispatch_fused(h, snap_hi, snap_lo, bottommost: bool):
     uk_len = h["uk_len"]
     w = (max(uk_len, 4) + 3) // 4
     has_tombs = h["tomb_hi"] is not None
     t_hi = h["tomb_hi"] if has_tombs else np.zeros(1, dtype=np.uint32)
     t_lo = h["tomb_lo"] if has_tombs else np.zeros(1, dtype=np.uint32)
-    out = _fused_uniform_shard_impl(
+    return _fused_uniform_shard_impl(
         h["ukb"], h["packed_hi"], h["packed_lo"], t_hi, t_lo, snap_hi, snap_lo,
         np.int32(h["total"]), w, uk_len, np.bool_(bottommost), has_tombs,
     )
-    for a in out:
-        if hasattr(a, "copy_to_host_async"):
-            a.copy_to_host_async()
-    return out
 
 
 def fused_uniform_shard_finish(pending):

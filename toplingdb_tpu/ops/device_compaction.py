@@ -16,6 +16,7 @@ run_device_compaction checks against what JAX reports.
 from __future__ import annotations
 
 import bisect
+import contextlib
 import os
 import time
 import types
@@ -95,6 +96,16 @@ def _tombstone_cover(sorted_user_keys: list[bytes], rd: RangeDelAggregator,
 # 4 key bytes, and XLA compile time grows with operand count. Longer keys
 # route to the host CompactionIterator (scheduler fallback-to-local).
 MAX_DEVICE_KEY_BYTES = 128
+
+
+def _on_grant(devices):
+    """Programs that upload nothing of their own (the whole-job program,
+    the per-entry stream) run on the grant's first chip."""
+    if not devices:
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.default_device(devices[0])
 
 
 def _host_sort() -> bool:
@@ -734,7 +745,7 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
                                     table_options, snapshots, merge_operator,
                                     new_file_number, creation_time,
                                     device_name, column_family=(0, "default"),
-                                    blob_resolver=None):
+                                    blob_resolver=None, devices=None):
     from toplingdb_tpu.compaction.compaction_job import (
         surviving_tombstone_fragments,
     )
@@ -761,6 +772,7 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
                 snapshots, new_file_number, creation_time, pstats,
                 MAX_DEVICE_KEY_BYTES, column_family,
                 merge_operator=merge_operator, blob_resolver=blob_resolver,
+                devices=devices,
             )
         except (pl.PipelineIneligible, NotSupported) as e:
             # The serial path decides (and re-raises what it must); the
@@ -844,12 +856,13 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
             col = types.SimpleNamespace(seq=seq_a, vtype=vt_a, n=kv.n)
         elif shards is not None:
             # Upload + dispatch through the mesh seam: serial mode uploads
-            # every shard up front to the default device (device_put and
-            # jit dispatch are async; shard s+1's transfer streams while
-            # shard s computes, and fused_uniform_shard_start enqueues
-            # each D2H copy so results stream back); TPULSM_MESH_COMPACT
-            # places shards round-robin over every chip instead, double-
-            # buffered per chip (ops/mesh_compaction.py).
+            # every shard up front to the job's chip (the grant's, else the
+            # default device; device_put and jit dispatch are async; shard
+            # s+1's transfer streams while shard s computes, and
+            # fused_uniform_shard_start enqueues each D2H copy so results
+            # stream back); a grant of several chips (or
+            # TPULSM_MESH_COMPACT) places shards round-robin over them
+            # instead, double-buffered per chip (ops/mesh_compaction.py).
             from toplingdb_tpu.ops import mesh_compaction as mc
 
             t_up = time.time()
@@ -857,7 +870,7 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
                 finish_shard, _mesh_on = mc.dispatch_shards(
                     shards, cover, snapshots, compaction.bottommost,
                     stats=stats, any_complex=bool(any_complex),
-                    trace=_tele.current_handle(),
+                    trace=_tele.current_handle(), devices=devices,
                 )
                 up.tag(h2d_bytes=stats.h2d_bytes)
             # Upload-enqueue span (device_put is async, so this is a lower
@@ -895,11 +908,12 @@ def _run_device_compaction_columnar(env, dbname, icmp, compaction, table_cache,
                 cx_flags = (np.concatenate(cxs) if cxs
                             else np.empty(0, bool))
         else:
-            order, zero_flags, cx_flags, has_complex = \
-                ck.fused_encode_sort_gc(
-                    kv.key_buf, kv.key_offs, kv.key_lens, mkb, snapshots,
-                    compaction.bottommost, cover,
-                )
+            with _on_grant(devices):
+                order, zero_flags, cx_flags, has_complex = \
+                    ck.fused_encode_sort_gc(
+                        kv.key_buf, kv.key_offs, kv.key_lens, mkb, snapshots,
+                        compaction.bottommost, cover,
+                    )
     except NotSupported:
         raise _FallbackToEntries()  # non-dense buffers, >cap snapshots etc.
 
@@ -1003,7 +1017,7 @@ def run_device_compaction(env, dbname, icmp, compaction, table_cache,
                           compaction_filter=None, new_file_number=None,
                           creation_time=None, device_name="tpu",
                           blob_resolver=None, blob_gc=None,
-                          column_family=(0, "default")):
+                          column_family=(0, "default"), devices=None):
     """Device counterpart of run_compaction_to_tables — same signature shape,
     byte-identical outputs (including output cutting). Jobs without a
     compaction filter take the fully-columnar native fast path; the rest
@@ -1014,7 +1028,13 @@ def run_device_compaction(env, dbname, icmp, compaction, table_cache,
     unless JAX runs on the platform it names (device_runtime), and it
     raises when the native library is missing — the device path has no
     quiet detour through the CPU or through per-entry Python. A kernel
-    that fails to compile raises out of here as it is."""
+    that fails to compile raises out of here as it is.
+
+    `devices`: the chips a dcompact service granted the job (None: the
+    backend's default device). Every upload is committed to them, so every
+    program runs there; the whole-job program and the per-entry stream,
+    which upload nothing of their own, run under `jax.default_device` of
+    the first. The stats name the grant and the chips the shards ran on."""
     from toplingdb_tpu import native
 
     device_runtime.require_device(device_name)
@@ -1029,7 +1049,9 @@ def run_device_compaction(env, dbname, icmp, compaction, table_cache,
             env, dbname, icmp, compaction, table_cache, table_options,
             snapshots, merge_operator, compaction_filter, new_file_number,
             creation_time, device_name, blob_resolver, blob_gc,
-            column_family)
+            column_family, devices)
+    if devices:
+        stats.devices_granted = sorted(d.id for d in devices)
     gc_us1, gc_n1 = _tele.gc_totals()
     stats.gc_pause_usec = gc_us1 - gc_us0
     stats.gc_collections = gc_n1 - gc_n0
@@ -1043,7 +1065,7 @@ def _run_device_compaction(env, dbname, icmp, compaction, table_cache,
                            table_options, snapshots, merge_operator,
                            compaction_filter, new_file_number, creation_time,
                            device_name, blob_resolver, blob_gc,
-                           column_family):
+                           column_family, devices=None):
     if (compaction_filter is None
             and (blob_gc is None or not blob_gc.active)
             and not getattr(table_options, "properties_collector_factories", None)
@@ -1056,6 +1078,7 @@ def _run_device_compaction(env, dbname, icmp, compaction, table_cache,
                 env, dbname, icmp, compaction, table_cache, table_options,
                 snapshots, merge_operator, new_file_number, creation_time,
                 device_name, column_family, blob_resolver=blob_resolver,
+                devices=devices,
             )
         except _FallbackToEntries:
             pass  # eligibility, not error handling: per-entry semantics
@@ -1078,12 +1101,14 @@ def _run_device_compaction(env, dbname, icmp, compaction, table_cache,
     if blob_gc is not None and blob_gc.active:
         stream = blob_gc.rewrite(stream)
     try:
-        outputs = build_outputs(
-            env, dbname, icmp, compaction, stream, tombs, new_file_number,
-            table_options, stats,
-            creation_time if creation_time is not None else int(time.time()),
-            column_family=column_family,
-        )
+        with _on_grant(devices):    # the stream runs its programs here
+            outputs = build_outputs(
+                env, dbname, icmp, compaction, stream, tombs,
+                new_file_number, table_options, stats,
+                creation_time if creation_time is not None
+                else int(time.time()),
+                column_family=column_family,
+            )
     except BaseException:
         if blob_gc is not None:
             blob_gc.abort()

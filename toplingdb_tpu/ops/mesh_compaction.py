@@ -17,7 +17,10 @@ flight per device): shard s+D's H2D transfer streams while shard s
 computes on the same chip, and every program's D2H copies are enqueued at
 dispatch, so the writer's encode overlaps the remaining chips' compute.
 
-Gating: `TPULSM_MESH_COMPACT=1` enables the mode; ineligible jobs
+Gating: a job granted two chips or more by a dcompact service (`devices`,
+the grant, passed down from `worker.run_job`) meshes over exactly those
+chips; one granted chip holds every shard of its job. Without a grant,
+`TPULSM_MESH_COMPACT=1` enables the mode over the visible devices; ineligible jobs
 (complex merge groups, non-uniform shards, below the row floor, a single
 shard/device) fall back to the serial single-device plane automatically —
 mesh_plan.check_eligibility is the one fallback matrix. A chip that
@@ -47,19 +50,30 @@ def mesh_enabled() -> bool:
     return os.environ.get("TPULSM_MESH_COMPACT") == "1"
 
 
-def maybe_plan(shards, any_complex: bool = False, stats=None,
-               trace=None):
-    """A MeshPlan when the knob is on and the job is eligible, else None.
-    Eligibility misses while the knob is ON are fallbacks: counted on
-    `stats.mesh_fallbacks` and emitted as a `compaction.mesh.fallback`
-    event so waterfalls show WHY a job stayed single-chip."""
+def _mesh_candidates(devices):
+    """The chips a job may mesh over: its grant (two chips or more), else,
+    with the knob on, the visible devices; None: no mesh."""
+    if devices is not None:
+        return mesh_plan.mesh_devices(devices) if len(devices) > 1 else None
     if not mesh_enabled():
         return None
     try:
-        devices = mesh_plan.mesh_devices()
+        return mesh_plan.mesh_devices()
     except Exception as e:  # no jax backend at all → serial plane
         _errors.swallow(reason="mesh-no-backend", exc=e)
-        devices = []
+        return []
+
+
+def maybe_plan(shards, any_complex: bool = False, stats=None,
+               trace=None, devices=None):
+    """A MeshPlan when the job may mesh (a grant of two chips or more, or
+    the knob on) and is eligible, else None. Eligibility misses then are
+    fallbacks: counted on `stats.mesh_fallbacks` and emitted as a
+    `compaction.mesh.fallback` event so waterfalls show WHY a job stayed
+    single-chip."""
+    devices = _mesh_candidates(devices)
+    if devices is None:
+        return None
     plan, reason = mesh_plan.plan_shards(shards, any_complex, devices)
     if plan is None:
         if stats is not None:
@@ -84,7 +98,7 @@ class MeshShardRun:
     bench's 1-chip runs and mesh runs share this driver."""
 
     def __init__(self, plan, shards, cover, snapshots, bottommost,
-                 stats=None, trace=None):
+                 stats=None, trace=None, home=None):
         from toplingdb_tpu.ops import compaction_kernels as ck
 
         self._ck = ck
@@ -97,6 +111,10 @@ class MeshShardRun:
         self._trace = trace
         self._mesh = (mesh_plan.build_range_mesh(plan.devices)
                       if plan is not None else None)
+        # The chips this job may run on: the plan's, or the one it was
+        # granted (`home`), or none (the backend's default device).
+        self._devices = (list(plan.devices) if plan is not None
+                         else [home] if home is not None else [])
         self._wedged: set[int] = set()
         self._pend: dict[int, tuple] = {}
         self._next = 0
@@ -106,30 +124,29 @@ class MeshShardRun:
     # -- placement ---------------------------------------------------------
 
     def _device_for(self, s: int):
-        """Shard s's chip: the plan's round-robin assignment, re-mapped
-        onto the surviving chips once any are wedged; None (= default
-        device) when no planned chip survives."""
-        if self._plan is None:
-            return None
-        if not self._wedged:
+        """Shard s's chip: the plan's round-robin assignment (or the home
+        chip), re-mapped onto the surviving chips once any are wedged;
+        None (= default device) when no chip of the job survives."""
+        if not self._wedged and self._plan is not None:
             return self._plan.devices[self._plan.assignments[s]]
-        healthy = [d for i, d in enumerate(self._plan.devices)
+        healthy = [d for i, d in enumerate(self._devices)
                    if i not in self._wedged]
         if not healthy:
             return None
         return healthy[s % len(healthy)]
 
     def _wedge(self, device, exc) -> None:
-        if self._plan is None or device is None:
+        if device is None:
             return
-        for i, d in enumerate(self._plan.devices):
+        for i, d in enumerate(self._devices):
             if d is device and i not in self._wedged:
                 self._wedged.add(i)
                 if self._stats is not None:
                     self._stats.mesh_fallbacks = getattr(
                         self._stats, "mesh_fallbacks", 0) + 1
-                    self._stats.mesh_chips = max(
-                        1, len(self._plan.devices) - len(self._wedged))
+                    if self._plan is not None:
+                        self._stats.mesh_chips = max(
+                            1, len(self._devices) - len(self._wedged))
                 telemetry.span_event_under(
                     self._trace, "compaction.mesh.fallback", 0,
                     reason="chip-wedged", chip=str(device),
@@ -151,6 +168,7 @@ class MeshShardRun:
                                           device=device)
         if self._stats is not None:
             self._stats.h2d_bytes += self._ck.shard_upload_nbytes(h)
+            self._stats.note_device(self._ck.shard_device(h).id)
         return self._ck.fused_uniform_shard_start(
             h, self._snapshots, self._bottommost)
 
@@ -198,7 +216,7 @@ class MeshShardRun:
         # Callers time the blocking wait into stats.device_wait_usec and
         # the span `pipeline.merge_gc` around finish() itself; here that
         # span learns which chip the shard ran on.
-        if self._plan is not None:
+        if self._devices:
             sp = telemetry.current_span()
             if sp is not None:
                 sp.tag(chip=str(device) if device is not None
@@ -208,32 +226,28 @@ class MeshShardRun:
 
 
 def dispatch_shards(shards, cover, snapshots, bottommost, stats=None,
-                    any_complex: bool = False, trace=None):
-    """The single seam device_compaction.py calls: plan (knob + the
-    eligibility matrix), then return (finish(s) callable, mesh_active).
-    Ineligible/disabled jobs get the classic serial dispatch — every
-    shard uploaded up front to the default device — so callers never
-    branch on the mode."""
+                    any_complex: bool = False, trace=None, devices=None):
+    """The single seam device_compaction.py calls: plan (the grant or the
+    knob, and the eligibility matrix), then return (finish(s) callable,
+    mesh_active). Jobs that do not mesh get the classic serial dispatch —
+    every shard uploaded up front to the grant's first chip, or to the
+    default device without a grant — so callers never branch on the
+    mode."""
     plan = maybe_plan(shards, any_complex=any_complex, stats=stats,
-                      trace=trace)
+                      trace=trace, devices=devices)
     run = MeshShardRun(plan, shards, cover, snapshots, bottommost,
-                       stats=stats, trace=trace)
+                       stats=stats, trace=trace,
+                       home=devices[0] if devices else None)
     return run.finish, plan is not None
 
 
-def pipeline_devices(n_shards: int, stats=None, trace=None):
+def pipeline_devices(n_shards: int, stats=None, trace=None, devices=None):
     """Chips for the pipelined plane's compute stage: the same gate as
     dispatch_shards minus the shard-shape checks (the pipeline validates
     uniformity itself, shard by shard, as scans land). Returns a device
     list (len >= 2) or None for the classic single-buffer path."""
-    if not mesh_enabled() or n_shards < 2:
-        return None
-    try:
-        devices = mesh_plan.mesh_devices()
-    except Exception as e:
-        _errors.swallow(reason="mesh-no-backend", exc=e)
-        return None
-    if len(devices) < 2:
+    devices = _mesh_candidates(devices)
+    if n_shards < 2 or len(devices or ()) < 2:
         return None
     if stats is not None:
         stats.mesh_chips = len(devices)

@@ -687,11 +687,14 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
                     bottommost, frags, max_dev_key):
     """Compute worker, device mode: upload each shard's uniform chunks as
     soon as its scan lands (async H2D + dispatch), finish in order —
-    double-buffered so shard s+1 transfers while shard s computes. Under
-    TPULSM_MESH_COMPACT shards round-robin over every chip instead
-    (committed uploads pin each program, ops/mesh_compaction.py) and the
-    lookahead widens to UPLOAD_DEPTH per chip; a chip that fails mid-job
-    demotes the remaining shards to the default device.
+    double-buffered so shard s+1 transfers while shard s computes. Every
+    upload is committed to the job's chip (`shared.devices`, a dcompact
+    service's grant; the default device without one). Under a grant of
+    two chips or more (or TPULSM_MESH_COMPACT without a grant) shards
+    round-robin over them instead (committed uploads pin each program,
+    ops/mesh_compaction.py) and the lookahead widens to UPLOAD_DEPTH per
+    chip; a chip that fails mid-job demotes the remaining shards to the
+    default device.
 
     This thread feeds the device, so every step of it is a span: whatever
     the device's trace shows as idle inside a job has the name of what
@@ -705,14 +708,18 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
     n_shards = len(splitters) + 1
     snaps = np.asarray(sorted(snapshots), dtype=np.uint64)
     stats, trace = shared.stats, shared.trace
-    mesh_devs = mc.pipeline_devices(n_shards, stats=stats, trace=trace)
+    mesh_devs = mc.pipeline_devices(n_shards, stats=stats, trace=trace,
+                                    devices=shared.devices or None) or []
+    # The one chip of a job that does not mesh (None: the default device).
+    home = [shared.devices[0] if shared.devices else None]
     depth = [mp.UPLOAD_DEPTH * len(mesh_devs) if mesh_devs else 1]
     pendings = []  # (ranges, lmap, pending, s, dev, chunks, covers, cov)
 
     def _demote(exc) -> None:
-        # Wedged chip: the rest of the job runs single-device; bytes are
-        # unchanged (same kernels), only placement degrades.
+        # Wedged chip: the rest of the job runs on the default device;
+        # bytes are unchanged (same kernels), only placement degrades.
         mesh_devs.clear()
+        home[0] = None
         depth[0] = 1
         stats.mesh_chips = 1
         stats.mesh_fallbacks = getattr(stats, "mesh_fallbacks", 0) + 1
@@ -723,11 +730,14 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
     def start_one(s, chunks, covers, dev):
         """H2D enqueue, then jit dispatch (and the compile, when the
         shape is met for the first time)."""
+        if mc._FAULT_HOOK is not None:
+            mc._FAULT_HOOK(s, dev)
         with telemetry.span_under(trace, "pipeline.upload",
                                   shard=s) as sp:
             h = ck.upload_uniform_shard(chunks, covers, device=dev)
             nb = ck.shard_upload_nbytes(h)
             stats.h2d_bytes += nb
+            stats.note_device(ck.shard_device(h).id)
             sp.tag(h2d_bytes=nb)
         with telemetry.span_under(trace, "pipeline.dispatch",
                                   shard=s) as sp:
@@ -785,7 +795,7 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
                                       shard=s):
                 chunks, covers, cov = _prepare_shard_chunks(
                     ck, kv, ranges, frags, snaps, max_dev_key, shared)
-            dev = mesh_devs[s % len(mesh_devs)] if mesh_devs else None
+            dev = mesh_devs[s % len(mesh_devs)] if mesh_devs else home[0]
             try:
                 pending = start_one(s, chunks, covers, dev)
             except Exception as e:
@@ -911,16 +921,17 @@ class _Shared:
     the telemetry handle stage workers parent their spans under."""
 
     __slots__ = ("trailer_override", "seqs", "vtypes", "stats", "trace",
-                 "fold")
+                 "fold", "devices")
 
 
 def run_pipelined(env, dbname, icmp, compaction, table_cache, table_options,
                   snapshots, new_file_number, creation_time, stats,
                   max_dev_key, column_family=(0, "default"),
-                  merge_operator=None, blob_resolver=None):
+                  merge_operator=None, blob_resolver=None, devices=None):
     """Run one compaction through the three-stage pipeline. Returns the
     write_tables_columnar file tuples plus the shared arrays used to
-    build output metadata: (files, kv, vtypes, tombs).
+    build output metadata: (files, kv, vtypes, tombs). `devices`: the
+    chips a dcompact service granted the job (None: the default device).
 
     Raises PipelineIneligible for shapes the serial path must take and
     propagates hard errors (Corruption, IO) after partial outputs are
@@ -971,6 +982,7 @@ def run_pipelined(env, dbname, icmp, compaction, table_cache, table_options,
         shared.vtypes = np.zeros(kv.n, dtype=np.int32)
         shared.stats = stats
         shared.trace = trace
+        shared.devices = list(devices) if devices else []
         shared.fold = _Fold(icmp, snapshots, compaction.bottommost,
                             merge_operator, None if rd.empty() else rd,
                             blob_resolver, *slack)

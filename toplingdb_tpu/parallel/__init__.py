@@ -12,6 +12,9 @@ mechanisms (SURVEY.md §2.3):
            all_gather → all_to_all redistribution → local merge → boundary
            halo exchange (ppermute) for the GC mask.
 
-distributed_gc.py implements the 'range' axis; fanout.py stacks jobs on the
-'jobs' axis and drives whole pods.
+distributed_gc.py implements the 'range' axis. The 'jobs' axis is the dcompact
+service's chip pool (compaction/dcompact_service.py: `--chips N --workers N`
+grants each job a chip of its own and hands the granted `jax.Device` down to
+the job's uploads); mesh_plan.py places one job's shards over a grant of
+several chips.
 """

@@ -77,12 +77,16 @@ def mesh_min_rows() -> int:
     return DEFAULT_MESH_MIN_ROWS
 
 
-def mesh_devices(limit: int | None = None) -> list:
-    """The chips a mesh plan may schedule onto: jax.devices() of the
-    default backend, capped by `limit` / TPULSM_MESH_DEVICES."""
-    import jax
+def mesh_devices(granted=None, limit: int | None = None) -> list:
+    """The chips a mesh plan may schedule onto: `granted` (the chips a
+    dcompact service granted the job), else jax.devices() of the default
+    backend; capped by `limit` / TPULSM_MESH_DEVICES."""
+    if granted is not None:
+        devs = list(granted)
+    else:
+        import jax
 
-    devs = list(jax.devices())
+        devs = list(jax.devices())
     lim = limit if limit is not None else device_limit()
     if lim is not None:
         devs = devs[: max(1, lim)]
