@@ -1,15 +1,17 @@
-"""The writer's own clocks inside DB.write (write.cpu.micros,
-write.native.micros, write.gil.wait.micros from the native plane's
-out[8..9]), every thread of a process by CPU and run-queue wait
+"""The writer's own clocks inside DB.write (write.native.micros,
+write.gil.wait.micros from the native plane's out[8..9]; none of them a
+system call), every thread of a process by CPU and run-queue wait
 (telemetry.thread_times: the DB's tpulsm.thread-times, the dcompact
-service's /stats?threads=1), and lint rule 5 (no declared name that
-nobody records)."""
+service's /stats?threads=1; the writer's CPU over a window is the led
+writer's delta there), and lint rule 5 (no declared name that nobody
+records)."""
 
 from __future__ import annotations
 
 import json
 import os
 import threading
+import time
 import urllib.request
 
 import pytest
@@ -22,8 +24,7 @@ from toplingdb_tpu.tools import check_telemetry
 from toplingdb_tpu.utils import statistics as st
 from toplingdb_tpu.utils import telemetry
 
-CLOCKS = (st.WRITE_CPU_MICROS, st.WRITE_NATIVE_MICROS,
-          st.WRITE_GIL_WAIT_MICROS)
+CLOCKS = (st.WRITE_NATIVE_MICROS, st.WRITE_GIL_WAIT_MICROS)
 
 
 def _plane_available() -> bool:
@@ -51,6 +52,30 @@ def _batch(i: int, n: int = 500) -> WriteBatch:
 def _clocks(stats) -> dict:
     t = stats.tickers()
     return {k: t.get(k, 0) for k in CLOCKS}
+
+
+def _thread_times(db) -> dict:
+    return json.loads(db.get_property("tpulsm.thread-times"))
+
+
+def _led(doc: dict) -> dict:
+    led = [e for e in doc["threads"] if e["led_writer"]]
+    assert len(led) == 1, doc
+    return led[0]
+
+
+def _write_checked(db, stats, n: int = 40) -> None:
+    """n batches of 500, each holding native + gil.wait <= wall, wall
+    being db.write.micros."""
+    h = stats.get_histogram(st.DB_WRITE_MICROS)
+    for i in range(n):
+        c0, w0 = _clocks(stats), h.sum
+        db.write(_batch(i))
+        c1, wall = _clocks(stats), h.sum - w0
+        d = {k: c1[k] - c0[k] for k in CLOCKS}
+        assert d[st.WRITE_NATIVE_MICROS] > 0
+        assert (d[st.WRITE_NATIVE_MICROS] + d[st.WRITE_GIL_WAIT_MICROS]
+                <= wall + 1e-6), (d, wall)
 
 
 @needs_plane
@@ -91,26 +116,90 @@ def test_gil_hog_raises_gil_wait(tmp_path):
 @needs_plane
 @pytest.mark.parametrize("pipelined", [False, True])
 def test_identities_hold_on_every_batch(tmp_path, pipelined):
-    """(b) native + gil.wait <= wall and cpu <= wall, batch by batch, over
-    20,000 puts, wall being db.write.micros; also on the staged path, whose
-    frame and insert calls the leader makes in two steps."""
+    """(b) native + gil.wait <= wall, batch by batch, over 20,000 puts,
+    wall being db.write.micros; also on the staged path, whose frame and
+    insert calls the leader makes in two steps. The writer's CPU over the
+    40 batches, the led writer's cpu_ns delta in tpulsm.thread-times, is
+    never negative and, on a kernel with schedstat, within the window's
+    wall."""
     db, stats = _open(tmp_path, enable_pipelined_write=pipelined)
-    h = stats.get_histogram(st.DB_WRITE_MICROS)
     try:
-        for i in range(40):
-            c0, w0 = _clocks(stats), h.sum
-            db.write(_batch(i))
-            c1, wall = _clocks(stats), h.sum - w0
-            d = {k: c1[k] - c0[k] for k in CLOCKS}
-            assert d[st.WRITE_NATIVE_MICROS] > 0
-            assert (d[st.WRITE_NATIVE_MICROS] + d[st.WRITE_GIL_WAIT_MICROS]
-                    <= wall + 1e-6), (d, wall)
-            assert d[st.WRITE_CPU_MICROS] <= wall + 1e-6, (d, wall)
+        db.write(_batch(40))  # the led mark set before the window opens
+        a = _thread_times(db)
+        _write_checked(db, stats)
+        b = _thread_times(db)
     finally:
+        db.close()
+    la, lb = _led(a), _led(b)
+    assert la["tid"] == lb["tid"] == threading.get_native_id()
+    cpu = lb["cpu_ns"] - la["cpu_ns"]
+    assert cpu >= 0
+    if not lb["coarse"]:
+        assert cpu <= b["monotonic_ns"] - a["monotonic_ns"], (cpu, a, b)
+    t = stats.tickers()
+    assert t[st.WRITE_GROUP_NATIVE_COMMITS] == 41
+    assert t[st.MEMTABLE_INSERT_RECORDS] == 20_500
+
+
+@needs_plane
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_write_path_makes_no_clock_syscall(tmp_path, monkeypatch,
+                                           pipelined):
+    """DB.write with statistics on calls neither time.thread_time_ns nor
+    threading.get_native_id (each a system call), on the native plane and
+    on the staged path, and its clocks keep their identities."""
+    db, stats = _open(tmp_path, enable_pipelined_write=pipelined,
+                      write_buffer_size=64 << 20)  # no flush, no new thread
+
+    def syscall(*_):
+        raise AssertionError("a system call on the writer's path")
+
+    me = threading.get_native_id()
+    try:
+        monkeypatch.setattr(time, "thread_time_ns", syscall)
+        monkeypatch.setattr(threading, "get_native_id", syscall)
+        _write_checked(db, stats)
+        assert _led(_thread_times(db))["tid"] == me
+    finally:
+        monkeypatch.undo()
         db.close()
     t = stats.tickers()
     assert t[st.WRITE_GROUP_NATIVE_COMMITS] == 40
-    assert t[st.MEMTABLE_INSERT_RECORDS] == 20_000
+    assert stats.get_histogram(st.DB_WRITE_MICROS).count == 40
+
+
+def test_led_writer_resolved_at_read(tmp_path):
+    """The led mark is the thread's ident, resolved to its kernel tid when
+    tpulsm.thread-times is read: a writer on another thread is marked while
+    it lives, and resolves to None once it has exited."""
+    db, _ = _open(tmp_path)
+    wrote, release = threading.Event(), threading.Event()
+    seen = {}
+
+    def writer():
+        db.write(_batch(0))
+        seen["tid"] = threading.get_native_id()
+        wrote.set()
+        release.wait(timeout=30)
+
+    th = threading.Thread(target=writer, name="side-writer")
+    try:
+        th.start()
+        assert wrote.wait(timeout=30)
+        live = _thread_times(db)
+        release.set()
+        th.join(timeout=30)
+        assert not th.is_alive()
+        gone = _thread_times(db)
+    finally:
+        release.set()
+        db.close()
+    led = _led(live)
+    assert led["tid"] == seen["tid"] == live["led_writer_tid"]
+    assert led["name"] == "side-writer"
+    assert seen["tid"] != threading.get_native_id()
+    assert gone["led_writer_tid"] is None
+    assert not any(e["led_writer"] for e in gone["threads"])
 
 
 def _check_doc(doc: dict) -> None:
@@ -208,7 +297,7 @@ def test_thread_times_coarse_without_schedstat(monkeypatch):
 @needs_plane
 def test_zero_stamps_record_nothing(tmp_path):
     """(d) A native side that leaves out[8..9] at 0 (an older .so) books no
-    native or GIL time; the CPU clock, the Python side's own, still runs."""
+    native or GIL time; the wall, the Python side's own, still runs."""
     db, stats = _open(tmp_path)
     real = db._resolve_write_plane()
 
@@ -229,7 +318,8 @@ def test_zero_stamps_record_nothing(tmp_path):
     assert t[st.WRITE_GROUP_NATIVE_COMMITS] == 20
     assert st.WRITE_NATIVE_MICROS not in t
     assert st.WRITE_GIL_WAIT_MICROS not in t
-    assert t[st.WRITE_CPU_MICROS] > 0
+    h = stats.get_histogram(st.DB_WRITE_MICROS)
+    assert h.count == 20 and h.sum > 0
 
 
 def test_lint_rule5_unrecorded_names(tmp_path):

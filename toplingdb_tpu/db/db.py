@@ -385,9 +385,10 @@ class DB:
         self._write_plane_knob = (
             _os.environ.get("TPULSM_WRITE_PLANE", "1") != "0")
         self._write_plane = None
-        # native_id of the thread that last led a write group (one store a
-        # group), marked in get_property("tpulsm.thread-times").
-        self._led_tid = None
+        # threading ident of the thread that last led a write group (one
+        # store a group); get_property("tpulsm.thread-times") resolves it
+        # to the kernel tid it marks.
+        self._led_ident = None
         self._wclk = _WriterClocks()
         # Async WAL writer ring (Options.enable_async_wal): WAL durability
         # leaves the commit critical section and concurrent leaders' syncs
@@ -1139,10 +1140,11 @@ class DB:
         if stats is None and tracer is None:
             return self._write_impl(batch, opts, on_sequenced)
         # time/_st are module-level imports: no per-call import
-        # machinery on the write hot path. The CPU and native clocks are
-        # read inside the wall's, so cpu <= wall and native + gil <= wall.
+        # machinery on the write hot path. The native clocks are read
+        # inside the wall's, so native + gil <= wall. No clock here costs
+        # a system call (monotonic_ns is served by the vDSO); the writer's
+        # CPU over a window is tpulsm.thread-times' delta.
         t0 = time.monotonic_ns()
-        c0 = time.thread_time_ns()
         clk = self._wclk
         n0, g0 = clk.native_ns, clk.gil_ns
         try:
@@ -1153,17 +1155,14 @@ class DB:
                 tracer.note_seq(seq, root)
             return seq
         finally:
-            cpu_ns = time.thread_time_ns() - c0
             micros = (time.monotonic_ns() - t0) / 1000
             if stats is not None:
                 stats.record_in_histogram(_st.DB_WRITE_MICROS, micros)
                 dn, dg = clk.native_ns - n0, clk.gil_ns - g0
-                stats.record_ticks(
-                    ((_st.WRITE_CPU_MICROS, cpu_ns // 1000),
-                     (_st.WRITE_NATIVE_MICROS, dn // 1000),
-                     (_st.WRITE_GIL_WAIT_MICROS, dg // 1000))
-                    if dn or dg else
-                    ((_st.WRITE_CPU_MICROS, cpu_ns // 1000),))
+                if dn or dg:
+                    stats.record_ticks(
+                        ((_st.WRITE_NATIVE_MICROS, dn // 1000),
+                         (_st.WRITE_GIL_WAIT_MICROS, dg // 1000)))
             if root is not None:
                 root.finish()
             elif tracer is not None and tracer.slow_usec \
@@ -1260,7 +1259,7 @@ class DB:
         return group
 
     def _lead_write_group(self, leader: _Writer) -> None:
-        self._led_tid = threading.get_native_id()
+        self._led_ident = threading.get_ident()  # pthread_self: no syscall
         group = self._snapshot_group(leader)
         if self.options.unordered_write or self.options.enable_pipelined_write:
             self._lead_write_group_staged(leader, group)
@@ -1776,13 +1775,11 @@ class DB:
         seq_top = self.versions.last_sequence + 1
         self._maybe_sample_seqno_time(seq_top - 1)
         if self.stats is not None:
-            from toplingdb_tpu.utils import statistics as st
-
             self.stats.record_tick(
-                st.NUMBER_KEYS_WRITTEN, sum(w.batch.count() for w in group)
+                _st.NUMBER_KEYS_WRITTEN, sum(w.batch.count() for w in group)
             )
             self.stats.record_tick(
-                st.BYTES_WRITTEN, sum(w.batch.data_size() for w in group)
+                _st.BYTES_WRITTEN, sum(w.batch.data_size() for w in group)
             )
         self._sync_wbm()
         if self._memtable_full():
@@ -1895,17 +1892,15 @@ class DB:
             self.versions.last_sequence = seq - 1
             self._maybe_sample_seqno_time(seq - 1)
             if self.stats is not None:
-                from toplingdb_tpu.utils import statistics as st
-
                 self.stats.record_tick(
-                    st.NUMBER_KEYS_WRITTEN, sum(w.batch.count() for w in group)
-                )
+                    _st.NUMBER_KEYS_WRITTEN,
+                    sum(w.batch.count() for w in group))
                 bw = sum(w.batch.data_size() for w in group)
-                self.stats.record_tick(st.BYTES_WRITTEN, bw)
-                self.stats.record_in_histogram(st.BYTES_PER_WRITE, bw)
-                self.stats.record_tick(st.WRITE_DONE_BY_SELF)
+                self.stats.record_tick(_st.BYTES_WRITTEN, bw)
+                self.stats.record_in_histogram(_st.BYTES_PER_WRITE, bw)
+                self.stats.record_tick(_st.WRITE_DONE_BY_SELF)
                 if len(group) > 1:
-                    self.stats.record_tick(st.WRITE_DONE_BY_OTHER,
+                    self.stats.record_tick(_st.WRITE_DONE_BY_OTHER,
                                            len(group) - 1)
             self._sync_wbm()
             if self._memtable_full():
@@ -4525,7 +4520,9 @@ class DB:
         if name == "tpulsm.thread-times":
             import json as _json
 
-            return _json.dumps(_tm.thread_times(self._led_tid))
+            # A led writer that has exited resolves to None.
+            tids = {t.ident: t.native_id for t in threading.enumerate()}
+            return _json.dumps(_tm.thread_times(tids.get(self._led_ident)))
         if name == "tpulsm.threads":
             import json as _json
 

@@ -92,11 +92,11 @@ MEMTABLE_INSERT_RECORDS = "memtable.insert.records"
 MEMTABLE_INSERT_RUN_RECORDS = "memtable.insert.run.records"
 MEMTABLE_INSERT_PARALLEL_RECORDS = "memtable.insert.parallel.records"
 # The writer's own clocks inside DB.write (db/db.py; one record_ticks an
-# outer call, beside db.write.micros): the thread's CPU time; the sum over
-# the group's native write-plane calls of exit minus entry (out[8..9]); and
-# of Python's time.monotonic_ns() on return minus the exit stamp: the wait
-# to get the GIL back. native + gil.wait <= db.write.micros, cpu <= it.
-WRITE_CPU_MICROS = "write.cpu.micros"
+# outer call, beside db.write.micros): the sum over the group's native
+# write-plane calls of exit minus entry (out[8..9]); and of Python's
+# time.monotonic_ns() on return minus the exit stamp: the wait to get the
+# GIL back. native + gil.wait <= db.write.micros. The writer's CPU is no
+# ticker: a window's delta of tpulsm.thread-times (the led writer's cpu_ns).
 WRITE_NATIVE_MICROS = "write.native.micros"
 WRITE_GIL_WAIT_MICROS = "write.gil.wait.micros"
 # -- compaction ------------------------------------------------------
