@@ -168,9 +168,55 @@ def test_whole_job_program_stops_at_the_row_bucket(monkeypatch, over):
 
 def test_job_of_two_key_lengths_over_the_bucket_runs_per_entry(
         tmp_path, monkeypatch):
-    """What the refusal leads to: a job of mixed key lengths (no shard
-    program) and more padded rows than the bucket (no whole-job program)
-    is run per entry by the same worker, with the CPU worker's rows."""
+    """What no device program takes runs per entry: a job of two key
+    lengths, more rows than the whole-job program's bucket, on the serial
+    branch (one shard), whose parts do not lie densely (a byte of slack
+    after each part's keys), so that no shard program takes it either. The
+    same worker runs it per entry, with the CPU worker's rows."""
+    import numpy as np
+
+    from test_job_trace import make_job, output_rows, results_of
+    from toplingdb_tpu.compaction import worker
+    from toplingdb_tpu.ops import device_compaction as dc
+    from toplingdb_tpu.ops.columnar_io import ColumnarKV
+
+    monkeypatch.setattr(ck, "ROW_BUCKET", 4096)
+    monkeypatch.setattr(ck, "shard_count", lambda total_rows: 1)
+    per_entry, refused = [], []
+    gc_entries = dc.device_gc_entries
+    prepare = dc._prepare_uniform_shards
+
+    def spy(entries, *a, **k):
+        per_entry.append(len(entries))
+        return gc_entries(entries, *a, **k)
+
+    def with_slack(parts, stats=None):
+        loose = [ColumnarKV(np.concatenate([p.key_buf, np.zeros(1, np.uint8)]),
+                            p.key_offs, p.key_lens, p.val_buf, p.val_offs,
+                            p.val_lens) for p in parts]
+        shards = prepare(loose, stats)
+        refused.append(shards is None)
+        return shards
+
+    monkeypatch.setattr(dc, "device_gc_entries", spy)
+    monkeypatch.setattr(dc, "_prepare_uniform_shards", with_slack)
+    job_dir = make_job(tmp_path, last_run_key_len=12)
+    assert worker.run_job(job_dir) == 0
+    st = results_of(job_dir)["stats"]
+    assert refused == [True] and per_entry == [9000]
+    assert not st["pipelined"] and st["varlen_shards"] == 0
+    cpu_dir = make_job(tmp_path, name="cpu", last_run_key_len=12,
+                       device="cpu")
+    assert worker.run_job(cpu_dir) == 0
+    assert output_rows(job_dir) == output_rows(cpu_dir)
+
+
+def test_job_of_two_key_lengths_takes_the_mixed_length_program(
+        tmp_path, monkeypatch):
+    """The same job with dense parts runs pipelined on the mixed-length
+    shard program (per entry not at all), and on the serial branch too
+    (one shard) it meets that program, with the CPU worker's rows either
+    way."""
     from test_job_trace import make_job, output_rows, results_of
     from toplingdb_tpu.compaction import worker
     from toplingdb_tpu.ops import device_compaction as dc
@@ -187,11 +233,19 @@ def test_job_of_two_key_lengths_over_the_bucket_runs_per_entry(
     job_dir = make_job(tmp_path, last_run_key_len=12)
     assert worker.run_job(job_dir) == 0
     st = results_of(job_dir)["stats"]
-    assert per_entry == [9000] and not st["pipelined"]
+    assert per_entry == [] and st["pipelined"]
+    assert st["varlen_shard_rows"] == 9000 and st["varlen_shards"] == 4
     cpu_dir = make_job(tmp_path, name="cpu", last_run_key_len=12,
                        device="cpu")
     assert worker.run_job(cpu_dir) == 0
     assert output_rows(job_dir) == output_rows(cpu_dir)
+    monkeypatch.setattr(ck, "shard_count", lambda total_rows: 1)
+    serial_dir = make_job(tmp_path, name="serial", last_run_key_len=12)
+    assert worker.run_job(serial_dir) == 0
+    st = results_of(serial_dir)["stats"]
+    assert per_entry == [] and not st["pipelined"]
+    assert st["varlen_shards"] == 1
+    assert output_rows(serial_dir) == output_rows(cpu_dir)
 
 
 # The variables that chose a route or a kernel on the compaction path until

@@ -44,7 +44,8 @@ def make_job(tmp_path, name="job", runs=3, rows=3000, seq_gap=0, trace=None,
     to the sequence numbers of half the last run's rows, so that every
     chunk of that file spans it. `last_run_key_len` other than 8 gives the
     last run longer keys (the same digits, then '0's): a job of two key
-    lengths, which the pipeline refuses. `seed` draws the keys."""
+    lengths, whose shards take the mixed-length program. `seed` draws the
+    keys."""
     from toplingdb_tpu.ops.columnar_io import ColumnarKV, write_tables_columnar
 
     env = default_env()
@@ -254,22 +255,30 @@ def test_sampled_job_returns_its_spans_in_one_write(tmp_path, pipelined,
     assert tm.current_span() is None
 
 
-def test_job_that_leaves_the_pipeline_says_why(tmp_path, pipelined):
+def test_job_that_leaves_the_pipeline_says_why(tmp_path, pipelined,
+                                               monkeypatch):
+    # Keys of two lengths no longer leave the pipeline (they take the
+    # mixed-length shard program); a job of one shard still does, and the
+    # serial branch runs the same mixed-length program on it.
+    from toplingdb_tpu.ops import compaction_kernels as ck
+
+    monkeypatch.setattr(ck, "shard_count", lambda total_rows: 1)
     job_dir = make_job(tmp_path, last_run_key_len=12)
     tracer = tm.Tracer(proc="dcompact-worker")
     assert run_under_request(tracer, job_dir) == 0
     st = results_of(job_dir)["stats"]
     assert st["pipelined"] is False
-    assert st["pipeline_exit"] == (
-        "PipelineIneligible: non-uniform key length")
+    assert st["pipeline_exit"] == "PipelineIneligible: single-shard job"
     assert st["output_records"] > 0
+    assert st["varlen_shards"] == 1 and st["varlen_shard_rows"] == 9000
     (trace,) = tracer.finished()
     names = {s.name for s in trace.spans}
     # The serial program's path, under the same names for the same work.
     assert {"compaction.input_scan", "pipeline.encode_write",
-            "compaction.finish", "sst.sync_close"} <= names
+            "compaction.finish", "sst.sync_close",
+            "pipeline.varlen_pack"} <= names
     errors = [s.tags["error"] for s in trace.spans
-              if s.name == "pipeline.chunk_prepare" and "error" in s.tags]
+              if s.name == "pipeline.plan" and "error" in s.tags]
     assert errors and "PipelineIneligible" in errors[0]
 
 
@@ -401,7 +410,7 @@ def test_failed_job_is_counted_on_both_sides(tmp_path, monkeypatch):
         svc.stop()
 
 
-def test_service_serves_its_ring(tmp_path, pipelined):
+def test_service_serves_its_ring(tmp_path, pipelined, monkeypatch):
     from toplingdb_tpu.compaction.dcompact_service import DcompactWorkerService
 
     svc = DcompactWorkerService(device="cpu-jax")
@@ -413,9 +422,13 @@ def test_service_serves_its_ring(tmp_path, pipelined):
             return json.loads(r.read())
 
     try:
+        from toplingdb_tpu.ops import compaction_kernels as ck
+
         for name, key_len in (("a", 8), ("b", 12)):
             job_dir = make_job(tmp_path, name=name,
                                last_run_key_len=key_len)
+            if name == "b":  # one shard: it leaves the pipeline
+                monkeypatch.setattr(ck, "shard_count", lambda rows: 1)
             req = urllib.request.Request(
                 url + "/dcompact",
                 data=json.dumps({"job_dir": job_dir}).encode(),

@@ -147,6 +147,24 @@ class CompactionStats:
     sft_output_bytes: int = 0
     sft_build_usec: int = 0
 
+    # Shards whose user keys differ in length, run by the mixed-length
+    # program (ops/compaction_kernels.py::prepare_varlen_chunk): how many,
+    # their rows, the true user-key bytes of those rows, and the zero bytes
+    # the width bucket added to them on the way up (`pipeline.varlen_pack`).
+    varlen_shards: int = 0
+    varlen_shard_rows: int = 0
+    varlen_key_bytes: int = 0
+    varlen_pad_bytes: int = 0
+
+    def count_varlen_shard(self, chunks) -> None:
+        """One shard's VarlenChunks, as they go up."""
+        rows = sum(int(c.n) for c in chunks)
+        key_bytes = sum(int(c.klen.sum(dtype="int64")) for c in chunks)
+        self.varlen_shards += 1
+        self.varlen_shard_rows += rows
+        self.varlen_key_bytes += key_bytes
+        self.varlen_pad_bytes += rows * chunks[0].width - key_bytes
+
     def note_device(self, device_id: int) -> None:
         """A shard program ran on JAX device `device_id`."""
         if device_id not in self.devices_used:

@@ -200,7 +200,8 @@ JOB_SUM_COUNTERS = (
     "zip_output_bytes", "zip_output_raw_bytes", "zip_encode_usec",
     "zip_dict_train_usec", "sft_input_files", "sft_input_rows",
     "sft_scan_usec", "sft_output_files", "sft_output_rows",
-    "sft_output_bytes", "sft_build_usec")
+    "sft_output_bytes", "sft_build_usec", "varlen_shards",
+    "varlen_shard_rows", "varlen_key_bytes", "varlen_pad_bytes")
 
 
 class DcompactWorkerService:

@@ -1879,6 +1879,9 @@ typedef size_t (*zstd_dec_dict_fn)(void*, void*, size_t, const void*, size_t,
 typedef size_t (*zdict_train_fn)(void*, size_t, const void*, const size_t*,
                                  unsigned);
 typedef unsigned (*zdict_err_fn)(size_t);
+typedef int (*lz4_dec_fn)(const char*, char*, int, int);
+typedef int (*lz4_cmp_fn)(const char*, char*, int, int);
+typedef int (*lz4_bound_fn)(int);
 
 struct Codecs {
   snappy_len_fn snappy_len = nullptr;
@@ -1901,6 +1904,11 @@ struct Codecs {
   zstd_dec_dict_fn zstd_dec_dict = nullptr;
   zdict_train_fn zdict_train = nullptr;
   zdict_err_fn zdict_err = nullptr;
+  // LZ4 blocks (types 4 and 5) are framed as utils/codecs.py frames them:
+  // varint32 raw length, then one LZ4 block (lz4_frame_* below).
+  lz4_dec_fn lz4_dec = nullptr;
+  lz4_cmp_fn lz4_cmp = nullptr;
+  lz4_bound_fn lz4_bound = nullptr;
 };
 
 const Codecs& codecs() {
@@ -1936,10 +1944,43 @@ const Codecs& codecs() {
       r.zdict_train = (zdict_train_fn)dlsym(z, "ZDICT_trainFromBuffer");
       r.zdict_err = (zdict_err_fn)dlsym(z, "ZDICT_isError");
     }
+    void* l = dlopen("liblz4.so.1", RTLD_NOW);
+    if (!l) l = dlopen("liblz4.so", RTLD_NOW);
+    if (l) {
+      r.lz4_dec = (lz4_dec_fn)dlsym(l, "LZ4_decompress_safe");
+      r.lz4_cmp = (lz4_cmp_fn)dlsym(l, "LZ4_compress_default");
+      r.lz4_bound = (lz4_bound_fn)dlsym(l, "LZ4_compressBound");
+    }
 #endif
     return r;
   }();
   return c;
+}
+
+// The raw length of an LZ4-framed block (varint32 prefix), and where its
+// LZ4 block starts; false when the prefix is malformed.
+bool lz4_frame_len(const uint8_t* p, int64_t len, size_t* ulen,
+                   const uint8_t** body) {
+  uint32_t v = 0;
+  const uint8_t* q = get_varint32(p, p + len, &v);
+  if (!q) return false;
+  *ulen = v;
+  *body = q;
+  return true;
+}
+
+// Inflate one LZ4-framed block of `len` bytes into dst[ulen]: true when it
+// yields exactly ulen bytes.
+bool lz4_frame_inflate(const Codecs& c, const uint8_t* p, int64_t len,
+                       uint8_t* dst, size_t ulen) {
+  size_t want = 0;
+  const uint8_t* body = nullptr;
+  if (!lz4_frame_len(p, len, &want, &body) || want != ulen ||
+      ulen > (size_t)INT32_MAX)
+    return false;
+  int got = c.lz4_dec((const char*)body, (char*)dst,
+                      (int)(len - (body - p)), (int)ulen);
+  return got >= 0 && (size_t)got == ulen;
 }
 
 }  // namespace
@@ -1982,8 +2023,13 @@ int64_t tpulsm_inflate_blocks(const uint8_t* file_buf, int64_t file_len,
         return -1;  // unknown size / not a frame (dict etc.): Python path
       if (s > (1ull << 31)) return -3;
       ulen = (size_t)s;
+    } else if ((t == 4 || t == 5) && c.lz4_dec) {
+      const uint8_t* body = nullptr;
+      if (!lz4_frame_len(file_buf + off, len, &ulen, &body) ||
+          ulen > (1ull << 31))
+        return -3;
     } else {
-      return -1;  // lz4/zlib/bzip2: Python fallback
+      return -1;  // zlib/bzip2 (or no liblz4): Python fallback
     }
     out_offs[b] = used;
     out_lens[b] = (int64_t)ulen;
@@ -2023,6 +2069,8 @@ int64_t tpulsm_inflate_blocks(const uint8_t* file_buf, int64_t file_len,
         size_t got = ulen;
         ok = c.snappy_unc((const char*)file_buf + off, (size_t)len,
                           (char*)dst, &got) == 0 && got == ulen;
+      } else if (t == 4 || t == 5) {
+        ok = lz4_frame_inflate(c, file_buf + off, len, dst, ulen);
       } else {
         size_t got = c.zstd_dec(dst, ulen, file_buf + off, (size_t)len);
         if (c.zstd_err(got)) {
@@ -2139,8 +2187,22 @@ int64_t tpulsm_scan_blocks(
       if (c.zstd_err(got) || got != (size_t)s) return -8;
       payload = scratch.data();
       plen = (int64_t)s;
+    } else if ((t == 4 || t == 5) && c.lz4_dec) {
+      size_t ulen = 0;
+      const uint8_t* body = nullptr;
+      if (!lz4_frame_len(payload, len, &ulen, &body) || ulen > (1ull << 31))
+        return -8;
+      try {
+        if (scratch.size() < ulen) scratch.resize(ulen);
+      } catch (...) {
+        return -1;  // resource exhaustion, NOT corruption: fall back
+      }
+      if (!lz4_frame_inflate(c, payload, len, scratch.data(), ulen))
+        return -8;
+      payload = scratch.data();
+      plen = (int64_t)ulen;
     } else if (t != 0) {
-      return -1;  // lz4/zlib/bzip2: Python fallback
+      return -1;  // zlib/bzip2 (or no liblz4): Python fallback
     }
     int64_t rc = tpulsm_decode_block(
         payload, plen, key_out + key_used, key_cap - key_used,
@@ -2396,7 +2458,8 @@ int64_t tpulsm_build_data_section_c(
   const Codecs& c = codecs();
   if (ctype == 1 && (!c.snappy_maxlen || !c.snappy_cmp)) return -9;
   if (ctype == 7 && (!c.zstd_cmp || !c.zstd_bound || !c.zstd_err)) return -9;
-  if (ctype != 1 && ctype != 7) return -9;
+  if (ctype == 4 && (!c.lz4_cmp || !c.lz4_bound)) return -9;
+  if (ctype != 1 && ctype != 4 && ctype != 7) return -9;
   // level semantics must MATCH the Python path byte-for-byte: the caller
   // passes INT32_MIN for "unset" (Python None -> zstd default 3); real
   // levels — including zstd's valid negative fast levels and 0 — pass
@@ -2495,8 +2558,10 @@ int64_t tpulsm_build_data_section_c(
         int64_t i = next.fetch_add(1, std::memory_order_relaxed);
         if (i >= (int64_t)blks.size()) return;
         Blk& b = blks[(size_t)i];
-        size_t bound = ctype == 1 ? c.snappy_maxlen((size_t)b.raw_len)
-                                  : c.zstd_bound((size_t)b.raw_len);
+        size_t bound =
+            ctype == 1   ? c.snappy_maxlen((size_t)b.raw_len)
+            : ctype == 4 ? 5 + (size_t)c.lz4_bound((int)b.raw_len)
+                         : c.zstd_bound((size_t)b.raw_len);
         b.bound = bound;
         try {
           if (cbuf.size() < bound) cbuf.resize(bound);
@@ -2509,6 +2574,15 @@ int64_t tpulsm_build_data_section_c(
         if (ctype == 1) {
           ok = c.snappy_cmp((const char*)b.raw.data(), (size_t)b.raw_len,
                             (char*)cbuf.data(), &clen) == 0;
+        } else if (ctype == 4) {
+          // utils/codecs.py::lz4_compress's frame: varint32 raw length,
+          // then the block LZ4_compress_default makes.
+          uint8_t* q = put_varint32(cbuf.data(), (uint32_t)b.raw_len);
+          size_t head = (size_t)(q - cbuf.data());
+          int n = c.lz4_cmp((const char*)b.raw.data(), (char*)q,
+                            (int)b.raw_len, (int)(bound - head));
+          ok = n > 0;
+          clen = head + (size_t)(n > 0 ? n : 0);
         } else {
           clen = c.zstd_cmp(cbuf.data(), bound, b.raw.data(),
                             (size_t)b.raw_len, level);
@@ -4091,6 +4165,16 @@ std::shared_ptr<std::string> nfetch_block(NTable* t, uint64_t off,
     size_t r = c.zstd_dec(&(*out)[0], (size_t)ulen, raw.data(), size);
     if (c.zstd_err && c.zstd_err(r)) return nullptr;
     out->resize(r);
+  } else if ((type == 4 || type == 5) && c.lz4_dec) {
+    size_t ulen = 0;
+    const uint8_t* body = nullptr;
+    if (!lz4_frame_len((const uint8_t*)raw.data(), (int64_t)size, &ulen,
+                       &body) || ulen > (1ull << 31))
+      return nullptr;
+    out->resize(ulen);
+    if (!lz4_frame_inflate(c, (const uint8_t*)raw.data(), (int64_t)size,
+                           (uint8_t*)&(*out)[0], ulen))
+      return nullptr;
   } else {
     return nullptr;  // dict-compressed or unknown: python path
   }

@@ -875,7 +875,7 @@ def write_tables_columnar(env, dbname, new_file_number, icmp, options,
     if options.compression == fmt.NO_COMPRESSION:
         use_section = hasattr(lib, "tpulsm_build_data_section")
     elif (options.compression in (fmt.SNAPPY_COMPRESSION,
-                                  fmt.ZSTD_COMPRESSION)
+                                  fmt.LZ4_COMPRESSION, fmt.ZSTD_COMPRESSION)
           and not (copts0 is not None and copts0.max_dict_bytes > 0)
           and hasattr(lib, "tpulsm_build_data_section_c")):
         use_section = True

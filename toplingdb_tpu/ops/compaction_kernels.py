@@ -800,9 +800,9 @@ def _warm_program(h, snap_hi, bottommost, device, done) -> None:
 
 
 def fused_uniform_shard_start(handle, snapshots: list[int], bottommost: bool):
-    """Dispatch one shard's fused program over an upload_uniform_shard
-    handle; enqueues the D2H copies so results stream back as the program
-    finishes. Decode with fused_uniform_shard_finish."""
+    """Dispatch one shard's program (decode: fused_uniform_shard_finish)."""
+    if "klen" in handle:  # upload_varlen_shard's: keys of mixed length
+        return fused_varlen_shard_start(handle, snapshots, bottommost)
     if len(snapshots) > MAX_SNAPSHOTS:
         raise NotSupported(
             f"device GC supports <= {MAX_SNAPSHOTS} live snapshots"
@@ -941,3 +941,240 @@ def gc_mask(sorted_cols: dict, snapshots: list[int],
         np.asarray(keep)[:n], np.asarray(zero_seq)[:n],
         np.asarray(host_resolve)[:n], np.asarray(group_id)[:n],
     )
+
+
+# ---------------------------------------------------------------------------
+# Shards whose user keys differ in length (ARCHITECTURE.md §2.2.1.1). Each
+# row's user key goes up zero-padded to the shard's width bucket, beside its
+# true length (one byte a row); the program sorts on the padded words and
+# then on the length. That is bytewise order exactly: where two padded keys
+# differ, the first difference is a real byte or a real byte against a pad
+# zero (the shorter key is then a prefix of the longer: it sorts first), and
+# where they tie, the lengths decide ("ab" < "ab\0" < "abc"). Equal keys are
+# equal words AND equal lengths, which `_gc_mask_impl` already asks for.
+# The uniform program above stays line for line as it was: the lines of the
+# frames that trace a program are part of its compile-cache key.
+# ---------------------------------------------------------------------------
+
+# The width bucket's step: a shard's longest user key rounded up to whole
+# 8-byte steps, so keys of 17 to 24 bytes (YCSB's "user" + a decimal hash,
+# 5-23 B) meet one program a row bucket.
+VARLEN_WIDTH_STEP = 8
+
+# The mixed-length program's row floor: a shard of VARLEN_ROW_BUCKET_FROM to
+# ROW_BUCKET rows pads to ROW_BUCKET (the uniform rule's ROW_BUCKET_FROM is
+# 2^17). A deployment of 1 KB records sends remote jobs from half a write
+# buffer on, ~55,000 rows, and a one-shard job of 55,000 to 131,072 rows
+# must not meet a program of its own: minutes of compile against
+# milliseconds of pad rows (ROW_BUCKET's note above).
+VARLEN_ROW_BUCKET_FROM = 1 << 15
+
+from typing import NamedTuple  # noqa: E402  (here: see the note above)
+
+
+class VarlenChunk(NamedTuple):
+    """prepare_varlen_chunk's output: the first four fields stand where
+    prepare_uniform_chunk's do (`n` is field 2, the width field 3)."""
+    ukb: np.ndarray        # [n * width] u8, user keys zero-padded
+    tw: np.ndarray         # [n, 2] u32, the trailer's (low, high) words
+    n: int
+    width: int
+    klen: np.ndarray       # [n] u8, each user key's true length
+
+
+def varlen_width(max_uk_len: int) -> int:
+    """The width bucket of a shard whose longest user key has
+    `max_uk_len` bytes."""
+    step = VARLEN_WIDTH_STEP
+    return max(step, -(-int(max_uk_len) // step) * step)
+
+
+def prepare_varlen_chunk(key_buf: np.ndarray, key_lens: np.ndarray,
+                         width: int) -> VarlenChunk:
+    """Host half of the mixed-length upload: rows whose internal keys lie
+    densely from key_buf[0] on, `key_lens` long each, as their user keys
+    zero-padded to `width` bytes, their lengths and their trailers' words.
+    Vectorised: one mask drops the trailer bytes of the dense span, and the
+    user-key bytes left land, in row order, where a [n, width] mask of
+    each row's first length bytes is set."""
+    import sys as _sys
+
+    lens = np.asarray(key_lens, dtype=np.int64)
+    n = len(lens)
+    uk = lens - 8
+    if n and (int(uk.min()) < 0 or int(uk.max()) > width):
+        raise NotSupported(f"user keys of {int(uk.min())}-{int(uk.max())} "
+                           f"bytes do not fit a width of {width}")
+    ends = np.cumsum(lens)
+    tr = (ends - 8)[:, None] + np.arange(8)[None, :]
+    tw = np.ascontiguousarray(key_buf[tr]).view(np.uint32).reshape(n, 2)
+    if _sys.byteorder == "big":
+        tw = tw.byteswap()
+    span = int(ends[-1]) if n else 0
+    is_uk = np.ones(span, dtype=bool)
+    is_uk[tr.reshape(-1)] = False
+    out = np.zeros((n, width), dtype=np.uint8)
+    out[np.arange(width)[None, :] < uk[:, None]] = key_buf[:span][is_uk]
+    return VarlenChunk(out.reshape(-1), tw, n, width, uk.astype(np.uint8))
+
+
+def upload_varlen_shard(chunks, covers=None, device=None):
+    """upload_uniform_shard for VarlenChunks of one width: the row bucket
+    from VARLEN_ROW_BUCKET_FROM rows on, the same trailer and cover planes,
+    the user keys at the width, and a fourth plane, `klen`, of each row's
+    true length (pad rows 0)."""
+    width = chunks[0].width
+    total = sum(int(c.n) for c in chunks)
+    if total > MAX_SHARD_ROWS:
+        raise NotSupported(
+            f"shard rows {total} exceed the 24-bit packed-order budget"
+        )
+    p = (ROW_BUCKET if VARLEN_ROW_BUCKET_FROM <= total <= ROW_BUCKET
+         else _next_pow2(max(1, total)))
+    ukb = np.zeros(p * width, dtype=np.uint8)
+    klen = np.zeros(p, dtype=np.uint8)
+    packed_hi = np.zeros(p, dtype=np.uint32)
+    packed_lo = np.zeros(p, dtype=np.uint32)
+    has_tombs = covers is not None
+    if has_tombs:
+        tomb_hi = np.zeros(p, dtype=np.uint32)
+        tomb_lo = np.zeros(p, dtype=np.uint32)
+    pos = 0
+    for ci, c in enumerate(chunks):
+        n = c.n
+        ukb[pos * width:(pos + n) * width] = c.ukb
+        klen[pos:pos + n] = c.klen
+        packed_lo[pos:pos + n] = c.tw[:, 0]
+        packed_hi[pos:pos + n] = c.tw[:, 1]
+        if has_tombs and covers[ci] is not None:
+            cv = covers[ci]
+            tomb_hi[pos:pos + n] = (cv >> np.uint64(32)).astype(np.uint32)
+            tomb_lo[pos:pos + n] = (cv & np.uint64(0xFFFFFFFF)).astype(
+                np.uint32)
+        pos += n
+    put = functools.partial(jax.device_put, device=device)
+    return {
+        "ukb": put(ukb), "klen": put(klen), "packed_hi": put(packed_hi),
+        "packed_lo": put(packed_lo), "total": total, "uk_len": width,
+        "tomb_hi": put(tomb_hi) if has_tombs else None,
+        "tomb_lo": put(tomb_lo) if has_tombs else None,
+    }
+
+
+def upload_shard(chunks, covers=None, device=None):
+    """One shard's upload, by the kind of its chunks: VarlenChunks (keys of
+    mixed length) or prepare_uniform_chunk's (one key length)."""
+    if isinstance(chunks[0], VarlenChunk):
+        return upload_varlen_shard(chunks, covers, device=device)
+    return upload_uniform_shard(chunks, covers, device=device)
+
+
+def fused_varlen_shard_start(handle, snapshots: list[int], bottommost: bool):
+    """fused_uniform_shard_start for an upload_varlen_shard handle: the
+    result decodes with fused_uniform_shard_finish. Each chip of a pool
+    compiles it when it first meets it (or loads it from the persistent
+    cache): `share_programs` warms the uniform program only."""
+    if len(snapshots) > MAX_SNAPSHOTS:
+        raise NotSupported(
+            f"device GC supports <= {MAX_SNAPSHOTS} live snapshots"
+        )
+    snap_hi, snap_lo = _split_snapshots(snapshots)
+    h = handle
+    has_tombs = h["tomb_hi"] is not None
+    t_hi = h["tomb_hi"] if has_tombs else np.zeros(1, dtype=np.uint32)
+    t_lo = h["tomb_lo"] if has_tombs else np.zeros(1, dtype=np.uint32)
+    out = _fused_varlen_shard_impl(
+        h["ukb"], h["klen"], h["packed_hi"], h["packed_lo"], t_hi, t_lo,
+        snap_hi, snap_lo, np.int32(h["total"]), h["uk_len"],
+        np.bool_(bottommost), has_tombs,
+    )
+    for a in out:
+        if hasattr(a, "copy_to_host_async"):
+            a.copy_to_host_async()  # results stream back as it finishes
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("width", "has_tombs"))
+def _fused_varlen_shard_impl(ukb, klen, packed_hi, packed_lo, tomb_hi,
+                             tomb_lo, snap_hi, snap_lo, total, width,
+                             bottommost, has_tombs):
+    """_fused_uniform_shard_impl over a mixed-length shard: the user keys
+    zero-padded to `width` (a multiple of 4: width // 4 sort words) and
+    `klen`, each row's true length, which becomes the `key_len` operand of
+    the sort and of the GC mask. The same scopes, the same result; the
+    sort is `_varlen_sort`'s passes, not one multi-operand sort."""
+    u32 = jnp.uint32
+    int32max = jnp.int32(2**31 - 1)
+    sign = u32(_SIGN)
+    i32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
+    num_key_words = width // 4
+    p = packed_lo.shape[0]
+    iota = jnp.arange(p, dtype=jnp.int32)
+    valid = iota < total
+
+    with jax.named_scope("encode_words"):
+        kbp = ukb.reshape(p, num_key_words, 4).astype(u32)
+        words = (
+            (kbp[:, :, 0] << 24) | (kbp[:, :, 1] << 16)
+            | (kbp[:, :, 2] << 8) | kbp[:, :, 3]
+        )
+        key_words = jnp.where(valid[:, None], i32(words ^ sign), int32max)
+        vt0 = packed_lo & u32(0xFF)
+        inv_hi = jnp.where(valid, i32(~packed_hi ^ sign), int32max)
+        inv_lo = jnp.where(valid, i32(~packed_lo ^ sign), int32max)
+        vtype = jnp.where(valid, vt0.astype(jnp.int32), -1)
+        key_len = jnp.where(valid, klen.astype(jnp.int32), int32max)
+
+    with jax.named_scope("sort"):
+        kw, kl, ih, il, vt, perm = _varlen_sort(
+            key_words, key_len, inv_hi, inv_lo, vtype, iota,
+        )
+    with jax.named_scope("gc_mask"):
+        if has_tombs:
+            th = tomb_hi[perm]
+            tl = tomb_lo[perm]
+        else:
+            th = tl = jnp.zeros(p, dtype=jnp.uint32)
+        keep, zero_seq, host_resolve, _ = _gc_mask_impl(
+            kw, kl, ih, il, vt, snap_hi, snap_lo, th, tl,
+            num_key_words, bottommost,
+        )
+    with jax.named_scope("compact"):
+        out = keep | host_resolve
+        take = jnp.argsort(~out, stable=True)
+        po = (
+            jax.lax.bitcast_convert_type(perm[take], u32)
+            | (zero_seq[take].astype(u32) << 23)
+            | (host_resolve[take].astype(u32) << 22)
+        )
+    with jax.named_scope("pack"):
+        packed_bytes = jnp.concatenate([
+            (po & u32(0xFF)).astype(jnp.uint8),
+            ((po >> 8) & u32(0xFF)).astype(jnp.uint8),
+            ((po >> 16) & u32(0xFF)).astype(jnp.uint8),
+        ])
+        meta = jnp.stack([
+            jnp.sum(out.astype(jnp.int32)),
+            jnp.any(host_resolve).astype(jnp.int32),
+        ])
+    return packed_bytes, meta
+
+
+def _varlen_sort(key_words, key_len, inv_hi, inv_lo, vtype, iota):
+    """`_sort_impl`'s order (rows with every key equal: by row) as one pass
+    a key, last key first, of ONE stable two-operand sort in a loop. The
+    TPU compiler's time for a sort grows with its operands, and faster than
+    their count: `_sort_impl` over the 11 operands of a 24-byte width took
+    293 s of a cold set-up on a v5e (PERF.md, PR 42), where the loop
+    compiles one small sort whatever the width."""
+    keys = jnp.concatenate(
+        [key_words.T, key_len[None], inv_hi[None], inv_lo[None]])
+    last = keys.shape[0] - 1
+
+    def one_pass(i, perm):
+        key = jax.lax.dynamic_index_in_dim(keys, last - i, keepdims=False)
+        return jax.lax.sort((key[perm], perm), num_keys=1, is_stable=True)[1]
+
+    perm = jax.lax.fori_loop(0, last + 1, one_pass, iota)
+    return (key_words[perm], key_len[perm], inv_hi[perm], inv_lo[perm],
+            vtype[perm], perm)

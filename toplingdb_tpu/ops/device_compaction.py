@@ -254,11 +254,11 @@ def _collect_raw_columnar(compaction, table_cache, icmp, want_uploads=False,
     and SingleFastTables, in any mix: a whole-file scan of the latter two is
     `pipeline.zip_scan` / `pipeline.sft_scan`, counted in `stats`. With
     want_uploads, ALSO split the sorted parts into user-key-range shards
-    and prepare (host-side, no device traffic yet) each shard's uniform
-    chunk columns. Returns (kv, rd, shards, parts) where shards is None
-    when the sharded uniform device path does not apply (sparse layout,
-    non-uniform key lengths, oversized shards); otherwise shards[s] =
-    (chunks, row_ranges): prepare_uniform_chunk outputs plus the
+    and prepare (host-side, no device traffic yet) each shard's chunk
+    columns. Returns (kv, rd, shards, parts) where shards is None when
+    the sharded device path does not apply (sparse layout, oversized
+    shards); otherwise shards[s] = (chunks, row_ranges): the
+    prepare_uniform_chunk or prepare_varlen_chunk outputs plus the
     (global_lo, global_hi) row spans into the concatenated kv that each
     chunk covers, in chunk order."""
     from concurrent.futures import ThreadPoolExecutor
@@ -307,35 +307,36 @@ def _collect_raw_columnar(compaction, table_cache, icmp, want_uploads=False,
 
     shards = None
     if want_uploads:
-        shards = _prepare_uniform_shards(parts)
+        shards = _prepare_uniform_shards(parts, stats)
     return kv, rd, shards, parts
 
 
-def _prepare_uniform_shards(parts):
-    """Host half of the sharded uniform device path: validate density +
-    uniform key length, pick range splitters (as many shards as the
-    pipeline would cut: ck.shard_count), slice every part into per-shard
-    chunks. Returns shards list or None when ineligible."""
-    uniform_len = 0
+def _prepare_uniform_shards(parts, stats=None):
+    """Host half of the sharded device path: validate density, pick range
+    splitters (as many shards as the pipeline would cut: ck.shard_count),
+    slice every part into per-shard pieces and cut each shard's chunks
+    where the pipeline cuts them (`pipeline.shard_chunks`): prepared
+    uniform chunks where the shard's keys have one length, VarlenChunks at
+    a width bucket where they differ, counted in `stats`. A job that
+    leaves the pipeline so meets the programs the pipeline would have run.
+    Returns the shards, or None: a part whose keys do not lie densely, or
+    a shard past the 24-bit row budget (the caller refuses long keys)."""
+    from toplingdb_tpu.ops import pipeline as pl
+
     total_rows = 0
     for part in parts:
         if not part.n:
             continue
-        L = int(part.key_lens[0])
-        dense_uniform = (
-            part.key_lens.min() == part.key_lens.max()
-            and len(part.key_buf) == part.n * L
+        dense = (
+            len(part.key_buf) == int(part.key_lens.sum(dtype=np.int64))
             and int(part.key_offs[0]) == 0
             and np.array_equal(
                 part.key_offs[1:],
                 (np.cumsum(part.key_lens) - part.key_lens)[1:],
             )
         )
-        if not dense_uniform:
+        if not dense:
             return None
-        if uniform_len and L != uniform_len:
-            return None
-        uniform_len = L
         total_rows += part.n
     if not total_rows:
         return None
@@ -355,14 +356,13 @@ def _prepare_uniform_shards(parts):
             lo, hi = bounds[s], bounds[s + 1]
             if lo == hi:
                 continue
-            blo = int(part.key_offs[lo])
-            bhi = int(part.key_offs[hi - 1]) + int(part.key_lens[hi - 1])
-            shards[s][0].append(ck.prepare_uniform_chunk(
-                part.key_buf[blo:bhi], hi - lo, uniform_len,
-            ))
+            shards[s][0].append((part.key_buf[int(part.key_offs[lo]):],
+                                 part.key_lens[lo:hi]))
             shards[s][1].append((row_base + lo, row_base + hi))
         row_base += part.n
-    shards = [sh for sh in shards if sh[0]]
+    trace = _tele.current_handle()
+    shards = [(pl.shard_chunks(pieces, stats, trace), ranges)
+              for pieces, ranges in shards if pieces]
     for chunks, _ranges in shards:
         if sum(c[2] for c in chunks) > ck.MAX_SHARD_ROWS:
             return None  # skewed splitters blew the 24-bit row budget

@@ -164,8 +164,8 @@ class MeshShardRun:
         chunks, ranges = self._shards[s]
         if _FAULT_HOOK is not None:
             _FAULT_HOOK(s, device)
-        h = self._ck.upload_uniform_shard(chunks, self._covers_for(ranges),
-                                          device=device)
+        h = self._ck.upload_shard(chunks, self._covers_for(ranges),
+                                  device=device)
         if self._stats is not None:
             self._stats.h2d_bytes += self._ck.shard_upload_nbytes(h)
             self._stats.note_device(self._ck.shard_device(h).id)

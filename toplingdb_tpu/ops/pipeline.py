@@ -734,7 +734,7 @@ def _device_compute(kv, files, splitters, prog, outq, shared, snapshots,
             mc._FAULT_HOOK(s, dev)
         with telemetry.span_under(trace, "pipeline.upload",
                                   shard=s) as sp:
-            h = ck.upload_uniform_shard(chunks, covers, device=dev)
+            h = ck.upload_shard(chunks, covers, device=dev)
             nb = ck.shard_upload_nbytes(h)
             stats.h2d_bytes += nb
             stats.note_device(ck.shard_device(h).id)
@@ -885,25 +885,25 @@ class _Fold:
 
 
 def _prepare_shard_chunks(ck, kv, ranges, frags, snaps, max_dev_key, shared):
-    """(chunks, covers, cov) of one shard for upload_uniform_shard: one
-    prepared uniform chunk a file range, and the covering-tombstone seqnos
-    (by chunk, and whole) when the job has range tombstones."""
-    chunks = []
-    klen = None
+    """(chunks, covers, cov) of one shard for ck.upload_shard: a chunk a
+    file range (`shard_chunks`: one key length, or keys of mixed length at
+    a width bucket), and the covering-tombstone seqnos (by chunk, and
+    whole) when the job has range tombstones. A range of a file is dense:
+    its internal keys lie back to back from its first one on, which is all
+    either kind of chunk needs. Keys past the device budget leave the
+    pipeline (the serial branch then sends the job per entry, ARCHITECTURE.md
+    §2.2.1.1); below it a shard of any mix of lengths stays on the plane.
+    Which program a shard meets is a property of its own rows: a shard of
+    one key length takes the uniform program with the shapes and static
+    arguments it always had, so a deployment of one key length compiles
+    nothing new, and a job's shards may take either."""
+    pieces = []
     for lo, hi in ranges:
         lens = kv.key_lens[lo:hi]
-        if int(lens.min()) != int(lens.max()):
-            raise PipelineIneligible("non-uniform key length")
-        if klen is None:
-            klen = int(lens[0])
-        elif klen != int(lens[0]):
-            raise PipelineIneligible("non-uniform key length")
-        if klen - 8 > max_dev_key:
+        if int(lens.max()) - 8 > max_dev_key:
             raise PipelineIneligible("keys exceed the device budget")
-        b0 = int(kv.key_offs[lo])
-        chunks.append(ck.prepare_uniform_chunk(
-            kv.key_buf[b0:b0 + (hi - lo) * klen], hi - lo, klen,
-        ))
+        pieces.append((kv.key_buf[int(kv.key_offs[lo]):], lens))
+    chunks = shard_chunks(pieces, shared.stats, shared.trace)
     cov = _timed_cover(kv, ranges, frags, snaps, shared)
     if cov is None:
         return chunks, None, None
@@ -1092,3 +1092,30 @@ def _drain_join(outq: Queue, threads) -> None:
             pass
     for t in threads:
         t.join(timeout=1.0)
+
+
+def shard_chunks(pieces, stats=None, trace=None) -> list:
+    """One shard's chunks for ck.upload_shard from its pieces, a (key bytes
+    from the range's first internal key on, its key lengths) pair a file
+    range: prepare_uniform_chunk's where the shard's keys have one length,
+    else VarlenChunks at the width bucket of its longest user key
+    (ck.varlen_width), packed under the span `pipeline.varlen_pack` and
+    counted in the job's `varlen_*` stats. The pipeline and the serial
+    branch (ops/device_compaction.py::_prepare_uniform_shards) both cut
+    here, so a job that leaves the pipeline meets the same program."""
+    from toplingdb_tpu.ops import compaction_kernels as ck
+
+    kmin = min(int(lens.min()) for _, lens in pieces)
+    kmax = max(int(lens.max()) for _, lens in pieces)
+    if kmin == kmax:
+        return [ck.prepare_uniform_chunk(buf, len(lens), kmax)
+                for buf, lens in pieces]
+    width = ck.varlen_width(kmax - 8)
+    with telemetry.span_under(trace, "pipeline.varlen_pack",
+                              width=width) as sp:
+        chunks = [ck.prepare_varlen_chunk(buf, lens, width)
+                  for buf, lens in pieces]
+        sp.tag(rows=sum(c.n for c in chunks))
+    if stats is not None:
+        stats.count_varlen_shard(chunks)
+    return chunks
