@@ -387,8 +387,8 @@ def test_db_http_view_write_plane(tmp_path):
     assert view is not None
     t = view["tickers"]
     for key in (st.WAL_BYTES, st.WRITE_GROUP_LED,
-                st.WRITE_GROUP_NATIVE_COMMITS, st.WRITE_GROUP_FALLBACKS,
-                st.WRITE_GROUP_FSYNCS_COALESCED):
+                st.WRITE_GROUP_NATIVE_COMMITS, st.WRITE_GROUP_ONE_CALL,
+                st.WRITE_GROUP_FALLBACKS, st.WRITE_GROUP_FSYNCS_COALESCED):
         assert key in t
     assert t[st.WRITE_GROUP_LED] > 0
     assert view["write_group_bytes"]["count"] > 0
@@ -794,3 +794,207 @@ def test_deferred_ticker_silent_for_merges(tmp_path):
     assert stats.get_ticker_count(st.WRITE_BATCH_DEFERRED_RECORDS) == 0
     assert struct.unpack("<Q", db.get(b"c007"))[0] == 10
     db.close()
+
+
+# -- a group committed in one native call: frame, WAL write, insert ---------
+
+def _one_call_db(d, path, monkeypatch, **kw):
+    """A DB whose groups take `path`: "python" (the Python interiors),
+    "two_calls" (the plane's frame call, Python's WAL append, the insert
+    call: the WAL file's type hidden from the one-call test until
+    monkeypatch.undo()) or "one_call"."""
+    from toplingdb_tpu.db import db as db_mod
+
+    monkeypatch.setenv("TPULSM_WRITE_PLANE", "0" if path == "python" else "1")
+    if path == "two_calls":
+        monkeypatch.setattr(db_mod, "_PosixWritable", type("Hidden", (), {}))
+    stats = st.Statistics()
+    db = DB.open(d, Options(create_if_missing=True, statistics=stats,
+                            write_buffer_size=64 << 20, **kw))
+    return db, stats
+
+
+def _one_call_batches(per):
+    """Batches of one 1,140-byte put (the YCSB update) or of 500 8B/20B
+    puts (db_bench's), three phases of them."""
+    out = []
+    for phase in range(3):
+        for g in range(60 if per == 1 else 8):
+            b = WriteBatch()
+            pairs = []
+            for j in range(per):
+                i = (phase * 1000 + g) * per + j
+                pairs.append((b"user%d" % (i * 2654435761 % 10**9)
+                              if per == 1 else b"%08d" % (i * 7919 % 10**8),
+                              bytes([i % 251]) * (1140 if per == 1 else 20)))
+                b.put(*pairs[-1])
+            out.append((phase, b, pairs))
+    return out
+
+
+@pytest.mark.parametrize("per", [1, 500])
+@pytest.mark.parametrize("fmt", ["classic", "recyclable"])
+def test_one_call_wal_parity_and_recovery(tmp_path, monkeypatch, fmt, per):
+    """The one-call commit writes the WAL byte for byte as the plane's two
+    calls and the Python interiors do, in the classic and the recyclable
+    format (the third phase into a reused WAL file), for groups of one put
+    and of 500; the file's size and the iostats byte count agree; every
+    acknowledged put reads back after WAL replay."""
+    kw = {"recycle_log_file_num": 2} if fmt == "recyclable" else {}
+    seen = {}
+    for path in ("python", "two_calls", "one_call"):
+        d = str(tmp_path / path)
+        db, stats = _one_call_db(d, path, monkeypatch, **kw)
+        io_bytes = 0
+        st.perf_level = 1
+        try:
+            phase_seen = 0
+            for phase, b, _ in _one_call_batches(per):
+                if phase != phase_seen:
+                    db.flush()  # a new WAL; the second reuses the first
+                    phase_seen = phase
+                ctx = st.iostats_context()
+                before = ctx.bytes_written
+                db.write(b)
+                io_bytes += ctx.bytes_written - before
+        finally:
+            st.perf_level = 0
+            monkeypatch.undo()
+        wf = db._wal._f
+        assert ("+" in wf._f.mode) == (fmt == "recyclable")
+        led = stats.get_ticker_count(st.WRITE_GROUP_LED)
+        assert led == 3 * (60 if per == 1 else 8)
+        assert stats.get_ticker_count(st.WRITE_GROUP_ONE_CALL) == (
+            led if path == "one_call" else 0)
+        assert stats.get_ticker_count(st.WRITE_GROUP_NATIVE_COMMITS) == (
+            0 if path == "python" else led)
+        wal = _crash_copy(d)
+        seen[path] = (wal, wf.file_size(), io_bytes,
+                      stats.get_ticker_count(st.WAL_BYTES),
+                      db.versions.last_sequence)
+        if fmt == "classic":
+            assert wf.file_size() == max(len(v) for v in wal.values())
+        db.close()
+        with DB.open(d + "-crash", Options()) as r:
+            want = {k: v for _, _, pairs in _one_call_batches(per)
+                    for k, v in pairs}
+            assert all(r.get(k) == v for k, v in want.items())
+            assert r.versions.last_sequence == seen[path][-1]
+    assert seen["python"] == seen["two_calls"] == seen["one_call"]
+    assert seen["one_call"][2] > 0
+
+
+@pytest.mark.parametrize("fd", ["read_only", "dev_full", "torn_pipe"])
+def test_one_call_failed_wal_write_inserts_nothing(tmp_path, fd):
+    """A WAL write that fails inside the one call (the WAL's descriptor
+    pointed at a read-only file, at /dev/full, or at a full non-blocking
+    pipe that takes part of the record) raises IOError_ with nothing in the
+    memtable and last_sequence unmoved. Where nothing reached the file, the
+    file is as it was and the next put goes through; after WAL replay the
+    failed put is not there and the others are. A torn record that cannot
+    be cut off again (a pipe has no offset) latches a FATAL background
+    error: no later record may follow it."""
+    from toplingdb_tpu.utils.status import IOError_, NoSpace, Severity
+
+    if fd == "dev_full" and not os.path.exists("/dev/full"):
+        pytest.skip("no /dev/full")
+    d = str(tmp_path / "db")
+    stats = st.Statistics()
+    db = DB.open(d, Options(create_if_missing=True, statistics=stats))
+    torn = fd == "torn_pipe"
+    try:
+        db.put(b"before", b"v")
+        mem = db._cfs[0].mem
+        seq0, n0 = db.versions.last_sequence, mem.num_entries
+        wf = db._wal._f
+        size0 = wf.file_size()
+        wal_fd = wf._f.fileno()
+        saved = os.dup(wal_fd)
+        if fd == "read_only":
+            bad, extra = os.open(os.devnull, os.O_RDONLY), None
+        elif fd == "dev_full":
+            bad, extra = os.open("/dev/full", os.O_WRONLY), None
+        else:
+            extra, bad = os.pipe()
+            os.set_blocking(bad, False)
+        os.dup2(bad, wal_fd)
+        try:
+            with pytest.raises(IOError_) as err:
+                db.put(b"lost", b"x" * (1 << 20 if torn else 1140))
+        finally:
+            os.dup2(saved, wal_fd)
+            for f in (saved, bad, extra):
+                if f is not None:
+                    os.close(f)
+        assert isinstance(err.value, NoSpace) == (fd == "dev_full")
+        assert db.versions.last_sequence == seq0
+        assert mem.num_entries == n0
+        assert db.get(b"lost") is None
+        if torn:
+            assert wf.file_size() > size0
+            assert db._bg_error_severity == Severity.FATAL_ERROR
+            with pytest.raises(IOError_):
+                db.put(b"after", b"w")
+        else:
+            assert wf.file_size() == size0
+            db.put(b"after", b"w")  # publishes a sequence above the failed
+            assert db.get(b"lost") is None and db.get(b"after") == b"w"
+            assert stats.get_ticker_count(st.WRITE_GROUP_ONE_CALL) == 2
+        _crash_copy(d)
+    finally:
+        db.close()
+    with DB.open(d + "-crash", Options()) as r:
+        assert r.get(b"before") == b"v" and r.get(b"lost") is None
+        assert r.get(b"after") == (None if torn else b"w")
+
+
+@pytest.mark.parametrize("case", ["posix", "async_wal", "fault_env",
+                                  "kill_point", "sync", "merge_heavy"])
+def test_one_call_ticker(tmp_path, monkeypatch, case):
+    """write.group.one.call counts each group once on the plain POSIX WAL
+    and stays 0 where the one call would skip what the two calls do: the
+    async WAL writer's ring, a fault-injection env's file, an armed kill
+    point between WAL and insert, a sync group, a merge-heavy group (which
+    never reaches the plane)."""
+    import struct
+
+    from toplingdb_tpu.env import PosixEnv
+    from toplingdb_tpu.env.fault_injection import FaultInjectionEnv
+    from toplingdb_tpu.utils import kill_point
+    from toplingdb_tpu.utils.merge_operator import UInt64AddOperator
+
+    kw = {"async_wal": {"enable_async_wal": True},
+          "merge_heavy": {"merge_operator": UInt64AddOperator()}}.get(
+              case, {})
+    env = FaultInjectionEnv(PosixEnv()) if case == "fault_env" else None
+    if case == "kill_point":
+        # Armed at the marker, at odds it never fires at.
+        monkeypatch.setenv("TPULSM_KILL_ODDS", str(10**15))
+        monkeypatch.setenv("TPULSM_KILL_SEED", "7")
+        monkeypatch.setenv("TPULSM_KILL_PREFIX", "DBImpl::WriteImpl:AfterWAL")
+    kill_point.reset_for_tests()
+    stats = st.Statistics()
+    db = DB.open(str(tmp_path / "db"),
+                 Options(create_if_missing=True, statistics=stats, **kw),
+                 **({"env": env} if env is not None else {}))
+    wo = WriteOptions(sync=case == "sync")
+    try:
+        for i in range(30):
+            if case == "merge_heavy":
+                db.merge(b"ctr", struct.pack("<Q", 1), wo)
+            else:
+                db.put(b"k%04d" % i, b"v%d" % i, wo)
+        led = stats.get_ticker_count(st.WRITE_GROUP_LED)
+        assert led == 30
+        assert stats.get_ticker_count(st.WRITE_GROUP_ONE_CALL) == (
+            led if case == "posix" else 0)
+        assert stats.get_ticker_count(st.WRITE_GROUP_NATIVE_COMMITS) == (
+            0 if case == "merge_heavy" else led)
+        if case == "merge_heavy":
+            assert struct.unpack("<Q", db.get(b"ctr"))[0] == 30
+        else:
+            assert db.get(b"k0029") == b"v29"
+    finally:
+        db.close()
+        monkeypatch.undo()
+        kill_point.reset_for_tests()

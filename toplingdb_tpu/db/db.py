@@ -30,12 +30,14 @@ from toplingdb_tpu.db.snapshot import SnapshotList
 from toplingdb_tpu.db.table_cache import TableCache
 from toplingdb_tpu.db.version_edit import VersionEdit
 from toplingdb_tpu.db.version_set import VersionSet
+from toplingdb_tpu.utils import kill_point as _kill_point
 from toplingdb_tpu.utils.kill_point import test_kill_random
 from toplingdb_tpu.utils.listener import FlushJobInfo, notify
 from toplingdb_tpu.utils.sync_point import sync_point
 from toplingdb_tpu.utils.thread_status import thread_operation
 from toplingdb_tpu.db.write_batch import WriteBatch
 from toplingdb_tpu.env import Env, default_env
+from toplingdb_tpu.env.env import _PosixWritable
 from toplingdb_tpu.options import FlushOptions, Options, ReadOptions, WriteOptions
 from toplingdb_tpu.utils import statistics as _st
 from toplingdb_tpu.utils import telemetry as _tm
@@ -56,6 +58,71 @@ _MAX_WRITE_GROUP_BYTES = 1 << 20
 # marshalling (n_batches -> (c_char_p*n, c_int64*n)); building fresh array
 # TYPES per group dominates small-group dispatch cost.
 _GC_ARR_TYPES: dict = {}
+
+
+def _group_arrays(reps: list):
+    """The plane's (reps, lens) ctypes arrays of a group's wire images."""
+    n = len(reps)
+    at = _GC_ARR_TYPES.get(n)
+    if at is None:
+        import ctypes
+
+        if len(_GC_ARR_TYPES) > 512:
+            _GC_ARR_TYPES.clear()
+        at = _GC_ARR_TYPES[n] = (ctypes.c_char_p * n, ctypes.c_int64 * n)
+    return at[0](*reps), at[1](*[len(r) for r in reps])
+
+
+def _framed_cap(merged_len: int) -> int:
+    """Tight bound on a merged record's framed bytes: one 7/11B header a
+    fragment + <=10B of block-tail padding (a fragment spans at most
+    BLOCK-hdr bytes)."""
+    return merged_len + 11 * (merged_len // 32757 + 2) + 16
+
+# The kill point between a group's WAL write and its memtable insert; the
+# one-call commit has no such moment, so it stands aside while it is armed.
+_AFTER_WAL = "DBImpl::WriteImpl:AfterWAL"
+
+
+class _PlaneBuffers:
+    """The one-call commit's ctypes arguments, made once a DB (the commit
+    runs under _mutex to its end, so one set serves every group): the out
+    array, the arrays of a group of one, and the framed-record scratch,
+    grown to the largest group seen."""
+
+    __slots__ = ("out", "rep1", "len1", "buf", "cap")
+
+    def __init__(self):
+        import ctypes
+
+        self.out = (ctypes.c_int64 * 12)()
+        self.rep1 = (ctypes.c_char_p * 1)()
+        self.len1 = (ctypes.c_int64 * 1)()
+        self.buf = None
+        self.cap = 0
+
+    def scratch(self, cap: int):
+        if cap > self.cap:
+            import ctypes
+
+            self.cap = max(cap, 2 * self.cap, 64 << 10)
+            self.buf = (ctypes.c_ubyte * self.cap)()
+        return self.buf
+
+
+def _adopt_filled(group: list, prot_ptr, n: int) -> None:
+    """Hand the protection vectors a fill-mode plane call computed back to
+    the batches (the same zero-copy shape _native_protect produces), so the
+    memtable carry and any later verify see them."""
+    import numpy as np
+
+    vec = np.frombuffer(prot_ptr, dtype=np.uint64)
+    off = 0
+    for w in group:
+        c = w.batch._count
+        w.batch._prot = vec if n == 1 else vec[off:off + c]
+        w.batch._prot_n = c
+        off += c
 
 
 class _WriterClocks(threading.local):
@@ -385,6 +452,7 @@ class DB:
         self._write_plane_knob = (
             _os.environ.get("TPULSM_WRITE_PLANE", "1") != "0")
         self._write_plane = None
+        self._plane_bufs: _PlaneBuffers | None = None
         # threading ident of the thread that last led a write group (one
         # store a group); get_property("tpulsm.thread-times") resolves it
         # to the kernel tid it marks.
@@ -1506,7 +1574,7 @@ class DB:
         return fn
 
     def _native_group_commit(self, group: list[_Writer], first_seq: int,
-                             mems, frame: bool):
+                             mems, frame: bool, one_call: bool = False):
         """The fused native write plane for one group
         (tpulsm_wb_group_commit): the frame call re-sequences the merged
         header, frames the WAL record gather-style (no Python append_from
@@ -1519,6 +1587,13 @@ class DB:
         caller runs insert_fn() as the memtable phase (outside _mutex in
         the staged modes; the insert call skips re-validation because the
         frame call just proved these exact buffers).
+        one_call=True as well (the caller holds _mutex to the end of the
+        memtable phase): where the WAL is a plain POSIX file with nothing
+        in its Python buffer, no member asks for sync and no kill point is
+        armed, ONE call validates, frames, writes the record to the WAL's
+        fd and only then inserts; it returns (None, book_fn), book_fn the
+        memtable phase's bookkeeping. A failed write raises IOError_ with
+        nothing inserted.
         frame=False (WAL off for this group): validates + inserts in ONE
         call and returns (None, None).
         Returns None on fallback — the Python interiors stay the oracle:
@@ -1564,13 +1639,6 @@ class DB:
         import ctypes
 
         n = len(reps)
-        at = _GC_ARR_TYPES.get(n)
-        if at is None:
-            if len(_GC_ARR_TYPES) > 512:
-                _GC_ARR_TYPES.clear()
-            at = _GC_ARR_TYPES[n] = (ctypes.c_char_p * n, ctypes.c_int64 * n)
-        rep_arr = at[0](*reps)
-        len_arr = at[1](*[len(r) for r in reps])
         prot_ptr = None
         n_prots = 0
         pv = None
@@ -1596,6 +1664,18 @@ class DB:
                     prot_ptr = pv.ctypes.data_as(
                         ctypes.POINTER(ctypes.c_uint64))
                     n_prots = len(pv)
+        if frame and one_call:
+            wfile = self._wal._f
+            if (type(wfile) is _PosixWritable
+                    and not any(w.opts.sync for w in group)
+                    and "add_record" not in self._wal.__dict__
+                    and not _kill_point.armed(_AFTER_WAL)):
+                fd = wfile.raw_fd()
+                if fd is not None:
+                    return self._commit_in_one_call(
+                        fn, group, first_seq, mem0, gh, reps, total, pb,
+                        fill, prot_ptr, n_prots, wfile, fd)
+        rep_arr, len_arr = _group_arrays(reps)
         # out[0..4]: framed bytes / new block offset / mem byte delta /
         # delete count / merged record length. out[5..7]: native interior
         # timings in ns (validate / WAL frame / memtable insert) — the
@@ -1607,56 +1687,18 @@ class DB:
         out = (ctypes.c_int64 * 11)()
 
         def run(mode, block_off=0, log_no=-1, wal_ptr=None, cap=0):
-            rc = fn(gh[0], gh[1], rep_arr, len_arr, n, first_seq, prot_ptr,
-                    n_prots, pb, mode, block_off,
-                    log_no, wal_ptr, cap, out)
-            t_back = time.monotonic_ns()
-            t_exit = out[9]
-            if t_exit:
-                clk = self._wclk
-                clk.native_ns += t_exit - out[8]
-                clk.gil_ns += t_back - t_exit
-            if rc <= -5:
-                raise Corruption(
-                    f"write batch protection mismatch at record "
-                    f"{-(rc + 5)} during group commit"
-                )
-            return rc
-
-        def adopt_filled():
-            # Hand the fused-computed vectors back to the batches (the
-            # same zero-copy shape _native_protect produces), so the
-            # memtable carry and any later verify see them.
-            import numpy as np
-
-            vec = np.frombuffer(prot_ptr, dtype=np.uint64)
-            off = 0
-            for w in group:
-                c = w.batch._count
-                w.batch._prot = vec if n == 1 else vec[off:off + c]
-                w.batch._prot_n = c
-                off += c
+            return self._plane_returned(
+                fn(gh[0], gh[1], rep_arr, len_arr, n, first_seq, prot_ptr,
+                   n_prots, pb, mode, block_off, log_no, wal_ptr, cap, -1,
+                   out), out)
 
         def insert(validated=True):
             rc = run(2 | (4 if validated else 8 if fill else 0))
             if rc < 0:  # only reachable from the unvalidated single call
                 return None
-            parallel = int(out[10])
-            if out[7]:
-                _tm.span_event("native.memtable_insert", out[7] // 1000,
-                               records=total,
-                               threads=2 if parallel else 1)
             if fill and not validated:
-                adopt_filled()
-            seq = first_seq
-            meta = []
-            for w, rep in zip(group, reps):
-                meta.append((seq, rep, w.batch._prot if pb else None))
-                seq += w.batch._count
-            mem0.note_group_applied(meta, int(out[2]), int(out[3]), rc,
-                                    insert_ns=int(out[7]),
-                                    runs=[w.batch._count for w in group],
-                                    parallel=parallel)
+                _adopt_filled(group, prot_ptr, n)
+            self._note_plane_applied(mem0, group, reps, first_seq, rc, out)
             return rc
 
         if not frame:
@@ -1667,10 +1709,7 @@ class DB:
             # add_record): the hook must see every record — Python path.
             return None
         block_off, log_no = self._wal.framing_state()
-        merged_len = 12 + sum(len(r) - 12 for r in reps)
-        # Tight framed bound: one 7/11B header per fragment + <=10B of
-        # block-tail padding (a fragment spans at most BLOCK-hdr bytes).
-        cap = merged_len + 11 * (merged_len // 32757 + 2) + 16
+        cap = _framed_cap(12 + sum(len(r) - 12 for r in reps))
         wal_buf = bytearray(cap)
         wal_ptr = (ctypes.c_ubyte * cap).from_buffer(wal_buf)
         rc = run(1 | (8 if fill else 0), block_off, log_no, wal_ptr, cap)
@@ -1684,10 +1723,121 @@ class DB:
             _tm.span_event("native.wal_frame", out[6] // 1000,
                            bytes=int(out[0]))
         if fill:
-            adopt_filled()
+            _adopt_filled(group, prot_ptr, n)
         self._wal.append_preframed(memoryview(wal_buf)[:int(out[0])],
                                    int(out[1]))
         return (self._group_wal_durability(group, int(out[4])), insert)
+
+    def _commit_in_one_call(self, fn, group: list[_Writer], first_seq: int,
+                            mem0, gh, reps: list, total: int, pb: int,
+                            fill: bool, prot_ptr, n_prots: int, wfile,
+                            fd: int):
+        """_native_group_commit's one-call commit (mode 1|2|16): validate,
+        frame, write(2) to the WAL's fd, insert, in one GIL-released call
+        with the DB's own ctypes arguments; then adopt the framer's block
+        offset and the file's size, and tick what _group_wal_durability
+        ticks (there is nothing to flush: the bytes went past the file's
+        Python buffer, which was empty)."""
+        bufs = self._plane_bufs
+        if bufs is None:
+            bufs = self._plane_bufs = _PlaneBuffers()
+        n = len(reps)
+        if n == 1:
+            rep_arr, len_arr = bufs.rep1, bufs.len1
+            rep_arr[0] = reps[0]
+            merged_len = len_arr[0] = len(reps[0])
+        else:
+            rep_arr, len_arr = _group_arrays(reps)
+            merged_len = 12 + sum(len(r) - 12 for r in reps)
+        cap = _framed_cap(merged_len)
+        out = bufs.out
+        block_off, log_no = self._wal.framing_state()
+        rc = self._plane_returned(
+            fn(gh[0], gh[1], rep_arr, len_arr, n, first_seq, prot_ptr,
+               n_prots, pb, 19 | (8 if fill else 0), block_off, log_no,
+               bufs.scratch(cap), cap, fd, out), out)
+        if rc < 0:
+            if rc == -1:
+                self._one_call_wal_failed(wfile, int(out[11]), int(out[0]))
+            return None  # -2/-4, nothing written: the Python path decides
+        if fill:
+            _adopt_filled(group, prot_ptr, n)
+        framed = out[0]
+        self._wal.adopt_block_offset(out[1])
+        wfile.wrote_raw(framed, out[11])
+        rec_len = out[4]
+        if _st.perf_level:
+            _st.perf_context().wal_write_bytes += rec_len
+        stats = self.stats
+        if stats is not None:
+            stats.record_ticks(((_st.WAL_BYTES, rec_len),
+                                (_st.WRITE_WITH_WAL, len(group)),
+                                (_st.WRITE_GROUP_ONE_CALL, 1)))
+        if _tm.current_span() is not None:
+            if out[5]:
+                _tm.span_event("native.wal_validate", out[5] // 1000,
+                               records=rc)
+            if out[6]:
+                _tm.span_event("native.wal_frame", out[6] // 1000,
+                               bytes=framed, write_us=out[11] // 1000)
+        return (None, lambda: self._note_plane_applied(
+            mem0, group, reps, first_seq, rc, out))
+
+    def _plane_returned(self, rc: int, out) -> int:
+        """Book a plane call's native and GIL-wait ns (out[8..9]; 0 from
+        an older .so books nothing) and raise its protection mismatch."""
+        t_back = time.monotonic_ns()
+        t_exit = out[9]
+        if t_exit:
+            clk = self._wclk
+            clk.native_ns += t_exit - out[8]
+            clk.gil_ns += t_back - t_exit
+        if rc <= -5:
+            raise Corruption(
+                f"write batch protection mismatch at record "
+                f"{-(rc + 5)} during group commit"
+            )
+        return rc
+
+    def _one_call_wal_failed(self, wfile, err: int, torn: int) -> None:
+        """Raise the one-call commit's failed WAL write (nothing was
+        inserted, nothing publishes). Where the call could not cut a torn
+        record off the file again, no later record may follow it (a reader
+        would meet the torn one mid-log, not at the tail): a FATAL
+        background error stops writes until the DB is reopened."""
+        import errno as _errno
+        import os as _os
+
+        msg = f"WAL write: {_os.strerror(err)}"
+        e = NoSpace(msg) if err == _errno.ENOSPC else IOError_(msg)
+        e.__cause__ = OSError(err, _os.strerror(err))
+        if torn:
+            wfile.wrote_raw(torn, 0)
+            self._set_background_error(
+                Corruption(f"the WAL ends in a torn record: {msg}"),
+                reason="wal")
+        raise e
+
+    def _note_plane_applied(self, mem0, group: list[_Writer], reps: list,
+                            first_seq: int, total: int, out) -> None:
+        """The memtable phase's bookkeeping after the plane inserted a
+        group: out[7] the insert's ns, out[2]/out[3] its byte delta and
+        deletes, out[10] the records the pool's worker took."""
+        parallel = int(out[10])
+        if out[7]:
+            _tm.span_event("native.memtable_insert", out[7] // 1000,
+                           records=total,
+                           threads=2 if parallel else 1)
+        pb = self._protection
+        seq = first_seq
+        meta = []
+        for w, rep in zip(group, reps):
+            meta.append((seq, rep, w.batch._prot if pb else None))
+            seq += w.batch._count
+        mem0.note_group_applied(meta, int(out[2]), int(out[3]), total,
+                                insert_ns=int(out[7]),
+                                runs=[w.batch._count for w in group],
+                                parallel=parallel)
 
     def _tick_write_group(self, group: list[_Writer], native: bool) -> None:
         """WRITE_GROUP_* observability for one committed group."""
@@ -1819,14 +1969,16 @@ class DB:
             # Fused native plane: frame+append the merged WAL record first
             # (mode 1 — durability ordering matches the Python path: a WAL
             # failure inserts NOTHING), then apply the whole group to the
-            # memtable rep in one GIL-released call (mode 2).
+            # memtable rep in one GIL-released call (mode 2). On a plain
+            # POSIX WAL with no sync asked, both halves and the WAL's
+            # write(2) between them are ONE call (mode 1|2|16).
             wal_on = (self.options.wal_enabled
                       and not group[0].opts.disable_wal)
             wal_wait = None
             with _tm.span("write.wal_frame", group=len(group),
                           wal=wal_on):
                 plane = self._native_group_commit(group, first_seq, mems,
-                                                  frame=wal_on)
+                                                  frame=wal_on, one_call=True)
                 if plane is None and wal_on:
                     wal_wait = self._append_group_wal(group, first_seq)
             _mt0 = time.perf_counter() if _st.perf_level >= 2 else 0.0

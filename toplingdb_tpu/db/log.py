@@ -89,6 +89,12 @@ class LogWriter:
         self._f.append(data)
         self._block_offset = new_block_offset
 
+    def adopt_block_offset(self, new_block_offset: int) -> None:
+        """A record framed from framing_state() went to the file past this
+        writer (the native plane wrote it to the fd): frame the next one
+        after it."""
+        self._block_offset = new_block_offset
+
     def _emit(self, t: int, frag: bytes) -> None:
         if self._recycled:
             t = _RECYCLE_OF[t]

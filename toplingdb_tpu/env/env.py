@@ -412,6 +412,9 @@ class _PosixWritable(WritableFile):
         if reuse:
             self._f.seek(0)
         self._size = 0
+        # _size at the last flush: equal to _size when the Python buffer
+        # holds nothing (raw_fd()).
+        self._flushed = 0
 
     def append(self, data: bytes) -> None:
         # IOStatsContext twin of PerfContext (reference iostats_context.h):
@@ -432,11 +435,13 @@ class _PosixWritable(WritableFile):
 
     def flush(self) -> None:
         self._f.flush()
+        self._flushed = self._size
 
     def sync(self) -> None:
         lvl = _stats_mod.perf_level
         t0 = time.perf_counter() if lvl >= 2 else 0.0
         self._f.flush()
+        self._flushed = self._size
         os.fsync(self._f.fileno())
         if lvl >= 2:
             _stats_mod.iostats_context().fsync_nanos += int(
@@ -448,6 +453,24 @@ class _PosixWritable(WritableFile):
 
     def file_size(self) -> int:
         return self._size
+
+    def raw_fd(self) -> int | None:
+        """The file's descriptor while the Python buffer holds nothing, so
+        that bytes written to it land in file order (the native write
+        plane, db.py _native_group_commit); else None."""
+        return self._f.fileno() if self._size == self._flushed else None
+
+    def wrote_raw(self, n: int, write_ns: int) -> None:
+        """Account n bytes written to raw_fd() in write_ns: the file's
+        size and the IOStatsContext feed append() keeps."""
+        self._size += n
+        self._flushed = self._size
+        lvl = _stats_mod.perf_level
+        if lvl:
+            ctx = _stats_mod.iostats_context()
+            ctx.bytes_written += n
+            if lvl >= 2:
+                ctx.write_nanos += write_ns
 
 
 def _pread_full(fd: int, n: int, offset: int) -> bytes:

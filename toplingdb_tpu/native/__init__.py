@@ -43,7 +43,7 @@ _tried = False
 # Must match TPULSM_ABI_VERSION in tpulsm_native.cc. The loader refuses a
 # .so reporting a different version: mtime staleness alone cannot catch a
 # restored backup or a clock-skewed rebuild.
-_ABI_VERSION = 1
+_ABI_VERSION = 2
 
 
 def _compile(src: str, so: str, extra_flags: list[str]) -> bool:
@@ -269,8 +269,9 @@ def lib() -> ctypes.CDLL | None:
         try:
             # Fused group-commit write plane: validate + protect-verify a
             # whole write group, frame the merged WAL record gather-style,
-            # and apply every record to the memtable rep — one GIL-free
-            # call per group (db.py _native_group_commit).
+            # write it to the WAL's fd and apply every record to the
+            # memtable rep — one GIL-free call per group
+            # (db.py _native_group_commit).
             u64p = ctypes.POINTER(ctypes.c_uint64)
             l.tpulsm_wb_group_commit.restype = ctypes.c_int64
             l.tpulsm_wb_group_commit.argtypes = [
@@ -280,7 +281,8 @@ def lib() -> ctypes.CDLL | None:
                 u64p, ctypes.c_int64, ctypes.c_int32,       # prots, n, pb
                 ctypes.c_int32,                             # mode
                 ctypes.c_int64, ctypes.c_int64,             # blk_off, log_no
-                u8p, ctypes.c_int64, i64p,                  # wal out/cap, out
+                u8p, ctypes.c_int64,                        # wal out, cap
+                ctypes.c_int32, i64p,                       # wal fd, out
             ]
         except AttributeError:
             pass

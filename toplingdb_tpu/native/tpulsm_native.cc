@@ -13,6 +13,7 @@
 #include <chrono>
 #include <cstdint>
 #include <cstddef>
+#include <cerrno>
 #include <cstring>
 #include <functional>
 #include <list>
@@ -56,7 +57,7 @@ extern "C" {
 // differs from its own expectation, so a stale artifact (mtime lies —
 // e.g. a restored backup or clock skew) can never drift silently.
 // Bump whenever any exported signature changes shape.
-#define TPULSM_ABI_VERSION 1
+#define TPULSM_ABI_VERSION 2
 
 int32_t tpulsm_abi_version(void) { return TPULSM_ABI_VERSION; }
 
@@ -5292,19 +5293,34 @@ static int64_t gc_frame_merged(GcCursor& cur, int64_t total_len,
 //   n_prots — the validation pass writes each counted record's truncated
 //   checksum instead of comparing (fusing tpulsm_wb_protect into the WAL
 //   frame walk: the protected write path hashes each record ONCE).
+//   bit 4 (16) = WRITE the framed record to wal_fd (with bits 0 and 1: the
+//   whole group commit in one call). The order is validate, frame, write(2)
+//   of the framed bytes (looping on short writes and EINTR), and only after
+//   the complete write the memtable insert: an acknowledged put is in the
+//   WAL before it is in the memtable. A failed write inserts nothing and
+//   returns -1 with the errno in out[11]; a record the write left torn is
+//   cut off the file again (ftruncate back to where the call began), and
+//   out[0] says how many of its bytes could not be (0 when the file is as
+//   before the call).
 // block_offset/log_number: the LogWriter's framing state (log_number >= 0
 //   selects the recyclable format stamped with that number; -1 = classic).
+// wal_fd: the WAL's file descriptor, read only under mode bit 4; its file
+//   offset is where the record goes (the caller's file holds no buffered
+//   bytes of its own).
 // out[0]=framed bytes, out[1]=new block offset, out[2]=memtable byte delta,
 // out[3]=point-delete count, out[4]=merged (unframed) record length,
-// out[5..7]=interior phase timings in ns (validate / WAL frame / memtable
-// insert) for the telemetry plane, out[8..9]=this call's entry and exit
-// stamps on steady_clock (Linux CLOCK_MONOTONIC, the clock of Python's
-// time.monotonic_ns(); out[9] is written on every return), out[10]=the
-// records the apply pool's workers inserted (0 where the calling thread
-// applied the group alone) — the caller must size out >= 11.
-// Returns total counted records, or -2 (unsupported record: Python path),
-// -3 (wal_cap too small), -4 (corrupt image), -5 - i (protection mismatch
-// at group record index i).
+// out[5..7]=interior phase timings in ns (validate / WAL frame, and under
+// bit 4 its write / memtable insert) for the telemetry plane,
+// out[8..9]=this call's entry and exit stamps on steady_clock (Linux
+// CLOCK_MONOTONIC, the clock of Python's time.monotonic_ns(); out[9] is
+// written on every return), out[10]=the records the apply pool's workers
+// inserted (0 where the calling thread applied the group alone) — the
+// caller must size out >= 11; under bit 4, >= 12: out[11]=the ns of the
+// WAL write (0 <= out[11] <= out[6]), or its errno on a return of -1.
+// Returns total counted records, or -1 (WAL write failed, bit 4 only:
+// nothing inserted), -2 (unsupported record: Python path), -3 (wal_cap too
+// small), -4 (corrupt image), -5 - i (protection mismatch at group record
+// index i). Every code below 0 leaves the memtable untouched.
 int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
                                const uint8_t* const* reps,
                                const int64_t* lens,
@@ -5312,7 +5328,8 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
                                uint64_t* prots, int64_t n_prots,
                                int32_t pb, int32_t mode, int64_t block_offset,
                                int64_t log_number, uint8_t* wal_out,
-                               int64_t wal_cap, int64_t* out) {
+                               int64_t wal_cap, int32_t wal_fd,
+                               int64_t* out) {
   const uint64_t kKey = 0x9E3779B97F4A7C15ull, kVal = 0xC2B2AE3D27D4EB4Full,
                  kType = 0x165667B19E3779F9ull, kCf = 0x27D4EB2F165667C5ull;
   const uint64_t mask = prot_trunc_mask(pb);
@@ -5395,11 +5412,11 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
   int64_t merged_len = 12;
   for (int64_t b = 0; b < n_batches; b++) merged_len += lens[b] - 12;
   int64_t wal_len = 0, new_bo = block_offset;
-  if ((mode & 3) == 1 && mem && mem_kind == 0 && total >= kPairApply &&
+  if ((mode & 1) && mem && mem_kind == 0 && total >= kPairApply &&
       apply_cpus() >= 2) {
     // The frame call of a group whose insert will be shared: wake the
     // pool's worker now, so that its wake-up overlaps the framing, the
-    // caller's WAL append and the insert call's parse and sort.
+    // WAL write and the insert's parse and sort.
     apply_pool().prewake();
   }
   if (mode & 1) {
@@ -5417,6 +5434,31 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
     wal_len = gc_frame_merged(cur, merged_len, block_offset, log_number,
                               wal_out, wal_cap, &new_bo);
     if (wal_len < 0) return wal_len;
+  }
+  int64_t write_ns = 0;
+  if ((mode & 17) == 17) {
+    const int64_t t_write_ns = gc_now_ns();
+    int64_t done = 0;
+    while (done < wal_len) {
+      ssize_t w = ::write(wal_fd, wal_out + done, (size_t)(wal_len - done));
+      if (w > 0) {
+        done += w;
+        continue;
+      }
+      if (w < 0 && errno == EINTR) continue;
+      const int err = w < 0 ? errno : EIO;
+      if (done > 0) {
+        // A torn record: cut it off, so that the log ends where it did.
+        off_t at = ::lseek(wal_fd, 0, SEEK_CUR);
+        if (at >= done && ::ftruncate(wal_fd, at - done) == 0 &&
+            ::lseek(wal_fd, at - done, SEEK_SET) == at - done)
+          done = 0;
+      }
+      out[0] = done;
+      out[11] = err;
+      return -1;
+    }
+    write_ns = gc_now_ns() - t_write_ns;
   }
   const int64_t t_framed_ns = gc_now_ns();
   int64_t delta = 0, deletes = 0;
@@ -5610,6 +5652,7 @@ int64_t tpulsm_wb_group_commit(void* mem, int32_t mem_kind,
   out[5] = t_validated_ns - t_entry_ns;
   out[6] = t_framed_ns - t_validated_ns;
   out[7] = gc_now_ns() - t_framed_ns;
+  if (mode & 16) out[11] = write_ns;
   return total;
 }
 

@@ -151,7 +151,7 @@ def group_commit_insert(rep, data: bytes, first_seq: int, out=None):
         out = (ctypes.c_int64 * 11)()
     rc = fn(rep._h, rep._nget_mem_kind, (ctypes.c_char_p * 1)(data),
             (ctypes.c_int64 * 1)(len(data)), 1, first_seq, None, 0, 0, 2, 0,
-            -1, None, 0, out)
+            -1, None, 0, -1, out)
     return rc if rc >= 0 else None
 
 

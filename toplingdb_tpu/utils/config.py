@@ -969,6 +969,7 @@ class SidePluginRepo:
                               _st.WRITE_GROUP_LED,
                               _st.WRITE_GROUP_FOLLOWERS,
                               _st.WRITE_GROUP_NATIVE_COMMITS,
+                              _st.WRITE_GROUP_ONE_CALL,
                               _st.WRITE_GROUP_FALLBACKS,
                               _st.WRITE_GROUP_FSYNCS_COALESCED)
                 }

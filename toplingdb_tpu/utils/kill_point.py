@@ -55,6 +55,15 @@ def test_kill_random(name: str) -> None:
         os._exit(KILLED_EXIT_CODE)
 
 
+def armed(name: str) -> bool:
+    """May the marker `name` fire in this process? (A path that would skip
+    the marker takes the one that passes it while this holds.)"""
+    st = _state if _state is not None else _load()
+    odds, _, prefixes = st
+    return bool(odds) and (not prefixes
+                           or any(name.startswith(p) for p in prefixes))
+
+
 def reset_for_tests() -> None:
     """Re-read the environment (tests flip env vars mid-process)."""
     global _state

@@ -72,6 +72,10 @@ WAL_BYTES = "wal.bytes"
 WRITE_GROUP_LED = "write.group.led"
 WRITE_GROUP_FOLLOWERS = "write.group.followers"
 WRITE_GROUP_NATIVE_COMMITS = "write.group.native.commits"
+# Of the native commits, the groups framed, written to the WAL's fd and
+# inserted in ONE native call (db.py _native_group_commit, plain POSIX WAL,
+# no sync): over write.group.led, the share of groups that take that path.
+WRITE_GROUP_ONE_CALL = "write.group.one.call"
 WRITE_GROUP_FALLBACKS = "write.group.fallbacks"
 WRITE_GROUP_FSYNCS_COALESCED = "write.group.fsyncs.coalesced"
 # Records that WriteBatch.put kept as references and encoded in one native
